@@ -9,7 +9,9 @@ from __future__ import annotations
 import os as _os
 
 # Multi-controller bootstrap MUST precede any backend use (jax.devices,
-# device_put, ...), and importing the framework touches the backend —
+# device_put, ...). Importing the framework does not touch the backend
+# (tests/test_chip_smoke.py pins that: the launcher parent lives in this
+# package and must not take the chip), but the first op after it does —
 # so a launched worker rendezvouses here, at import. The PJRT
 # coordination service replaces the reference's TCPStore (SURVEY.md
 # §2.3 TCPStore row — unverified). Gated on the launcher-private marker:

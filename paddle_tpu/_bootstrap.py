@@ -42,9 +42,8 @@ def rendezvous_from_env():
         if "must be called before" in str(e):
             raise RuntimeError(
                 "multi-process rendezvous requires the PADDLE_* env to be "
-                "set BEFORE `import paddle_tpu` (importing touches the "
-                "XLA backend). Use paddle.distributed.launch, or export "
-                "the env first."
+                "set BEFORE the first XLA backend use. Use "
+                "paddle.distributed.launch, or export the env first."
             ) from e
         raise
     return True

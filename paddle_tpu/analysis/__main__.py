@@ -187,10 +187,9 @@ _REEXEC_GUARD = "_PADDLE_TPU_ANALYSIS_REEXEC"
 
 
 def _ensure_mesh_devices(argv, need=8):
-    """The TP x ZeRO recipes need an 8-device mesh. `import paddle_tpu`
-    already initialized the jax backend by the time this CLI runs, so
-    on a too-small host platform the only way to grow it is to re-exec
-    ourselves with the conftest trick
+    """The TP x ZeRO recipes need an 8-device mesh. Counting the devices
+    initializes the jax backend, so on a too-small host platform the
+    only way to grow it is to re-exec ourselves with the conftest trick
     (--xla_force_host_platform_device_count) set in the environment.
     Inert on machines that already expose enough devices."""
     import jax

@@ -444,7 +444,7 @@ def _chip_specs():
         "v5p": 2765e9,
         "v6e": 1638e9,
     }
-    alias = {"v5 lite": "v5e", "v5": "v5p", "v6 lite": "v6e"}
+    alias = {"v5 lite": "v5e", "v6 lite": "v6e"}
     specs = {}
     for kind, peak in _PEAKS.items():
         key = alias.get(kind, kind)
@@ -517,17 +517,10 @@ def quantum_flops_per_token(engine):
     """Jaxpr-counted decode-quantum FLOPs per emitted token (at full
     slot occupancy) for a ServingEngine — the preferred MFU numerator,
     counting what the ``2N`` weight-matmul floor deliberately excludes
-    (attention over live context, lm-head at full vocab). Returns 0.0
-    when the quantum cannot be traced (caller falls back to the
-    floor)."""
-    try:
-        quantum = engine._quantum
-        args = engine._quantum_args()
-        cfg = getattr(engine, "config", engine)
-        tokens = max(int(getattr(cfg, "num_slots", 1))
-                     * int(getattr(cfg, "decode_quantum", 1)), 1)
-        closed = jax.make_jaxpr(quantum)(*args)
-        stats = jaxpr_cost(closed, unroll_loops=True)
-        return stats.flops / tokens
-    except Exception:
-        return 0.0
+    (attention over live context, lm-head at full vocab). A quantum
+    that cannot be traced raises: the caller asked for this number."""
+    cfg = getattr(engine, "config", engine)
+    tokens = max(int(getattr(cfg, "num_slots", 1))
+                 * int(getattr(cfg, "decode_quantum", 1)), 1)
+    closed = jax.make_jaxpr(engine._quantum)(*engine._quantum_args())
+    return jaxpr_cost(closed, unroll_loops=True).flops / tokens

@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Literal, Var
 
 __all__ = ["DtypeReport", "F32ComputeEvent", "audit_dtype_promotion"]
 
@@ -91,13 +92,13 @@ def _walk(jaxpr, tainted, events, path, seen_upcasts,
         i8_tainted = set()
     for eqn in jaxpr.eqns:
         in_taint = [
-            (isinstance(v, jax.core.Var) and v in tainted)
+            (isinstance(v, Var) and v in tainted)
             or _is_source_lit(v)
             for v in eqn.invars
         ]
         any_taint = any(in_taint)
         in_i8 = [
-            isinstance(v, jax.core.Var) and v in i8_tainted
+            isinstance(v, Var) and v in i8_tainted
             for v in eqn.invars
         ]
         any_i8 = any(in_i8)
@@ -173,7 +174,7 @@ def _aval(v):
 
 
 def _is_source_lit(v):
-    if not isinstance(v, jax.core.Literal):
+    if not isinstance(v, Literal):
         return False
     a = _aval(v)
     return a is not None and getattr(a, "dtype", None) in _SOURCE_DTYPES

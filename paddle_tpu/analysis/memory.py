@@ -23,14 +23,14 @@ capped by the ``max_temp_bytes`` / ``max_peak_live_bytes`` /
 """
 from __future__ import annotations
 
-from jax.core import Var
+from jax.extend.core import Var
 
 __all__ = [
     "LivenessStats", "MemoryReport", "analyze_memory",
     "compiled_memory_stats", "jaxpr_liveness",
 ]
 
-_INLINE_CALL_PRIMS = ("pjit", "closed_call", "core_call", "xla_call")
+_INLINE_CALL_PRIMS = ("jit", "closed_call", "core_call")
 
 
 def _aval_bytes(v):
@@ -82,7 +82,7 @@ class LivenessStats:
 
 
 def _inline_single_call(jaxpr, donated_vars):
-    """Descend through a jaxpr that is one big pjit/call eqn (the shape
+    """Descend through a jaxpr that is one big jit/call eqn (the shape
     ``jax.make_jaxpr(jax.jit(f))`` produces) so the walk sees the real
     body; translates the donated-invar set positionally."""
     while len(jaxpr.eqns) == 1 \

@@ -237,10 +237,10 @@ def _build_serving_decode_step():
         max_f32_matmuls=0,        # bf16 pool/params stay bf16
         max_host_callbacks=0,     # host scheduler only at boundaries
         require_donated=True,     # the 2L KV pool leaves
-        # audited 207 KB temp / 891 KB trace peak: the quantum works
+        # audited 330 KB temp / 891 KB trace peak: the quantum works
         # in-place over the donated pool — a lost donation or an
         # unrolled scan materializing per-token buffers blows this
-        max_temp_bytes=300_000,
+        max_temp_bytes=430_000,
         max_peak_live_bytes=1_300_000,
         # cost model: 2.49M flops / 18.8 MB accessed per quantum over
         # 2 slots x 4 decode steps = 8 tokens (311k flops / 2.36 MB
@@ -287,9 +287,9 @@ def _build_speculative_verify_step():
         max_f32_matmuls=0,        # bf16 pools/params stay bf16
         max_host_callbacks=0,     # host scheduler only at boundaries
         require_donated=True,     # draft AND target KV pool leaves
-        # audited 229 KB temp / 1.38 MB trace peak (draft + target
+        # audited 336 KB temp / 1.38 MB trace peak (draft + target
         # pools both in flight; donation saves 402 KB of that)
-        max_temp_bytes=330_000,
+        max_temp_bytes=440_000,
         max_peak_live_bytes=2_000_000,
         # cost model: 2.87M flops / 12.8 MB per round over 2 slots x
         # (gamma+1)=3 tokens = 6 tokens at full acceptance (478k
@@ -348,11 +348,11 @@ def _build_serving_frontdoor_step():
         max_f32_matmuls=0,        # bf16 pool/params stay bf16
         max_host_callbacks=0,     # ALL front-door policy is host-side
         require_donated=True,     # the 2L KV pool leaves
-        # audited 208 KB temp / 891 KB trace peak — the sampling filter
+        # audited 333 KB temp / 891 KB trace peak — the sampling filter
         # (top-k cut + per-slot temperature scale) fuses into the
         # greedy quantum's existing (S, V) temporaries; caps leave
         # ~30% headroom like the other serving recipes
-        max_temp_bytes=280_000,
+        max_temp_bytes=430_000,
         max_peak_live_bytes=1_300_000,
         # cost model: the sampling filter adds ~14k flops to the plain
         # quantum (2.50M / 19.0 MB over 8 tokens audited) — same caps
@@ -410,7 +410,7 @@ def _build_serving_prefix_step():
         require_donated=True,     # the 2L KV pool leaves
         # same caps as serving_decode_step: the prefix cache must not
         # change the compiled quantum at all
-        max_temp_bytes=300_000,
+        max_temp_bytes=430_000,
         max_peak_live_bytes=1_300_000,
         # cost model: identical numbers to serving_decode_step (2.49M
         # flops / 18.8 MB over 8 tokens) — the cache must be free in
@@ -531,11 +531,11 @@ def _build_serving_tp_step():
         # replicates the pool per chip and doubles its HBM cost
         min_sharded_params=4,
         max_replicated_param_bytes=0,
-        # audited 138 KB compiled temp (per-chip halves of the tp1
+        # audited 199 KB compiled temp (per-chip shares of the tp1
         # quantum's buffers) / 891 KB jaxpr trace peak — the liveness
         # walk is LOGICAL (pre-partitioning), so the peak cap matches
         # serving_decode_step's; same ~30% headroom on both
-        max_temp_bytes=180_000,
+        max_temp_bytes=260_000,
         max_peak_live_bytes=1_300_000,
         # cost model: 2.77M flops / 21.1 MB LOGICAL (pre-partitioning
         # jaxpr) over 8 tokens — the tp collectives add ~11% flops of

@@ -5,15 +5,20 @@ arguments actually CARRY their shardings — a refactor that drops a
 ``NamedSharding`` (or a state-init path that stops threading the axis)
 silently replicates the leaf on every device, multiplying its HBM cost
 by the mesh size, and tier-1 numerics stay green. The StableHLO entry
-signature records each argument's layout as an ``mhlo.sharding`` (or
-``sdy.sharding``) attribute::
+signature records each argument's layout as a Shardy ``sdy.sharding``
+attribute over a module-level ``sdy.mesh`` (the installed JAX's
+partitioner), or as a GSPMD ``mhlo.sharding`` string::
 
+    sdy.mesh @mesh = <["mp"=2, "sharding"=4]>
+    %arg3: tensor<64x128xf32>
+        {sdy.sharding = #sdy.sharding<@mesh, [{"mp", "sharding"}, {}]>}
     %arg3: tensor<64x128xf32>
         {mhlo.sharding = "{devices=[2,1,4]<=[8] last_tile_dim_replicate}"}
 
 so the audit parses the attrs per argument and classifies each as
-sharded or fully replicated (no attr, ``{replicated}``, ``{maximal
-...}``, or a tile assignment whose data dims are all 1). Declarative
+sharded or fully replicated (no attr, no dim split over an axis larger
+than 1, ``{replicated}``, ``{maximal ...}``, or a tile assignment
+whose data dims are all 1). Declarative
 expectations ride on the Budget:
 
 - ``max_replicated_param_bytes``: no fully-replicated donatable leaf
@@ -30,9 +35,21 @@ from .donation import _ARG_HEAD_RE, _scan_attrs, _tensor_bytes
 
 __all__ = ["ArgSharding", "ShardingReport", "audit_sharding"]
 
-_SHARDING_ATTR_RE = re.compile(
-    r'(?:mhlo|sdy)\.sharding\s*=\s*"([^"]*)"')
+_SHARDING_ATTR_RE = re.compile(r'mhlo\.sharding\s*=\s*"([^"]*)"')
 _DEVICES_RE = re.compile(r"devices=\[([\d,]+)\]")
+_SDY_ATTR_RE = re.compile(
+    r"sdy\.sharding\s*=\s*(#sdy\.sharding<@(\w+),\s*\[([^\]]*)\][^>]*>)")
+_SDY_MESH_RE = re.compile(r"sdy\.mesh\s+@(\w+)\s*=\s*<\[([^\]]*)\]")
+_SDY_AXIS_SIZE_RE = re.compile(r'"([^"]+)"\s*=\s*(\d+)')
+_SDY_AXIS_RE = re.compile(r'"([^"]+)"')
+
+
+def _sdy_replicated(dims, axis_sizes):
+    """A Shardy dim list (``{"mp", "sharding"}, {}``) is replicated
+    when no dim names an axis larger than 1 (an axis the mesh decl
+    does not list is taken as splitting)."""
+    return all(axis_sizes.get(a, 2) <= 1
+               for a in _SDY_AXIS_RE.findall(dims))
 
 
 def _classify(attr):
@@ -149,15 +166,24 @@ def audit_sharding(stablehlo_text, n_donatable=None):
     """Parse @main's per-argument sharding attributes into a
     :class:`ShardingReport` (same signature walk as the donation
     audit, so arg indices line up between the two reports)."""
+    meshes = {
+        name: {a: int(n) for a, n in _SDY_AXIS_SIZE_RE.findall(axes)}
+        for name, axes in _SDY_MESH_RE.findall(stablehlo_text)}
     seen = {}
     for m in _ARG_HEAD_RE.finditer(stablehlo_text):
         idx = int(m.group(1))
         if idx in seen:  # inner funcs reuse %argN; keep the entry's
             continue
         attrs = _scan_attrs(stablehlo_text, m.end())
-        sm = _SHARDING_ATTR_RE.search(attrs)
-        spec = sm.group(1) if sm else ""
-        replicated, unknown = _classify(spec)
+        sdy = _SDY_ATTR_RE.search(attrs)
+        if sdy:
+            spec = sdy.group(1)
+            replicated, unknown = _sdy_replicated(
+                sdy.group(3), meshes.get(sdy.group(2), {})), False
+        else:
+            sm = _SHARDING_ATTR_RE.search(attrs)
+            spec = sm.group(1) if sm else ""
+            replicated, unknown = _classify(spec)
         seen[idx] = ArgSharding(
             idx, _tensor_bytes(m.group(2)), spec, replicated,
             unknown=unknown)

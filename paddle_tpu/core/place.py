@@ -91,11 +91,9 @@ _current_place: Place | None = None
 
 
 def _accelerator_devices():
-    """Non-CPU jax devices, if any."""
-    try:
-        devs = jax.devices()
-    except RuntimeError:
-        return []
+    """Non-CPU jax devices, else the CPU ones. A backend that fails to
+    initialise raises here: it must not quietly become the CPU."""
+    devs = jax.devices()
     return [d for d in devs if d.platform != "cpu"] or devs
 
 
@@ -128,8 +126,7 @@ def get_device() -> str:
 
 
 def _default_place() -> Place:
-    devs = _accelerator_devices()
-    if devs and devs[0].platform != "cpu":
+    if _accelerator_devices()[0].platform != "cpu":
         return TPUPlace(0)
     return CPUPlace()
 
@@ -139,23 +136,13 @@ def current_place() -> Place:
 
 
 def device_for_place(place: Place | None = None):
-    """Resolve a Place to a concrete jax Device (or None = jax default)."""
+    """Resolve a Place to a concrete jax Device."""
     place = place or current_place()
-    try:
-        devs = jax.devices()
-    except RuntimeError:
-        return None
     if place.is_cpu_place():
-        cpus = [d for d in devs if d.platform == "cpu"]
-        if not cpus:
-            try:
-                cpus = jax.devices("cpu")
-            except RuntimeError:
-                return None
-        return cpus[0] if cpus else None
-    accel = [d for d in devs if d.platform != "cpu"] or devs
-    idx = min(place.device_id, len(accel) - 1)
-    return accel[idx]
+        # the host backend exists beside any accelerator
+        return jax.devices("cpu")[0]
+    accel = _accelerator_devices()
+    return accel[min(place.device_id, len(accel) - 1)]
 
 
 def is_compiled_with_cuda() -> bool:

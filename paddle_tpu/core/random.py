@@ -30,13 +30,14 @@ class Generator:
     def __init__(self, seed_: int | None = None):
         if seed_ is None:
             seed_ = time.time_ns() % (2**31)
-        self._seed = int(seed_)
-        self._key = jax.random.PRNGKey(self._seed)
-        self._counter = 0
+        self.manual_seed(seed_)
 
     def manual_seed(self, seed_: int):
         self._seed = int(seed_)
-        self._key = jax.random.PRNGKey(self._seed)
+        # made on the first draw: building a key initialises the XLA
+        # backend, and `import paddle_tpu` (hence the launcher parent)
+        # must not take the chip from the process that needs it
+        self._key = None
         self._counter = 0
         return self
 
@@ -44,6 +45,8 @@ class Generator:
         return self._seed
 
     def next_key(self):
+        if self._key is None:
+            self._key = jax.random.PRNGKey(self._seed)
         k = jax.random.fold_in(self._key, self._counter)
         self._counter += 1
         return k

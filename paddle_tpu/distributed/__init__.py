@@ -230,7 +230,6 @@ def _collective_fn(op_name, shape, dtype_str, n, backend_token, ranks=None):
     every call."""
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec
-    from jax.experimental.shard_map import shard_map
 
     mesh = _world_mesh_one_dev_per_proc(ranks)
 
@@ -256,7 +255,7 @@ def _collective_fn(op_name, shape, dtype_str, n, backend_token, ranks=None):
         "prod": prod,
         "gather": gather,
     }[op_name]
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda x: red(x)[0] if op_name != "gather" else red(x),
         mesh=mesh, in_specs=PartitionSpec("world"),
         out_specs=PartitionSpec(),

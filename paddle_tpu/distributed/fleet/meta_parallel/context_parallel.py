@@ -33,19 +33,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """Version shim: jax>=0.6 top-level shard_map (check_vma), older
-    jax.experimental.shard_map (check_rep)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _sm  # pragma: no cover
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)  # pragma: no cover
-
 from ....parallel import mesh as mesh_state
 from ....tensor._helpers import apply, ensure_tensor
 
@@ -213,13 +200,14 @@ def _sep_call(local_fn, query, key, value, is_causal, scale, axis):
 
     q_spec = P(batch_ax, axis, head_ax, None)
     kv_spec = P(batch_ax, axis, head_ax, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(
             local_fn, axis=axis, n=n, causal=is_causal, scale=scale
         ),
         mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec),
         out_specs=q_spec,
+        check_vma=False,
     )
     return apply(fn, query, key, value, op_name="sep_attention")
 

@@ -180,9 +180,6 @@ class MoELayer(Layer):
         k = cfg.gate.top_k
         cap = _capacity(t, e, cfg.gate.capacity_factor, k)
         slice_rows = min(epp * cap, t * k)
-        from .....distributed.fleet.meta_parallel.context_parallel import (
-            shard_map,
-        )
         from jax.sharding import PartitionSpec as P
 
         def body(xt_loc, gw_, w1_, b1_, w2_, b2_):
@@ -234,10 +231,11 @@ class MoELayer(Layer):
             return y_loc, jax.lax.pmean(aux, ax)
 
         xt = xv.reshape(t, cfg.d_model)
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(ax), P(), P(ax), P(ax), P(ax), P(ax)),
             out_specs=(P(ax), P()),
+            check_vma=False,
         )(xt, gw, w1, b1, w2, b2)
         return y.reshape(*lead, cfg.d_model), aux
 

@@ -223,9 +223,9 @@ class JittedTrainStep:
     def donate(self):
         return self._donate
 
-    def run_steps(self, inputs_stacked, labels_stacked):
-        """Run K train steps in ONE dispatch. inputs/labels carry a leading
-        step dim (K, batch, ...); returns the (K,) per-step losses."""
+    def _steps_args(self, inputs_stacked, labels_stacked):
+        """The K-step program's arguments for the current state, exactly
+        as run_steps feeds them."""
         if not isinstance(inputs_stacked, (list, tuple)):
             inputs_stacked = [inputs_stacked]
         if not isinstance(labels_stacked, (list, tuple)):
@@ -234,15 +234,25 @@ class JittedTrainStep:
         lb_vals = [self._place_input(t, stacked=True) for t in labels_stacked]
         from ..core.random import next_key
 
-        k = in_vals[0].shape[0]
         lr = jnp.asarray(self._optimizer.get_lr(), jnp.float32)
         step0 = jnp.asarray(self._step_no + 1, jnp.int32)
-        losses, self._p_vals, self._s_vals, self._b_vals = self._jitted_multi(
-            self._p_vals, self._s_vals, self._b_vals, next_key(), lr,
-            step0, in_vals, lb_vals,
-        )
-        self._step_no += k
+        return (self._p_vals, self._s_vals, self._b_vals, next_key(), lr,
+                step0, in_vals, lb_vals)
+
+    def run_steps(self, inputs_stacked, labels_stacked):
+        """Run K train steps in ONE dispatch. inputs/labels carry a leading
+        step dim (K, batch, ...); returns the (K,) per-step losses."""
+        args = self._steps_args(inputs_stacked, labels_stacked)
+        losses, self._p_vals, self._s_vals, self._b_vals = \
+            self._jitted_multi(*args)
+        self._step_no += losses.shape[0]
         return Tensor(losses)
+
+    def lower_steps(self, inputs_stacked, labels_stacked):
+        """Lower (do not run) the K-step program run_steps dispatches —
+        :meth:`lower`'s twin for the scan-fused program."""
+        return self._jitted_multi.lower(
+            *self._steps_args(inputs_stacked, labels_stacked))
 
     def _place_input(self, t, stacked=False):
         v = t._value if isinstance(t, Tensor) else jnp.asarray(t)

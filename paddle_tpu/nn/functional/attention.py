@@ -9,7 +9,6 @@ softmax-attention elsewhere.
 from __future__ import annotations
 
 import math
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -64,9 +63,7 @@ def sliding_window_attention(query, key, value, window_size,
                           ensure_tensor(value))
     w = int(window_size)
     if w < 1:
-        # validated HERE: the kernel's own ValueError would be swallowed
-        # by the capability-fallback except below, and the XLA path's
-        # empty band would softmax to NaN
+        # the XLA path's empty band would softmax to NaN
         raise ValueError(f"window_size must be >= 1, got {window_size}")
     flags = get_flags(["FLAGS_use_pallas_kernels", "FLAGS_pallas_force"])
     use_pallas = (
@@ -75,16 +72,11 @@ def sliding_window_attention(query, key, value, window_size,
         and query._value.shape[-1] >= 64
     )
     if use_pallas:
-        try:
-            return apply(
-                lambda q, k, v: _pallas_flash(q, k, v, causal=True,
-                                              window_size=w),
-                query, key_, value, op_name="sliding_window_attention",
-            )
-        except ValueError as e:
-            warnings.warn(
-                f"Pallas sliding-window attention fell back to XLA: {e}",
-                RuntimeWarning)
+        return apply(
+            lambda q, k, v: _pallas_flash(q, k, v, causal=True,
+                                          window_size=w),
+            query, key_, value, op_name="sliding_window_attention",
+        )
 
     def fn(q, k, v):
         sq, sk = q.shape[1], k.shape[1]
@@ -112,16 +104,10 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         and query._value.shape[-1] >= 64
     )
     if use_pallas:
-        try:
-            return apply(
-                lambda q, k, v: _pallas_flash(q, k, v, causal=is_causal),
-                query, key_, value, op_name="flash_attention",
-            )
-        except ValueError as e:
-            # unsupported head config (e.g. H % HK != 0) — fall back, loudly
-            warnings.warn(
-                f"Pallas flash attention fell back to XLA: {e}", RuntimeWarning
-            )
+        return apply(
+            lambda q, k, v: _pallas_flash(q, k, v, causal=is_causal),
+            query, key_, value, op_name="flash_attention",
+        )
 
     rng_key = None
     if dropout_p > 0.0 and training:

@@ -8,8 +8,6 @@ fuses it fully).
 """
 from __future__ import annotations
 
-import warnings
-
 import jax
 import jax.numpy as jnp
 
@@ -48,7 +46,8 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5, name=N
 def rms_norm(x, weight=None, bias=None, epsilon=1e-6, begin_norm_axis=-1, name=None):
     """RMSNorm over dims [begin_norm_axis:]; the hot path of Llama-family
     models. Routes to the Pallas kernel (normalized dims flattened to one
-    feature axis) with a warned XLA fallback."""
+    feature axis) on TPU; the XLA path serves other backends and the
+    weightless / biased forms the kernel does not take."""
     x = ensure_tensor(x)
     from ...core.flags import get_flags
 
@@ -61,18 +60,15 @@ def rms_norm(x, weight=None, bias=None, epsilon=1e-6, begin_norm_axis=-1, name=N
         jax.default_backend() == "tpu" or flags["FLAGS_pallas_force"]
     )
     if use_pallas and weight is not None and bias is None:
-        try:
-            def pk(v, w):
-                # flatten the normalized dims into one feature axis
-                lead = v.shape[:axis0]
-                out = _pallas_rms_norm(
-                    v.reshape(*lead, -1), w.reshape(-1), epsilon)
-                return out.reshape(v.shape)
+        # no fallback: a kernel the compiler refuses fails the call
+        def pk(v, w):
+            # flatten the normalized dims into one feature axis
+            lead = v.shape[:axis0]
+            out = _pallas_rms_norm(
+                v.reshape(*lead, -1), w.reshape(-1), epsilon)
+            return out.reshape(v.shape)
 
-            return apply(pk, x, ensure_tensor(weight), op_name="rms_norm")
-        except Exception as e:  # Mosaic/VMEM limits → XLA path, loudly
-            warnings.warn(
-                f"Pallas rms_norm fell back to XLA: {e}", RuntimeWarning)
+        return apply(pk, x, ensure_tensor(weight), op_name="rms_norm")
 
     def fn(v, *wb):
         var = jnp.mean(

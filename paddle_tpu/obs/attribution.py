@@ -115,8 +115,8 @@ class CostLedger:
             "windowed tok/s x configured model FLOPs/token")
         self._g_mfu = r.gauge(
             "serving_mfu_fraction",
-            "model FLOP/s over peak_flops_per_chip (0 when the chip "
-            "is unknown, e.g. the CPU smoke)")
+            "model FLOP/s over peak_flops_per_chip (0 on the CPU "
+            "backend, which has no such peak)")
         self.flops_per_token = 0.0
         self.peak_flops = 0.0
 

@@ -1,7 +1,12 @@
 """Shared helpers for the Pallas kernel tier."""
 from __future__ import annotations
 
+import math
+import re
+
 import jax
+
+from ...parallel import mesh as mesh_state
 
 
 def interpret_mode():
@@ -11,3 +16,53 @@ def interpret_mode():
 
 def round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
+
+
+def per_shard(kernel, in_specs, out_specs):
+    """Mosaic kernels cannot be partitioned by GSPMD ("wrap the call in
+    a shard_map" — the TPU lowering refuses them under a mesh of more
+    than one device, whatever the operands' layout). So under an
+    installed mesh every kernel entry point runs its kernel PER SHARD,
+    split over the dims whose work is independent (batch rows over the
+    data axes, heads over ``mp``) and replicated over the rest. With no
+    mesh, or already inside a shard_map body, it is the kernel itself."""
+    mesh = mesh_state.get_mesh()
+    if (mesh is None or mesh.size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return kernel
+    return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def data_axes(n: int):
+    """The data-parallel mesh axes (``dp``, then ZeRO's ``sharding``) a
+    batch dim of ``n`` rows divides over, as a PartitionSpec entry."""
+    axes = [a for a in ("dp", "sharding")
+            if mesh_state.mesh_axis_size(a) > 1]
+    while axes and n % math.prod(
+            mesh_state.mesh_axis_size(a) for a in axes):
+        axes.pop()
+    return tuple(axes) or None
+
+
+def head_axis(*head_counts: int):
+    """``"mp"`` when every head count divides over it, else None."""
+    mp = mesh_state.mesh_axis_size("mp")
+    return "mp" if mp > 1 and all(h % mp == 0 for h in head_counts) \
+        else None
+
+
+_PALLAS_OP = re.compile(r'op_name="[^"]*?([A-Za-z0-9_]+)\)*/pallas_call"')
+
+
+def compiled_kernel_names(hlo_text: str) -> set[str]:
+    """The ``name=`` of every Pallas kernel that is in a COMPILED TPU
+    program's text (``compiled.as_text()``) as a Mosaic custom call —
+    the proof that a kernel ran there and not a reference path (which
+    interpret mode, off-TPU, would lower to plain HLO instead)."""
+    names = set()
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            m = _PALLAS_OP.search(line)
+            names.add(m.group(1) if m else "<unnamed>")
+    return names

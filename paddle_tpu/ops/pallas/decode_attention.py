@@ -21,7 +21,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._utils import interpret_mode as _interpret_mode, round_up as _round_up
+from jax.sharding import PartitionSpec as P
+
+from ._utils import (
+    data_axes as _data_axes, head_axis as _head_axis,
+    interpret_mode as _interpret_mode, per_shard as _per_shard,
+    round_up as _round_up,
+)
 
 DEFAULT_BLOCK_K = 256
 NEG_INF = -1e30
@@ -85,6 +91,19 @@ def decode_attention(q, k_cache, v_cache, seq_lens, sm_scale=None,
             (including the token being decoded, already written).
     Returns (B, H, D) (or (B, 1, H, D) matching q's rank).
     """
+    # batch rows and kv-head groups are independent: one kernel per shard
+    b_ax = _data_axes(q.shape[0])
+    h_ax = _head_axis(q.shape[-2], k_cache.shape[2])
+    q_spec = P(b_ax, *([None] * (q.ndim - 3)), h_ax, None)
+    c_spec = P(b_ax, None, h_ax, None)
+    return _per_shard(
+        functools.partial(_decode_attention, sm_scale=sm_scale,
+                          block_k=block_k),
+        (q_spec, c_spec, c_spec, P(b_ax)), q_spec,
+    )(q, k_cache, v_cache, seq_lens)
+
+
+def _decode_attention(q, k_cache, v_cache, seq_lens, *, sm_scale, block_k):
     squeeze = False
     if q.ndim == 4:
         q = q[:, 0]
@@ -133,6 +152,7 @@ def decode_attention(q, k_cache, v_cache, seq_lens, sm_scale=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, group, d), q.dtype),
         interpret=_interpret_mode(),
+        name="decode_attention",
     )(seq_lens.astype(jnp.int32), qg, kt, vt)
     out = out.reshape(b, h, d)
     return out[:, None] if squeeze else out

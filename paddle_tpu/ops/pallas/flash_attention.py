@@ -30,7 +30,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._utils import interpret_mode as _interpret_mode, round_up as _round_up
+from jax.sharding import PartitionSpec as P
+
+from ._utils import (
+    data_axes as _data_axes, head_axis as _head_axis,
+    interpret_mode as _interpret_mode, per_shard as _per_shard,
+    round_up as _round_up,
+)
 
 NEG_INF = -1e30
 
@@ -248,6 +254,7 @@ def _flash_fwd(q, k, v, causal, causal_offset, kv_len, sm_scale,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=_interpret_mode(),
+        name="flash_attention_fwd",
     )(q, k, v)
     return out, lse
 
@@ -420,6 +427,7 @@ def _flash_bwd(causal, causal_offset, kv_len, sm_scale, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret_mode(),
+        name="flash_attention_bwd_dq",
     )(q, k, v, do, lse, delta)
 
     dk_r, dv_r = pl.pallas_call(
@@ -450,6 +458,7 @@ def _flash_bwd(causal, causal_offset, kv_len, sm_scale, block_q, block_k,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=_interpret_mode(),
+        name="flash_attention_bwd_dkv",
     )(q, k_r, v_r, do, lse, delta)
 
     if group > 1:
@@ -514,6 +523,16 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
     h, hk = q.shape[2], k.shape[2]
     if h % hk != 0:
         raise ValueError(f"query heads ({h}) must be a multiple of kv heads ({hk})")
+    win = None if window_size is None else int(window_size)
+    # batch rows and heads are independent: one kernel per mesh shard
+    spec = P(_data_axes(q.shape[0]), None, _head_axis(h, hk), None)
+    return _per_shard(
+        functools.partial(_flash_bshd, causal=causal, sm_scale=sm_scale,
+                          block_q=block_q, block_k=block_k, win=win),
+        (spec, spec, spec), spec)(q, k, v)
+
+
+def _flash_bshd(q, k, v, *, causal, sm_scale, block_q, block_k, win):
     qt = jnp.swapaxes(q, 1, 2)  # (B, H, Sq, D)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
@@ -528,7 +547,6 @@ def flash_attention(q, k, v, causal=False, sm_scale=None,
         kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
         vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     causal_offset = sk - sq  # bottom-right alignment, real lengths
-    win = None if window_size is None else int(window_size)
     out = _flash_attention_bhsd(qt, kt, vt, causal, causal_offset, sk,
                                 sm_scale, bq, bk, win)
     if pad_q:

@@ -26,7 +26,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._utils import interpret_mode as _interpret_mode
+from jax.sharding import PartitionSpec as P
+
+from ._utils import (
+    head_axis as _head_axis, interpret_mode as _interpret_mode,
+    per_shard as _per_shard,
+)
 
 NEG_INF = -1e30
 
@@ -102,6 +107,27 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens,
     Returns (B, H, D) (or (B, 1, H, D) matching q's rank), in the
     QUERY's dtype.
     """
+    # kv-head groups are independent (a TP engine's pools are split
+    # along that dim): one kernel per mp shard, slots replicated
+    hk = k_pool.shape[2]
+    h_ax = _head_axis(q.shape[-2], hk)
+    q_spec = P(*([None] * (q.ndim - 2)), h_ax, None)
+    pool_spec = P(None, None, h_ax, None)
+    args = [q, k_pool, v_pool, block_tables, seq_lens]
+    specs = [q_spec, pool_spec, pool_spec, P(None, None), P(None)]
+    if k_scale is not None or v_scale is not None:
+        one = jnp.ones((hk,), jnp.float32)
+        args += [one if sc is None else
+                 jnp.asarray(sc, jnp.float32).reshape(hk)
+                 for sc in (k_scale, v_scale)]
+        specs += [P(h_ax), P(h_ax)]
+    return _per_shard(
+        functools.partial(_paged_decode_attention, sm_scale=sm_scale),
+        tuple(specs), q_spec)(*args)
+
+
+def _paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens,
+                            k_scale=None, v_scale=None, *, sm_scale):
     squeeze = False
     if q.ndim == 4:
         q = q[:, 0]
@@ -163,6 +189,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, group, d), q.dtype),
         interpret=_interpret_mode(),
+        name="paged_decode_attention",
     )(tables, lens, ks, vs, qg, kp, vp)
     out = out.reshape(b, h, d)
     return out[:, None] if squeeze else out

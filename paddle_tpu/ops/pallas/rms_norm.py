@@ -21,7 +21,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._utils import interpret_mode as _interpret_mode, round_up as _round_up
+from jax.sharding import PartitionSpec as P
+
+from ._utils import (
+    data_axes as _data_axes, interpret_mode as _interpret_mode,
+    per_shard as _per_shard, round_up as _round_up,
+)
 
 DEFAULT_BLOCK_ROWS = 256
 
@@ -80,6 +85,7 @@ def _rms_fwd(x2d, w, eps, block_rows):
             jax.ShapeDtypeStruct((rows, 1), jnp.float32),
         ],
         interpret=_interpret_mode(),
+        name="rms_norm_fwd",
     )(x2d, w.reshape(1, n))
     return y, r
 
@@ -107,6 +113,7 @@ def _rms_bwd(x2d, w, r, dy2d, block_rows):
         ],
         scratch_shapes=[pltpu.VMEM((1, n), jnp.float32)],
         interpret=_interpret_mode(),
+        name="rms_norm_bwd",
     )(x2d, w.reshape(1, n), r, dy2d)
     return dx, dw
 
@@ -133,6 +140,16 @@ _rms_norm_2d.defvjp(_fwd_rule, _bwd_rule)
 
 def rms_norm(x, weight, epsilon=1e-6, block_rows=None):
     """RMSNorm over the last axis; x (..., N), weight (N,)."""
+    # rows are independent: one kernel per data shard of the leading dim
+    lead_spec = (_data_axes(x.shape[0]),) if x.ndim > 1 else ()
+    x_spec = P(*lead_spec, *([None] * (x.ndim - len(lead_spec))))
+    return _per_shard(
+        functools.partial(_rms_norm_rows, epsilon=epsilon,
+                          block_rows=block_rows),
+        (x_spec, P(None)), x_spec)(x, weight)
+
+
+def _rms_norm_rows(x, weight, *, epsilon, block_rows):
     n = x.shape[-1]
     lead = x.shape[:-1]
     rows = 1

@@ -38,7 +38,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._utils import interpret_mode as _interpret_mode, round_up as _round_up
+from jax.sharding import PartitionSpec as P
+
+from ._utils import (
+    head_axis as _head_axis, interpret_mode as _interpret_mode,
+    per_shard as _per_shard, round_up as _round_up,
+)
 
 NEG_INF = -1e30
 LANES = 128       # minor-dim tile for the q-side aux arrays
@@ -191,6 +196,7 @@ def _varlen_fwd(q, k, v, qs, qr, ks, kr, run_map, full_map,
             jax.ShapeDtypeStruct((h, tq, 1), jnp.float32),
         ],
         interpret=_interpret_mode(),
+        name="varlen_flash_attention_fwd",
     )(run_map, full_map, q, k, v, qs, qr, ks, kr)
     return out, lse
 
@@ -363,6 +369,7 @@ def _varlen_bwd(causal, sm_scale, block_q, block_k, window, residuals, g):
         ),
         out_shape=jax.ShapeDtypeStruct((h, tq, d), q.dtype),
         interpret=_interpret_mode(),
+        name="varlen_flash_attention_bwd_dq",
     )(run_map, full_map, q, k, v, do, lse, delta, qs, qr, ks, kr)
 
     # dkv: grid (h, ki, qi); dead tiles skip the q-side DMAs instead
@@ -410,6 +417,7 @@ def _varlen_bwd(causal, sm_scale, block_q, block_k, window, residuals, g):
             jax.ShapeDtypeStruct((h, tk, d), v.dtype),
         ],
         interpret=_interpret_mode(),
+        name="varlen_flash_attention_bwd_dkv",
     )(run_map, full_map, q, k_r, v_r, do, lse, delta, qs, qr, ks, kr)
 
     if group > 1:
@@ -529,6 +537,20 @@ def varlen_flash_attention(q, k, v, cu_seqlens_q, cu_seqlens_k,
         dbq, dbk = _default_blocks(d)
         block_q = block_q or dbq
         block_k = block_k or dbk
+    win = None if window_size is None else int(window_size)
+    # heads are independent (packed tokens are not): one kernel per mp
+    # shard of the head dim
+    spec = P(None, _head_axis(h, hk), None)
+    return _per_shard(
+        functools.partial(_varlen_thd, causal=causal, sm_scale=sm_scale,
+                          block_q=block_q, block_k=block_k, win=win),
+        (spec, spec, spec, P(None), P(None)), spec,
+    )(q, k, v, cu_seqlens_q, cu_seqlens_k)
+
+
+def _varlen_thd(q, k, v, cu_seqlens_q, cu_seqlens_k, *, causal, sm_scale,
+                block_q, block_k, win):
+    tq, tk = q.shape[0], k.shape[0]
     # lane-aligned blocks; cap at the (padded) token counts
     bq = min(block_q, _round_up(tq, LANES))
     bk = min(block_k, _round_up(tk, LANES))
@@ -540,7 +562,6 @@ def varlen_flash_attention(q, k, v, cu_seqlens_q, cu_seqlens_k,
     seg_q, rel_q = _aux_arrays(cu_q, tq + pad_q, _Q_PAD_SEG, _REL_LO,
                                cu_other=cu_k)
     seg_k, rel_k = _aux_arrays(cu_k, tk + pad_k, _K_PAD_SEG, _REL_HI)
-    win = None if window_size is None else int(window_size)
     run_map, full_map = _tile_maps(seg_q, rel_q, seg_k, rel_k, bq, bk,
                                    causal, win)
 

@@ -17,7 +17,6 @@ _PEAKS = {
     "v5 lite": 197e12,   # v5e
     "v5e": 197e12,
     "v5p": 459e12,
-    "v5": 459e12,        # bare "v5" → assume v5p
     "v4": 275e12,
     "v6 lite": 918e12,   # Trillium
     "v6e": 918e12,
@@ -27,14 +26,20 @@ _PEAKS = {
 
 
 def peak_flops_per_chip(device=None):
-    """Best-effort peak bf16 FLOP/s for the attached chip (0 if unknown —
-    callers should then report raw throughput, not MFU)."""
+    """Peak bf16 FLOP/s of the attached chip. The CPU backend has no
+    such peak and reads 0.0 (callers then report raw throughput, not
+    MFU); an accelerator whose ``device_kind`` is not in the table is an
+    error, never a guess."""
     device = device or jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
+    if device.platform == "cpu":
+        return 0.0
+    kind = device.device_kind.lower()
     for key in sorted(_PEAKS, key=len, reverse=True):
         if key in kind:
             return _PEAKS[key]
-    return 0.0
+    raise ValueError(
+        f"no peak FLOP/s known for device_kind {device.device_kind!r}; "
+        "add it to profiler.mfu._PEAKS with its source")
 
 
 def transformer_train_flops(n_params, tokens, num_layers=0, seq_len=0,

@@ -1058,8 +1058,9 @@ class ServingEngine:
         self.obs.set_quantum_collectives(self.quantum_collectives)
         # cost-ledger MFU constants (obs/attribution.py): target-model
         # FLOPs per decoded token (2N weight-matmul floor, embedding
-        # gathers excluded) and the chip peak (0.0 off TPU — the MFU
-        # gauge then honestly reads 0 and raw FLOP/s is the number)
+        # gathers excluded) and the chip peak (0.0 on the CPU backend —
+        # the MFU gauge then reads 0 and raw FLOP/s is the number; an
+        # accelerator the peak table does not know raises)
         from ..obs.attribution import decode_flops_per_token
         from ..profiler.mfu import peak_flops_per_chip
 
@@ -1074,14 +1075,10 @@ class ServingEngine:
         if cost_model:
             # opt-in: count the ACTUAL decode quantum's jaxpr (attention
             # over live context + lm-head, which 2N excludes) and take
-            # the larger — the walker returns 0.0 when the quantum
-            # cannot be traced, so the floor always survives
-            try:
-                from ..analysis.cost import quantum_flops_per_token
+            # the larger
+            from ..analysis.cost import quantum_flops_per_token
 
-                flops_tok = max(quantum_flops_per_token(self), flops_tok)
-            except Exception:
-                pass
+            flops_tok = max(quantum_flops_per_token(self), flops_tok)
         self.obs.ledger.configure(
             flops_per_token=flops_tok,
             peak_flops=peak_flops_per_chip()

@@ -2,8 +2,7 @@
 unverified, SURVEY.md §0).
 
 Framing/windowing/overlap-add are real-valued jnp ops on the tape; the
-DFT itself routes through ``paddle.fft`` (which host-offloads on
-backends without complex support — see fft.py)."""
+DFT itself routes through ``paddle.fft``."""
 from __future__ import annotations
 
 import numpy as np

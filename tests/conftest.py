@@ -19,12 +19,7 @@ import jax
 import pytest
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 8)
-except AttributeError:
-    # older jax (< 0.5): the XLA_FLAGS above (set before backend init)
-    # provides the 8 virtual CPU devices instead
-    pass
+jax.config.update("jax_num_cpu_devices", 8)
 
 
 @pytest.fixture(autouse=True)

@@ -373,6 +373,30 @@ def test_sharding_unknown_syntax_counted_in_report():
     assert "unknown_shardings" not in clean.summary_dict()
 
 
+def test_sharding_shardy_attrs_classified():
+    """The installed JAX lowers with the Shardy partitioner: layouts
+    are ``#sdy.sharding<@mesh, [dims]>`` over a module-level mesh. A
+    dim that names an axis of size > 1 is sharded; empty dims, or an
+    axis of size 1, are replicated."""
+    from paddle_tpu.analysis.sharding import audit_sharding
+
+    hlo = (
+        'sdy.mesh @mesh = <["dp"=1, "mp"=2, "sharding"=4]>\n'
+        'func.func public @main('
+        '%arg0: tensor<64x128xf32> {sdy.sharding = #sdy.sharding<@mesh, '
+        '[{"mp", "sharding"}, {}]>, tf.aliasing_output = 0 : i32}, '
+        '%arg1: tensor<64xf32> {sdy.sharding = #sdy.sharding<@mesh, '
+        '[{}]>}, '
+        '%arg2: tensor<8x4xf32> {sdy.sharding = #sdy.sharding<@mesh, '
+        '[{"dp"}, {}]>}, '
+        '%arg3: tensor<4xf32>) -> tensor<4xf32> {'
+    )
+    rep = audit_sharding(hlo, n_donatable=2)
+    assert [a.replicated for a in rep.args] == [False, True, True, True]
+    assert rep.sharded_param_count == 1 and rep.unknown_count == 0
+    assert rep.max_replicated_param_bytes == 64 * 4
+
+
 def test_sharding_pass_flags_replicated_param():
     """Known-bad: a large param left replicated over a real mesh while
     the mesh is in play; max_replicated_param_bytes catches it, and the

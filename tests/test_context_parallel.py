@@ -140,12 +140,6 @@ def test_ring_in_jit_under_mesh():
     )
 
 
-@pytest.mark.xfail(
-    reason="pre-existing under this container's jax: XLA donation "
-           "aliases a replicated param buffer to a resharded output "
-           "('Expected aliased input ... to have the same size') in "
-           "the dp2xmp2xsep2 hybrid step; present at seed",
-    strict=False)
 def test_llama_ring_cp_train_matches_serial():
     """Full Llama train step with ring context parallelism over sep==2
     matches the serial step (sep axis end-to-end through the model)."""

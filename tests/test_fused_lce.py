@@ -67,12 +67,6 @@ def test_fused_lce_bias():
     np.testing.assert_allclose(loss, loss_ref, rtol=1e-6)
 
 
-@pytest.mark.xfail(
-    reason="pre-existing under this container's jax: XLA donation "
-           "aliases a replicated param buffer to an mp-resharded "
-           "output ('Expected aliased input ... to have the same "
-           "size') in the dp4xmp2 hybrid step; present at seed",
-    strict=False)
 def test_fused_lce_under_tensor_parallel_matches_serial():
     """The fused criterion composed with TP (mp2 x dp) on the 8-device
     mesh: the llama model's mp-sharded layers + fused lm-head+CE must

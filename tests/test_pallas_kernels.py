@@ -500,3 +500,86 @@ def test_llama_packed_sliding_window_matches_per_sequence():
         np.testing.assert_allclose(out[ofs:ofs + len(seg)], alone,
                                    rtol=2e-4, atol=2e-4)
         ofs += len(seg)
+
+
+# ------------------------------------------------ kernels under a mesh
+# The TPU lowering refuses a Mosaic kernel inside a GSPMD program
+# ("cannot be automatically partitioned"), so under an installed mesh
+# every kernel entry point runs per shard inside jax.shard_map
+# (ops/pallas/_utils.per_shard). Interpret mode lowers to plain HLO and
+# would hide a wrong split, so parity with the mesh-less call is pinned
+# here: values and gradients.
+@pytest.fixture
+def hybrid_mesh():
+    """dp2 x sharding2 x mp2 over the 8 virtual devices."""
+    from jax.sharding import Mesh
+    from paddle_tpu.parallel import mesh as mesh_state
+
+    mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 2, 1, 2),
+                ("dp", "sharding", "sep", "mp"))
+    mesh_state.set_mesh(mesh)
+    yield mesh
+    mesh_state.set_mesh(None)
+
+
+def _under_mesh_matches(fn, args, argnums=None):
+    """fn's output (and, given ``argnums``, its gradients), jitted
+    under the installed mesh, against the same call with no mesh."""
+    from paddle_tpu.parallel import mesh as mesh_state
+
+    def loss(*a):
+        return jnp.sum(jnp.sin(fn(*a).astype(jnp.float32)))
+
+    run = jax.jit(fn if argnums is None
+                  else jax.value_and_grad(loss, argnums))
+    mesh = mesh_state.get_mesh()
+    got = run(*args)
+    assert "manual" in run.lower(*args).as_text(), \
+        "kernel was not wrapped in a shard_map"
+    mesh_state.set_mesh(None)
+    jax.clear_caches()  # the mesh is read at trace time, not a jit key
+    want = run(*args)
+    assert "manual" not in run.lower(*args).as_text()
+    mesh_state.set_mesh(mesh)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=GTOL)
+
+
+@pytest.mark.parametrize("h,hk,b", [(4, 2, 4), (3, 1, 3)],
+                         ids=["split-batch-and-heads", "indivisible"])
+def test_flash_attention_per_shard_under_mesh(hybrid_mesh, h, hk, b):
+    rng = np.random.RandomState(0)
+    q = jnp.asarray(rng.randn(b, 64, h, 64), jnp.float32)
+    k = jnp.asarray(rng.randn(b, 64, hk, 64), jnp.float32)
+    v = jnp.asarray(rng.randn(b, 64, hk, 64), jnp.float32)
+    _under_mesh_matches(
+        lambda q, k, v: flash_attention(q, k, v, causal=True),
+        (q, k, v), (0, 1, 2))
+
+
+def test_rms_norm_per_shard_under_mesh(hybrid_mesh):
+    """dw sums over rows that live on different shards."""
+    rng = np.random.RandomState(1)
+    x = jnp.asarray(rng.randn(4, 24, 128), jnp.float32)
+    w = jnp.asarray(rng.randn(128), jnp.float32)
+    _under_mesh_matches(lambda x, w: rms_norm(x, w, 1e-6), (x, w), (0, 1))
+
+
+def test_decode_kernels_per_shard_under_mesh(hybrid_mesh):
+    from paddle_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention,
+    )
+
+    rng = np.random.RandomState(2)
+    q = jnp.asarray(rng.randn(4, 4, 64), jnp.float32)
+    kc = jnp.asarray(rng.randn(4, 48, 2, 64), jnp.float32)
+    lens = jnp.asarray([7, 48, 1, 20], jnp.int32)
+    _under_mesh_matches(lambda q, kc: decode_attention(q, kc, kc, lens),
+                        (q, kc))
+    pool = jnp.asarray(rng.randn(13, 16, 2, 64), jnp.float32)
+    tables = jnp.asarray(rng.permutation(12).reshape(4, 3) + 1, jnp.int32)
+    _under_mesh_matches(
+        lambda q, p: paged_decode_attention(q, p, p, tables, lens),
+        (q, pool))

@@ -1,0 +1,135 @@
+"""The main path's Pallas kernels compiled, at Llama-2-7B widths, by the
+installed TPU compiler for a DESCRIBED (not attached) v5e chip — what
+interpret mode cannot show: tiling alignment, VMEM budgets, Mosaic
+lowering. Nothing runs, so a pass here says nothing about results or
+times and is never reported as a chip run (``chip_smoke.py`` is that).
+Skipped where the topology cannot be described.
+"""
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+H, D, HIDDEN, SEQ = 32, 128, 4096, 4096   # Llama-2-7B attention widths
+SLOTS, BLOCK, TABLE_W = 8, 32, 64         # serving engine defaults @ 2048
+POOL_BLOCKS = SLOTS * TABLE_W + 1
+
+_KERNEL_MODULES = ("flash_attention", "rms_norm", "decode_attention",
+                   "paged_attention", "varlen_flash_attention")
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e chip, kernels steered out of interpret mode
+    (they ask ``jax.default_backend()``, which still says cpu here) and
+    the persistent compile cache off: an entry compiled for a described
+    device cannot be read back without the chip and would only warn."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    mp = pytest.MonkeyPatch()
+    for name in _KERNEL_MODULES:
+        mod = importlib.import_module(f"paddle_tpu.ops.pallas.{name}")
+        mp.setattr(mod, "_interpret_mode", lambda: False)
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    compilation_cache.reset_cache()
+    mp.undo()
+
+
+def _compiled_kernels(chip, fn, *shapes):
+    """Compile ``fn`` for the described chip from shapes alone; returns
+    the names of the Pallas kernels in the compiled program."""
+    from paddle_tpu.ops.pallas._utils import compiled_kernel_names
+
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
+    return compiled_kernel_names(
+        jax.jit(fn).lower(*args).compile().as_text())
+
+
+def test_flash_attention_fwd_bwd(chip):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).astype(
+            jnp.float32).sum()
+
+    qkv = ((1, SEQ, H, D), jnp.bfloat16)
+    names = _compiled_kernels(
+        chip, jax.value_and_grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+    assert names == {"flash_attention_fwd", "flash_attention_bwd_dq",
+                     "flash_attention_bwd_dkv"}
+
+
+def test_rms_norm_fwd_bwd(chip):
+    from paddle_tpu.ops.pallas.rms_norm import rms_norm
+
+    def loss(x, w):
+        return rms_norm(x, w, 1e-6).astype(jnp.float32).sum()
+
+    names = _compiled_kernels(
+        chip, jax.value_and_grad(loss, argnums=(0, 1)),
+        ((SEQ, HIDDEN), jnp.bfloat16), ((HIDDEN,), jnp.bfloat16))
+    assert names == {"rms_norm_fwd", "rms_norm_bwd"}
+
+
+def test_decode_attention(chip):
+    from paddle_tpu.ops.pallas.decode_attention import decode_attention
+
+    cache = ((SLOTS, SEQ, H, D), jnp.bfloat16)
+    names = _compiled_kernels(
+        chip, decode_attention, ((SLOTS, H, D), jnp.bfloat16), cache,
+        cache, ((SLOTS,), jnp.int32))
+    assert names == {"decode_attention"}
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+def test_paged_decode_attention(chip, pool_dtype):
+    from paddle_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention,
+    )
+
+    pool = ((POOL_BLOCKS, BLOCK, H, D), pool_dtype)
+    shapes = [((SLOTS, H, D), jnp.bfloat16), pool, pool,
+              ((SLOTS, TABLE_W), jnp.int32), ((SLOTS,), jnp.int32)]
+    if pool_dtype == jnp.int8:
+        # static per-kv-head dequant scales ride into the kernel
+        shapes += [((H,), jnp.float32)] * 2
+
+        def fn(q, kp, vp, tables, lens, ks, vs):
+            return paged_decode_attention(q, kp, vp, tables, lens,
+                                          k_scale=ks, v_scale=vs)
+    else:
+        fn = paged_decode_attention
+    assert _compiled_kernels(chip, fn, *shapes) == {
+        "paged_decode_attention"}
+
+
+def test_varlen_flash_attention_prefill(chip):
+    """The serving engine's chunked-prefill attention: 5 rows of 64 new
+    tokens each, attending over their cached context + the chunk."""
+    from paddle_tpu.ops.pallas.varlen_flash_attention import (
+        varlen_flash_attention,
+    )
+
+    def fn(q, k, v, cu_q, cu_k):
+        return varlen_flash_attention(q, k, v, cu_q, cu_k, causal=True)
+
+    names = _compiled_kernels(
+        chip, fn, ((320, H, D), jnp.bfloat16), ((960, H, D), jnp.bfloat16),
+        ((960, H, D), jnp.bfloat16), ((6,), jnp.int32), ((6,), jnp.int32))
+    assert names == {"varlen_flash_attention_fwd"}

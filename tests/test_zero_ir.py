@@ -130,16 +130,7 @@ def test_no_involuntary_remat_reshards(stage3):
                               require_donated=True), x, x)
 
 
-@pytest.mark.parametrize(
-    "fused_lce",
-    [pytest.param(False, marks=pytest.mark.xfail(
-        reason="pre-existing under this container's jax 0.4.37: the "
-               "XLA SPMD partitioner reshards one RowParallel param "
-               "via replicate-then-repartition in the UNFUSED "
-               "criterion graph (present at seed; the fused-LCE "
-               "recipe — the protected one — is clean)",
-        strict=False)),
-     True])
+@pytest.mark.parametrize("fused_lce", [False, True])
 def test_no_involuntary_remat_with_tp_and_zero(fused_lce):
     """TP(mp=2) x ZeRO(sharding=4): dim-0 mp-sharded params (vocab
     embedding) must get moments whose dim-0 spec keeps mp MAJOR and adds
