@@ -70,50 +70,51 @@ def result_line(ok, device, **extra):
     return json.dumps({"ok": bool(ok), "device": device, **extra})
 
 
-class CompileMeter:
-    """Seconds this process spent in XLA backend compiles (a persistent-
-    cache hit counts its retrieval instead), and the cache's hits and
-    misses — read off jax.monitoring, so eager ops and jitted programs
-    are counted alike."""
+def compile_counts():
+    """What the program's own compile counters hold for this process so
+    far, over every step label (``profiler.count_compile_events``:
+    ``jax.monitoring`` events, so eager ops and jitted programs count
+    alike): seconds in XLA backend compiles (a persistent-cache hit counts
+    its load instead), the cache's hits and misses, and per step label the
+    executables JAX asked for."""
+    from paddle_tpu.obs import MetricsRegistry
+    from paddle_tpu.profiler import count_compile_events
 
-    def __init__(self):
-        import jax
+    count_compile_events()  # listening from the first phase on
+    series = {m["name"]: m["series"]
+              for m in MetricsRegistry.process().snapshot()["metrics"]}
 
-        self.seconds = 0.0
-        self.hits = self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
+    def total(name, **want):
+        return sum(s["value"] for s in series[name]
+                   if want.items() <= s["labels"].items())
 
-    def _duration(self, event, seconds, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += seconds
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def snapshot(self):
-        return self.seconds, self.hits, self.misses
+    by_step = {s["labels"]["step"]: int(s["value"])
+               for s in series["jax_compile_requests_total"]}
+    return (total("jax_compile_seconds_total", stage="backend")
+            + total("jax_compile_seconds_total", stage="cache_load"),
+            int(total("jax_compile_cache_hits_total")),
+            int(total("jax_compile_cache_misses_total")), by_step)
 
 
-def run_phase(name, meter, fn, **kwargs):
+def run_phase(name, fn, **kwargs):
     """Run one phase and print its JSON line. A phase that raises or
     whose pass condition fails propagates: nothing is let through."""
     import jax
 
     t0 = time.perf_counter()
-    s0, h0, m0 = meter.snapshot()
+    s0, h0, m0, r0 = compile_counts()
     detail = fn(**kwargs)
     gc.collect()  # the phase's params/state leave the device with it
-    s1, h1, m1 = meter.snapshot()
+    s1, h1, m1, r1 = compile_counts()
     stats = [d.memory_stats() or {} for d in jax.devices()]
     print(json.dumps({
         "phase": name, "ok": True,
         "seconds": round(time.perf_counter() - t0, 2),
         "compile_seconds": round(s1 - s0, 2),
         "compile_cache": {"hits": h1 - h0, "misses": m1 - m0},
+        # executables JAX asked for, by the step that caused them
+        "compile_requests": {k: v - r0.get(k, 0) for k, v in r1.items()
+                             if v - r0.get(k, 0)},
         "device": device_report(),
         # process-wide high-water mark so far, and what is still held
         "peak_bytes_in_use": [s.get("peak_bytes_in_use") for s in stats],
@@ -365,7 +366,6 @@ def run(chips, device):
         raise RuntimeError(
             f"--chips {chips} but jax sees {device['count']} device(s)")
     enable_compile_cache()
-    meter = CompileMeter()
     print(json.dumps({"phase": "start", "device": device,
                       "model": "Llama-2-7B at its published widths, depth "
                                "cut from 32 layers to the 4 one 16 GB chip "
@@ -374,16 +374,16 @@ def run(chips, device):
                       "compile_cache_dir":
                           jax.config.jax_compilation_cache_dir}), flush=True)
     if chips == 1:
-        run_phase("fft", meter, fft_phase)
-        run_phase("train", meter, train_phase, cfg=headline_config(),
+        run_phase("fft", fft_phase)
+        run_phase("train", train_phase, cfg=headline_config(),
                   **TRAIN)
-        run_phase("serve", meter, serve_phase, cfg=headline_config(),
+        run_phase("serve", serve_phase, cfg=headline_config(),
                   **SERVE)
     else:
         tp_cfg = headline_config(tensor_parallel=True)
-        run_phase("mesh_train", meter, mesh_train_phase, cfg=tp_cfg,
+        run_phase("mesh_train", mesh_train_phase, cfg=tp_cfg,
                   **MESH_TRAIN)
-        run_phase("tp_serve", meter, tp_serve_phase, cfg=tp_cfg,
+        run_phase("tp_serve", tp_serve_phase, cfg=tp_cfg,
                   **TP_SERVE)
 
 

@@ -20,6 +20,7 @@ from ..core.tensor import Tensor
 from ..core import autograd
 from . import functional_call
 from ..parallel import mesh as mesh_state
+from ..profiler import RecordEvent, count_compile_events
 
 __all__ = ["JittedTrainStep"]
 
@@ -160,6 +161,8 @@ class JittedTrainStep:
         self._jitted = jax.jit(step_fn, donate_argnums=donate_args, **jit_kw)
         self._jitted_multi = jax.jit(
             multi_step_fn, donate_argnums=donate_args, **jit_kw)
+        # JAX's compile events, charged to the step span that caused them
+        count_compile_events()
 
     def _batch_args(self, inputs, labels):
         """Normalize/place one example batch: (in_vals, lb_vals, lr,
@@ -175,15 +178,22 @@ class JittedTrainStep:
         return in_vals, lb_vals, lr, step_no
 
     def __call__(self, inputs, labels):
-        """inputs/labels: Tensor or list of Tensors. Returns loss Tensor."""
-        in_vals, lb_vals, lr, step_no = self._batch_args(inputs, labels)
+        """inputs/labels: Tensor or list of Tensors. Returns loss Tensor.
+        One dispatch, spanned like :meth:`run_steps`."""
         from ..core.random import next_key
 
-        loss, self._p_vals, self._s_vals, self._b_vals = self._jitted(
-            self._p_vals, self._s_vals, self._b_vals, next_key(), lr,
-            step_no, in_vals, lb_vals,
-        )
-        self._step_no += 1
+        with RecordEvent("train.run_steps", step_kind="train",
+                         step=self._step_no):
+            with RecordEvent("train.args"):
+                in_vals, lb_vals, lr, step_no = self._batch_args(
+                    inputs, labels)
+                key = next_key()
+            with RecordEvent("train.enqueue"):
+                loss, self._p_vals, self._s_vals, self._b_vals = \
+                    self._jitted(
+                        self._p_vals, self._s_vals, self._b_vals, key, lr,
+                        step_no, in_vals, lb_vals)
+            self._step_no += 1
         return Tensor(loss)
 
     # -- lowered-IR hooks (paddle_tpu.analysis audits compile THESE) -------
@@ -241,11 +251,18 @@ class JittedTrainStep:
 
     def run_steps(self, inputs_stacked, labels_stacked):
         """Run K train steps in ONE dispatch. inputs/labels carry a leading
-        step dim (K, batch, ...); returns the (K,) per-step losses."""
-        args = self._steps_args(inputs_stacked, labels_stacked)
-        losses, self._p_vals, self._s_vals, self._b_vals = \
-            self._jitted_multi(*args)
-        self._step_no += losses.shape[0]
+        step dim (K, batch, ...); returns the (K,) per-step losses. The
+        host's part is the span ``train.run_steps``: ``train.args``
+        (placing the batch, the key, the scalars) and ``train.enqueue``
+        (the jitted call until it returns; the device runs on)."""
+        with RecordEvent("train.run_steps", step_kind="train",
+                         step=self._step_no):
+            with RecordEvent("train.args"):
+                args = self._steps_args(inputs_stacked, labels_stacked)
+            with RecordEvent("train.enqueue"):
+                losses, self._p_vals, self._s_vals, self._b_vals = \
+                    self._jitted_multi(*args)
+            self._step_no += losses.shape[0]
         return Tensor(losses)
 
     def lower_steps(self, inputs_stacked, labels_stacked):

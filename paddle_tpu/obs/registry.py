@@ -191,8 +191,30 @@ class MetricsRegistry:
     names are unique across kinds; re-registration with a different
     kind (or different histogram buckets) raises."""
 
+    _process = None
+
     def __init__(self):
         self._metrics = {}
+
+    @classmethod
+    def process(cls):
+        """The process's own registry (built on first use): what is
+        counted once per process whichever engine or train step caused
+        it, e.g. JAX's compile events (``profiler.count_compile_events``).
+        An engine's registry shows those too, through :meth:`share`."""
+        if cls._process is None:
+            cls._process = cls()
+        return cls._process
+
+    def share(self, metric):
+        """Show an instrument another registry made under its own name
+        here as well (one object, two scrapes). A different instrument
+        already under that name is an error."""
+        mine = self._metrics.setdefault(metric.name, metric)
+        if mine is not metric:
+            raise ValueError(
+                f"metric {metric.name!r} already registered here")
+        return metric
 
     def _get(self, cls, name, help, **kw):
         m = self._metrics.get(name)

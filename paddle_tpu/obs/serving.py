@@ -7,9 +7,14 @@ observability enabled (the golden-fingerprint gate proves it).
 
 :class:`ServingObs` owns a :class:`~paddle_tpu.obs.registry.
 MetricsRegistry` (always-on: counters/gauges/histograms are dict ops)
-and an optional :class:`~paddle_tpu.obs.trace.TraceRecorder` (per-
-request lifecycle spans on per-slot tracks, quantum spans + counter
-tracks on the engine track — Perfetto-loadable). The engine's legacy
+and, with ``trace=True``, writes per-request lifecycle events on
+per-slot tracks and counter tracks on the engine track into the
+process's one :class:`~paddle_tpu.obs.trace.TraceRecorder` — the buffer
+that always holds the program's host spans (``profiler.RecordEvent``:
+``engine.mixed``, ``engine.decode``, ``engine.spec_round`` and their
+parts are the per-dispatch events; ``request.queued`` is recorded here
+at admission), so ``engine.obs.tracer.save(path)`` is one
+Perfetto-loadable trace with both. The engine's legacy
 ``stats`` dict survives as :class:`_LegacyStatsView`, a thin
 MutableMapping over the same registry counters, so pre-observability
 callers (benches, tests) read/reset the exact values the registry
@@ -134,10 +139,12 @@ class ServingObs:
 
     Args:
         registry: share a registry across engines (default: fresh).
-        trace: record Chrome trace events (bounded buffer; off by
-            default — the metrics registry alone is always on).
-        tracer: bring your own :class:`TraceRecorder` (wins over
-            ``trace``).
+        trace: also record per-request lifecycle events and counter
+            tracks, into the process's recorder (off by default — the
+            metrics registry and the program's host spans are always
+            on).
+        tracer: bring your own :class:`TraceRecorder` for those events
+            (wins over ``trace``; the host spans stay in the process's).
         enabled: ``False`` short-circuits every rich hook (histograms,
             gauges, tracer, time series) — the ``obs="off"`` arm of the
             ``serving_obs_overhead`` bench; the legacy stats counters
@@ -151,7 +158,7 @@ class ServingObs:
         self.registry = registry if registry is not None \
             else MetricsRegistry()
         self.tracer = tracer if tracer is not None \
-            else (TraceRecorder() if trace else None)
+            else (TraceRecorder.process() if trace else None)
         self.window_s = float(window_s)
         r = self.registry
         self._legacy = {
@@ -376,6 +383,11 @@ class ServingObs:
             return
         self._c_admitted.inc()
         self._h_queue.observe(now - req.arrival_time)
+        # the wait in the queue as a span of its own: submit -> admit,
+        # from the two stamps already taken
+        TraceRecorder.process().span(
+            "request.queued", req.arrival_time, now,
+            args={"req_id": str(req.req_id)})
         if self.tracer is not None:
             tid = req.slot + 1
             self.tracer.thread_name(tid, f"slot{req.slot}")
@@ -578,10 +590,9 @@ class ServingObs:
         self.ledger.on_quantum(kind, t0, t1, tokens,
                                breakdown=breakdown,
                                window_rate=self._g_rate.value())
+        # the dispatch itself is the program's own span (engine.mixed |
+        # engine.decode | engine.spec_round); only the counter track
         if self.tracer is not None:
-            self.tracer.complete(kind, t0, t1, tid=0,
-                                 args={"tokens": int(tokens),
-                                       "rows": int(rows)})
             self.tracer.counter("tokens_per_s", t1,
                                 {"window": self._g_rate.value()})
 

@@ -7,15 +7,27 @@ XPlane tracing: ``Profiler`` drives ``jax.profiler.start_trace`` /
 ``stop_trace`` (TensorBoard-loadable), ``RecordEvent`` maps to
 ``jax.profiler.TraceAnnotation``, and scheduler windows are honored by
 step counting in ``step()``.
+
+``RecordEvent`` is also the program's ONE host-span call: besides the
+annotation (which any profiler session shows on the device trace's
+clock) it appends a row to the process's one
+:class:`~paddle_tpu.obs.trace.TraceRecorder`, always, so the spans read
+the same with and without a profiler session. The serving pump and the
+train dispatch are spanned with it (``PERF.md`` section 3 has the
+table), and :func:`count_compile_events` charges JAX's compile events
+to the step span that was open when they happened.
 """
 from __future__ import annotations
 
 import enum
 import os
+import threading
 import time
 
 import jax
 
+from ..obs.registry import MetricsRegistry
+from ..obs.trace import TraceRecorder
 from .mfu import MFUMeter, transformer_train_flops, peak_flops_per_chip  # noqa: F401
 
 __all__ = [
@@ -80,22 +92,62 @@ def load_profiler_result(path):
     )
 
 
-class RecordEvent:
-    """Context manager annotating a host region; shows up on the XLA
-    trace timeline (reference: paddle.profiler.RecordEvent)."""
+# .stack: this thread's open RecordEvents; .cache_load: seconds of a
+# persistent-cache load whose backend-compile event is still to come
+_open = threading.local()
 
-    def __init__(self, name, event_type=None):
-        self._name = name
-        self._ann = None
+
+class RecordEvent:
+    """Context manager annotating a host region (reference:
+    paddle.profiler.RecordEvent). One call does two things: it enters a
+    ``jax.profiler.TraceAnnotation(name)``, so a profiler session shows
+    the region on the XLA trace timeline, and on exit it appends one row
+    to the process's :class:`~paddle_tpu.obs.trace.TraceRecorder`: name,
+    start and end on ``time.perf_counter`` (``t0`` / ``t1``, readable by
+    the caller), the span open on this thread when it began (its
+    parent), and ``args`` — the identifiers handed in (``req_id``,
+    ``step``, ...), which the caller may add to while the span is open.
+    ``step_kind`` marks the span as one STEP of the program (the engine
+    and the trainer name theirs: mixed, decode, spec_round, train):
+    JAX's compile events are charged to the outermost such span open on
+    the thread (:func:`count_compile_events`). Host code only: never
+    inside a jitted body."""
+
+    __slots__ = ("name", "args", "step_kind", "id", "parent", "t0", "t1",
+                 "_ann", "_stack")
+
+    def __init__(self, name, event_type=None, *, step_kind=None, **ids):
+        self.name = name
+        self.args = ids
+        self.step_kind = step_kind
+        self.id = self.parent = self.t0 = self.t1 = None
+        self._ann = self._stack = None
 
     def begin(self):
-        self._ann = jax.profiler.TraceAnnotation(self._name)
+        stack = getattr(_open, "stack", None)
+        if stack is None:
+            stack = _open.stack = []
+        self.id = TraceRecorder.process().next_id()
+        self.parent = stack[-1].id if stack else None
+        self._stack = stack  # its thread's open spans: end() leaves these
+        stack.append(self)
+        self._ann = jax.profiler.TraceAnnotation(self.name)
         self._ann.__enter__()
+        self.t0 = time.perf_counter()
 
     def end(self):
-        if self._ann is not None:
-            self._ann.__exit__(None, None, None)
-            self._ann = None
+        if self._ann is None:
+            return
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(None, None, None)
+        self._ann = None
+        stack = self._stack
+        if stack[-1] is self:
+            stack.pop()
+        else:  # ended out of order, or on another thread than it began on
+            stack.remove(self)
+        TraceRecorder.process().span(self.name, self.t0, self.t1, self.id,
+                                     self.parent, self.args)
 
     def __enter__(self):
         self.begin()
@@ -104,6 +156,107 @@ class RecordEvent:
     def __exit__(self, *exc):
         self.end()
         return False
+
+
+_STAGE_OF = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+_compile_counters = None
+
+
+def _step_span():
+    """The outermost open span on this thread that its caller marked as
+    a step, and its kind: what a compile event is charged to."""
+    for span in getattr(_open, "stack", ()):
+        if span.step_kind is not None:
+            return span, span.step_kind
+    return None, "none"
+
+
+def _bump(span, key, amount=1):
+    if span is not None:
+        span.args[key] = span.args.get(key, 0) + amount
+
+
+def count_compile_events(registry=None):
+    """Listen to ``jax.monitoring`` (once per process; the first engine
+    or train step built calls this, ``import paddle_tpu`` does not) and
+    count JAX's compile events into the process's registry
+    (``MetricsRegistry.process()``), labelled by the outermost span open
+    on the calling thread that was given a ``step_kind`` (``step`` =
+    that kind, as the engine and the trainer name theirs: mixed | decode
+    | spec_round | train; ``none`` outside any):
+
+    - ``jax_compile_requests_total{step}``: executables JAX asked its
+      backend for (each ends in a persistent-cache load or a compile;
+      the in-memory jit cache never gets this far),
+    - ``jax_compile_cache_hits_total{step}`` /
+      ``jax_compile_cache_misses_total{step}``: the persistent cache's,
+    - ``jax_compile_seconds_total{stage,step}``: ``stage`` = trace |
+      lower | backend | cache_load, disjoint: JAX's backend-compile
+      event spans the cache lookup too, so a request's ``cache_load``
+      seconds are taken off its ``backend`` seconds here.
+
+    Each event is also added to that step span's own row
+    (``compile_requests``, ``compile_cache_hits``,
+    ``compile_cache_misses``, ``compile_<stage>_s``). With ``registry``
+    the same counters are shown by it too (an engine's ``/metrics``)."""
+    global _compile_counters
+    if _compile_counters is None:
+        proc = MetricsRegistry.process()
+        requests = proc.counter(
+            "jax_compile_requests_total",
+            "executables JAX asked for (cache load or backend compile), "
+            "by the step that caused them")
+        hits = proc.counter(
+            "jax_compile_cache_hits_total",
+            "persistent compile cache hits, by step")
+        misses = proc.counter(
+            "jax_compile_cache_misses_total",
+            "persistent compile cache misses, by step")
+        seconds = proc.counter(
+            "jax_compile_seconds_total",
+            "seconds JAX spent tracing, lowering, compiling and loading "
+            "from the cache, by stage and step")
+
+        def on_event(event, **_):
+            if event == "/jax/compilation_cache/cache_hits":
+                span, step = _step_span()
+                hits.inc(step=step)
+                _bump(span, "compile_cache_hits")
+            elif event == "/jax/compilation_cache/cache_misses":
+                span, step = _step_span()
+                misses.inc(step=step)
+                _bump(span, "compile_cache_misses")
+
+        def on_duration(event, secs, **_):
+            stage = _STAGE_OF.get(event)
+            if stage is None:
+                return
+            span, step = _step_span()
+            if stage == "cache_load":
+                # raised inside the backend event that follows on this
+                # thread: remembered until then
+                _open.cache_load = secs
+            elif stage == "backend":
+                requests.inc(step=step)
+                _bump(span, "compile_requests")
+                secs -= getattr(_open, "cache_load", 0.0)
+                _open.cache_load = 0.0
+            secs = max(secs, 0.0)
+            seconds.inc(secs, stage=stage, step=step)
+            _bump(span, f"compile_{stage}_s", secs)
+
+        jax.monitoring.register_event_listener(on_event)
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        _compile_counters = (requests, hits, misses, seconds)
+    if registry is not None:
+        for c in _compile_counters:
+            registry.share(c)
+    return _compile_counters
 
 
 class Profiler:

@@ -107,6 +107,7 @@ from ..obs.serving import ServingObs
 from ..obs.slo import SLOSet
 from ..parallel import mesh as mesh_state
 from ..parallel.mesh import MeshScope
+from ..profiler import RecordEvent, count_compile_events
 from .faults import FaultInjector, InjectedFault
 from .resilience import QuantumWatchdog, ResiliencePolicy
 from .scheduler import Request, Scheduler, SchedulerConfig
@@ -1050,7 +1051,10 @@ class ServingEngine:
             if trace and self.obs.tracer is None:
                 from ..obs.trace import TraceRecorder
 
-                self.obs.tracer = TraceRecorder()
+                self.obs.tracer = TraceRecorder.process()
+        # JAX's compile events, charged to the step span that caused
+        # them (profiler.count_compile_events), on this registry too
+        count_compile_events(self.obs.registry)
         self._now = self.obs.now
         self.stats = self.obs.legacy_stats_view()
         # static per-build collective profile -> registry gauges (zeros
@@ -1222,34 +1226,37 @@ class ServingEngine:
         the host is free to run OTHER work — the cluster front door
         dispatches every replica before collecting any, and a single
         engine's ``step()`` is exactly ``step_collect(step_dispatch())``
-        (same ordering, same fault boundaries, bit-identical streams)."""
-        self.stats["steps"] += 1
-        if self.resilience is not None:
-            self._audit_pools()
-        if self.faults.armed:
-            self.faults.maybe_corrupt(self.pool)
-        pending = None
-        try:
-            self._admit()
-            live = self.scheduler.live()
-            self.stats["occupancy_sum"] += (
-                len(live) / self.config.num_slots)
-            self.obs.on_step(self._now(), len(live),
-                             self.config.num_slots, self.pool,
-                             self.d_pool)
-            if self.scheduler.prefilling():
-                self._mixed_step()
-            elif self.scheduler.decoding():
-                pending = self._decode_dispatch()
-        except InjectedFault as e:
-            self._contain_fault(e)
-        finally:
-            if pending is None:
-                # the step ran to completion (or contained a fault)
-                # inside this half — close the fault boundary here
-                self._sync_faults()
-                self._sync_prefix_quarantines()
-        return pending
+        (same ordering, same fault boundaries, bit-identical streams).
+        Each half is its own ``engine.step`` span (``half=dispatch`` |
+        ``collect``), so none stays open across another engine's work."""
+        with RecordEvent("engine.step", half="dispatch"):
+            self.stats["steps"] += 1
+            if self.resilience is not None:
+                self._audit_pools()
+            if self.faults.armed:
+                self.faults.maybe_corrupt(self.pool)
+            pending = None
+            try:
+                self._admit()
+                live = self.scheduler.live()
+                self.stats["occupancy_sum"] += (
+                    len(live) / self.config.num_slots)
+                self.obs.on_step(self._now(), len(live),
+                                 self.config.num_slots, self.pool,
+                                 self.d_pool)
+                if self.scheduler.prefilling():
+                    self._mixed_step()
+                elif self.scheduler.decoding():
+                    pending = self._decode_dispatch()
+            except InjectedFault as e:
+                self._contain_fault(e)
+            finally:
+                if pending is None:
+                    # the step ran to completion (or contained a fault)
+                    # inside this half — close the fault boundary here
+                    self._sync_faults()
+                    self._sync_prefix_quarantines()
+            return pending
 
     def step_collect(self, pending):
         """COLLECT HALF of :meth:`step`: force the pending dispatch's
@@ -1258,14 +1265,15 @@ class ServingEngine:
         :meth:`step_dispatch`) just reports whether work remains."""
         if pending is None:
             return self.scheduler.has_work
-        try:
-            self._decode_collect(pending)
-        except InjectedFault as e:
-            self._contain_fault(e)
-        finally:
-            self._sync_faults()
-            self._sync_prefix_quarantines()
-        return self.scheduler.has_work
+        with RecordEvent("engine.step", half="collect"):
+            try:
+                self._decode_collect(pending)
+            except InjectedFault as e:
+                self._contain_fault(e)
+            finally:
+                self._sync_faults()
+                self._sync_prefix_quarantines()
+            return self.scheduler.has_work
 
     def run(self, requests=None):
         """Submit ``requests`` (if given) and drive until idle; returns
@@ -1832,23 +1840,24 @@ class ServingEngine:
             hidden = core.embed_tokens(
                 paddle.to_tensor(ids[None, :]))          # (1, T, E)
             for i, layer in enumerate(core.layers):
-                attn = layer.self_attn
-                residual = hidden
-                x = layer.input_layernorm(hidden)
-                q = attn.q_proj(x)
-                k = attn.k_proj(x)
-                v = attn.v_proj(x)
-                qkv = paddle.concat([q, k, v], axis=-1) \
-                    .reshape([total, (h + 2 * hk) * d])
-                scales = ({} if ks_t is None else
-                          dict(cache_k_scale_pool=ks_t[i],
-                               cache_v_scale_pool=vs_t[i]))
-                att = block_multihead_attention(
-                    qkv, kc_t[i], vc_t[i], **common, **scales)
-                att3 = att.reshape([1, total, h * d])
-                hidden = residual + attn.o_proj(att3)
-                hidden = hidden + layer.mlp(
-                    layer.post_attention_layernorm(hidden))
+                with RecordEvent("engine.mixed.layer", layer=i):
+                    attn = layer.self_attn
+                    residual = hidden
+                    x = layer.input_layernorm(hidden)
+                    q = attn.q_proj(x)
+                    k = attn.k_proj(x)
+                    v = attn.v_proj(x)
+                    qkv = paddle.concat([q, k, v], axis=-1) \
+                        .reshape([total, (h + 2 * hk) * d])
+                    scales = ({} if ks_t is None else
+                              dict(cache_k_scale_pool=ks_t[i],
+                                   cache_v_scale_pool=vs_t[i]))
+                    att = block_multihead_attention(
+                        qkv, kc_t[i], vc_t[i], **common, **scales)
+                    att3 = att.reshape([1, total, h * d])
+                    hidden = residual + attn.o_proj(att3)
+                    hidden = hidden + layer.mlp(
+                        layer.post_attention_layernorm(hidden))
             hidden = core.norm(hidden)
         # the mutated pool Tensors are the new truth (re-pinned to the
         # pool's mesh layout under tp — the quantum donates them and
@@ -1868,147 +1877,165 @@ class ServingEngine:
         interleaved with decode, the reference's serving batch shape).
         The speculative arm pushes the SAME batch through the draft
         model into the draft pool (token selection stays the target's;
-        the draft forward exists only for its KV writes)."""
-        model = self.model
-        t0 = self._now()
-        self.stats["mixed_steps"] += 1
-        chunk = self.config.prefill_chunk
-        pre = self.scheduler.prefilling()
-        dec = self.scheduler.decoding()
-        rows = pre + dec
-        spec = self.spec_draft is not None and not self._spec_disabled
-        # the mixed step's fault boundary: BEFORE any pool mutation, so
-        # a raised step retries cleanly from the next step()
-        self.faults.before_dispatch("mixed", [r.req_id for r in rows])
-        toks, this_time, enc_lens, dec_lens = [], [], [], []
-        # cost-ledger work split: a resumed row's chunk re-computes KV
-        # a preemption dropped (recompute debt); a fresh row's chunk is
-        # novel prefill work (obs/attribution.py)
-        novel_toks = recompute_toks = 0
-        for req in pre:
-            n = min(chunk, req.prefill_target - req.prefill_pos)
-            if req.preemptions > 0:
-                recompute_toks += n
-            else:
-                novel_toks += n
-            toks.append(
-                req.prefill_src[req.prefill_pos:req.prefill_pos + n])
-            this_time.append(n)
-            enc_lens.append(n)
-            dec_lens.append(req.prefill_pos)
-            self.pool.ensure(req.req_id, req.prefill_pos + n)
-            if spec:
-                self.d_pool.ensure(req.req_id, req.prefill_pos + n)
-            if self.prefix_cache:
-                # copy-on-write before the forward: the chunk's KV
-                # writes must never land in a block another holder
-                # (sequence or prefix index) still maps
-                self.pool.make_writable(req.req_id, req.prefill_pos,
-                                        req.prefill_pos + n)
-                if spec:
-                    self.d_pool.make_writable(
-                        req.req_id, req.prefill_pos,
-                        req.prefill_pos + n)
-        for req in dec:
-            slot = req.slot
-            toks.append(np.asarray([self._last_tok[slot]], np.int32))
-            this_time.append(1)
-            enc_lens.append(0)
-            dec_lens.append(int(self._seq_lens[slot]))
-            self.pool.ensure(req.req_id, int(self._seq_lens[slot]) + 1)
-            if spec:
-                self.d_pool.ensure(req.req_id,
-                                   int(self._seq_lens[slot]) + 1)
-            if self.prefix_cache:
-                seq = int(self._seq_lens[slot])
-                self.pool.make_writable(req.req_id, seq, seq + 1)
-                if spec:
-                    self.d_pool.make_writable(req.req_id, seq, seq + 1)
-        ids = np.concatenate(toks).astype(np.int32)
-        total = int(ids.shape[0])
-        self.stats["prefill_tokens"] += int(sum(enc_lens))
-        cu = np.concatenate([[0], np.cumsum(this_time)]).astype(np.int32)
-        row_ids = [r.req_id for r in rows]
-        tables = self.pool.block_table_array(
-            row_ids, pad_to=self._table_width)
-        hidden = self._mixed_forward(
-            model, self.pool, tables, self._rotary, enc_lens, dec_lens,
-            this_time, ids, total)
-        if spec:
-            d_tables = self.d_pool.block_table_array(
-                row_ids, pad_to=self._table_width)
-            self._mixed_forward(
-                self.spec_draft, self.d_pool, d_tables, self._d_rotary,
-                enc_lens, dec_lens, this_time, ids, total)
+        the draft forward exists only for its KV writes).
 
-        # logits only where a next token is due: rows completing their
-        # prefill this chunk, and every decode row
-        need = [i for i, req in enumerate(rows)
-                if (i >= len(pre)) or
-                (req.prefill_pos + this_time[i] >= req.prefill_target)]
-        if need:
-            last_idx = np.asarray([cu[i + 1] - 1 for i in need], np.int32)
-            scope = (MeshScope(self.mesh) if self.mesh is not None
-                     else contextlib.nullcontext())
-            with scope, autograd.no_grad():
-                hs = Tensor(hidden._value[0, last_idx],
-                            stop_gradient=True)
-                logits = model.lm_head(hs)._value        # (R, V)
-            nxt = self._select_host(logits,
-                                    [rows[i] for i in need])
-        now = self._now()
-        emitted = prefill_emitted = 0
-        for i, req in enumerate(rows):
-            slot = req.slot
-            if i < len(pre):
-                req.prefill_pos += this_time[i]
-                self._seq_lens[slot] = req.prefill_pos
-                if self.flight is not None:
-                    self.flight.on_prefill_chunk(
-                        req, now, this_time[i], req.prefill_pos)
-                if req.prefill_pos >= req.prefill_target:
+        The spans are inline and five single-use temporaries of this
+        body went (``model``, ``chunk``, ``d_tables``, ``scope``,
+        ``hs``) so that the frames from ``door.pump`` down to
+        ``_mixed_forward`` take as many words of Python's data stack as
+        before the spans: the eager forward's time swings by a third
+        with where the stack's 16 KiB chunks end under it (``PERF.md``
+        section 6, PR 26; ``tests/test_program_spans.py`` holds the
+        sum)."""
+        with RecordEvent("engine.mixed", step_kind="mixed",
+                         step=self.stats["steps"]) as span:
+            self.stats["mixed_steps"] += 1
+            pre = self.scheduler.prefilling()
+            dec = self.scheduler.decoding()
+            rows = pre + dec
+            spec = self.spec_draft is not None and not self._spec_disabled
+            # the mixed step's fault boundary: BEFORE any pool mutation, so
+            # a raised step retries cleanly from the next step()
+            self.faults.before_dispatch("mixed", [r.req_id for r in rows])
+            with RecordEvent("engine.mixed.prepare"):
+                toks, this_time, enc_lens, dec_lens = [], [], [], []
+                # cost-ledger work split: a resumed row's chunk re-computes
+                # KV a preemption dropped (recompute debt); a fresh row's
+                # chunk is novel prefill work (obs/attribution.py)
+                novel_toks = recompute_toks = 0
+                for req in pre:
+                    n = min(self.config.prefill_chunk,
+                            req.prefill_target - req.prefill_pos)
+                    if req.preemptions > 0:
+                        recompute_toks += n
+                    else:
+                        novel_toks += n
+                    toks.append(
+                        req.prefill_src[req.prefill_pos:req.prefill_pos + n])
+                    this_time.append(n)
+                    enc_lens.append(n)
+                    dec_lens.append(req.prefill_pos)
+                    self.pool.ensure(req.req_id, req.prefill_pos + n)
+                    if spec:
+                        self.d_pool.ensure(req.req_id, req.prefill_pos + n)
                     if self.prefix_cache:
-                        # the whole prefill source is in the pool now:
-                        # publish its full blocks into the prefix index
-                        # (both pools — lockstep) so the next request
-                        # with this prefix aliases instead of computing
-                        self.pool.publish_prefix(req.req_id,
-                                                 req.prefill_src)
+                        # copy-on-write before the forward: the chunk's KV
+                        # writes must never land in a block another holder
+                        # (sequence or prefix index) still maps
+                        self.pool.make_writable(req.req_id, req.prefill_pos,
+                                                req.prefill_pos + n)
                         if spec:
-                            self.d_pool.publish_prefix(
-                                req.req_id, req.prefill_src)
-                        self.scheduler.clear_cow_debt(req)
-                    tok = int(nxt[need.index(i)])
-                    if req.first_token_time is None:
-                        # TTFT observes exactly ONCE per request — a
-                        # resumed request's re-prefill completion emits
-                        # a continuation token, not a first token
-                        req.first_token_time = now
-                        self.obs.on_first_token(req, now)
+                            self.d_pool.make_writable(
+                                req.req_id, req.prefill_pos,
+                                req.prefill_pos + n)
+                for req in dec:
+                    slot = req.slot
+                    toks.append(np.asarray([self._last_tok[slot]], np.int32))
+                    this_time.append(1)
+                    enc_lens.append(0)
+                    dec_lens.append(int(self._seq_lens[slot]))
+                    self.pool.ensure(req.req_id, int(self._seq_lens[slot]) + 1)
+                    if spec:
+                        self.d_pool.ensure(req.req_id,
+                                           int(self._seq_lens[slot]) + 1)
+                    if self.prefix_cache:
+                        seq = int(self._seq_lens[slot])
+                        self.pool.make_writable(req.req_id, seq, seq + 1)
+                        if spec:
+                            self.d_pool.make_writable(req.req_id, seq, seq + 1)
+                ids = np.concatenate(toks).astype(np.int32)
+                total = int(ids.shape[0])
+                self.stats["prefill_tokens"] += int(sum(enc_lens))
+                cu = np.concatenate(
+                    [[0], np.cumsum(this_time)]).astype(np.int32)
+                row_ids = [r.req_id for r in rows]
+                tables = self.pool.block_table_array(
+                    row_ids, pad_to=self._table_width)
+            span.args.update(rows=len(rows),
+                             prefill_tokens=int(sum(enc_lens)))
+            with RecordEvent("engine.mixed.forward", model="target"):
+                hidden = self._mixed_forward(
+                    self.model, self.pool, tables, self._rotary, enc_lens,
+                    dec_lens, this_time, ids, total)
+            if spec:
+                with RecordEvent("engine.mixed.forward", model="draft"):
+                    self._mixed_forward(
+                        self.spec_draft, self.d_pool,
+                        self.d_pool.block_table_array(
+                            row_ids, pad_to=self._table_width),
+                        self._d_rotary, enc_lens, dec_lens, this_time, ids,
+                        total)
+            with RecordEvent("engine.mixed.select"):
+                # logits only where a next token is due: rows completing
+                # their prefill this chunk, and every decode row
+                need = [i for i, req in enumerate(rows)
+                        if (i >= len(pre)) or
+                        (req.prefill_pos + this_time[i]
+                         >= req.prefill_target)]
+                if need:
+                    last_idx = np.asarray(
+                        [cu[i + 1] - 1 for i in need], np.int32)
+                    with (MeshScope(self.mesh) if self.mesh is not None
+                          else contextlib.nullcontext()), autograd.no_grad():
+                        logits = self.model.lm_head(Tensor(
+                            hidden._value[0, last_idx],
+                            stop_gradient=True))._value      # (R, V)
+                    nxt = self._select_host(logits,
+                                            [rows[i] for i in need])
+            now = self._now()  # the stamp of every token of the step
+            with RecordEvent("engine.mixed.emit"):
+                emitted = prefill_emitted = 0
+                for i, req in enumerate(rows):
+                    slot = req.slot
+                    if i < len(pre):
+                        req.prefill_pos += this_time[i]
+                        self._seq_lens[slot] = req.prefill_pos
                         if self.flight is not None:
-                            self.flight.on_first_token(
-                                req, now, now - req.arrival_time)
-                    self._emit(req, tok)
-                    emitted += 1
-                    prefill_emitted += 1
-                    self._record_host(slot, req, tok)
-            else:
-                tok = int(nxt[need.index(i)])
-                self._seq_lens[slot] += 1  # last_tok entered the cache
-                self._emit(req, tok)
-                emitted += 1
-                self._record_host(slot, req, tok)
-        self.obs.on_quantum(
-            "mixed", t0, now, emitted, len(rows),
-            breakdown={"prefill_emitted": prefill_emitted,
-                       "decode_emitted": emitted - prefill_emitted,
-                       "novel_tokens": novel_toks,
-                       "recompute_tokens": recompute_toks,
-                       "decode_rows": len(dec)})
-        if self.watchdog is not None and self.watchdog.check(
-                "mixed", now - t0):
-            self.obs.on_watchdog("mixed", now - t0)
-        self._retire_finished()
+                            self.flight.on_prefill_chunk(
+                                req, now, this_time[i], req.prefill_pos)
+                        if req.prefill_pos >= req.prefill_target:
+                            if self.prefix_cache:
+                                # the whole prefill source is in the pool now:
+                                # publish its full blocks into the prefix index
+                                # (both pools — lockstep) so the next request
+                                # with this prefix aliases instead of computing
+                                self.pool.publish_prefix(req.req_id,
+                                                         req.prefill_src)
+                                if spec:
+                                    self.d_pool.publish_prefix(
+                                        req.req_id, req.prefill_src)
+                                self.scheduler.clear_cow_debt(req)
+                            tok = int(nxt[need.index(i)])
+                            if req.first_token_time is None:
+                                # TTFT observes exactly ONCE per request — a
+                                # resumed request's re-prefill completion emits
+                                # a continuation token, not a first token
+                                req.first_token_time = now
+                                self.obs.on_first_token(req, now)
+                                if self.flight is not None:
+                                    self.flight.on_first_token(
+                                        req, now, now - req.arrival_time)
+                            self._emit(req, tok)
+                            emitted += 1
+                            prefill_emitted += 1
+                            self._record_host(slot, req, tok)
+                    else:
+                        tok = int(nxt[need.index(i)])
+                        self._seq_lens[slot] += 1  # last_tok entered the cache
+                        self._emit(req, tok)
+                        emitted += 1
+                        self._record_host(slot, req, tok)
+                breakdown = {"prefill_emitted": prefill_emitted,
+                             "decode_emitted": emitted - prefill_emitted,
+                             "novel_tokens": novel_toks,
+                             "recompute_tokens": recompute_toks,
+                             "decode_rows": len(dec)}
+                self.obs.on_quantum("mixed", span.t0, now, emitted,
+                                    len(rows), breakdown=breakdown)
+            if self.watchdog is not None and self.watchdog.check(
+                    "mixed", now - span.t0):
+                self.obs.on_watchdog("mixed", now - span.t0)
+            self._retire_finished()
 
     def _emit(self, req, tok):
         """Append ONE generated token to a request's stream (retirement
@@ -2192,35 +2219,38 @@ class ServingEngine:
         return jax.device_put(v, self._rep_sharding)
 
     def _quantum_args(self):
-        # the scale tuples ride right after their pool's v_pools (empty
-        # on a float engine — no avals, goldens untouched); donation
-        # covers all leading pool pytrees
-        if self.spec_draft is not None and not self._spec_disabled:
-            return (list(self.pool.k_pools), list(self.pool.v_pools),
-                    tuple(self.pool.k_scales),
-                    tuple(self.pool.v_scales),
-                    list(self.d_pool.k_pools),
-                    list(self.d_pool.v_pools),
-                    tuple(self.d_pool.k_scales),
-                    tuple(self.d_pool.v_scales),
-                    self._p_vals, self._d_p_vals,
-                    self._dev(self._tables),
-                    self._dev(self._d_tables),
+        """The quantum's argument tuple; its uploads (the ``_dev``
+        calls) are the span ``engine.decode.args``."""
+        with RecordEvent("engine.decode.args"):
+            # the scale tuples ride right after their pool's v_pools (empty
+            # on a float engine — no avals, goldens untouched); donation
+            # covers all leading pool pytrees
+            if self.spec_draft is not None and not self._spec_disabled:
+                return (list(self.pool.k_pools), list(self.pool.v_pools),
+                        tuple(self.pool.k_scales),
+                        tuple(self.pool.v_scales),
+                        list(self.d_pool.k_pools),
+                        list(self.d_pool.v_pools),
+                        tuple(self.d_pool.k_scales),
+                        tuple(self.d_pool.v_scales),
+                        self._p_vals, self._d_p_vals,
+                        self._dev(self._tables),
+                        self._dev(self._d_tables),
+                        self._dev(self._seq_lens),
+                        self._dev(self._last_tok),
+                        self._dev(self._n_gen), self._dev(self._done),
+                        self._dev(self._max_new),
+                        self._dev(self._keys))
+            args = (list(self.pool.k_pools), list(self.pool.v_pools),
+                    tuple(self.pool.k_scales), tuple(self.pool.v_scales),
+                    self._p_vals, self._dev(self._tables),
                     self._dev(self._seq_lens),
-                    self._dev(self._last_tok),
-                    self._dev(self._n_gen), self._dev(self._done),
-                    self._dev(self._max_new),
+                    self._dev(self._last_tok), self._dev(self._n_gen),
+                    self._dev(self._done), self._dev(self._max_new),
                     self._dev(self._keys))
-        args = (list(self.pool.k_pools), list(self.pool.v_pools),
-                tuple(self.pool.k_scales), tuple(self.pool.v_scales),
-                self._p_vals, self._dev(self._tables),
-                self._dev(self._seq_lens),
-                self._dev(self._last_tok), self._dev(self._n_gen),
-                self._dev(self._done), self._dev(self._max_new),
-                self._dev(self._keys))
-        if self._per_request_sampling:
-            args = args + (self._dev(self._temps),)
-        return args
+            if self._per_request_sampling:
+                args = args + (self._dev(self._temps),)
+            return args
 
     def _dispatch_quantum(self, quanta=1):
         """Run ONE quantum dispatch. Single chip: the jitted callable,
@@ -2253,85 +2283,88 @@ class ServingEngine:
         subset of the decoding rows (the bisect-quarantine probe path):
         excluded rows ride along done-masked — inert through the
         dispatch — and their host state is restored afterwards."""
-        g = self.spec_gamma
-        t0 = self._now()
-        self.stats["spec_rounds"] += 1
-        rows = self.scheduler.decoding()
-        excluded = []
-        if include is not None:
-            keep = {id(r) for r in include}
-            excluded = [r for r in rows if id(r) not in keep]
-            rows = [r for r in rows if id(r) in keep]
+        with RecordEvent("engine.spec_round", step_kind="spec_round",
+                         step=self.stats["steps"]) as span:
+            g = self.spec_gamma
+            t0 = span.t0
+            self.stats["spec_rounds"] += 1
+            rows = self.scheduler.decoding()
+            excluded = []
+            if include is not None:
+                keep = {id(r) for r in include}
+                excluded = [r for r in rows if id(r) not in keep]
+                rows = [r for r in rows if id(r) in keep]
+                for r in excluded:
+                    self._done[r.slot] = True
+            try:
+                for req in rows:
+                    slot = req.slot
+                    # cover the round's worst-case writes (γ proposals past
+                    # the accepted history) in BOTH pools before entering
+                    # the device loop — tables are static inside
+                    need = int(self._seq_lens[slot]) + g + 1
+                    for pool, tables in ((self.pool, self._tables),
+                                         (self.d_pool, self._d_tables)):
+                        if need > pool.seq_len(req.req_id):
+                            pool.ensure(req.req_id, need)
+                        if self.prefix_cache:
+                            pool.make_writable(
+                                req.req_id, int(self._seq_lens[slot]), need)
+                        row = pool.block_table_array(
+                            [req.req_id], pad_to=self._table_width)
+                        tables[slot] = np.asarray(row)[0][
+                            :self._table_width]
+                (t_kc, t_vc, t_ks, t_vs, d_kc, d_vc, d_ks, d_vs, seq_lens,
+                 last_tok, n_gen, done, stream, counts,
+                 acc) = self._guarded_dispatch("spec_round", rows)
+            except BaseException:
+                for r in excluded:
+                    self._done[r.slot] = r.finished
+                raise
+            self.pool.k_pools = list(t_kc)
+            self.pool.v_pools = list(t_vc)
+            self.d_pool.k_pools = list(d_kc)
+            self.d_pool.v_pools = list(d_vc)
+            if self.pool.quantized:
+                self.pool.k_scales = list(t_ks)
+                self.pool.v_scales = list(t_vs)
+                self.d_pool.k_scales = list(d_ks)
+                self.d_pool.v_scales = list(d_vs)
+            stream = np.asarray(stream)                      # (S, γ+1) sync
+            counts = np.asarray(counts)
+            acc = np.asarray(acc)
+            self._seq_lens = np.asarray(seq_lens).copy()
+            self._last_tok = np.asarray(last_tok).copy()
+            self._n_gen = np.asarray(n_gen).copy()
+            self._done = np.asarray(done).copy()
             for r in excluded:
-                self._done[r.slot] = True
-        try:
+                # a masked row's device state carried through unchanged;
+                # only its done flag was forced — restore the host truth
+                self._done[r.slot] = r.finished
+            span.args["rows"] = len(rows)
+            self.stats["quantum_tokens"] += int(counts.sum())
+            self.stats["spec_proposed"] += g * len(rows)
+            self.stats["spec_accepted"] += int(acc.sum())
+            now = self._now()
+            emitted = 0
             for req in rows:
                 slot = req.slot
-                # cover the round's worst-case writes (γ proposals past
-                # the accepted history) in BOTH pools before entering
-                # the device loop — tables are static inside
-                need = int(self._seq_lens[slot]) + g + 1
-                for pool, tables in ((self.pool, self._tables),
-                                     (self.d_pool, self._d_tables)):
-                    if need > pool.seq_len(req.req_id):
-                        pool.ensure(req.req_id, need)
-                    if self.prefix_cache:
-                        pool.make_writable(
-                            req.req_id, int(self._seq_lens[slot]), need)
-                    row = pool.block_table_array(
-                        [req.req_id], pad_to=self._table_width)
-                    tables[slot] = np.asarray(row)[0][
-                        :self._table_width]
-            (t_kc, t_vc, t_ks, t_vs, d_kc, d_vc, d_ks, d_vs, seq_lens,
-             last_tok, n_gen, done, stream, counts,
-             acc) = self._guarded_dispatch("spec_round", rows)
-        except BaseException:
-            for r in excluded:
-                self._done[r.slot] = r.finished
-            raise
-        self.pool.k_pools = list(t_kc)
-        self.pool.v_pools = list(t_vc)
-        self.d_pool.k_pools = list(d_kc)
-        self.d_pool.v_pools = list(d_vc)
-        if self.pool.quantized:
-            self.pool.k_scales = list(t_ks)
-            self.pool.v_scales = list(t_vs)
-            self.d_pool.k_scales = list(d_ks)
-            self.d_pool.v_scales = list(d_vs)
-        stream = np.asarray(stream)                      # (S, γ+1) sync
-        counts = np.asarray(counts)
-        acc = np.asarray(acc)
-        self._seq_lens = np.asarray(seq_lens).copy()
-        self._last_tok = np.asarray(last_tok).copy()
-        self._n_gen = np.asarray(n_gen).copy()
-        self._done = np.asarray(done).copy()
-        for r in excluded:
-            # a masked row's device state carried through unchanged;
-            # only its done flag was forced — restore the host truth
-            self._done[r.slot] = r.finished
-        self.stats["quantum_tokens"] += int(counts.sum())
-        self.stats["spec_proposed"] += g * len(rows)
-        self.stats["spec_accepted"] += int(acc.sum())
-        now = self._now()
-        emitted = 0
-        for req in rows:
-            slot = req.slot
-            got = 0
-            for k in range(int(counts[slot])):
+                got = 0
+                for k in range(int(counts[slot])):
+                    if req.finished:
+                        break
+                    self._emit(req, int(stream[slot, k]))
+                    emitted += 1
+                    got += 1
+                if self.flight is not None:
+                    self.flight.on_spec_round(
+                        req, now, proposed=g, accepted=int(acc[slot]),
+                        emitted=got)
                 if req.finished:
-                    break
-                self._emit(req, int(stream[slot, k]))
-                emitted += 1
-                got += 1
-            if self.flight is not None:
-                self.flight.on_spec_round(
-                    req, now, proposed=g, accepted=int(acc[slot]),
-                    emitted=got)
-            if req.finished:
-                req.finish_time = now
-        self.obs.on_quantum("spec_round", t0, now, emitted, len(rows))
-        self.obs.on_spec_round(now, g * len(rows), int(acc.sum()))
-        self._retire_finished()
+                    req.finish_time = now
+            self.obs.on_quantum("spec_round", t0, now, emitted, len(rows))
+            self.obs.on_spec_round(now, g * len(rows), int(acc.sum()))
+            self._retire_finished()
 
     def _choose_k(self):
         """How many decode quanta the NEXT dispatch may run on-device.
@@ -2368,56 +2401,70 @@ class ServingEngine:
         ride along done-masked — inert through the dispatch — and
         their host state is restored at collect. A speculative round
         (host needs its acceptance counts to proceed) runs to
-        completion here and returns None."""
+        completion here and returns None.
+
+        As in ``_mixed_step``, the frames from ``door.pump`` down to
+        the jitted call take the words of data stack they took before
+        the spans (the quantum's outputs are adopted through one starred
+        name for that): the quantum's first trace, 11 s of a run's
+        set-up, is as sensitive to it as the eager forward."""
         if self.spec_draft is not None and not self._spec_disabled:
             self._spec_round_step(include=include)
             return None
-        t0 = self._now()
-        t_steps = self.config.decode_quantum
-        k = 1 if include is not None else self._choose_k()
-        rows = self.scheduler.decoding()
-        excluded = []
-        if include is not None:
-            keep = {id(r) for r in include}
-            excluded = [r for r in rows if id(r) not in keep]
-            rows = [r for r in rows if id(r) in keep]
-            for r in excluded:
-                self._done[r.slot] = True
-        try:
-            # grow each live slot's block table to cover the whole
-            # dispatch (K quanta) before entering the device loop
-            # (tables static inside); capped by the request's own
-            # prompt+max_new bound, which admission already reserved —
-            # K-wide growth can never oversubscribe the pool
-            for req in rows:
-                slot = req.slot
-                cap = req.prompt_len + req.max_new_tokens - 1
-                need = min(int(self._seq_lens[slot]) + k * t_steps, cap)
-                row = self.pool.grow_decode_table(
-                    req.req_id, need, int(self._seq_lens[slot]),
-                    pad_to=self._table_width, cow=self.prefix_cache)
-                self._tables[slot] = row[:self._table_width]
-            out = self._guarded_dispatch("decode", rows, quanta=k)
-        except BaseException:
-            for r in excluded:
-                self._done[r.slot] = r.finished
-            raise
-        if k > 1:
-            (kc, vc, ks, vs, seq_lens, last_tok, n_gen, done, toks,
-             nq) = out
-        else:
-            kc, vc, ks, vs, seq_lens, last_tok, n_gen, done, toks = out
-            nq = None
-        # adopt the donated pool outputs NOW (async handles — no sync):
-        # the pre-dispatch buffers were consumed by donation
-        self.pool.k_pools = list(kc)
-        self.pool.v_pools = list(vc)
-        if self.pool.quantized:
-            self.pool.k_scales = list(ks)
-            self.pool.v_scales = list(vs)
-        return {"rows": rows, "excluded": excluded, "t0": t0,
-                "t_disp": self._now(), "k": k,
-                "out": (seq_lens, last_tok, n_gen, done, toks, nq)}
+        with RecordEvent("engine.decode", step_kind="decode",
+                         step=self.stats["steps"],
+                         half="dispatch") as span:
+            t_steps = self.config.decode_quantum
+            k = 1 if include is not None else self._choose_k()
+            rows = self.scheduler.decoding()
+            excluded = []
+            if include is not None:
+                keep = {id(r) for r in include}
+                excluded = [r for r in rows if id(r) not in keep]
+                rows = [r for r in rows if id(r) in keep]
+                for r in excluded:
+                    self._done[r.slot] = True
+            span.args.update(rows=len(rows), k=k)
+            try:
+                # grow each live slot's block table to cover the whole
+                # dispatch (K quanta) before entering the device loop
+                # (tables static inside); capped by the request's own
+                # prompt+max_new bound, which admission already reserved
+                # — K-wide growth can never oversubscribe the pool
+                with RecordEvent("engine.decode.prepare"):
+                    for req in rows:
+                        slot = req.slot
+                        cap = req.prompt_len + req.max_new_tokens - 1
+                        need = min(
+                            int(self._seq_lens[slot]) + k * t_steps, cap)
+                        row = self.pool.grow_decode_table(
+                            req.req_id, need, int(self._seq_lens[slot]),
+                            pad_to=self._table_width,
+                            cow=self.prefix_cache)
+                        self._tables[slot] = row[:self._table_width]
+                # the jitted call until it returns (its uploads, the
+                # span engine.decode.args, lie inside)
+                with RecordEvent("engine.decode.enqueue") as enqueue:
+                    kc, vc, ks, vs, *out = self._guarded_dispatch(
+                        "decode", rows, quanta=k)
+            except BaseException:
+                for r in excluded:
+                    self._done[r.slot] = r.finished
+                raise
+            # adopt the donated pool outputs NOW (async handles — no
+            # sync): the pre-dispatch buffers were consumed by donation
+            self.pool.k_pools = list(kc)
+            self.pool.v_pools = list(vc)
+            if self.pool.quantized:
+                self.pool.k_scales = list(ks)
+                self.pool.v_scales = list(vs)
+            # out: seq_lens, last_tok, n_gen, done, toks, and the count
+            # of quanta that ran where the dispatch was of several. The
+            # device's share of the wall starts where the call returned:
+            # the enqueue span's end
+            return {"rows": rows, "excluded": excluded, "t0": span.t0,
+                    "t_disp": enqueue.t1, "k": k,
+                    "out": (*out, None) if k == 1 else tuple(out)}
 
     def _decode_collect(self, pending):
         """COLLECT HALF of the decode step: force the device results
@@ -2427,58 +2474,64 @@ class ServingEngine:
         ledger, host-gap gauge — each sub-quantum gets an equal slice
         of the wall, so the conservation invariants partition exactly),
         and retire finished rows."""
-        rows, excluded = pending["rows"], pending["excluded"]
-        t0, k = pending["t0"], pending["k"]
-        seq_lens, last_tok, n_gen, done, toks, nq = pending["out"]
-        t_steps = self.config.decode_quantum
-        toks = np.asarray(toks)                          # sync
-        self._seq_lens = np.asarray(seq_lens).copy()
-        self._last_tok = np.asarray(last_tok).copy()
-        self._n_gen = np.asarray(n_gen).copy()
-        self._done = np.asarray(done).copy()
-        for r in excluded:
-            # a masked row's device state carried through unchanged;
-            # only its done flag was forced — restore the host truth
-            self._done[r.slot] = r.finished
-        if k > 1:
-            # (K, T, S) buffer + on-device loop counter: keep only the
-            # quanta that ran before the all-done early exit fired
-            n_exec = int(np.asarray(nq))
-            toks = toks[:n_exec].reshape(-1, toks.shape[2])
-            n_exec = max(n_exec, 1)
-        else:
-            n_exec = 1                                   # (T, S)
-        self.stats["decode_quanta"] += n_exec
-        self.stats["quantum_tokens"] += int(toks.shape[0]) * int(
-            toks.shape[1])
-        now = self._now()
-        device_s = max(now - pending["t_disp"], 0.0)
-        emitted_k = [0] * n_exec
-        for req in rows:
-            slot = req.slot
-            got = 0
-            for j in range(toks.shape[0]):
-                if req.finished:
-                    break
-                self._emit(req, int(toks[j, slot]))
-                emitted_k[j // t_steps] += 1
-                got += 1
-            if self.flight is not None and got:
-                self.flight.on_quantum_tokens(req, now, got)
-            if req.finished:
-                req.finish_time = now
-        # a K-quantum dispatch is K quanta to every seam downstream:
-        # the sub-intervals partition [t0, now] exactly (last edge IS
-        # `now`), so Σ phase seconds == histogram sums stays exact
-        dt = (now - t0) / n_exec
-        dev_dt = device_s / n_exec
-        prev = t0
-        for j in range(n_exec):
-            edge = now if j == n_exec - 1 else t0 + (j + 1) * dt
-            self.obs.on_quantum("decode", prev, edge, emitted_k[j],
-                                len(rows), device_s=dev_dt)
-            prev = edge
-        self._retire_finished()
+        with RecordEvent("engine.decode", step_kind="decode",
+                         step=self.stats["steps"], half="collect"):
+            rows, excluded = pending["rows"], pending["excluded"]
+            t0, k = pending["t0"], pending["k"]
+            seq_lens, last_tok, n_gen, done, toks, nq = pending["out"]
+            t_steps = self.config.decode_quantum
+            with RecordEvent("engine.decode.sync") as sync:
+                toks = np.asarray(toks)                      # sync
+                self._seq_lens = np.asarray(seq_lens).copy()
+                self._last_tok = np.asarray(last_tok).copy()
+                self._n_gen = np.asarray(n_gen).copy()
+                self._done = np.asarray(done).copy()
+            # the device's share of the wall: from the jitted call's return
+            # (the enqueue span's end) to the sync span's end
+            now = sync.t1
+            device_s = max(now - pending["t_disp"], 0.0)
+            with RecordEvent("engine.decode.emit"):
+                for r in excluded:
+                    # a masked row's device state carried through unchanged;
+                    # only its done flag was forced — restore the host truth
+                    self._done[r.slot] = r.finished
+                if k > 1:
+                    # (K, T, S) buffer + on-device loop counter: keep only
+                    # the quanta that ran before the all-done early exit
+                    n_exec = int(np.asarray(nq))
+                    toks = toks[:n_exec].reshape(-1, toks.shape[2])
+                    n_exec = max(n_exec, 1)
+                else:
+                    n_exec = 1                               # (T, S)
+                self.stats["decode_quanta"] += n_exec
+                self.stats["quantum_tokens"] += int(toks.shape[0]) * int(
+                    toks.shape[1])
+                emitted_k = [0] * n_exec
+                for req in rows:
+                    slot = req.slot
+                    got = 0
+                    for j in range(toks.shape[0]):
+                        if req.finished:
+                            break
+                        self._emit(req, int(toks[j, slot]))
+                        emitted_k[j // t_steps] += 1
+                        got += 1
+                    if self.flight is not None and got:
+                        self.flight.on_quantum_tokens(req, now, got)
+                    if req.finished:
+                        req.finish_time = now
+                # a K-quantum dispatch is K quanta to every seam downstream:
+                # the sub-intervals partition [t0, now] exactly (last edge
+                # IS `now`), so Σ phase seconds == histogram sums stays exact
+                dt = (now - t0) / n_exec
+                dev_dt = device_s / n_exec
+                prev = t0
+                for j in range(n_exec):
+                    edge = now if j == n_exec - 1 else t0 + (j + 1) * dt
+                    self.obs.on_quantum("decode", prev, edge, emitted_k[j],
+                                        len(rows), device_s=dev_dt)
+                    prev = edge
+            self._retire_finished()
 
     def _retire_finished(self):
         now = self._now()

@@ -60,6 +60,7 @@ from collections import deque
 
 import numpy as np
 
+from ..profiler import RecordEvent
 from .policy import NORMAL, FrontDoorPolicy, choose_victim
 from .scheduler import Request
 
@@ -357,11 +358,12 @@ class ServingFrontDoor:
         """One front-door iteration: preemption policy, then one engine
         scheduler step (admit -> mixed prefill | decode quantum ->
         retire), then the finished-stream reap. Returns True while work
-        remains."""
-        self._apply_preemption()
-        alive = self.engine.step()
-        self._reap_finished()
-        return alive
+        remains. One ``door.pump`` span."""
+        with RecordEvent("door.pump"):
+            self._apply_preemption()
+            alive = self.engine.step()
+            self._reap_finished()
+            return alive
 
     def pump_dispatch(self):
         """DISPATCH HALF of :meth:`pump` — preemption policy + the
@@ -372,16 +374,20 @@ class ServingFrontDoor:
         replica's device wall; ``pump()`` is equivalent to
         ``pump_collect(pump_dispatch())`` (it goes through
         ``engine.step()`` — the composition of the same two halves — so
-        wrappers around ``step`` still see every pump)."""
-        self._apply_preemption()
-        return self.engine.step_dispatch()
+        wrappers around ``step`` still see every pump). Driven apart,
+        each half is its own ``door.pump`` row (``half=dispatch`` |
+        ``collect``)."""
+        with RecordEvent("door.pump", half="dispatch"):
+            self._apply_preemption()
+            return self.engine.step_dispatch()
 
     def pump_collect(self, pending):
         """COLLECT HALF of :meth:`pump`: force the pending dispatch,
         reap finished streams, report whether work remains."""
-        alive = self.engine.step_collect(pending)
-        self._reap_finished()
-        return alive
+        with RecordEvent("door.pump", half="collect"):
+            alive = self.engine.step_collect(pending)
+            self._reap_finished()
+            return alive
 
     def run_until_idle(self):
         """Drive synchronously until no work remains; returns the

@@ -153,9 +153,12 @@ def test_engine_metrics_ragged_slot_reuse(tiny_model):
     prompts = [rng.randint(1, cfg.vocab_size, n).astype(np.int32)
                for n in (5, 9, 3, 7, 4)]
     max_new = [4, 3, 6, 2, 5]
+    # the trace buffer is the process's one: start it empty
+    TraceRecorder.process().clear()
     engine = ServingEngine(model, num_slots=2, block_size=4,
                            prefill_chunk=4, decode_quantum=3,
                            trace=True)
+    assert engine.obs.tracer is TraceRecorder.process()
     reqs = [engine.submit(p, max_new_tokens=mn)
             for p, mn in zip(prompts, max_new)]
     done = engine.run()
@@ -190,7 +193,14 @@ def test_engine_metrics_ragged_slot_reuse(tiny_model):
     obj = validate_chrome_trace(engine.obs.tracer.chrome_trace())
     names = [e["name"] for e in obj["traceEvents"]]
     assert sum(1 for n in names if n.startswith("req ")) == n_req
-    assert "decode" in names and "mixed" in names
+    # the per-dispatch events are the engine's own spans (ISSUE 26), not
+    # a push from on_quantum: one a mixed step, and one for each half of
+    # a quantum (dispatched, then collected)
+    assert names.count("engine.mixed") == engine.stats["mixed_steps"]
+    assert names.count("engine.decode") \
+        == 2 * engine.stats["decode_quanta"]
+    assert names.count("request.queued") == n_req
+    assert "decode" not in names and "mixed" not in names
     # engine_stats keeps its historical dict shape
     st = engine.engine_stats()
     for key in ("steps", "mixed_steps", "decode_quanta", "pool",
