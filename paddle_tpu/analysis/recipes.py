@@ -48,6 +48,14 @@ known-good graph shape.
   contractions are fed from int8 storage, so "quantization silently
   disabled" cannot pass tier-1 even though it would be bit-identical.
 
+- ``serving_mixed_step``: the engine's MIXED prefill step
+  (``ServingEngine.mixed_step_target`` — the one jitted program a step
+  with prefilling rows dispatches, audited with a prefill chunk and a
+  decode row riding along). Budget: the serving caps (0 host callbacks:
+  the next tokens are picked in the program; KV pool leaves donated;
+  collective-free; bf16 stays bf16) plus a temp cap that (S, C, V)
+  logits or a lost donation would blow.
+
 ``build(name)`` constructs the recipe (installing the mesh it needs)
 and returns a :class:`Recipe`; call ``recipe.check()`` for the audited
 report and ``recipe.close()`` (or use ``run(name)``) to restore global
@@ -287,13 +295,13 @@ def _build_speculative_verify_step():
         max_f32_matmuls=0,        # bf16 pools/params stay bf16
         max_host_callbacks=0,     # host scheduler only at boundaries
         require_donated=True,     # draft AND target KV pool leaves
-        # audited 336 KB temp / 1.38 MB trace peak (draft + target
+        # audited 335 KB temp / 1.18 MB trace peak (draft + target
         # pools both in flight; donation saves 402 KB of that)
         max_temp_bytes=440_000,
         max_peak_live_bytes=2_000_000,
-        # cost model: 2.87M flops / 12.8 MB per round over 2 slots x
-        # (gamma+1)=3 tokens = 6 tokens at full acceptance (478k
-        # flops / 2.13 MB per token audited)
+        # cost model: 2.86M flops / 10.7 MB per round over 2 slots x
+        # (gamma+1)=3 tokens = 6 tokens at full acceptance (477k
+        # flops / 1.79 MB per token audited)
         cost_tokens_per_dispatch=6,
         max_flops_per_token=640_000,
         max_hbm_bytes_per_token=2_900_000,
@@ -616,6 +624,58 @@ def _build_serving_multiquantum_step():
     return recipe
 
 
+def _build_serving_mixed_step():
+    import numpy as np
+    import paddle_tpu as paddle
+    from ..nlp import LlamaConfig, LlamaForCausalLM
+    from ..serving import FaultInjector, ServingEngine
+
+    paddle.seed(0)
+    cfg = LlamaConfig.tiny(tensor_parallel=False, dtype="bfloat16")
+    model = LlamaForCausalLM(cfg)
+    # the engine of serving_decode_step (full observability, the
+    # resilience tier over a DISARMED injector), caught with BOTH kinds
+    # of row in one step: the first request has finished its prefill
+    # and rides along as a decode row while the second brings a chunk
+    engine = ServingEngine(model, num_slots=2, block_size=4,
+                           prefill_chunk=8, decode_quantum=4,
+                           trace=True, slo=True, flight=True,
+                           faults=FaultInjector(seed=0),
+                           resilience=True)
+    rng = np.random.RandomState(0)
+    engine.submit(rng.randint(1, cfg.vocab_size, 6).astype(np.int32),
+                  max_new_tokens=8)
+    engine.step()  # admit + prefill: the first request decodes now
+    engine.submit(rng.randint(1, cfg.vocab_size, 7).astype(np.int32),
+                  max_new_tokens=8)
+    engine._admit()  # the second one takes its slot; nothing dispatched
+    target, args = engine.mixed_step_target()
+    budget = Budget(
+        name="serving mixed prefill step (bf16, single chip)",
+        max_remat=0,
+        max_total_collectives=0,  # single-chip serving program
+        max_f32_matmuls=0,        # bf16 pool/params stay bf16
+        max_host_callbacks=0,     # tokens are picked in the program
+        require_donated=True,     # the 2L KV pool leaves
+        # audited 205 KB temp / 837 KB trace peak over 2 slots x 8
+        # positions; a lost donation (+263 KB) or (S, C, V) logits
+        # instead of (S, V) blow these
+        max_temp_bytes=270_000,
+        max_peak_live_bytes=1_100_000,
+        # cost model: 4.71M flops / 5.27 MB per step over 16 positions
+        # (294k flops / 330 KB per position audited): the weights are
+        # read once for the whole chunk, not once a token, hence seven
+        # times the quantum's 0.13 FLOP/B
+        cost_tokens_per_dispatch=16,
+        max_flops_per_token=380_000,
+        max_hbm_bytes_per_token=430_000,
+        min_arithmetic_intensity=0.6,
+    )
+    recipe = Recipe("serving_mixed_step", target, args, budget)
+    recipe.engine = engine  # obs CLI asserts the instrumented engine
+    return recipe
+
+
 RECIPES = {
     "llama_tp_zero_fused_lce": _build_llama_tp_zero_fused_lce,
     "llama_decode_greedy": _build_llama_decode_greedy,
@@ -626,6 +686,7 @@ RECIPES = {
     "serving_int8_step": _build_serving_int8_step,
     "serving_tp_step": _build_serving_tp_step,
     "serving_multiquantum_step": _build_serving_multiquantum_step,
+    "serving_mixed_step": _build_serving_mixed_step,
 }
 
 

@@ -821,6 +821,27 @@ class PagedKVCachePool:
         divide for the pool to shard at all)."""
         return self.bytes_in_use() // self.tp_shards
 
+    def adopt(self, k_pools, v_pools, k_scales=(), v_scales=()):
+        """Take a jitted step's donated-and-returned buffers as the
+        pool's new truth (async handles: no sync). The scale pools of a
+        float pool are empty and stay so."""
+        self.k_pools, self.v_pools = list(k_pools), list(v_pools)
+        self.k_scales, self.v_scales = list(k_scales), list(v_scales)
+
+    def commit_like(self, ref):
+        """Give the buffers the commitment of ``ref``, a weight of the
+        model that will write them. A jitted step's outputs are
+        committed to a device when any of its inputs is, so beside
+        committed weights a pool of fresh (uncommitted) zeros turns
+        committed with its first dispatch, and every program that takes
+        the pools would compile a second time the next time it runs.
+        Under a mesh the buffers are committed to their layout from the
+        start."""
+        if self.mesh is None and ref.committed:
+            self.adopt(*([jax.device_put(a, ref.sharding) for a in leaves]
+                         for leaves in (self.k_pools, self.v_pools,
+                                        self.k_scales, self.v_scales)))
+
     # -- device views ------------------------------------------------------
     def block_table_array(self, seq_ids, pad_to=None):
         """(B, max_blocks) int32 table for the given sequences (dead

@@ -254,7 +254,7 @@ def _cmd_watch(args):
 _CHECK_RECIPES = ("serving_decode_step", "speculative_verify_step",
                   "serving_frontdoor_step", "serving_prefix_step",
                   "serving_int8_step", "serving_tp_step",
-                  "serving_multiquantum_step")
+                  "serving_multiquantum_step", "serving_mixed_step")
 
 _REEXEC_GUARD = "_PADDLE_TPU_OBS_REEXEC"
 
@@ -591,8 +591,8 @@ def _check_resilience_smoke():
     paddle.seed(0)
     model = LlamaForCausalLM(LlamaConfig.tiny(tensor_parallel=False))
     model.eval()
-    # 6 rounds keeps the smoke under the eager mixed-prefill budget
-    # (~30 s on CPU) while still landing a couple of injected faults
+    # 6 rounds keep the smoke short (~30 s on the CPU when the mixed
+    # step was eager) while still landing a couple of injected faults
     rep = run_soak(model, rounds=6, seed=2)
     if rep["faults_injected"] < 1:
         raise AssertionError(

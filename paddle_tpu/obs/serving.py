@@ -165,6 +165,12 @@ class ServingObs:
             key: r.counter(name, f"legacy engine.stats[{key!r}]")
             for key, name in _LEGACY_KEYS.items()
         }
+        self._c_mixed_programs = r.counter(
+            "serving_mixed_programs_total",
+            "mixed-step programs built, by chunk-length bucket")
+        self._c_mixed_padded = r.counter(
+            "serving_mixed_padded_tokens_total",
+            "positions a mixed step computed that carried no token")
         self._c_submitted = r.counter(
             "serving_requests_submitted_total", "requests queued")
         self._c_admitted = r.counter(
@@ -557,10 +563,20 @@ class ServingObs:
             self._g_coll_bytes.set(float(d["bytes"]), kind=kind)
             self._g_coll_count.set(float(d["count"]), kind=kind)
 
+    def on_mixed_dispatch(self, bucket, padded_tokens, built):
+        """One mixed step is about to dispatch the program of chunk
+        length ``bucket``: count its padding beside
+        ``serving_prefill_tokens_total`` and, on the bucket's first use,
+        the ``built`` programs (the target's, and a draft's). Counters
+        like the legacy stats, so on with ``obs="off"`` too."""
+        self._c_mixed_padded.inc(padded_tokens)
+        if built:
+            self._c_mixed_programs.inc(built, bucket=str(bucket))
+
     def on_quantum(self, kind, t0, t1, tokens, rows, breakdown=None,
                    device_s=None):
         """One dispatch boundary: ``kind`` is ``mixed`` (chunked
-        prefill + decode rows through block_mha), ``decode`` (the
+        prefill + decode rows in one jitted program), ``decode`` (the
         jitted quantum) or ``spec_round``; ``tokens`` is how many
         tokens the dispatch appended to request streams. A mixed step
         passes ``breakdown`` (prefill/decode emission split + novel vs

@@ -19,9 +19,12 @@ shape of that loop:
   involuntary remat, zero host callbacks, pools donated). The host
   scheduler runs only at quantum boundaries.
 - **chunked prefill interleaved with decode**: new arrivals push their
-  prompt through ``block_multihead_attention`` in ``prefill_chunk``-
-  token slices, sharing MIXED batches with the in-flight slots' decode
-  rows — admission never stalls the running requests.
+  prompt through the pool in ``prefill_chunk``-token slices, sharing
+  MIXED batches with the in-flight slots' decode rows — admission never
+  stalls the running requests. A mixed step is ONE jitted,
+  pool-donating program too (``paged_chunk_math`` with per-row counts,
+  a program per power-of-two chunk length), and it ends in the
+  quantum's own token selection: the host reads ``num_slots`` int32s.
 - **block accounting**: retirement returns blocks to the pool free
   list for immediate reuse; admission is gated on worst-case demand so
   the pool cannot exhaust mid-flight (scheduler.py).
@@ -334,35 +337,85 @@ def _fused_paged_decode_attn(q, kp, vp, tables, lens, ks=None, vs=None):
     return out.astype(q.dtype)
 
 
-def _xla_paged_chunk_attn(q, kp, vp, tables, base_lens, ks=None, vs=None):
-    """Chunked decode attention over the paged pool (the speculative
-    VERIFY pass): query position j of each slot attends pool positions
-    < base+j+1 — the same gather + f32 masked softmax as
-    `_xla_paged_decode_attn` with an extra in-chunk causal dimension.
-    q is (S, C, H, D); no Pallas analog yet, the gather fallback runs
-    on every backend."""
+# the f32 score tile of `_paged_chunk_attn` may take this many bytes; a
+# chunk whose scores over its whole block table would take more streams
+# over the table in tiles of key blocks (a shape rule: no knob)
+_CHUNK_SCORE_BYTES = 256 << 20
+
+
+def _paged_chunk_attn(q, kp, vp, tables, base_lens, ks=None, vs=None):
+    """Chunk attention over the paged pool, shared by the speculative
+    VERIFY pass and the mixed prefill step: query position j of each
+    slot attends pool positions < base+j+1 (the row's cached context
+    and the chunk's own positions up to j, which the caller has already
+    written). q is (S, C, H, D).
+
+    The query heads are grouped over their KV head (K and V are never
+    repeated), operands keep the pool's dtype with f32 accumulation,
+    and the softmax is f32 with the same -1e30 mask as the decode
+    paths. The (S, H, C, keys) f32 scores are built for
+    ``_CHUNK_SCORE_BYTES`` worth of key blocks at a time and folded
+    into running (m, l, acc) statistics — the online softmax of
+    `_fused_paged_decode_attn` with a chunk dimension; a table whose
+    scores fit that size is one tile and no loop. ``ks``/``vs`` are the
+    int8 pool's per-row scale pools: a tile dequantizes in f32 as it
+    streams through. No Pallas analog yet: this runs on every
+    backend."""
     s_, c, h, d = q.shape
     w = tables.shape[1]
     bs, hk = kp.shape[1], kp.shape[2]
-    k = kp[tables].reshape(s_, w * bs, hk, d)
-    v = vp[tables].reshape(s_, w * bs, hk, d)
-    if ks is not None:
-        k = k.astype(jnp.float32) * ks[tables].reshape(
-            s_, w * bs, hk)[..., None]
-        v = v.astype(jnp.float32) * vs[tables].reshape(
-            s_, w * bs, hk)[..., None]
-    rep = h // hk
-    kr = jnp.repeat(k, rep, axis=2) if rep > 1 else k
-    vr = jnp.repeat(v, rep, axis=2) if rep > 1 else v
+    g = h // hk
     sc = 1.0 / math.sqrt(d)
-    logits = jnp.einsum("bchd,bkhd->bhck", q.astype(jnp.float32),
-                        kr.astype(jnp.float32)) * sc
+    tile = max(1, min(w, _CHUNK_SCORE_BYTES // (s_ * h * c * bs * 4)))
+    n_tiles = -(-w // tile)
+    # whole tiles: the padding columns point at pool block 0 and lie
+    # past every row's length, so the mask below hides them
+    tiled = jnp.pad(tables, ((0, 0), (0, n_tiles * tile - w))).reshape(
+        s_, n_tiles, tile).transpose(1, 0, 2)           # (N, S, tile)
     lens = base_lens[:, None] + jnp.arange(c)[None, :] + 1   # (S, C)
-    mask = jnp.arange(w * bs)[None, None, :] < lens[:, :, None]
-    logits = jnp.where(mask[:, None], logits, -1e30)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhck,bkhd->bchd", p, vr.astype(jnp.float32))
-    return out.astype(q.dtype)
+    neg = jnp.float32(-1e30)
+    qg = q.reshape(s_, c, hk, g, d)
+
+    def fold(carry, ti):
+        m, l, acc = carry
+        blk = tiled[ti]                                 # (S, tile)
+        k = kp[blk].reshape(s_, tile * bs, hk, d)
+        v = vp[blk].reshape(s_, tile * bs, hk, d)
+        if ks is not None:
+            k = k.astype(jnp.float32) * ks[blk].reshape(
+                s_, tile * bs, hk)[..., None]
+            v = v.astype(jnp.float32) * vs[blk].reshape(
+                s_, tile * bs, hk)[..., None]
+        ct = jnp.promote_types(q.dtype, k.dtype)
+        logits = jnp.einsum(
+            "bchgd,bkhd->bhgck", qg.astype(ct), k.astype(ct),
+            preferred_element_type=jnp.float32) * sc    # (S,HK,G,C,K)
+        kpos = ti * (tile * bs) + jnp.arange(tile * bs)
+        mask = kpos[None, None, :] < lens[:, :, None]   # (S, C, K)
+        logits = jnp.where(mask[:, None, None], logits, neg)
+        m2 = jnp.maximum(m, jnp.max(logits, axis=-1))
+        alpha = jnp.exp(m - m2)                         # (S, HK, G, C)
+        p = jnp.exp(logits - m2[..., None])
+        l2 = l * alpha + jnp.sum(p, axis=-1)
+        acc2 = acc * alpha[..., None] + jnp.einsum(
+            "bhgck,bkhd->bhgcd", p.astype(ct), v.astype(ct),
+            preferred_element_type=jnp.float32)
+        return (m2, l2, acc2), None
+
+    # every query sees pool position 0 (base >= 0), so the first tile
+    # lifts m above the -1e30 init before any masked tile's exp(neg - m)
+    # underflows to an exact 0
+    carry = (jnp.full((s_, hk, g, c), neg, jnp.float32),
+             jnp.zeros((s_, hk, g, c), jnp.float32),
+             jnp.zeros((s_, hk, g, c, d), jnp.float32))
+    if n_tiles == 1:
+        carry, _ = fold(carry, 0)
+    else:
+        carry, _ = jax.lax.scan(fold, carry, jnp.arange(n_tiles))
+    _, l, acc = carry
+    out = acc / jnp.maximum(l, 1e-30)[..., None]        # (S,HK,G,C,D)
+    return out.transpose(0, 3, 1, 2, 4).reshape(s_, c, h, d).astype(
+        q.dtype)
 
 
 def _paged_attn(q, kp, vp, tables, lens, ks=None, vs=None,
@@ -495,16 +548,25 @@ def paged_decode_math(model, scratch_block, ids_t, seq_lens, tables,
 
 
 def paged_chunk_math(model, scratch_block, ids_t, seq_lens, tables,
-                     kc, vc, live, ks=(), vs=()):
-    """C-token suffix forward for every slot over a paged pool — the
+                     kc, vc, live, ks=(), vs=(), counts=None):
+    """C tokens for every slot over a paged pool — ONE body for the
     speculative round's TARGET verify pass (reference: the speculative
     verify forward of the reference's serving stack — unverified,
-    SURVEY.md §0). Chunk position j writes its KV at ``seq_lens + j``
-    (masked rows go to the scratch block) and attends its own prefix;
-    one batched forward covers all slots and all γ+1 positions. Stale
+    SURVEY.md §0) and for the engine's mixed prefill step. Chunk
+    position j writes its KV at ``seq_lens + j`` (masked rows go to the
+    scratch block) and attends its own prefix; one batched forward
+    covers all slots and all C positions.
+
+    ``counts=None`` is the verify pass: every position of a live row is
+    valid and the logits of all of them come back, (S, C, V). Stale
     tail slots from rejected proposals are rolled back by LENGTH MASK:
     the caller shrinks ``seq_lens`` and the next round's writes simply
-    overwrite them."""
+    overwrite them. ``counts`` (S,) is the mixed step: row s brings
+    ``counts[s] <= C`` tokens (a prefill chunk, one token for a decode
+    row riding along, 0 for an idle slot); the positions past a row's
+    count write to the scratch block, so no valid position ever reads
+    them, and the head runs only at each row's last valid position:
+    (S, V) logits, never (S, C, V)."""
     cfg = model.config
     core = model.llama
     s, c = ids_t.shape
@@ -521,11 +583,14 @@ def paged_chunk_math(model, scratch_block, ids_t, seq_lens, tables,
     freqs = pos_f[..., None] * inv_freq              # (S, C, D/2)
     cos, sin = jnp.cos(freqs), jnp.sin(freqs)
 
+    valid = live[:, None]
+    if counts is not None:
+        valid = valid & (jnp.arange(c)[None, :] < counts[:, None])
     wpos = seq_lens[:, None] + jnp.arange(c)[None, :]
     blk_idx = jnp.clip(wpos // bs, 0, w - 1)
     own_blk = jnp.take_along_axis(tables, blk_idx, axis=1)
-    write_blk = jnp.where(live[:, None], own_blk, scratch_block)
-    write_off = jnp.where(live[:, None], wpos % bs, 0)
+    write_blk = jnp.where(valid, own_blk, scratch_block)
+    write_off = jnp.where(valid, wpos % bs, 0)
     base_lens = jnp.where(live, seq_lens, 0)
 
     quant = len(ks) > 0
@@ -556,15 +621,20 @@ def paged_chunk_math(model, scratch_block, ids_t, seq_lens, tables,
             vv.astype(vc[i].dtype)))
         new_kc.append(kci)
         new_vc.append(vci)
-        att = _xla_paged_chunk_attn(qv, kci, vci, tables, base_lens,
-                                    ks=ksi, vs=vsi)
+        att = _paged_chunk_attn(qv, kci, vci, tables, base_lens,
+                                ks=ksi, vs=vsi)
         att_t = Tensor(att.reshape(s, c, h * d), stop_gradient=True)
         hidden = residual + attn.o_proj(att_t)
         hidden = hidden + layer.mlp(
             layer.post_attention_layernorm(hidden))
-    hidden = core.norm(hidden)
-    logits = model.lm_head(hidden)
-    return logits._value, new_kc, new_vc, tuple(new_ks), tuple(new_vs)
+    if counts is None:
+        logits = model.lm_head(core.norm(hidden))._value
+    else:
+        last = jnp.maximum(counts - 1, 0)[:, None, None]
+        logits = model.lm_head(core.norm(Tensor(
+            jnp.take_along_axis(hidden._value, last, axis=1),
+            stop_gradient=True)))._value[:, 0]
+    return logits, new_kc, new_vc, tuple(new_ks), tuple(new_vs)
 
 
 class _AuditedStep:
@@ -900,6 +970,7 @@ class ServingEngine:
             num_layers=cfg.num_hidden_layers, dtype=cache_dtype,
             prefix_cache=self.prefix_cache, mesh=self.mesh,
             kv_dtype=kv_dtype)
+        self.pool.commit_like(self._p_vals[0])
         # masked (retired/empty) rows dump their KV writes here
         self._scratch_block = self.pool.ensure("__scratch__", 1)[0]
         self.d_pool = None
@@ -927,6 +998,7 @@ class ServingEngine:
                 dtype=d_cache_dtype,
                 prefix_cache=self.prefix_cache, mesh=self.mesh,
                 kv_dtype=kv_dtype)
+            self.d_pool.commit_like(self._d_p_vals[0])
             self._d_scratch_block = self.d_pool.ensure("__scratch__",
                                                        1)[0]
         self.scheduler = Scheduler(
@@ -952,23 +1024,26 @@ class ServingEngine:
         # boundary obs.on_token fires on
         self.token_sink = None
 
-        # rotary table shared by prefill (block_mha fused rope) and the
-        # quantum (per-row angles recomputed on device)
-        from ..nn.functional.rope import build_rope_cache
-
-        cos, sin = build_rope_cache(self.max_context, cfg.head_dim,
-                                    base=cfg.rope_theta)
-        self._rotary = Tensor(jnp.stack([cos, sin]), stop_gradient=True)
-
+        n_pool = 4 if self.pool.quantized else 2
+        # the mixed step: ONE jitted, pool-donating program per model
+        # (the draft's writes its KV only); jit keeps an executable per
+        # chunk-length bucket, built on the bucket's first use
+        self._mixed = _AuditedStep(
+            jax.jit(self._make_mixed(model, self._scratch_block, True),
+                    donate_argnums=(0, 1, 2, 3)),
+            n_donatable=n_pool * cfg.num_hidden_layers,
+            name="serving_mixed_step", mesh=self.mesh)
+        self._mixed_buckets = set()
         if spec_draft is not None:
             from .speculative import make_spec_round
 
             self._d_tables = np.zeros((s, w), np.int32)
-            d_cos, d_sin = build_rope_cache(
-                self.max_context, d_cfg.head_dim,
-                base=d_cfg.rope_theta)
-            self._d_rotary = Tensor(jnp.stack([d_cos, d_sin]),
-                                    stop_gradient=True)
+            self._d_mixed = _AuditedStep(
+                jax.jit(self._make_mixed(spec_draft,
+                                         self._d_scratch_block, False),
+                        donate_argnums=(0, 1, 2, 3)),
+                n_donatable=n_pool * d_cfg.num_hidden_layers,
+                name="serving_mixed_step_draft", mesh=self.mesh)
             # argnums 0..7 = target kc/vc/ks/vs + draft kc/vc/ks/vs; on
             # a float engine the scale tuples are EMPTY pytrees, so
             # donating them is a no-op and the flat donated set — and
@@ -978,7 +1053,7 @@ class ServingEngine:
                 donate_argnums=(0, 1, 2, 3, 4, 5, 6, 7))
             self._audited = _AuditedStep(
                 self._quantum,
-                n_donatable=(4 if self.pool.quantized else 2)
+                n_donatable=n_pool
                 * (cfg.num_hidden_layers + d_cfg.num_hidden_layers),
                 name="speculative_verify_step", mesh=self.mesh)
         else:
@@ -986,8 +1061,7 @@ class ServingEngine:
                                     donate_argnums=(0, 1, 2, 3))
             self._audited = _AuditedStep(
                 self._quantum,
-                n_donatable=(4 if self.pool.quantized else 2)
-                * cfg.num_hidden_layers,
+                n_donatable=n_pool * cfg.num_hidden_layers,
                 mesh=self.mesh)
         # the multi-quantum while_loop variant: built ONLY when asked
         # for (K > 1, non-speculative) — same signature as the plain
@@ -1001,8 +1075,7 @@ class ServingEngine:
                 donate_argnums=(0, 1, 2, 3))
             self._mq_audited = _AuditedStep(
                 self._mq_quantum,
-                n_donatable=(4 if self.pool.quantized else 2)
-                * cfg.num_hidden_layers,
+                n_donatable=n_pool * cfg.num_hidden_layers,
                 name="serving_multiquantum_step", mesh=self.mesh)
         # under tp the small per-slot state rides every dispatch
         # committed replicated, so the compiled quantum's input layouts
@@ -1784,7 +1857,9 @@ class ServingEngine:
                 req.prefill_pos = cached
                 self.obs.on_cached_prefill(req, cached)
             self._seq_lens[slot] = cached
-            self._n_gen[slot] = 0
+            # a resumed request goes on from the tokens it has: the mixed
+            # step keys its continuation token by fold_in(key, n_emitted)
+            self._n_gen[slot] = len(req.tokens)
             self._done[slot] = True  # not decodable until prefill ends
             self._max_new[slot] = req.max_new_tokens
             self._keys[slot] = np.asarray(jax.random.PRNGKey(req.seed))
@@ -1792,101 +1867,105 @@ class ServingEngine:
                                  if req.temperature is None
                                  else req.temperature)
 
-    def _mixed_forward(self, model, pool, tables, rotary, enc_lens,
-                       dec_lens, this_time, ids, total):
-        """One mixed prefill(+decode) forward of ``model`` over
-        ``pool`` through ``block_multihead_attention`` — shared by the
-        target and (in the speculative arm) the DRAFT, which must
-        ingest exactly the same rows so its cache stays in lockstep
-        with the target's. Returns the (1, T, E) hidden states; the
-        mutated pool Tensors are written back as the new truth."""
-        import paddle_tpu as paddle
-        from ..incubate.nn.functional import block_multihead_attention
+    def _make_mixed(self, model, scratch, select):
+        """Build the mixed step's callable for ``model``: one chunk of
+        ``paged_chunk_math`` over its pool (per-row counts, the head at
+        each row's last valid position) ending, for the target, in the
+        quantum's own ``_select_device`` — prefill and decode pick
+        tokens with one definition. The speculative arm's DRAFT ingests
+        the same rows through a program of its own that returns its
+        pools only (``select=False``: the forward exists for its KV
+        writes). Weights are arguments (``p_vals``), the pools the
+        leading, donated ones."""
+        def mixed(kc, vc, ks, vs, p_vals, tables, ids, seq_lens, counts,
+                  keys, n_gen, temps=None):
+            with autograd.no_grad():
+                def fwd(ids_t):
+                    return paged_chunk_math(
+                        model, scratch, ids_t, seq_lens, tables, kc, vc,
+                        counts > 0, ks=ks, vs=vs, counts=counts)
 
-        cfg = model.config
-        h, hk, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                    cfg.head_dim)
-        kc_t = [Tensor(pool.k_pools[i], stop_gradient=True)
-                for i in range(cfg.num_hidden_layers)]
-        vc_t = [Tensor(pool.v_pools[i], stop_gradient=True)
-                for i in range(cfg.num_hidden_layers)]
-        ks_t = vs_t = None
-        if pool.quantized:
-            # int8 pool: thread the per-row scale pools through the
-            # fused op; each written row quantizes in-graph and the
-            # mutated scale pools come back as the new truth
-            ks_t = [Tensor(pool.k_scales[i], stop_gradient=True)
-                    for i in range(cfg.num_hidden_layers)]
-            vs_t = [Tensor(pool.v_scales[i], stop_gradient=True)
-                    for i in range(cfg.num_hidden_layers)]
-        common = dict(
-            seq_lens_encoder=paddle.to_tensor(
-                np.asarray(enc_lens, np.int32)),
-            seq_lens_decoder=paddle.to_tensor(
-                np.asarray(dec_lens, np.int32)),
-            seq_lens_this_time=paddle.to_tensor(
-                np.asarray(this_time, np.int32)),
-            block_tables=Tensor(tables, stop_gradient=True),
-            rotary_embs=rotary,
-            use_neox_rotary_style=True,  # the model's rope layout
-            num_heads=h, kv_num_heads=hk, head_dim=d,
-        )
-        # under tp the eager prefill layers place their activations via
-        # the mp layers' constraints, which read the global mesh
-        scope = (MeshScope(self.mesh) if self.mesh is not None
-                 else contextlib.nullcontext())
-        with scope, autograd.no_grad():
-            core = model.llama
-            hidden = core.embed_tokens(
-                paddle.to_tensor(ids[None, :]))          # (1, T, E)
-            for i, layer in enumerate(core.layers):
-                with RecordEvent("engine.mixed.layer", layer=i):
-                    attn = layer.self_attn
-                    residual = hidden
-                    x = layer.input_layernorm(hidden)
-                    q = attn.q_proj(x)
-                    k = attn.k_proj(x)
-                    v = attn.v_proj(x)
-                    qkv = paddle.concat([q, k, v], axis=-1) \
-                        .reshape([total, (h + 2 * hk) * d])
-                    scales = ({} if ks_t is None else
-                              dict(cache_k_scale_pool=ks_t[i],
-                                   cache_v_scale_pool=vs_t[i]))
-                    att = block_multihead_attention(
-                        qkv, kc_t[i], vc_t[i], **common, **scales)
-                    att3 = att.reshape([1, total, h * d])
-                    hidden = residual + attn.o_proj(att3)
-                    hidden = hidden + layer.mlp(
-                        layer.post_attention_layernorm(hidden))
-            hidden = core.norm(hidden)
-        # the mutated pool Tensors are the new truth (re-pinned to the
-        # pool's mesh layout under tp — the quantum donates them and
-        # expects the exact layout it was compiled for)
-        for i in range(cfg.num_hidden_layers):
-            pool.k_pools[i] = pool._pin(kc_t[i]._value)
-            pool.v_pools[i] = pool._pin(vc_t[i]._value)
-            if ks_t is not None:
-                pool.k_scales[i] = pool._pin_scale(ks_t[i]._value)
-                pool.v_scales[i] = pool._pin_scale(vs_t[i]._value)
-        return hidden
+                (logits, kc2, vc2, ks2, vs2), _ = functional_call(
+                    model, fwd, [Tensor(ids, stop_gradient=True)], {},
+                    p_vals, [])
+            if not select:
+                return kc2, vc2, ks2, vs2
+            return (kc2, vc2, ks2, vs2,
+                    self._select_device(logits, keys, n_gen, temps))
+
+        return mixed
+
+    def _mixed_args(self, pre, dec, spec):
+        """Host half of one mixed step: grow (and copy-on-write) every
+        row's blocks, lay the step's tokens out by SLOT, and build the
+        argument tuples of the target's program and, in the speculative
+        arm, the draft's. A prefilling row brings its next chunk, a
+        decoding row its last token, every other slot nothing
+        (``counts`` 0). The chunk length compiled for is the smallest
+        power of two that holds the step's longest row, at most
+        ``prefill_chunk``: a pure function of the step's rows. Returns
+        ``(args, d_args, bucket, chunk_lens)``."""
+        s = self.config.num_slots
+        chunk_lens = [min(self.config.prefill_chunk,
+                          r.prefill_target - r.prefill_pos) for r in pre]
+        longest = max(chunk_lens + [1])
+        bucket = min(1 << (longest - 1).bit_length(),
+                     self.config.prefill_chunk)
+        ids = np.zeros((s, bucket), np.int32)
+        counts = np.zeros(s, np.int32)
+        seq_ids = [None] * s     # an idle slot's table row stays zeros
+        pools = (self.pool, self.d_pool) if spec else (self.pool,)
+        for req, n in zip(pre + dec, chunk_lens + [1] * len(dec)):
+            slot = req.slot
+            seq = int(self._seq_lens[slot])   # a prefill row's position
+            ids[slot, :n] = (req.prefill_src[seq:seq + n]
+                             if req.prefilling else self._last_tok[slot])
+            counts[slot] = n
+            seq_ids[slot] = req.req_id
+            for pool in pools:
+                pool.ensure(req.req_id, seq + n)
+                if self.prefix_cache:
+                    # copy-on-write before the dispatch: the chunk's KV
+                    # writes must never land in a block another holder
+                    # (sequence or prefix index) still maps
+                    pool.make_writable(req.req_id, seq, seq + n)
+        small = [self._dev(a) for a in (
+            ids, self._seq_lens, counts, self._keys, self._n_gen)]
+        if self._per_request_sampling:
+            small.append(self._dev(self._temps))
+
+        def args_of(pool, p_vals):
+            # the scale tuples are EMPTY on a float pool (no avals)
+            return (list(pool.k_pools), list(pool.v_pools),
+                    tuple(pool.k_scales), tuple(pool.v_scales), p_vals,
+                    self._dev(pool.block_table_array(
+                        seq_ids, pad_to=self._table_width)), *small)
+
+        return (args_of(self.pool, self._p_vals),
+                args_of(self.d_pool, self._d_p_vals) if spec else None,
+                bucket, chunk_lens)
+
+    def mixed_step_target(self):
+        """(auditable step, example args) for ``analysis.check_budget``:
+        the EXACT jitted program ``_mixed_step`` dispatches, with the
+        rows the scheduler holds now as the example batch (the
+        ``serving_mixed_step`` recipe fingerprints this)."""
+        args, _, _, _ = self._mixed_args(
+            self.scheduler.prefilling(), self.scheduler.decoding(), False)
+        return self._mixed, args
 
     def _mixed_step(self):
         """One chunk of prefill for every prefilling slot, one decode
-        token for every in-flight slot — a single MIXED batch through
-        ``block_multihead_attention`` per layer (chunked prefill
-        interleaved with decode, the reference's serving batch shape).
-        The speculative arm pushes the SAME batch through the draft
-        model into the draft pool (token selection stays the target's;
-        the draft forward exists only for its KV writes).
+        token for every in-flight slot: a single MIXED batch (chunked
+        prefill interleaved with decode, the reference's serving batch
+        shape) through ONE jitted, pool-donating program that ends in
+        the token selection — the host reads ``num_slots`` int32s. The
+        speculative arm pushes the SAME rows through the draft's program
+        into the draft pool (token selection stays the target's).
 
-        The spans are inline and five single-use temporaries of this
-        body went (``model``, ``chunk``, ``d_tables``, ``scope``,
-        ``hs``) so that the frames from ``door.pump`` down to
-        ``_mixed_forward`` take as many words of Python's data stack as
-        before the spans: the eager forward's time swings by a third
-        with where the stack's 16 KiB chunks end under it (``PERF.md``
-        section 6, PR 26; ``tests/test_program_spans.py`` holds the
-        sum)."""
+        On the host, in this order: the fault boundary (before anything
+        is donated), block growth and copy-on-write, the dispatch, the
+        one sync, then emission, prefix publication and accounting."""
         with RecordEvent("engine.mixed", step_kind="mixed",
                          step=self.stats["steps"]) as span:
             self.stats["mixed_steps"] += 1
@@ -1898,140 +1977,87 @@ class ServingEngine:
             # a raised step retries cleanly from the next step()
             self.faults.before_dispatch("mixed", [r.req_id for r in rows])
             with RecordEvent("engine.mixed.prepare"):
-                toks, this_time, enc_lens, dec_lens = [], [], [], []
+                args, d_args, bucket, chunk_lens = self._mixed_args(
+                    pre, dec, spec)
                 # cost-ledger work split: a resumed row's chunk re-computes
                 # KV a preemption dropped (recompute debt); a fresh row's
                 # chunk is novel prefill work (obs/attribution.py)
-                novel_toks = recompute_toks = 0
-                for req in pre:
-                    n = min(self.config.prefill_chunk,
-                            req.prefill_target - req.prefill_pos)
-                    if req.preemptions > 0:
-                        recompute_toks += n
-                    else:
-                        novel_toks += n
-                    toks.append(
-                        req.prefill_src[req.prefill_pos:req.prefill_pos + n])
-                    this_time.append(n)
-                    enc_lens.append(n)
-                    dec_lens.append(req.prefill_pos)
-                    self.pool.ensure(req.req_id, req.prefill_pos + n)
-                    if spec:
-                        self.d_pool.ensure(req.req_id, req.prefill_pos + n)
-                    if self.prefix_cache:
-                        # copy-on-write before the forward: the chunk's KV
-                        # writes must never land in a block another holder
-                        # (sequence or prefix index) still maps
-                        self.pool.make_writable(req.req_id, req.prefill_pos,
-                                                req.prefill_pos + n)
-                        if spec:
-                            self.d_pool.make_writable(
-                                req.req_id, req.prefill_pos,
-                                req.prefill_pos + n)
-                for req in dec:
-                    slot = req.slot
-                    toks.append(np.asarray([self._last_tok[slot]], np.int32))
-                    this_time.append(1)
-                    enc_lens.append(0)
-                    dec_lens.append(int(self._seq_lens[slot]))
-                    self.pool.ensure(req.req_id, int(self._seq_lens[slot]) + 1)
-                    if spec:
-                        self.d_pool.ensure(req.req_id,
-                                           int(self._seq_lens[slot]) + 1)
-                    if self.prefix_cache:
-                        seq = int(self._seq_lens[slot])
-                        self.pool.make_writable(req.req_id, seq, seq + 1)
-                        if spec:
-                            self.d_pool.make_writable(req.req_id, seq, seq + 1)
-                ids = np.concatenate(toks).astype(np.int32)
-                total = int(ids.shape[0])
-                self.stats["prefill_tokens"] += int(sum(enc_lens))
-                cu = np.concatenate(
-                    [[0], np.cumsum(this_time)]).astype(np.int32)
-                row_ids = [r.req_id for r in rows]
-                tables = self.pool.block_table_array(
-                    row_ids, pad_to=self._table_width)
-            span.args.update(rows=len(rows),
-                             prefill_tokens=int(sum(enc_lens)))
+                recompute_toks = sum(
+                    n for r, n in zip(pre, chunk_lens) if r.preemptions > 0)
+                prefill_toks = sum(chunk_lens)
+                padded = (self.config.num_slots * bucket
+                          - prefill_toks - len(dec))
+                self.stats["prefill_tokens"] += prefill_toks
+                # a bucket's first use builds its program (the draft's too)
+                self.obs.on_mixed_dispatch(
+                    bucket, padded, built=0 if bucket in self._mixed_buckets
+                    else 1 + spec)
+                self._mixed_buckets.add(bucket)
+            span.args.update(rows=len(rows), prefill_tokens=prefill_toks,
+                             bucket=bucket, padded_tokens=padded)
+            # a forward span is its program's enqueue to its end; the
+            # donated pools are adopted as soon as the call returns. The
+            # draft is waited for too: the uploads both programs read may
+            # alias the host mirrors (a CPU upload is zero-copy), which
+            # the emission below writes
             with RecordEvent("engine.mixed.forward", model="target"):
-                hidden = self._mixed_forward(
-                    self.model, self.pool, tables, self._rotary, enc_lens,
-                    dec_lens, this_time, ids, total)
+                *pools, toks = self._mixed(*args)
+                self.pool.adopt(*pools)
+                jax.block_until_ready(toks)
             if spec:
                 with RecordEvent("engine.mixed.forward", model="draft"):
-                    self._mixed_forward(
-                        self.spec_draft, self.d_pool,
-                        self.d_pool.block_table_array(
-                            row_ids, pad_to=self._table_width),
-                        self._d_rotary, enc_lens, dec_lens, this_time, ids,
-                        total)
+                    self.d_pool.adopt(*self._d_mixed(*d_args))
+                    jax.block_until_ready(self.d_pool.k_pools[-1])
             with RecordEvent("engine.mixed.select"):
-                # logits only where a next token is due: rows completing
-                # their prefill this chunk, and every decode row
-                need = [i for i, req in enumerate(rows)
-                        if (i >= len(pre)) or
-                        (req.prefill_pos + this_time[i]
-                         >= req.prefill_target)]
-                if need:
-                    last_idx = np.asarray(
-                        [cu[i + 1] - 1 for i in need], np.int32)
-                    with (MeshScope(self.mesh) if self.mesh is not None
-                          else contextlib.nullcontext()), autograd.no_grad():
-                        logits = self.model.lm_head(Tensor(
-                            hidden._value[0, last_idx],
-                            stop_gradient=True))._value      # (R, V)
-                    nxt = self._select_host(logits,
-                                            [rows[i] for i in need])
+                nxt = np.asarray(toks)               # (S,) int32
             now = self._now()  # the stamp of every token of the step
             with RecordEvent("engine.mixed.emit"):
-                emitted = prefill_emitted = 0
-                for i, req in enumerate(rows):
+                prefill_emitted = 0
+                for req, n in zip(pre, chunk_lens):
                     slot = req.slot
-                    if i < len(pre):
-                        req.prefill_pos += this_time[i]
-                        self._seq_lens[slot] = req.prefill_pos
+                    req.prefill_pos += n
+                    self._seq_lens[slot] = req.prefill_pos
+                    if self.flight is not None:
+                        self.flight.on_prefill_chunk(
+                            req, now, n, req.prefill_pos)
+                    if req.prefill_pos < req.prefill_target:
+                        continue
+                    if self.prefix_cache:
+                        # the whole prefill source is in the pool now:
+                        # publish its full blocks into the prefix index
+                        # (both pools — lockstep) so the next request
+                        # with this prefix aliases instead of computing
+                        self.pool.publish_prefix(req.req_id,
+                                                 req.prefill_src)
+                        if spec:
+                            self.d_pool.publish_prefix(
+                                req.req_id, req.prefill_src)
+                        self.scheduler.clear_cow_debt(req)
+                    if req.first_token_time is None:
+                        # TTFT observes exactly ONCE per request — a
+                        # resumed request's re-prefill completion emits
+                        # a continuation token, not a first token
+                        req.first_token_time = now
+                        self.obs.on_first_token(req, now)
                         if self.flight is not None:
-                            self.flight.on_prefill_chunk(
-                                req, now, this_time[i], req.prefill_pos)
-                        if req.prefill_pos >= req.prefill_target:
-                            if self.prefix_cache:
-                                # the whole prefill source is in the pool now:
-                                # publish its full blocks into the prefix index
-                                # (both pools — lockstep) so the next request
-                                # with this prefix aliases instead of computing
-                                self.pool.publish_prefix(req.req_id,
-                                                         req.prefill_src)
-                                if spec:
-                                    self.d_pool.publish_prefix(
-                                        req.req_id, req.prefill_src)
-                                self.scheduler.clear_cow_debt(req)
-                            tok = int(nxt[need.index(i)])
-                            if req.first_token_time is None:
-                                # TTFT observes exactly ONCE per request — a
-                                # resumed request's re-prefill completion emits
-                                # a continuation token, not a first token
-                                req.first_token_time = now
-                                self.obs.on_first_token(req, now)
-                                if self.flight is not None:
-                                    self.flight.on_first_token(
-                                        req, now, now - req.arrival_time)
-                            self._emit(req, tok)
-                            emitted += 1
-                            prefill_emitted += 1
-                            self._record_host(slot, req, tok)
-                    else:
-                        tok = int(nxt[need.index(i)])
-                        self._seq_lens[slot] += 1  # last_tok entered the cache
-                        self._emit(req, tok)
-                        emitted += 1
-                        self._record_host(slot, req, tok)
+                            self.flight.on_first_token(
+                                req, now, now - req.arrival_time)
+                    self._emit(req, int(nxt[slot]))
+                    prefill_emitted += 1
+                    self._record_host(slot, req, int(nxt[slot]))
+                for req in dec:
+                    slot = req.slot
+                    self._seq_lens[slot] += 1  # last_tok entered the cache
+                    self._emit(req, int(nxt[slot]))
+                    self._record_host(slot, req, int(nxt[slot]))
                 breakdown = {"prefill_emitted": prefill_emitted,
-                             "decode_emitted": emitted - prefill_emitted,
-                             "novel_tokens": novel_toks,
+                             "decode_emitted": len(dec),
+                             "novel_tokens": prefill_toks - recompute_toks,
                              "recompute_tokens": recompute_toks,
                              "decode_rows": len(dec)}
-                self.obs.on_quantum("mixed", span.t0, now, emitted,
-                                    len(rows), breakdown=breakdown)
+                self.obs.on_quantum("mixed", span.t0, now,
+                                    prefill_emitted + len(dec), len(rows),
+                                    breakdown=breakdown)
             if self.watchdog is not None and self.watchdog.check(
                     "mixed", now - span.t0):
                 self.obs.on_watchdog("mixed", now - span.t0)
@@ -2052,30 +2078,6 @@ class ServingEngine:
         self._last_tok[slot] = tok
         self._n_gen[slot] = len(req.tokens)
         self._done[slot] = req.finished
-
-    def _select_host(self, logits, rows):
-        """First-token / mixed-step selection with the SAME math as the
-        device quantum: argmax, or filtered categorical keyed by each
-        slot's fold_in(key, n_emitted)."""
-        if self.decode_strategy == "greedy":
-            return np.asarray(jnp.argmax(logits, axis=-1)).astype(np.int32)
-        if self._per_request_sampling:
-            temps = jnp.asarray(np.asarray(
-                [self._temps[r.slot] for r in rows], np.float32))
-            filt = _filter_logits(
-                logits.astype(jnp.float32)
-                / jnp.maximum(temps, 1e-6)[:, None],
-                self.top_k, self.top_p, None)
-        else:
-            filt = _filter_logits(logits, self.top_k, self.top_p,
-                                  self.temperature)
-        keys = jnp.asarray(np.stack(
-            [self._keys[r.slot] for r in rows]))
-        steps = jnp.asarray(np.asarray(
-            [len(r.tokens) for r in rows], np.int32))
-        keys = jax.vmap(jax.random.fold_in)(keys, steps)
-        samp = jax.vmap(jax.random.categorical)(keys, filt)
-        return np.asarray(samp).astype(np.int32)
 
     # -- the jitted decode quantum ----------------------------------------
     def _select_device(self, logits, keys, n_gen, temps=None):
@@ -2321,15 +2323,8 @@ class ServingEngine:
                 for r in excluded:
                     self._done[r.slot] = r.finished
                 raise
-            self.pool.k_pools = list(t_kc)
-            self.pool.v_pools = list(t_vc)
-            self.d_pool.k_pools = list(d_kc)
-            self.d_pool.v_pools = list(d_vc)
-            if self.pool.quantized:
-                self.pool.k_scales = list(t_ks)
-                self.pool.v_scales = list(t_vs)
-                self.d_pool.k_scales = list(d_ks)
-                self.d_pool.v_scales = list(d_vs)
+            self.pool.adopt(t_kc, t_vc, t_ks, t_vs)
+            self.d_pool.adopt(d_kc, d_vc, d_ks, d_vs)
             stream = np.asarray(stream)                      # (S, γ+1) sync
             counts = np.asarray(counts)
             acc = np.asarray(acc)
@@ -2403,11 +2398,13 @@ class ServingEngine:
         (host needs its acceptance counts to proceed) runs to
         completion here and returns None.
 
-        As in ``_mixed_step``, the frames from ``door.pump`` down to
-        the jitted call take the words of data stack they took before
-        the spans (the quantum's outputs are adopted through one starred
-        name for that): the quantum's first trace, 11 s of a run's
-        set-up, is as sensitive to it as the eager forward."""
+        The frames from ``door.pump`` down to the jitted call take the
+        words of data stack they took before the spans (the quantum's
+        outputs are adopted through one starred name for that): the
+        quantum's first trace, 11 s of a run's set-up, moves by seconds
+        with where CPython's stack chunks end under it (``PERF.md``
+        section 6, PR 26; ``tests/test_program_spans.py`` holds the
+        sum)."""
         if self.spec_draft is not None and not self._spec_disabled:
             self._spec_round_step(include=include)
             return None
@@ -2453,11 +2450,7 @@ class ServingEngine:
                 raise
             # adopt the donated pool outputs NOW (async handles — no
             # sync): the pre-dispatch buffers were consumed by donation
-            self.pool.k_pools = list(kc)
-            self.pool.v_pools = list(vc)
-            if self.pool.quantized:
-                self.pool.k_scales = list(ks)
-                self.pool.v_scales = list(vs)
+            self.pool.adopt(kc, vc, ks, vs)
             # out: seq_lens, last_tok, n_gen, done, toks, and the count
             # of quanta that ran where the dispatch was of several. The
             # device's share of the wall starts where the call returned:

@@ -94,6 +94,17 @@ python -m paddle_tpu.analysis --check --fingerprint --cost
 # caps ride `--check`; the exact counts ride the goldens; the
 # cross-source ratio is also budget-guarded in BENCH_COST_r17.json.
 #
+# Mixed-step gate (ISSUE 27): `--check --fingerprint` above also audits
+# `serving_mixed_step` — the ONE jitted program a step with prefilling
+# rows dispatches (`paged_chunk_math` with per-row counts, a prefill
+# chunk and a decode row riding along, the quantum's own token selection
+# at its end): 0 host callbacks, 0 collectives at tp=1, every KV pool
+# leaf donated, bf16 stays bf16, and a temp cap that (S, C, V) logits
+# or a lost donation would blow. The verify pass shares its chunk
+# attention (`_paged_chunk_attn`: grouped heads, streamed over key
+# blocks past 256 MiB of f32 scores), so `speculative_verify_step`'s
+# golden was regenerated with it; every quantum golden is untouched.
+#
 # Multi-quantum gate (ISSUE 17): `--check --fingerprint` above also
 # audits `serving_multiquantum_step` — the K=4 on-device decode driver
 # (lax.while_loop over the scanned quantum, retiring rows against the

@@ -31,7 +31,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 VOCABULARY = [
     "door.pump", "engine.step", "request.queued",
     "engine.mixed", "engine.mixed.prepare", "engine.mixed.forward",
-    "engine.mixed.layer", "engine.mixed.select", "engine.mixed.emit",
+    "engine.mixed.select", "engine.mixed.emit",
     "engine.decode", "engine.decode.prepare", "engine.decode.args",
     "engine.decode.enqueue", "engine.decode.sync", "engine.decode.emit",
     "engine.spec_round",
@@ -47,7 +47,6 @@ PARENTS = {
     "engine.mixed": {"engine.step"},
     "engine.mixed.prepare": {"engine.mixed"},
     "engine.mixed.forward": {"engine.mixed"},
-    "engine.mixed.layer": {"engine.mixed.forward"},
     "engine.mixed.select": {"engine.mixed"},
     "engine.mixed.emit": {"engine.mixed"},
     "engine.decode": {"engine.step"},
@@ -196,14 +195,21 @@ def test_one_step_row_per_step_made(run):
     assert halves(run["rows"], "door.pump") == [None] * stats["steps"]
     assert names.count("train.run_steps") == run["train_calls"]
     assert names.count("request.queued") == len(run["streams"])
-    layers = run["door"].engine.model.config.num_hidden_layers
-    assert names.count("engine.mixed.layer") \
-        == layers * names.count("engine.mixed.forward")
+    # one program a step: one forward span (no draft here), no layers
+    assert names.count("engine.mixed.forward") == stats["mixed_steps"]
     mixed = [r for r in run["rows"] if r["name"] == "engine.mixed"]
     assert all(r["args"]["rows"] >= 1 and "prefill_tokens" in r["args"]
                and "step" in r["args"] for r in mixed)
     assert sum(r["args"]["prefill_tokens"] for r in mixed) \
         == stats["prefill_tokens"]
+    # the row says which program ran and what it padded: 2 slots of
+    # `bucket` positions, less the tokens the step's rows brought
+    reg = run["door"].engine.obs.registry
+    assert {r["args"]["bucket"] for r in mixed} <= {1, 2, 4}
+    assert all(0 <= r["args"]["padded_tokens"] < 2 * r["args"]["bucket"]
+               for r in mixed)
+    assert sum(r["args"]["padded_tokens"] for r in mixed) \
+        == reg.get("serving_mixed_padded_tokens_total").value()
 
 
 def test_the_halves_of_a_quantum_pair_by_their_step(run):
@@ -561,20 +567,18 @@ def test_a_span_costs_microseconds():
     assert best < 100e-6
 
 
-# ------------------------------------- the eager forward's stack offset
-def test_the_eager_forward_keeps_its_stack_offset():
+# --------------------------------- the jitted quantum's stack offset
+def test_the_quantum_keeps_its_stack_offset():
     """CPython keeps Python frames in 16 KiB chunks of a data stack; a
     call whose frame does not fit maps a new chunk and its return unmaps
-    it. JAX's tracer under the eager ``_mixed_forward`` makes millions
-    of calls, and where the chunks end among them decides a third of the
-    step's time (6.5 to 9.5 s on the chip for nothing but padding under
-    ``door.pump()``; ``PERF.md`` section 6, PR 26). Where they end
-    follows from the words (locals + stack) of the frames under the
-    forward, so the frames this repo owns there keep the sum they had
-    before they were spanned. A change that moves it is a change of the
-    mixed step's speed, either way: measure parent against change on the
-    chip by the benchmark's own command, then set the sum anew. (Goes
-    with the eager step, ROADMAP S1.)"""
+    it. JAX's tracer makes millions of calls under the quantum's first
+    trace and lowering (11 s of a run's set-up, which moved by 4 s with
+    ten words; ``PERF.md`` section 6, PR 26), and where the chunks end
+    among them follows from the words (locals + stack) of the frames
+    above it, so the frames this repo owns there keep the sum they had
+    before they were spanned. (The eager mixed forward, whose every
+    step hung on the same thing, went with ROADMAP S1, and its sum with
+    it.)"""
     from paddle_tpu.serving.engine import ServingEngine
     from paddle_tpu.serving.frontend import ServingFrontDoor
 
@@ -583,12 +587,6 @@ def test_the_eager_forward_keeps_its_stack_offset():
         return (len(c.co_varnames) + len(c.co_cellvars)
                 + len(c.co_freevars) + c.co_stacksize)
 
-    path = [ServingFrontDoor.pump, ServingEngine.step,
-            ServingEngine.step_dispatch, ServingEngine._mixed_step,
-            ServingEngine._mixed_forward]
-    assert sum(words(f) for f in path) == 118, [words(f) for f in path]
-    # and under the jitted quantum, whose first trace and lowering (11 s
-    # of a run's set-up) moved by 4 s with ten words
     path = [ServingFrontDoor.pump, ServingEngine.step,
             ServingEngine.step_dispatch, ServingEngine._decode_dispatch,
             ServingEngine._guarded_dispatch, ServingEngine._dispatch_quantum]
