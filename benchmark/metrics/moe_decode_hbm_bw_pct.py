@@ -1,0 +1,28 @@
+"""Device: bytes the traced decode steps must read — the weights outside
+the routed experts once a step, each expert the program's counter says got
+a row (not all of them: the share cannot pass 100 %), the live latent cache
+— over the device time of the jitted decode quantum, against the chip's
+memory bandwidth. For the latent-attention expert family only."""
+from benchmark.harness import counts_deepseek_v3 as counts
+from benchmark.harness import moe_spans
+
+PROGRAM = "jit_quantum"  # the engine's jitted decode step, as the trace names it
+
+
+def read(obs):
+    trace = obs.get("trace")
+    if not trace or "new_tokens" not in obs \
+            or not counts.is_family(obs["config"]):
+        return None
+    seconds = trace["module_seconds"].get(PROGRAM, 0.0)
+    moe = moe_spans.window_totals(obs)
+    if seconds <= 0 or not moe:
+        return None
+    cfg = obs["config"]
+    expert_layers = (int(cfg["num_hidden_layers"])
+                     - int(cfg["first_k_dense_replace"]))
+    nbytes = counts.decode_bytes_needed(
+        cfg, moe["moe_layer_steps"] // expert_layers,
+        moe["moe_experts_touched"], obs["batches"], obs["batch"],
+        obs["prompt_len"], obs["new_tokens"])
+    return 100.0 * nbytes / seconds / obs["peaks"]["hbm_bytes_per_s"]

@@ -8,7 +8,9 @@ axis; token dispatch/combine are einsums against one-hot capacity
 masks, so GSPMD lowers the dispatch to the same all-to-all the reference
 issues explicitly via GlobalScatter — no hand-written collectives.
 """
-from .gate import TopKGate, GShardGate, SwitchGate  # noqa: F401
+from .gate import (  # noqa: F401
+    TopKGate, GShardGate, SwitchGate, SigmoidTopKGate)
 from .moe_layer import MoELayer  # noqa: F401
 
-__all__ = ["MoELayer", "TopKGate", "GShardGate", "SwitchGate"]
+__all__ = ["MoELayer", "TopKGate", "GShardGate", "SwitchGate",
+           "SigmoidTopKGate"]

@@ -11,7 +11,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["TopKGate", "GShardGate", "SwitchGate"]
+__all__ = ["TopKGate", "GShardGate", "SwitchGate", "SigmoidTopKGate"]
 
 
 def _capacity(num_tokens, num_experts, capacity_factor, top_k):
@@ -110,3 +110,40 @@ class GShardGate(TopKGate):
 class SwitchGate(TopKGate):
     def __init__(self, capacity_factor=1.25):
         super().__init__(top_k=1, capacity_factor=capacity_factor)
+
+
+class SigmoidTopKGate:
+    """The DeepSeek-V3 router's decision (``topk_method`` ``noaux_tc``):
+    sigmoid scores, a per-expert selection bias that moves the CHOICE
+    and never the weight, the chosen scores normalised to sum to one and
+    scaled. No capacity: every token keeps all ``top_k`` experts, so
+    nothing is dropped at any imbalance, and there is no auxiliary loss
+    (the bias is what balances the load).
+
+    ``n_group`` / ``topk_group``: the published group-limited selection
+    first keeps the ``topk_group`` best of ``n_group`` expert groups;
+    with one group it is the identity, the only case taken here."""
+
+    def __init__(self, top_k, norm_topk_prob=True,
+                 routed_scaling_factor=1.0, n_group=1, topk_group=1):
+        if n_group != 1 or topk_group != 1:
+            raise NotImplementedError(
+                f"SigmoidTopKGate: group-limited routing (n_group="
+                f"{n_group}, topk_group={topk_group}) is not implemented;"
+                f" only one group")
+        self.top_k = int(top_k)
+        self.norm_topk_prob = bool(norm_topk_prob)
+        self.routed_scaling_factor = float(routed_scaling_factor)
+
+    def topk_assignments(self, logits, bias=None):
+        """logits (T, E) -> (expert_ids (T, k), weights (T, k) float32,
+        None). ``bias`` (E,) is added to the scores for the selection
+        only."""
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+        choice = scores if bias is None else \
+            scores + bias.astype(jnp.float32)
+        _, topi = jax.lax.top_k(choice, self.top_k)
+        w = jnp.take_along_axis(scores, topi, axis=-1)
+        if self.norm_topk_prob:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        return topi, w * self.routed_scaling_factor, None

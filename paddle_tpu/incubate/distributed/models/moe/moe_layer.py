@@ -18,7 +18,44 @@ from .....tensor._helpers import apply, ensure_tensor
 from .....parallel import mesh as mesh_state
 from .gate import TopKGate, SwitchGate
 
-__all__ = ["MoELayer"]
+__all__ = ["MoELayer", "grouped_expert_ffn"]
+
+
+def grouped_expert_ffn(xt, expert_ids, gate_vals, w1, w2, act, b1=None,
+                       b2=None):
+    """The routed experts' FFN as two grouped matrix products
+    (megablocks-style): the T*k routed rows are sorted by expert and fed
+    to ``jax.lax.ragged_dot`` with the per-expert group sizes, so the
+    work is O(T*k) rows whatever the imbalance and no row is ever
+    dropped by this function (a gate that drops hands in weight zero).
+
+    ``xt`` (T, M) tokens; ``expert_ids`` / ``gate_vals`` (T, k) each
+    token's experts and combine weights; ``w1`` (E, M, F1), ``w2``
+    (E, F2, M) the stacked expert weights with ``act`` between them
+    (F1 -> F2); ``b1`` / ``b2`` optional stacked biases. Returns the
+    combined output (T, M) in ``xt``'s dtype and the rows each expert
+    got, (E,) int32. The k expert outputs of a token are gathered back
+    and summed in float32 in the order of ``expert_ids`` (a gather, not
+    a scatter-add: TPUs serialise scatters)."""
+    t, k = expert_ids.shape
+    e = w1.shape[0]
+    expert_flat = expert_ids.reshape(-1)                  # (T*k,)
+    order = jnp.argsort(expert_flat)                      # stable
+    sorted_exp = expert_flat[order]
+    group_sizes = jnp.bincount(expert_flat, length=e).astype(jnp.int32)
+
+    xs = xt[order // k]                                   # (T*k, M)
+    h = jax.lax.ragged_dot(xs, w1.astype(xt.dtype), group_sizes)
+    if b1 is not None:
+        h = h + b1[sorted_exp].astype(xt.dtype)
+    h = act(h)
+    out = jax.lax.ragged_dot(h, w2.astype(xt.dtype), group_sizes)
+    if b2 is not None:
+        out = out + b2[sorted_exp].astype(xt.dtype)
+    back = out[jnp.argsort(order)].reshape(t, k, -1)      # token-major
+    y = jnp.sum(back.astype(jnp.float32)
+                * gate_vals.astype(jnp.float32)[..., None], axis=1)
+    return y.astype(xt.dtype), group_sizes
 
 
 class MoELayer(Layer):
@@ -115,35 +152,13 @@ class MoELayer(Layer):
         (T, E, C) dispatch tensor. Same gate, same capacity-drop
         semantics (dropped rows keep their slot but combine with weight
         zero), same aux loss."""
-        cfg = self
         lead = xv.shape[:-1]
-        t = 1
-        for s in lead:
-            t *= s
-        k = cfg.gate.top_k
-        e = cfg.num_experts
-        xt = xv.reshape(t, cfg.d_model)
+        xt = xv.reshape(-1, self.d_model)
         logits = xt.astype(jnp.float32) @ gw.astype(jnp.float32)
-        topi, gate_vals, aux = cfg.gate.topk_assignments(logits)
-
-        expert_flat = topi.reshape(-1)                    # (T*k,)
-        gv_flat = gate_vals.reshape(-1)
-        tok_flat = jnp.repeat(jnp.arange(t, dtype=jnp.int32), k)
-        order = jnp.argsort(expert_flat)                  # stable
-        sorted_tok = tok_flat[order]
-        sorted_exp = expert_flat[order]
-        sorted_gv = gv_flat[order].astype(xv.dtype)
-        group_sizes = jnp.bincount(expert_flat, length=e).astype(jnp.int32)
-
-        xs = xt[sorted_tok]                               # (T*k, M)
-        h = jax.lax.ragged_dot(xs, w1.astype(xv.dtype), group_sizes)
-        h = h + b1[sorted_exp].astype(xv.dtype)
-        h = self._act(h)
-        out = jax.lax.ragged_dot(h, w2.astype(xv.dtype), group_sizes)
-        out = out + b2[sorted_exp].astype(xv.dtype)
-        y = jnp.zeros((t, cfg.d_model), xv.dtype).at[sorted_tok].add(
-            out * sorted_gv[:, None])
-        return y.reshape(*lead, cfg.d_model), aux
+        topi, gate_vals, aux = self.gate.topk_assignments(logits)
+        y, _ = grouped_expert_ffn(xt, topi, gate_vals, w1, w2, self._act,
+                                  b1=b1, b2=b2)
+        return y.reshape(*lead, self.d_model), aux
 
     def _grouped_ep_fn(self, xv, gw, w1, b1, w2, b2):
         """Expert-parallel grouped dispatch: a ``shard_map`` schedule over

@@ -14,6 +14,10 @@ from .llama import (  # noqa: F401
     LlamaForCausalLM,
     LlamaPretrainingCriterion,
 )
+from .deepseek_v3 import (  # noqa: F401
+    DeepseekV3Config, DeepseekV3Attention, DeepseekV3MLP, DeepseekV3MoE,
+    DeepseekV3DecoderLayer, DeepseekV3Model, DeepseekV3ForCausalLM,
+)
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForPretraining,

@@ -152,6 +152,26 @@ def _mark_hidden(t, config):
     return apply(fn, ensure_tensor(t), op_name="hidden_constraint")
 
 
+def _paged_write(k, v, write_blk, write_off, cache):
+    """Write a step's rotated K rows and V rows (..., HK, D) into a
+    layer's pool arrays ``cache`` = ``(k, v, k_scale, v_scale)`` at
+    ``(write_blk, write_off)``; on an int8 pool (scales not None) each
+    row is quantized at its write site. Returns the new four."""
+    from ..nn.quant import quantize_kv_rows
+    from .paged_attention import _pin_kv, _pin_kv_scale
+
+    kc, vc, ks, vs = cache
+    ksi = vsi = None
+    if ks is not None:
+        k, k_sc = quantize_kv_rows(k)            # (..., HK, D)/(..., HK)
+        v, v_sc = quantize_kv_rows(v)
+        ksi = _pin_kv_scale(ks.at[write_blk, write_off].set(k_sc))
+        vsi = _pin_kv_scale(vs.at[write_blk, write_off].set(v_sc))
+    kci = _pin_kv(kc.at[write_blk, write_off].set(k.astype(kc.dtype)))
+    vci = _pin_kv(vc.at[write_blk, write_off].set(v.astype(vc.dtype)))
+    return kci, vci, ksi, vsi
+
+
 class LlamaAttention(Layer):
     """Self-attention with rotary embedding, GQA, and optional KV cache.
 
@@ -284,6 +304,74 @@ class LlamaAttention(Layer):
             out = F.scaled_dot_product_attention(q, k, v, is_causal=True)
         out = out.reshape([b, s, self.num_heads * self.head_dim])
         return self.o_proj(out), cache
+
+    # -- the serving engine's attention protocol --------------------------
+    # (serving/engine.py: paged_decode_math / paged_chunk_math keep the
+    # layer loop and ask the layer's attention module for the rest)
+    def paged_rope(self, positions):
+        """What the rotary embedding needs at ``positions`` (float32, any
+        shape): ``(cos, sin)`` with a trailing D/2. The engine's bodies
+        ask the first layer once and hand the result to every layer."""
+        import jax.numpy as jnp
+
+        d = self.head_dim
+        inv_freq = 1.0 / (self.config.rope_theta ** (
+            jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+        freqs = positions[..., None] * inv_freq
+        return jnp.cos(freqs), jnp.sin(freqs)
+
+    def paged_decode(self, x, rope, tables, lens, write_blk, write_off,
+                     cache, attn_impl="gather"):
+        """One token a slot over the paged pool. ``x`` (S, 1, E) is the
+        normed input; ``cache`` this layer's pool arrays ``(k, v,
+        k_scale, v_scale)`` (the scales None on a float pool). Writes
+        the step's K/V row at ``(write_blk, write_off)``, attends the
+        ``lens`` live positions of each row's ``tables`` and returns the
+        attention output (S, 1, E) and the layer's new pool arrays."""
+        from ..core.tensor import Tensor
+        from .paged_attention import _paged_attn, _rope_rows
+
+        s = x.shape[0]
+        h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
+        cos, sin = rope
+        q = self.q_proj(x).reshape([s, 1, h, d])
+        k = self.k_proj(x).reshape([s, 1, hk, d])
+        v = self.v_proj(x).reshape([s, 1, hk, d])
+        qv = _rope_rows(q._value[:, 0], cos, sin)    # (S, H, D)
+        kv = _rope_rows(k._value[:, 0], cos, sin)
+        vv = v._value[:, 0]
+        kci, vci, ksi, vsi = new = _paged_write(kv, vv, write_blk,
+                                                write_off, cache)
+        att = _paged_attn(qv, kci, vci, tables, lens, ks=ksi, vs=vsi,
+                          impl=attn_impl)
+        att_t = Tensor(att.reshape(s, 1, h * d), stop_gradient=True)
+        return self.o_proj(att_t), new
+
+    def paged_chunk(self, x, rope, tables, base_lens, write_blk,
+                    write_off, cache):
+        """C tokens a slot over the paged pool (the mixed prefill step
+        and the speculative verify): position j of a row writes at
+        ``(write_blk, write_off)[:, j]`` and attends the row's
+        ``base_lens`` cached positions and the chunk's own up to j.
+        Same contract as :meth:`paged_decode` with a chunk axis."""
+        from ..core.tensor import Tensor
+        from .paged_attention import _paged_chunk_attn, _rope_rows
+
+        s, c = x.shape[0], x.shape[1]
+        h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
+        cos, sin = rope
+        q = self.q_proj(x).reshape([s, c, h, d])
+        k = self.k_proj(x).reshape([s, c, hk, d])
+        v = self.v_proj(x).reshape([s, c, hk, d])
+        qv = _rope_rows(q._value, cos, sin)          # (S, C, H, D)
+        kv = _rope_rows(k._value, cos, sin)
+        vv = v._value
+        kci, vci, ksi, vsi = new = _paged_write(kv, vv, write_blk,
+                                                write_off, cache)
+        att = _paged_chunk_attn(qv, kci, vci, tables, base_lens,
+                                ks=ksi, vs=vsi)
+        att_t = Tensor(att.reshape(s, c, h * d), stop_gradient=True)
+        return self.o_proj(att_t), new
 
     def forward_no_cache(self, hidden, position_offset=0,
                          cu_seqlens=None, position_ids=None):
@@ -569,6 +657,21 @@ class LlamaForCausalLM(Layer):
         if caches is not None:
             return logits, new_caches
         return logits
+
+    # -- what the serving engine asks of a model ---------------------------
+    @property
+    def decoder(self):
+        """The stack the engine's bodies loop over: ``embed_tokens``,
+        ``layers`` (each with ``input_layernorm``, ``self_attn``,
+        ``post_attention_layernorm``, ``mlp``) and ``norm``."""
+        return self.llama
+
+    def paged_cache_layout(self):
+        """The pool geometry this model's attention caches: a K and a V
+        array a layer, each row ``num_key_value_heads x head_dim``."""
+        cfg = self.config
+        return {"layout": "kv", "num_kv_heads": cfg.num_key_value_heads,
+                "head_dim": cfg.head_dim}
 
     def generate(self, input_ids, max_new_tokens=32,
                  decode_strategy="greedy_search", **kwargs):

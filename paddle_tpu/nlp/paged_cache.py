@@ -107,6 +107,15 @@ class PagedKVCachePool:
         num_blocks: pool capacity in blocks (shared by all sequences).
         block_size: tokens per block (lane-friendly: 16/32/64...).
         num_kv_heads, head_dim, num_layers: cache geometry.
+        layout: ``"kv"`` (a K and a V array a layer) or ``"latent"``:
+            ONE array a layer whose row is the token's compressed
+            key/value (latent attention: ``num_kv_heads`` 1,
+            ``head_dim`` the latent width plus the rotary key's). The V
+            side is then an EMPTY list exactly as the scale pools are
+            empty on a float pool (zero avals), so ``adopt``,
+            ``commit_like``, copy-on-write, the accounting and the
+            donation audit hold for both. A model says which it caches
+            (``model.paged_cache_layout()``).
         dtype: cache dtype (bf16 for serving).
         kv_dtype: ``"int8"`` switches the block buffers to int8 and
             grows per-layer SCALE POOLS ``k_scales``/``v_scales`` of
@@ -131,7 +140,21 @@ class PagedKVCachePool:
 
     def __init__(self, num_blocks, block_size, num_kv_heads, head_dim,
                  num_layers=1, dtype=jnp.bfloat16, prefix_cache=False,
-                 mesh=None, kv_dtype=None):
+                 mesh=None, kv_dtype=None, layout="kv"):
+        if layout not in ("kv", "latent"):
+            raise ValueError(
+                f"unsupported pool layout {layout!r} (kv or latent)")
+        if layout == "latent" and kv_dtype is not None:
+            raise NotImplementedError(
+                "a latent pool with kv_dtype='int8' is not supported: "
+                "the latent row's one scale would be shared by the "
+                "compressed values and the rotary key")
+        if layout == "latent" and mesh is not None:
+            raise NotImplementedError(
+                "a latent pool under a mesh is not supported: its one "
+                "row a token is shared by every head, so there is no "
+                "head axis to shard")
+        self.layout = layout
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.num_kv_heads = int(num_kv_heads)
@@ -159,8 +182,9 @@ class PagedKVCachePool:
         pool_dtype = jnp.int8 if self.quantized else dtype
         self.k_pools = [jnp.zeros(shape, pool_dtype)
                         for _ in range(num_layers)]
-        self.v_pools = [jnp.zeros(shape, pool_dtype)
-                        for _ in range(num_layers)]
+        self.v_pools = ([] if layout == "latent" else
+                        [jnp.zeros(shape, pool_dtype)
+                         for _ in range(num_layers)])
         if self._pool_sharding is not None:
             self.k_pools = [jax.device_put(p, self._pool_sharding)
                             for p in self.k_pools]
@@ -458,8 +482,9 @@ class PagedKVCachePool:
             for i in range(self.num_layers):
                 self.k_pools[i] = self._pin(self.k_pools[i].at[fresh].set(
                     self.k_pools[i][blk]))
-                self.v_pools[i] = self._pin(self.v_pools[i].at[fresh].set(
-                    self.v_pools[i][blk]))
+                if self.v_pools:
+                    self.v_pools[i] = self._pin(
+                        self.v_pools[i].at[fresh].set(self.v_pools[i][blk]))
                 if self.quantized:
                     # the scale rows ARE the block's content on a
                     # quantized pool — a COW that left them behind
@@ -770,6 +795,7 @@ class PagedKVCachePool:
             "shared_blocks": shared,
             "cached_blocks": len(self._cached_blocks),
             "kv_dtype": str(self.k_pools[0].dtype),
+            "bytes_per_token": self.bytes_per_token(),
             "bytes_in_use": self.bytes_in_use(),
             "per_chip_bytes_in_use": self.per_chip_bytes_in_use(),
         }
@@ -806,12 +832,23 @@ class PagedKVCachePool:
         from the ACTUAL buffer itemsize (int8 pools report half a
         bf16 pool's bytes) plus the scale-pool rows that travel with
         each quantized block."""
-        per_block = (self.block_size * self.num_kv_heads * self.head_dim
-                     * self.k_pools[0].dtype.itemsize)
+        return (self.bytes_per_token() * self.block_size
+                * self.blocks_in_use)
+
+    @property
+    def arrays_per_layer(self):
+        """Block arrays a layer holds: K and V, or the one latent."""
+        return 1 if self.layout == "latent" else 2
+
+    def bytes_per_token(self):
+        """Pool bytes one cached token takes over all layers (the scale
+        rows of an int8 pool included): the pool's bytes over its token
+        capacity."""
+        per_row = (self.num_kv_heads * self.head_dim
+                   * self.k_pools[0].dtype.itemsize)
         if self.quantized:
-            per_block += (self.block_size * self.num_kv_heads
-                          * self.k_scales[0].dtype.itemsize)
-        return 2 * self.num_layers * self.blocks_in_use * per_block
+            per_row += self.num_kv_heads * self.k_scales[0].dtype.itemsize
+        return self.arrays_per_layer * self.num_layers * per_row
 
     def per_chip_bytes_in_use(self):
         """Live cache bytes RESIDENT PER CHIP: under a head-sharded

@@ -171,6 +171,21 @@ class ServingObs:
         self._c_mixed_padded = r.counter(
             "serving_mixed_padded_tokens_total",
             "positions a mixed step computed that carried no token")
+        # routed experts, per expert layer and decode step (the shared
+        # denominator ``serving_moe_layer_steps_total``); all zero for
+        # a model without experts
+        self._c_moe = {
+            key: r.counter(f"serving_moe_{key}_total", text)
+            for key, text in (
+                ("routed_rows", "rows the decode steps handed to experts"),
+                ("experts_touched", "experts that got at least one row"),
+                ("expert_rows_max", "the fullest expert's rows"),
+                ("layer_steps", "(expert layer, decode step) pairs"))}
+        # on the process's registry too: a reader outside the program
+        # finds it after the engine is gone
+        self._g_pool_token_bytes = r.share(MetricsRegistry.process().gauge(
+            "serving_pool_bytes_per_token",
+            "pool bytes one cached token takes over all layers"))
         self._c_submitted = r.counter(
             "serving_requests_submitted_total", "requests queued")
         self._c_admitted = r.counter(
@@ -563,6 +578,10 @@ class ServingObs:
             self._g_coll_bytes.set(float(d["bytes"]), kind=kind)
             self._g_coll_count.set(float(d["count"]), kind=kind)
 
+    def set_pool_bytes_per_token(self, nbytes, pool="target"):
+        """Published once at engine build (the pool's geometry)."""
+        self._g_pool_token_bytes.set(float(nbytes), pool=pool)
+
     def on_mixed_dispatch(self, bucket, padded_tokens, built):
         """One mixed step is about to dispatch the program of chunk
         length ``bucket``: count its padding beside
@@ -572,6 +591,24 @@ class ServingObs:
         self._c_mixed_padded.inc(padded_tokens)
         if built:
             self._c_mixed_programs.inc(built, bucket=str(bucket))
+
+    def on_moe_rows(self, rows):
+        """The decode steps just read back handed ``rows`` (an int array
+        ``(steps, expert layers, experts)``) to the experts. Returns what
+        the counters were raised by, which the step's span carries too
+        (``moe_rows``, ``moe_experts_touched``, ``moe_rows_max``,
+        ``moe_layer_steps``): a reader outside the program bounds a
+        window by its spans."""
+        by = {"routed_rows": int(rows.sum()),
+              "experts_touched": int((rows > 0).sum()),
+              "expert_rows_max": int(rows.max(axis=-1).sum()),
+              "layer_steps": rows.shape[0] * rows.shape[1]}
+        for key, n in by.items():
+            self._c_moe[key].inc(n)
+        return {"moe_rows": by["routed_rows"],
+                "moe_experts_touched": by["experts_touched"],
+                "moe_rows_max": by["expert_rows_max"],
+                "moe_layer_steps": by["layer_steps"]}
 
     def on_quantum(self, kind, t0, t1, tokens, rows, breakdown=None,
                    device_s=None):

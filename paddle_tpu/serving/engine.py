@@ -29,6 +29,20 @@ shape of that loop:
   list for immediate reuse; admission is gated on worst-case demand so
   the pool cannot exhaust mid-flight (scheduler.py).
 
+WHAT IS ASKED OF A MODEL. The two jitted bodies (``paged_decode_math``,
+``paged_chunk_math``) keep the layer loop, the embedding, the positions,
+the write addresses, the norms, the head and ``layer.mlp``; everything
+else belongs to the model: ``model.decoder`` (``embed_tokens``,
+``layers``, ``norm``), ``model.paged_cache_layout()`` (what a layer
+caches: K and V rows, or ONE latent row a token) and each layer's
+``self_attn.paged_rope`` / ``paged_decode`` / ``paged_chunk`` (the
+attention protocol: given the normed input, positions, tables, lengths,
+write block/offset and that layer's pool arrays, the attention output and
+the new pool arrays). ``nlp/llama.py`` has the K/V form,
+``nlp/deepseek_v3.py`` latent attention's two forms and routed experts in
+``layer.mlp``; both programs hand back the rows the experts got beside the
+tokens (``moe_rows``; an empty tuple, no aval, without experts).
+
 Token selection reuses the generation tier's ``_filter_logits``
 (greedy argmax or temperature/top-k/top-p sampling with per-slot key
 fold-in); the greedy arm is oracle-tested bit-exact against
@@ -93,7 +107,6 @@ same seed, no mesh at model build, identical weights either way.
 from __future__ import annotations
 
 import contextlib
-import math
 
 import numpy as np
 import jax
@@ -104,7 +117,7 @@ from ..core import autograd
 from ..jit import functional_call
 from ..nlp.generation import _filter_logits
 from ..nlp.paged_cache import PagedKVCachePool
-from ..nn.quant import quantize_for_serving, quantize_kv_rows
+from ..nn.quant import quantize_for_serving
 from ..obs.flight import FlightRecorder
 from ..obs.serving import ServingObs
 from ..obs.slo import SLOSet
@@ -225,249 +238,50 @@ def _tp_shard_params(model):
     return n_sharded
 
 
-def _rope_rows(x, cos, sin):
-    """Rotate (..., H, D) by per-row angles (..., D/2) — the model's
-    default (neox) rotary layout at each row's own cache position.
-    Broadcasts over any leading dims: (S, H, D) with (S, D/2) for the
-    decode quantum, (S, C, H, D) with (S, C, D/2) for the speculative
-    verify chunk."""
-    xf = x.astype(jnp.float32)
-    c = cos[..., None, :]
-    s = sin[..., None, :]
-    d = x.shape[-1]
-    x1, x2 = xf[..., : d // 2], xf[..., d // 2:]
-    out = jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
-    return out.astype(x.dtype)
+# the pool-attention math lives beside the pool (nlp/paged_attention.py);
+# the two decode paths the parity tests compare stay importable from here
+from ..nlp.paged_attention import (  # noqa: E402,F401
+    _fused_paged_decode_attn, _xla_paged_decode_attn)
 
 
-def _xla_paged_decode_attn(q, kp, vp, tables, lens, ks=None, vs=None):
-    """Off-TPU decode attention over the paged pool: gather the table's
-    blocks and run the same f32 masked softmax as the contiguous-cache
-    fallback (`_masked_decode_attn`). ``ks``/``vs`` are the optional
-    per-row scale pools of an int8 pool ((NB, BS, HK) f32): the gathered
-    rows dequantize in f32 before the softmax, so the math matches the
-    float path up to the quantization rounding itself."""
-    s_, h, d = q.shape
-    w = tables.shape[1]
-    bs, hk = kp.shape[1], kp.shape[2]
-    k = kp[tables].reshape(s_, w * bs, hk, d)
-    v = vp[tables].reshape(s_, w * bs, hk, d)
-    if ks is not None:
-        k = k.astype(jnp.float32) * ks[tables].reshape(
-            s_, w * bs, hk)[..., None]
-        v = v.astype(jnp.float32) * vs[tables].reshape(
-            s_, w * bs, hk)[..., None]
-    rep = h // hk
-    kr = jnp.repeat(k, rep, axis=2) if rep > 1 else k
-    vr = jnp.repeat(v, rep, axis=2) if rep > 1 else v
-    sc = 1.0 / math.sqrt(d)
-    logits = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32),
-                        kr.astype(jnp.float32)) * sc
-    mask = jnp.arange(w * bs)[None, :] < lens[:, None]
-    logits = jnp.where(mask[:, None, :], logits, -1e30)
-    p = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhk,bkhd->bhd", p, vr.astype(jnp.float32))
-    return out.astype(q.dtype)
+def _layer_cache(pools, i):
+    """Layer ``i``'s pool arrays ``(k, v, k_scale, v_scale)``; a side
+    the pool does not have (the scales of a float pool, the V side of a
+    latent pool) is an empty tuple and reads None."""
+    return tuple(p[i] if len(p) else None for p in pools)
 
 
-def _fused_paged_decode_attn(q, kp, vp, tables, lens, ks=None, vs=None):
-    """Fused (flash-style) decode attention over the paged pool: an
-    online-softmax scan over the BLOCK-TABLE entries, porting the two
-    tricks the Pallas paged kernel and the d128 varlen retune already
-    won (BENCH_NOTES "Paged KV-cache decode" / "flash/varlen kernel
-    retune") to the portable XLA level:
-
-      * no gathered copy — the oracle (`_xla_paged_decode_attn`)
-        materializes the whole (S, W*BS, HK, D) context twice before a
-        full-width softmax; here each scan step touches ONE pool block
-        per row and folds it into running (m, l, acc) f32 statistics,
-        so temp residency is per-block, not per-context.
-      * DMA elision analog — a row whose context ended before block
-        ``ki`` re-points its gather at pool block 0 (the Pallas
-        kernel's clamped ``pool_idx`` map) and masks the whole block,
-        so dead steps never touch cold pool memory.
-
-    Same f32 compute dtype, same -1e30 mask, same trailing cast as the
-    oracle; the online rescale chain reorders the softmax reductions,
-    which is exactly why the gather path stays wired in as the parity
-    oracle (streams compare bit-exact on the tiny recipe shapes — the
-    bf16 output cast absorbs the ulp-level reassociation).
-    ``ks``/``vs`` are the int8 pool's per-row scale pools: blocks
-    dequantize in f32 as they stream through, never all at once."""
-    s_, h, d = q.shape
-    w = tables.shape[1]
-    bs, hk = kp.shape[1], kp.shape[2]
-    rep = h // hk
-    sc = 1.0 / math.sqrt(d)
-    qf = q.astype(jnp.float32)                        # (S, H, D)
-    neg = jnp.float32(-1e30)
-
-    def body(carry, ki):
-        m, l, acc = carry
-        start = ki * bs
-        alive = start < lens                          # (S,)
-        blk = jnp.where(alive, tables[:, ki], 0)      # elision clamp
-        k = kp[blk].astype(jnp.float32)               # (S, BS, HK, D)
-        v = vp[blk].astype(jnp.float32)
-        if ks is not None:
-            k = k * ks[blk][..., None]
-            v = v * vs[blk][..., None]
-        if rep > 1:
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
-        logits = jnp.einsum("bhd,bkhd->bhk", qf, k) * sc   # (S, H, BS)
-        mask = alive[:, None] & (
-            (start + jnp.arange(bs))[None, :] < lens[:, None])
-        logits = jnp.where(mask[:, None, :], logits, neg)
-        m2 = jnp.maximum(m, jnp.max(logits, axis=-1))
-        alpha = jnp.exp(m - m2)                       # (S, H)
-        p = jnp.exp(logits - m2[..., None])           # (S, H, BS)
-        l2 = l * alpha + jnp.sum(p, axis=-1)
-        acc2 = acc * alpha[..., None] + jnp.einsum("bhk,bkhd->bhd", p, v)
-        return (m2, l2, acc2), None
-
-    m0 = jnp.full((s_, h), neg, jnp.float32)
-    l0 = jnp.zeros((s_, h), jnp.float32)
-    a0 = jnp.zeros((s_, h, d), jnp.float32)
-    # every row attends >= 1 position (masked rows carry lens == 1), so
-    # the first live block always lifts m above the -1e30 init before
-    # any dead block's exp(neg - m) underflows to an exact 0
-    (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), jnp.arange(w))
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.astype(q.dtype)
+def _collect_cache(new, out):
+    """Append a layer's new pool arrays to the per-side lists (a None
+    side stays empty)."""
+    for side, arr in zip(out, new):
+        if arr is not None:
+            side.append(arr)
 
 
-# the f32 score tile of `_paged_chunk_attn` may take this many bytes; a
-# chunk whose scores over its whole block table would take more streams
-# over the table in tiles of key blocks (a shape rule: no knob)
-_CHUNK_SCORE_BYTES = 256 << 20
+def _expert_blocks(model):
+    """The feed-forward blocks of ``model`` that route rows to experts."""
+    return [layer.mlp for layer in model.decoder.layers
+            if hasattr(layer.mlp, "rows_per_expert")]
 
 
-def _paged_chunk_attn(q, kp, vp, tables, base_lens, ks=None, vs=None):
-    """Chunk attention over the paged pool, shared by the speculative
-    VERIFY pass and the mixed prefill step: query position j of each
-    slot attends pool positions < base+j+1 (the row's cached context
-    and the chunk's own positions up to j, which the caller has already
-    written). q is (S, C, H, D).
-
-    The query heads are grouped over their KV head (K and V are never
-    repeated), operands keep the pool's dtype with f32 accumulation,
-    and the softmax is f32 with the same -1e30 mask as the decode
-    paths. The (S, H, C, keys) f32 scores are built for
-    ``_CHUNK_SCORE_BYTES`` worth of key blocks at a time and folded
-    into running (m, l, acc) statistics — the online softmax of
-    `_fused_paged_decode_attn` with a chunk dimension; a table whose
-    scores fit that size is one tile and no loop. ``ks``/``vs`` are the
-    int8 pool's per-row scale pools: a tile dequantizes in f32 as it
-    streams through. No Pallas analog yet: this runs on every
-    backend."""
-    s_, c, h, d = q.shape
-    w = tables.shape[1]
-    bs, hk = kp.shape[1], kp.shape[2]
-    g = h // hk
-    sc = 1.0 / math.sqrt(d)
-    tile = max(1, min(w, _CHUNK_SCORE_BYTES // (s_ * h * c * bs * 4)))
-    n_tiles = -(-w // tile)
-    # whole tiles: the padding columns point at pool block 0 and lie
-    # past every row's length, so the mask below hides them
-    tiled = jnp.pad(tables, ((0, 0), (0, n_tiles * tile - w))).reshape(
-        s_, n_tiles, tile).transpose(1, 0, 2)           # (N, S, tile)
-    lens = base_lens[:, None] + jnp.arange(c)[None, :] + 1   # (S, C)
-    neg = jnp.float32(-1e30)
-    qg = q.reshape(s_, c, hk, g, d)
-
-    def fold(carry, ti):
-        m, l, acc = carry
-        blk = tiled[ti]                                 # (S, tile)
-        k = kp[blk].reshape(s_, tile * bs, hk, d)
-        v = vp[blk].reshape(s_, tile * bs, hk, d)
-        if ks is not None:
-            k = k.astype(jnp.float32) * ks[blk].reshape(
-                s_, tile * bs, hk)[..., None]
-            v = v.astype(jnp.float32) * vs[blk].reshape(
-                s_, tile * bs, hk)[..., None]
-        ct = jnp.promote_types(q.dtype, k.dtype)
-        logits = jnp.einsum(
-            "bchgd,bkhd->bhgck", qg.astype(ct), k.astype(ct),
-            preferred_element_type=jnp.float32) * sc    # (S,HK,G,C,K)
-        kpos = ti * (tile * bs) + jnp.arange(tile * bs)
-        mask = kpos[None, None, :] < lens[:, :, None]   # (S, C, K)
-        logits = jnp.where(mask[:, None, None], logits, neg)
-        m2 = jnp.maximum(m, jnp.max(logits, axis=-1))
-        alpha = jnp.exp(m - m2)                         # (S, HK, G, C)
-        p = jnp.exp(logits - m2[..., None])
-        l2 = l * alpha + jnp.sum(p, axis=-1)
-        acc2 = acc * alpha[..., None] + jnp.einsum(
-            "bhgck,bkhd->bhgcd", p.astype(ct), v.astype(ct),
-            preferred_element_type=jnp.float32)
-        return (m2, l2, acc2), None
-
-    # every query sees pool position 0 (base >= 0), so the first tile
-    # lifts m above the -1e30 init before any masked tile's exp(neg - m)
-    # underflows to an exact 0
-    carry = (jnp.full((s_, hk, g, c), neg, jnp.float32),
-             jnp.zeros((s_, hk, g, c), jnp.float32),
-             jnp.zeros((s_, hk, g, c, d), jnp.float32))
-    if n_tiles == 1:
-        carry, _ = fold(carry, 0)
-    else:
-        carry, _ = jax.lax.scan(fold, carry, jnp.arange(n_tiles))
-    _, l, acc = carry
-    out = acc / jnp.maximum(l, 1e-30)[..., None]        # (S,HK,G,C,D)
-    return out.transpose(0, 3, 1, 2, 4).reshape(s_, c, h, d).astype(
-        q.dtype)
+def moe_rows(model):
+    """Rows each expert of each expert layer was handed in the forward
+    just traced: a ``(expert layers, experts)`` int32 array, or ``()``
+    for a model without routed experts (zero avals: such a model's
+    programs are what they were). Read inside the same trace as the
+    forward, as ``MoELayer.l_aux`` is."""
+    blocks = _expert_blocks(model)
+    return jnp.stack([b.rows_per_expert for b in blocks]) if blocks else ()
 
 
-def _paged_attn(q, kp, vp, tables, lens, ks=None, vs=None,
-                impl="gather"):
-    """Route decode attention: Pallas paged kernel on TPU (block tables
-    dereferenced in SMEM, one pool block DMA per grid step), XLA gather
-    fallback elsewhere. Per-row scale pools (int8 engine) always take
-    an XLA path: the Pallas kernel only supports STATIC per-head
-    scales, not per-(block, position, head) pools. ``impl="fused"``
-    selects the online-softmax block-streaming path
-    (`_fused_paged_decode_attn`) for the XLA tier — the engine's
-    ``attn_impl=`` knob; the default keeps every existing graph (and
-    golden fingerprint) byte-identical."""
-    from ..core.flags import get_flags
-
-    if ks is None:
-        flags = get_flags(
-            ["FLAGS_use_pallas_kernels", "FLAGS_pallas_force"])
-        use_pallas = flags["FLAGS_use_pallas_kernels"] and (
-            jax.default_backend() == "tpu" or flags["FLAGS_pallas_force"])
-        if use_pallas:
-            from ..ops.pallas.paged_attention import paged_decode_attention
-
-            return paged_decode_attention(q, kp, vp, tables, lens)
-    if impl == "fused":
-        return _fused_paged_decode_attn(q, kp, vp, tables, lens,
-                                        ks=ks, vs=vs)
-    return _xla_paged_decode_attn(q, kp, vp, tables, lens, ks=ks, vs=vs)
-
-
-def _pin_kv(arr):
-    """Constrain one per-layer pool array to the head-sharded mesh
-    layout (``P(None, None, 'mp', None)``) so GSPMD keeps the donated
-    pool outputs on exactly the layout they arrived in — the in-place
-    block write must never force a gather/reshard of the whole pool.
-    Identity when no mesh is installed, ``mp == 1``, or the KV-head dim
-    doesn't divide: the single-chip quantum graphs (and their golden
-    fingerprints) are untouched byte-for-byte."""
-    mp = mesh_state.mesh_axis_size("mp")
-    if mp > 1 and arr.shape[2] % mp == 0:
-        return mesh_state.constraint(arr, None, None, "mp", None)
-    return arr
-
-
-def _pin_kv_scale(arr):
-    """`_pin_kv` for the (NB, BS, HK) scale pools of an int8 pool: the
-    kv-head axis is the last one, so the constraint drops the trailing
-    head-dim entry. Same identity conditions as `_pin_kv`."""
-    mp = mesh_state.mesh_axis_size("mp")
-    if mp > 1 and arr.shape[2] % mp == 0:
-        return mesh_state.constraint(arr, None, None, "mp")
-    return arr
+def _moe_rows_buffer(model, *lead):
+    """Zeros for ``lead`` stacked :func:`moe_rows` results (``()`` for a
+    model without experts)."""
+    blocks = _expert_blocks(model)
+    if not blocks:
+        return ()
+    return jnp.zeros((*lead, len(blocks), blocks[0].num_experts), jnp.int32)
 
 
 def paged_decode_math(model, scratch_block, ids_t, seq_lens, tables,
@@ -478,27 +292,25 @@ def paged_decode_math(model, scratch_block, ids_t, seq_lens, tables,
     ``model`` so the plain quantum (target) and the speculative DRAFT
     scan (serving/speculative.py) share one decode-step definition.
 
+    The body keeps the layer loop, the embedding, the positions, the
+    write addresses, the norms, the head and ``layer.mlp``; the rest is
+    asked of each layer's attention module (``paged_rope`` once,
+    ``paged_decode`` a layer: ``nlp/llama.py`` has the K/V form,
+    ``nlp/deepseek_v3.py`` the latent one).
+
     ``ks``/``vs`` are the per-layer per-row scale pools of an int8
     pool (empty tuples on a float pool — zero extra avals, so the
-    unquantized quantum graph and its golden are byte-identical): each
-    KV row quantizes symmetrically at its write site and the gathered
-    context dequantizes inside the attention math. Returns
-    ``(logits, new_kc, new_vc, new_ks, new_vs)``; the scale tuples stay
-    ``()`` when unquantized."""
-    cfg = model.config
-    core = model.llama
-    s = ids_t.shape[0]
-    h, hk, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                cfg.head_dim)
+    unquantized quantum graph and its golden are byte-identical), and
+    ``vc`` is empty on a latent pool. Returns
+    ``(logits, new_kc, new_vc, new_ks, new_vs)``; a side that came in
+    empty goes out empty."""
+    core = model.decoder
     bs = kc[0].shape[1]
     w = tables.shape[1]
 
     hidden = core.embed_tokens(ids_t)                # (S, 1, E)
-    inv_freq = 1.0 / (cfg.rope_theta ** (
-        jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    pos = seq_lens.astype(jnp.float32)
-    freqs = pos[:, None] * inv_freq[None, :]
-    cos, sin = jnp.cos(freqs), jnp.sin(freqs)        # (S, D/2)
+    rope = core.layers[0].self_attn.paged_rope(
+        seq_lens.astype(jnp.float32))
 
     blk_idx = jnp.clip(seq_lens // bs, 0, w - 1)
     own_blk = jnp.take_along_axis(tables, blk_idx[:, None],
@@ -507,44 +319,20 @@ def paged_decode_math(model, scratch_block, ids_t, seq_lens, tables,
     write_off = jnp.where(live, seq_lens % bs, 0)
     lens = jnp.where(live, seq_lens + 1, 1)
 
-    quant = len(ks) > 0
-    new_kc, new_vc, new_ks, new_vs = [], [], [], []
+    out = ([], [], [], [])
     for i, layer in enumerate(core.layers):
-        attn = layer.self_attn
-        residual = hidden
-        x = layer.input_layernorm(hidden)
-        q = attn.q_proj(x).reshape([s, 1, h, d])
-        k = attn.k_proj(x).reshape([s, 1, hk, d])
-        v = attn.v_proj(x).reshape([s, 1, hk, d])
-        qv = _rope_rows(q._value[:, 0], cos, sin)    # (S, H, D)
-        kv = _rope_rows(k._value[:, 0], cos, sin)
-        vv = v._value[:, 0]
-        ksi = vsi = None
-        if quant:
-            kv, k_sc = quantize_kv_rows(kv)          # (S, HK, D)/(S, HK)
-            vv, v_sc = quantize_kv_rows(vv)
-            ksi = _pin_kv_scale(
-                ks[i].at[write_blk, write_off].set(k_sc))
-            vsi = _pin_kv_scale(
-                vs[i].at[write_blk, write_off].set(v_sc))
-            new_ks.append(ksi)
-            new_vs.append(vsi)
-        kci = _pin_kv(kc[i].at[write_blk, write_off].set(
-            kv.astype(kc[i].dtype)))
-        vci = _pin_kv(vc[i].at[write_blk, write_off].set(
-            vv.astype(vc[i].dtype)))
-        new_kc.append(kci)
-        new_vc.append(vci)
-        att = _paged_attn(qv, kci, vci, tables, lens, ks=ksi, vs=vsi,
-                          impl=attn_impl)
-        att_t = Tensor(att.reshape(s, 1, h * d), stop_gradient=True)
-        hidden = residual + attn.o_proj(att_t)
+        att, new = layer.self_attn.paged_decode(
+            layer.input_layernorm(hidden), rope, tables, lens,
+            write_blk, write_off, _layer_cache((kc, vc, ks, vs), i),
+            attn_impl=attn_impl)
+        _collect_cache(new, out)
+        hidden = hidden + att
         hidden = hidden + layer.mlp(
             layer.post_attention_layernorm(hidden))
     hidden = core.norm(hidden)
     logits = model.lm_head(hidden)
-    return (logits._value[:, 0], new_kc, new_vc,
-            tuple(new_ks), tuple(new_vs))
+    return (logits._value[:, 0], out[0], out[1],
+            tuple(out[2]), tuple(out[3]))
 
 
 def paged_chunk_math(model, scratch_block, ids_t, seq_lens, tables,
@@ -555,7 +343,8 @@ def paged_chunk_math(model, scratch_block, ids_t, seq_lens, tables,
     SURVEY.md §0) and for the engine's mixed prefill step. Chunk
     position j writes its KV at ``seq_lens + j`` (masked rows go to the
     scratch block) and attends its own prefix; one batched forward
-    covers all slots and all C positions.
+    covers all slots and all C positions. The attention itself is each
+    layer's ``self_attn.paged_chunk`` (see ``paged_decode_math``).
 
     ``counts=None`` is the verify pass: every position of a live row is
     valid and the logits of all of them come back, (S, C, V). Stale
@@ -567,21 +356,15 @@ def paged_chunk_math(model, scratch_block, ids_t, seq_lens, tables,
     count write to the scratch block, so no valid position ever reads
     them, and the head runs only at each row's last valid position:
     (S, V) logits, never (S, C, V)."""
-    cfg = model.config
-    core = model.llama
-    s, c = ids_t.shape
-    h, hk, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                cfg.head_dim)
+    core = model.decoder
+    c = ids_t.shape[1]
     bs = kc[0].shape[1]
     w = tables.shape[1]
 
     hidden = core.embed_tokens(ids_t)                # (S, C, E)
-    inv_freq = 1.0 / (cfg.rope_theta ** (
-        jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    pos_f = (seq_lens[:, None]
-             + jnp.arange(c)[None, :]).astype(jnp.float32)
-    freqs = pos_f[..., None] * inv_freq              # (S, C, D/2)
-    cos, sin = jnp.cos(freqs), jnp.sin(freqs)
+    rope = core.layers[0].self_attn.paged_rope(
+        (seq_lens[:, None]
+         + jnp.arange(c)[None, :]).astype(jnp.float32))
 
     valid = live[:, None]
     if counts is not None:
@@ -593,38 +376,13 @@ def paged_chunk_math(model, scratch_block, ids_t, seq_lens, tables,
     write_off = jnp.where(valid, wpos % bs, 0)
     base_lens = jnp.where(live, seq_lens, 0)
 
-    quant = len(ks) > 0
-    new_kc, new_vc, new_ks, new_vs = [], [], [], []
+    out = ([], [], [], [])
     for i, layer in enumerate(core.layers):
-        attn = layer.self_attn
-        residual = hidden
-        x = layer.input_layernorm(hidden)
-        q = attn.q_proj(x).reshape([s, c, h, d])
-        k = attn.k_proj(x).reshape([s, c, hk, d])
-        v = attn.v_proj(x).reshape([s, c, hk, d])
-        qv = _rope_rows(q._value, cos, sin)          # (S, C, H, D)
-        kv = _rope_rows(k._value, cos, sin)
-        vv = v._value
-        ksi = vsi = None
-        if quant:
-            kv, k_sc = quantize_kv_rows(kv)      # (S,C,HK,D)/(S,C,HK)
-            vv, v_sc = quantize_kv_rows(vv)
-            ksi = _pin_kv_scale(
-                ks[i].at[write_blk, write_off].set(k_sc))
-            vsi = _pin_kv_scale(
-                vs[i].at[write_blk, write_off].set(v_sc))
-            new_ks.append(ksi)
-            new_vs.append(vsi)
-        kci = _pin_kv(kc[i].at[write_blk, write_off].set(
-            kv.astype(kc[i].dtype)))
-        vci = _pin_kv(vc[i].at[write_blk, write_off].set(
-            vv.astype(vc[i].dtype)))
-        new_kc.append(kci)
-        new_vc.append(vci)
-        att = _paged_chunk_attn(qv, kci, vci, tables, base_lens,
-                                ks=ksi, vs=vsi)
-        att_t = Tensor(att.reshape(s, c, h * d), stop_gradient=True)
-        hidden = residual + attn.o_proj(att_t)
+        att, new = layer.self_attn.paged_chunk(
+            layer.input_layernorm(hidden), rope, tables, base_lens,
+            write_blk, write_off, _layer_cache((kc, vc, ks, vs), i))
+        _collect_cache(new, out)
+        hidden = hidden + att
         hidden = hidden + layer.mlp(
             layer.post_attention_layernorm(hidden))
     if counts is None:
@@ -634,7 +392,7 @@ def paged_chunk_math(model, scratch_block, ids_t, seq_lens, tables,
         logits = model.lm_head(core.norm(Tensor(
             jnp.take_along_axis(hidden._value, last, axis=1),
             stop_gradient=True)))._value[:, 0]
-    return logits, new_kc, new_vc, tuple(new_ks), tuple(new_vs)
+    return logits, out[0], out[1], tuple(out[2]), tuple(out[3])
 
 
 class _AuditedStep:
@@ -894,6 +652,23 @@ class ServingEngine:
             raise ValueError(
                 f"multi_quantum must be >= 1, got {multi_quantum}")
         self.mesh, self.tp = _resolve_tp_mesh(mesh, tp)
+        layout = model.paged_cache_layout()
+        if layout["layout"] == "latent":
+            # nothing is silently ignored: what the latent pool and the
+            # latent attention cannot do yet is refused by name
+            for name, asked in (("kv_dtype='int8'", kv_dtype == "int8"),
+                                ("tp > 1", self.tp > 1),
+                                ("spec_draft", spec_draft is not None)):
+                if asked:
+                    raise NotImplementedError(
+                        f"ServingEngine does not compose {name} with a "
+                        f"latent-attention model "
+                        f"({type(model).__name__}) yet")
+        elif spec_draft is not None and spec_draft.paged_cache_layout()[
+                "layout"] == "latent":
+            raise NotImplementedError(
+                "ServingEngine does not take a latent-attention model "
+                f"({type(spec_draft).__name__}) as spec_draft yet")
         if self.tp > 1:
             _check_tp_divisible(cfg, self.tp, "target")
             if spec_draft is not None:
@@ -966,10 +741,10 @@ class ServingEngine:
             num_blocks = s * w + 1  # +1: the masked-write scratch block
         self.prefix_cache = bool(prefix_cache)
         self.pool = PagedKVCachePool(
-            num_blocks, bs, cfg.num_key_value_heads, cfg.head_dim,
+            num_blocks, bs, layout["num_kv_heads"], layout["head_dim"],
             num_layers=cfg.num_hidden_layers, dtype=cache_dtype,
             prefix_cache=self.prefix_cache, mesh=self.mesh,
-            kv_dtype=kv_dtype)
+            kv_dtype=kv_dtype, layout=layout["layout"])
         self.pool.commit_like(self._p_vals[0])
         # masked (retired/empty) rows dump their KV writes here
         self._scratch_block = self.pool.ensure("__scratch__", 1)[0]
@@ -992,9 +767,10 @@ class ServingEngine:
                 self._d_p_vals[0].dtype)
             # the draft pool quantizes too: spec decoding doubles pool
             # pressure, so the residency win must cover both pools
+            d_layout = spec_draft.paged_cache_layout()
             self.d_pool = PagedKVCachePool(
-                num_blocks, bs, d_cfg.num_key_value_heads,
-                d_cfg.head_dim, num_layers=d_cfg.num_hidden_layers,
+                num_blocks, bs, d_layout["num_kv_heads"],
+                d_layout["head_dim"], num_layers=d_cfg.num_hidden_layers,
                 dtype=d_cache_dtype,
                 prefix_cache=self.prefix_cache, mesh=self.mesh,
                 kv_dtype=kv_dtype)
@@ -1024,7 +800,8 @@ class ServingEngine:
         # boundary obs.on_token fires on
         self.token_sink = None
 
-        n_pool = 4 if self.pool.quantized else 2
+        n_pool = (4 if self.pool.quantized
+                  else self.pool.arrays_per_layer)
         # the mixed step: ONE jitted, pool-donating program per model
         # (the draft's writes its KV only); jit keeps an executable per
         # chunk-length bucket, built on the bucket's first use
@@ -1133,6 +910,7 @@ class ServingEngine:
         # static per-build collective profile -> registry gauges (zeros
         # suppressed; a tp=1 engine leaves the series empty)
         self.obs.set_quantum_collectives(self.quantum_collectives)
+        self.obs.set_pool_bytes_per_token(self.pool.bytes_per_token())
         # cost-ledger MFU constants (obs/attribution.py): target-model
         # FLOPs per decoded token (2N weight-matmul floor, embedding
         # gathers excluded) and the chip peak (0.0 on the CPU backend —
@@ -1141,7 +919,10 @@ class ServingEngine:
         from ..obs.attribution import decode_flops_per_token
         from ..profiler.mfu import peak_flops_per_chip
 
-        n_params = sum(int(v.size) for v in self._p_vals)
+        # "params actually multiplied per token": of an expert layer's
+        # routed experts a token multiplies its top k, not all of them
+        n_params = sum(int(v.size) for v in self._p_vals) - sum(
+            b.inactive_params_per_token() for b in _expert_blocks(model))
         embed = (int(getattr(cfg, "vocab_size", 0))
                  * int(getattr(cfg, "hidden_size", 0)))
         # int8 flops model: a quantized stack feeds the MXU's int8 path,
@@ -1883,14 +1664,17 @@ class ServingEngine:
                 def fwd(ids_t):
                     return paged_chunk_math(
                         model, scratch, ids_t, seq_lens, tables, kc, vc,
-                        counts > 0, ks=ks, vs=vs, counts=counts)
+                        counts > 0, ks=ks, vs=vs,
+                        counts=counts), moe_rows(model)
 
-                (logits, kc2, vc2, ks2, vs2), _ = functional_call(
+                ((logits, kc2, vc2, ks2, vs2), rows), _ = functional_call(
                     model, fwd, [Tensor(ids, stop_gradient=True)], {},
                     p_vals, [])
             if not select:
                 return kc2, vc2, ks2, vs2
-            return (kc2, vc2, ks2, vs2,
+            # ``rows`` is () for a model without experts: no aval; the
+            # tokens stay the last output
+            return (kc2, vc2, ks2, vs2, rows,
                     self._select_device(logits, keys, n_gen, temps))
 
         return mixed
@@ -2001,7 +1785,7 @@ class ServingEngine:
             # alias the host mirrors (a CPU upload is zero-copy), which
             # the emission below writes
             with RecordEvent("engine.mixed.forward", model="target"):
-                *pools, toks = self._mixed(*args)
+                *pools, rows, toks = self._mixed(*args)
                 self.pool.adopt(*pools)
                 jax.block_until_ready(toks)
             if spec:
@@ -2010,6 +1794,9 @@ class ServingEngine:
                     jax.block_until_ready(self.d_pool.k_pools[-1])
             with RecordEvent("engine.mixed.select"):
                 nxt = np.asarray(toks)               # (S,) int32
+                if not isinstance(rows, tuple):
+                    # (expert layers, experts): the step's routed rows
+                    span.args["moe_rows"] = int(np.asarray(rows).sum())
             now = self._now()  # the stamp of every token of the step
             with RecordEvent("engine.mixed.emit"):
                 prefill_emitted = 0
@@ -2130,12 +1917,12 @@ class ServingEngine:
                         return paged_decode_math(
                             model, scratch, tok_t, seq_lens, tables,
                             kc, vc, live, ks=ks, vs=vs,
-                            attn_impl=attn_impl)
+                            attn_impl=attn_impl), moe_rows(model)
 
-                    (logits, kc2, vc2, ks2, vs2), _ = functional_call(
-                        model, fwd,
-                        [Tensor(last_tok[:, None], stop_gradient=True)],
-                        {}, p_vals, [])
+                    tok_t = Tensor(last_tok[:, None], stop_gradient=True)
+                    ((logits, kc2, vc2, ks2, vs2), rows), _ = \
+                        functional_call(model, fwd, [tok_t], {}, p_vals,
+                                        [])
                 nxt = self._select_device(logits, keys, n_gen, temps)
                 nxt = jnp.where(done, last_tok, nxt).astype(jnp.int32)
                 n_gen2 = n_gen + live.astype(jnp.int32)
@@ -2144,16 +1931,18 @@ class ServingEngine:
                     done2 = done2 | (live & (nxt == eos))
                 seq_lens2 = seq_lens + live.astype(jnp.int32)
                 return (kc2, vc2, ks2, vs2, seq_lens2, nxt, n_gen2,
-                        done2), nxt
+                        done2), (nxt, rows)
 
-            (kc, vc, ks, vs, seq_lens, last_tok, n_gen, done), toks = \
-                jax.lax.scan(
+            # ``rows``: (T, expert layers, experts) int32, or () for a
+            # model without experts (no aval: its graph is what it was)
+            (kc, vc, ks, vs, seq_lens, last_tok, n_gen, done), \
+                (toks, rows) = jax.lax.scan(
                     body,
                     (kc, vc, tuple(ks), tuple(vs), seq_lens, last_tok,
                      n_gen, done),
                     None, length=t_steps)
             return (kc, vc, ks, vs, seq_lens, last_tok, n_gen, done,
-                    toks)
+                    toks, rows)
 
         def multi_steps(kc, vc, ks, vs, p_vals, tables, seq_lens,
                         last_tok, n_gen, done, max_new, keys, temps):
@@ -2165,6 +1954,7 @@ class ServingEngine:
             # counter tells the host how many quanta to account.
             k_max = int(multi)
             buf0 = jnp.zeros((k_max, t_steps, n_slots), jnp.int32)
+            rbuf0 = _moe_rows_buffer(model, k_max, t_steps)
 
             def cond(carry):
                 qi, done = carry[0], carry[8]
@@ -2172,23 +1962,26 @@ class ServingEngine:
 
             def body(carry):
                 (qi, kc, vc, ks, vs, seq_lens, last_tok, n_gen, done,
-                 buf) = carry
+                 buf, rbuf) = carry
                 (kc, vc, ks, vs, seq_lens, last_tok, n_gen, done,
-                 toks) = scan_steps(kc, vc, ks, vs, p_vals, tables,
-                                    seq_lens, last_tok, n_gen, done,
-                                    max_new, keys, temps)
+                 toks, rows) = scan_steps(kc, vc, ks, vs, p_vals, tables,
+                                          seq_lens, last_tok, n_gen, done,
+                                          max_new, keys, temps)
                 buf = jax.lax.dynamic_update_slice(
                     buf, toks[None], (qi, 0, 0))
+                rbuf = jax.tree_util.tree_map(
+                    lambda b, r: jax.lax.dynamic_update_slice(
+                        b, r[None], (qi, 0, 0, 0)), rbuf, rows)
                 return (qi + 1, kc, vc, ks, vs, seq_lens, last_tok,
-                        n_gen, done, buf)
+                        n_gen, done, buf, rbuf)
 
             (qi, kc, vc, ks, vs, seq_lens, last_tok, n_gen, done,
-             buf) = jax.lax.while_loop(
+             buf, rbuf) = jax.lax.while_loop(
                 cond, body,
                 (jnp.int32(0), kc, vc, tuple(ks), tuple(vs), seq_lens,
-                 last_tok, n_gen, done, buf0))
+                 last_tok, n_gen, done, buf0, rbuf0))
             return (kc, vc, ks, vs, seq_lens, last_tok, n_gen, done,
-                    buf, qi)
+                    buf, rbuf, qi)
 
         inner = scan_steps if multi is None else multi_steps
         if self._per_request_sampling:
@@ -2451,8 +2244,9 @@ class ServingEngine:
             # adopt the donated pool outputs NOW (async handles — no
             # sync): the pre-dispatch buffers were consumed by donation
             self.pool.adopt(kc, vc, ks, vs)
-            # out: seq_lens, last_tok, n_gen, done, toks, and the count
-            # of quanta that ran where the dispatch was of several. The
+            # out: seq_lens, last_tok, n_gen, done, toks, the experts'
+            # rows (() without experts), and the count of quanta that
+            # ran where the dispatch was of several. The
             # device's share of the wall starts where the call returned:
             # the enqueue span's end
             return {"rows": rows, "excluded": excluded, "t0": span.t0,
@@ -2468,10 +2262,12 @@ class ServingEngine:
         of the wall, so the conservation invariants partition exactly),
         and retire finished rows."""
         with RecordEvent("engine.decode", step_kind="decode",
-                         step=self.stats["steps"], half="collect"):
+                         step=self.stats["steps"],
+                         half="collect") as step:
             rows, excluded = pending["rows"], pending["excluded"]
             t0, k = pending["t0"], pending["k"]
-            seq_lens, last_tok, n_gen, done, toks, nq = pending["out"]
+            seq_lens, last_tok, n_gen, done, toks, moe, nq = \
+                pending["out"]
             t_steps = self.config.decode_quantum
             with RecordEvent("engine.decode.sync") as sync:
                 toks = np.asarray(toks)                      # sync
@@ -2479,6 +2275,14 @@ class ServingEngine:
                 self._last_tok = np.asarray(last_tok).copy()
                 self._n_gen = np.asarray(n_gen).copy()
                 self._done = np.asarray(done).copy()
+                if not isinstance(moe, tuple):
+                    # (T, layers, experts), or (K, T, ...) of which the
+                    # quanta that ran: per expert layer and decode step
+                    moe = np.asarray(moe)
+                    if k > 1:
+                        moe = moe[:max(int(np.asarray(nq)), 1)]
+                    step.args.update(self.obs.on_moe_rows(
+                        moe.reshape(-1, *moe.shape[-2:])))
             # the device's share of the wall: from the jitted call's return
             # (the enqueue span's end) to the sync span's end
             now = sync.t1

@@ -47,7 +47,7 @@ the ``speculative_verify_step`` analysis budget (0 involuntary remat,
 TENSOR PARALLELISM: the round needs no code of its own — it is built
 from the SAME ``paged_decode_math`` / ``paged_chunk_math`` the plain
 quantum scans, whose KV writes re-pin the kv-head sharding under an
-installed mesh (engine.py ``_pin_kv``). When the engine runs ``tp>1``
+installed mesh (nlp/paged_attention.py ``_pin_kv``). When the engine runs ``tp>1``
 both models' params are mesh-sharded at build, BOTH paged pools carry
 the kv-head split, and the whole draft+verify round stays one dispatch
 whose collectives live in-graph — the ``serving_tp_step`` recipe's

@@ -1,0 +1,422 @@
+"""The DeepSeek-V3-shaped decoder (latent attention, routed experts beside
+shared ones) on the normal serving path, against the benchmark's plain
+reference (``benchmark/reference/deepseek_v3.py``: float32, HIGHEST,
+un-absorbed, no cache), on the toy configuration with seeded weights in
+float32.
+
+Tolerances: program and reference compute the same float32 numbers in
+another order (the program fuses gate|up, sorts rows by expert, folds
+attention tiles, absorbs the up-projection at decode), so they differ by
+summation order only: logits of magnitude ~1 agree to 2e-5. The reference's
+int8-operand control moves the same logits by ~0.1 and a served token's gap
+to ~1e-2, so each tolerance below is asserted to be tight enough that the
+control fails it.
+"""
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as paddle
+from paddle_tpu.incubate.distributed.models.moe import SigmoidTopKGate
+from paddle_tpu.incubate.distributed.models.moe.moe_layer import (
+    grouped_expert_ffn)
+from paddle_tpu.nlp import (DeepseekV3Config, DeepseekV3ForCausalLM,
+                            LlamaConfig, LlamaForCausalLM, PagedKVCachePool)
+from paddle_tpu.nlp.deepseek_v3 import DeepseekV3MoE
+from paddle_tpu.obs.trace import TraceRecorder
+from paddle_tpu.serving import ServingEngine, no_shed_policy
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark.families import deepseek_v3 as family  # noqa: E402
+
+SEED = 2147483659
+LOGIT_TOL = 2e-5     # summation order in float32, logits of magnitude ~1
+GAP_TOL = 1e-4       # a served token lies this close to the reference's best
+
+
+@pytest.fixture(scope="module")
+def toy():
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "toy-mla-moe.json")) as f:
+        cfg = json.load(f)
+    model = family.build_model(cfg)
+    family.install_weights(model, cfg, SEED)
+    model.eval()
+    return cfg, model, family.leaf_reader(cfg, SEED)
+
+
+def _serve(model, **kw):
+    kw = {"num_slots": 4, "block_size": 8, "num_blocks": 64,
+          "max_context": 96, "prefill_chunk": 16, "decode_quantum": 4, **kw}
+    return paddle.inference.serve(model, policy=no_shed_policy(), **kw)
+
+
+def _drain(door, prompts, new_tokens):
+    streams = [door.submit(p, max_new_tokens=new_tokens) for p in prompts]
+    while door.engine.has_work:
+        door.pump()
+    return [_host(s.request.tokens, np.int32) for s in streams]
+
+
+def _host(x, dtype=None):
+    """A device value on the host, said out loud (the repo's lint takes a
+    bare ``np.asarray`` / ``float`` over a jax value for an accident)."""
+    return np.asarray(jax.device_get(x), dtype)
+
+
+def _max_abs(a, b=0.0):
+    return float(np.abs(_host(a) - _host(b)).max())
+
+
+def _prompts(cfg, lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, cfg["vocab_size"], (n,), dtype=np.int32)
+            for n in lengths]
+
+
+# ------------------------------------------------------ forward, reference
+def test_forward_matches_the_reference_logits(toy):
+    cfg, model, get_leaf = toy
+    ids = np.stack(_prompts(cfg, (40, 40)))
+    ref = family.reference.logits(cfg, get_leaf, ids)
+    got = model(paddle.to_tensor(ids))._value
+    assert _max_abs(ref) > 0.5
+    assert _max_abs(ref, got) < LOGIT_TOL
+    # the tolerance is earned: the int8-operand control fails it
+    control = family.reference.logits(cfg, get_leaf, ids, control=True)
+    assert _max_abs(ref, control) > 100 * LOGIT_TOL
+
+
+@pytest.mark.parametrize("chunk,quantum", [(16, 4), (8, 1), (32, 8)])
+def test_served_tokens_are_the_references_best(toy, chunk, quantum):
+    """Prefill in chunks, then decode, through the engine's latent pool:
+    every served token is the reference's best to within GAP_TOL, and the
+    stream is the one a whole-sequence forward would pick greedily."""
+    cfg, model, get_leaf = toy
+    prompts = _prompts(cfg, (37, 20, 9), seed=chunk)
+    door = _serve(model, prefill_chunk=chunk, decode_quantum=quantum)
+    served = _drain(door, prompts, 12)
+    rows = list(zip(prompts, served))
+    gaps, _ = family.reference.gap_below_best(cfg, get_leaf, rows)
+    assert gaps.shape == (36,) and float(_host(gaps).max()) < GAP_TOL
+    # and the logits where the test can reach them: the program's own
+    # whole-sequence forward picks the same stream
+    for p, toks in rows:
+        ids = np.concatenate([p, toks[:-1]])[None]
+        lg = model(paddle.to_tensor(ids))._value[0, len(p) - 1:]
+        assert np.array_equal(_host(jnp.argmax(lg, -1)), toks)
+    pool = door.engine.pool
+    assert pool.layout == "latent" and pool.v_pools == []
+    assert pool.k_pools[0].shape[2:] == (1, 64 + 16)
+
+
+def test_the_int8_control_fails_the_gap_tolerance(toy):
+    """GAP_TOL is earned: over 160 served positions the reference with
+    int8 operands, standing in the program's place, lies further below the
+    best than any served token may."""
+    cfg, model, get_leaf = toy
+    prompts = _prompts(cfg, (24, 24, 24, 24), seed=7)
+    served = _drain(_serve(model), prompts, 40)
+    gaps, cgaps = family.reference.gap_below_best(
+        cfg, get_leaf, list(zip(prompts, served)), control=True)
+    assert float(_host(gaps).max()) < GAP_TOL < 10 * GAP_TOL < float(_host(cgaps).max())
+
+
+def test_absorbed_decode_equals_the_unabsorbed_form(toy):
+    """The decode form (query carried into latent space, the pool read
+    once) against the whole-sequence form (per-head keys and values from
+    ``c``) at the same position."""
+    cfg, model, _ = toy
+    attn = model.model.layers[1].self_attn
+    s, n, bs = 2, 21, 8
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.standard_normal((s, n, cfg["hidden_size"])),
+                    jnp.float32)
+    want = attn(paddle.to_tensor(x))._value[:, -1]
+    pool = jnp.zeros((8, bs, 1, 64 + 16), jnp.float32)
+    tables = jnp.asarray(1 + np.arange(s * 3).reshape(s, 3), jnp.int32)
+    pos = jnp.arange(n - 1)
+    rope = attn.paged_rope(jnp.broadcast_to(pos, (s, n - 1)).astype(
+        jnp.float32))
+    _, (pool, *_) = attn.paged_chunk(
+        paddle.to_tensor(x[:, :-1]), rope, tables, jnp.zeros(s, jnp.int32),
+        tables[jnp.arange(s)[:, None], pos[None, :] // bs],
+        jnp.broadcast_to(pos % bs, (s, n - 1)), (pool, None, None, None))
+    last = jnp.full((s,), n - 1)
+    got, (pool2, v, ks, vs) = attn.paged_decode(
+        paddle.to_tensor(x[:, -1:]), attn.paged_rope(last.astype(
+            jnp.float32)), tables, last + 1, tables[:, (n - 1) // bs],
+        last % bs, (pool, None, None, None))
+    assert v is None and ks is None and vs is None
+    assert _max_abs(got._value[:, 0], want) < LOGIT_TOL
+
+
+# ---------------------------------------------------------------- the gate
+def test_gate_against_the_reference_on_hand_made_scores():
+    """The bias moves the choice, not the weight; weights sum to the
+    scaling factor."""
+    gate = SigmoidTopKGate(2, True, 2.448)
+    logits = jnp.asarray([[2.0, 1.0, 0.0, -1.0],
+                          [0.0, 0.1, 0.2, 0.3]], jnp.float32)
+    bias = jnp.asarray([0.0, 0.0, 0.5, 0.0], jnp.float32)
+    sel, w, aux = gate.topk_assignments(logits, bias)
+    s = jax.nn.sigmoid(logits)
+    assert aux is None
+    # without the bias row 0 picks experts 0, 1; with it 0, 2
+    assert sorted(_host(gate.topk_assignments(logits)[0][0])) == [0, 1]
+    assert sorted(_host(sel[0])) == [0, 2]
+    picked = np.take_along_axis(_host(s), _host(sel), 1)
+    np.testing.assert_allclose(
+        _host(w), 2.448 * picked / picked.sum(1, keepdims=True),
+        rtol=1e-6)
+    np.testing.assert_allclose(_host(w).sum(1), 2.448, rtol=1e-6)
+    m = {"top_k": 2, "norm_topk": True, "scaling": 2.448}
+    eye = {"router_w": jnp.eye(4, dtype=jnp.float32), "router_b": bias}
+    rsel, rw = family.reference.route(logits, eye, m)
+    order = np.argsort(_host(sel), 1), np.argsort(_host(rsel), 1)
+    np.testing.assert_array_equal(
+        np.take_along_axis(_host(sel), order[0], 1),
+        np.take_along_axis(_host(rsel), order[1], 1))
+    np.testing.assert_allclose(
+        np.take_along_axis(_host(w), order[0], 1),
+        np.take_along_axis(_host(rw), order[1], 1), rtol=1e-6)
+
+
+def test_gate_refuses_groups_by_name():
+    with pytest.raises(NotImplementedError, match="n_group"):
+        SigmoidTopKGate(2, n_group=4, topk_group=2)
+
+
+@pytest.mark.parametrize("spread", ["one_expert_set", "even"])
+def test_no_token_is_dropped_at_any_imbalance(spread):
+    """All rows to one set of experts: every row is still multiplied by
+    all of its experts (no capacity), and the block equals a dense sum
+    over the chosen experts."""
+    paddle.seed(5)
+    cfg = DeepseekV3Config.tiny()
+    block = DeepseekV3MoE(cfg)
+    if spread == "one_expert_set":
+        # a selection bias that outweighs every score: experts 5, 6, 7
+        block.gate.e_score_correction_bias._value = jnp.asarray(
+            [0, 0, 0, 0, 0, 9, 9, 9], jnp.float32)
+    x = jnp.asarray(np.random.default_rng(1).standard_normal((3, 11, 64)),
+                    jnp.float32)
+    got = block(paddle.to_tensor(x))._value
+    rows = _host(block.rows_per_expert)
+    assert rows.sum() == 33 * cfg.num_experts_per_tok
+    if spread == "one_expert_set":
+        assert list(rows) == [0, 0, 0, 0, 0, 33, 33, 33]
+    xt = x.reshape(-1, 64)
+    s = jax.nn.sigmoid(xt @ block.gate.weight._value)
+    sel = jax.lax.top_k(s + block.gate.e_score_correction_bias._value, 3)[1]
+    w = jnp.take_along_axis(s, sel, 1)
+    w = 2.448 * w / w.sum(1, keepdims=True)
+    sel_host = _host(sel).tolist()
+    w1, w2 = (block.experts.gate_up_proj._value,
+              block.experts.down_proj._value)
+    want = block.shared_experts(paddle.to_tensor(xt))._value
+    for t in range(33):
+        for j in range(3):
+            e = sel_host[t][j]
+            gu = xt[t] @ w1[e]
+            want = want.at[t].add(
+                w[t, j] * ((jax.nn.silu(gu[:32]) * gu[32:]) @ w2[e]))
+    np.testing.assert_allclose(_host(got.reshape(-1, 64)),
+                               _host(want), atol=2e-5)
+
+
+def test_grouped_core_counts_every_row():
+    """The factored sort + ragged_dot core hands every row to its expert
+    whatever the weights, and reports the rows each expert got."""
+    rng = np.random.default_rng(0)
+    xt = jnp.asarray(rng.standard_normal((6, 4)), jnp.float32)
+    ids = jnp.asarray([[1, 1], [1, 2], [1, 0], [1, 3], [1, 1], [1, 2]])
+    w = jnp.ones((6, 2), jnp.float32)
+    w1 = jnp.asarray(rng.standard_normal((4, 4, 4)), jnp.float32)
+    w2 = jnp.asarray(rng.standard_normal((4, 4, 4)), jnp.float32)
+    y, rows = grouped_expert_ffn(xt, ids, w, w1, w2, jnp.tanh)
+    assert list(_host(rows)) == [1, 8, 2, 1]
+    want = sum(jnp.einsum("tf,tfm->tm", jnp.tanh(jnp.einsum(
+        "tm,tmf->tf", xt, w1[ids[:, j]])), w2[ids[:, j]]) for j in range(2))
+    np.testing.assert_allclose(_host(y), _host(want), atol=1e-5)
+
+
+# ---------------------------------------------------------------- the pool
+def _latent_pool(**kw):
+    return PagedKVCachePool(num_blocks=8, block_size=4, num_kv_heads=1,
+                            head_dim=12, num_layers=3, dtype=jnp.float32,
+                            layout="latent", **kw)
+
+
+def test_latent_pool_accounting():
+    pool = _latent_pool()
+    assert pool.v_pools == [] and pool.k_scales == [] == pool.v_scales
+    assert [a.shape for a in pool.k_pools] == [(8, 4, 1, 12)] * 3
+    assert pool.arrays_per_layer == 1
+    assert pool.bytes_per_token() == 3 * 12 * 4
+    pool.ensure("a", 9)                       # three blocks
+    st = pool.fragmentation_stats()
+    assert st["blocks_in_use"] == 3 and st["bytes_per_token"] == 144
+    assert st["bytes_in_use"] == 3 * 4 * 144
+    kv = PagedKVCachePool(num_blocks=8, block_size=4, num_kv_heads=1,
+                          head_dim=12, num_layers=3, dtype=jnp.float32)
+    assert kv.bytes_per_token() == 2 * pool.bytes_per_token()
+    # adopt / commit_like take the empty V side as they take empty scales
+    pool.adopt(list(pool.k_pools), [], (), ())
+    pool.commit_like(pool.k_pools[0])
+    assert pool.v_pools == []
+
+
+def test_latent_pool_copy_on_write_and_prefix_publication():
+    pool = _latent_pool(prefix_cache=True)
+    toks = np.arange(8, dtype=np.int32)
+    table = pool.ensure("a", 8)
+    for i in range(3):
+        pool.k_pools[i] = pool.k_pools[i].at[jnp.asarray(table)].set(
+            float(i + 1))
+    assert pool.publish_prefix("a", toks) == 2
+    assert pool.attach_prefix("b", toks) == 8
+    assert pool._tables["b"] == table
+    assert pool.make_writable("b", 4, 8) == 1 and pool.cow_copies == 1
+    fresh = pool._tables["b"][1]
+    assert fresh != table[1] and pool._tables["b"][0] == table[0]
+    for i in range(3):                       # the copy carries the rows
+        np.testing.assert_array_equal(_host(pool.k_pools[i][fresh]),
+                                      _host(pool.k_pools[i][table[1]]))
+    st = pool.fragmentation_stats()          # raises on accounting drift
+    assert st["shared_blocks"] >= 1 and st["cached_blocks"] == 2
+
+
+def test_engine_serves_a_shared_prefix_from_the_latent_pool(toy):
+    """Prefix publication and copy-on-write through the engine: the second
+    request aliases the first's blocks and both streams are what an
+    unshared engine serves."""
+    cfg, model, _ = toy
+    base = _prompts(cfg, (32,))[0]
+    prompts = [np.concatenate([base, t]) for t in _prompts(cfg, (5, 7), 9)]
+    plain = _drain(_serve(model), prompts, 8)
+    door = _serve(model, prefix_cache=True)
+    first = _drain(door, prompts[:1], 8)
+    second = _drain(door, prompts[1:], 8)
+    assert door.engine.pool.prefix_hits >= 4
+    for got, want in zip(first + second, plain):
+        assert np.array_equal(got, want)
+
+
+# ------------------------------------------------------------ the refusals
+def _tiny():
+    paddle.seed(0)
+    return DeepseekV3ForCausalLM(DeepseekV3Config.tiny())
+
+
+@pytest.mark.parametrize("kwargs,name", [
+    ({"kv_dtype": "int8"}, "kv_dtype='int8'"),
+    ({"tp": 2}, "tp > 1"),
+    ({"spec_draft": "llama"}, "spec_draft"),
+    ({"spec_draft": "latent"}, "spec_draft"),
+    ({"sliding_window": 16}, "sliding_window"),
+])
+def test_refusals_by_name(kwargs, name):
+    """What the latent pool and the latent attention cannot do yet is
+    refused by name; nothing is silently ignored."""
+    model, kwargs = _tiny(), dict(kwargs)
+    if kwargs.get("spec_draft") == "llama":
+        kwargs["spec_draft"] = LlamaForCausalLM(
+            LlamaConfig.tiny(tensor_parallel=False))
+    elif kwargs.get("spec_draft") == "latent":
+        model, kwargs["spec_draft"] = LlamaForCausalLM(
+            LlamaConfig.tiny(tensor_parallel=False)), _tiny()
+    if "sliding_window" in kwargs:
+        with pytest.raises(NotImplementedError, match="sliding_window"):
+            DeepseekV3ForCausalLM(DeepseekV3Config.tiny(**kwargs))
+        model.config.sliding_window = kwargs.pop("sliding_window")
+    with pytest.raises(NotImplementedError) as err:
+        ServingEngine(model, num_slots=2, block_size=8, max_context=32,
+                      **kwargs)
+    assert name in str(err.value)
+
+
+@pytest.mark.parametrize("kwargs", [{"kv_dtype": "int8"}, {"mesh": True}])
+def test_latent_pool_refusals(kwargs):
+    if kwargs.get("mesh"):
+        kwargs = {"mesh": jax.sharding.Mesh(_host(jax.devices()[:2]),
+                                            ("mp",))}
+    with pytest.raises(NotImplementedError, match="latent pool"):
+        _latent_pool(**kwargs)
+
+
+# ------------------------------------------------------ spans and counters
+def test_counters_spans_and_scopes(toy):
+    cfg, model, _ = toy
+    rec = TraceRecorder.process()
+    first = rec.next_id()
+    door = _serve(model)
+    _drain(door, _prompts(cfg, (20, 9)), 9)
+    eng = door.engine
+    reg = eng.obs.registry
+    rows, touched, fullest, steps = (
+        reg.get(f"serving_moe_{k}_total").value()
+        for k in ("routed_rows", "experts_touched", "expert_rows_max",
+                  "layer_steps"))
+    quanta = eng.stats["decode_quanta"]
+    # two expert layers, four steps a quantum, four slots x top 3 rows
+    assert steps == quanta * 4 * 2 and rows == steps * 4 * 3
+    assert steps <= touched <= steps * 8 and fullest * 8 >= rows
+    spans = [e for e in rec.events
+             if e.get("args", {}).get("id", -1) >= first]
+    collect = [e["args"] for e in spans if e["name"] == "engine.decode"
+               and e["args"].get("half") == "collect"]
+    assert sum(a["moe_rows"] for a in collect) == rows
+    assert sum(a["moe_experts_touched"] for a in collect) == touched
+    assert sum(a["moe_rows_max"] for a in collect) == fullest
+    assert sum(a["moe_layer_steps"] for a in collect) == steps
+    mixed = [e["args"] for e in spans if e["name"] == "engine.mixed"]
+    assert mixed and all(a["moe_rows"] == 4 * a["bucket"] * 3 * 2
+                         for a in mixed)
+    stats = eng.engine_stats()["pool"]
+    assert stats["bytes_per_token"] == 3 * (64 + 16) * 4
+    assert reg.get("serving_pool_bytes_per_token").value(
+        pool="target") == stats["bytes_per_token"]
+    # the cost ledger's 2N counts a token's top 3 of 8 experts a layer:
+    # all parameters but the embedding, less 2 layers x 5 experts x 3
+    # matrices of 128 x 64
+    n = sum(int(p._value.size) for _, p in model.named_parameters())
+    assert eng.obs.ledger.flops_per_token == 2.0 * (
+        n - 2048 * 128 - 2 * 5 * 3 * 128 * 64)
+    step, args = eng.decode_step_target()
+    text = step.lower(*args).as_text(debug_info=True)
+    for scope in ("mla", "moe.router", "moe.experts", "moe.shared"):
+        assert scope in text, scope
+
+
+def test_a_model_without_experts_returns_no_rows():
+    """Llama's programs return an empty tuple where the rows would be: no
+    aval, so its graphs are what they were (the goldens hold that)."""
+    paddle.seed(0)
+    model = LlamaForCausalLM(LlamaConfig.tiny(tensor_parallel=False))
+    eng = ServingEngine(model, num_slots=2, block_size=8, max_context=32)
+    step, args = eng.decode_step_target()
+    assert jax.eval_shape(step._jitted, *args)[-1] == ()
+    assert eng.obs.registry.get(
+        "serving_moe_layer_steps_total").value() == 0
+
+
+def test_multi_quantum_carries_the_rows(toy):
+    cfg, model, _ = toy
+    prompts = _prompts(cfg, (20, 9))
+    want = _drain(_serve(model), prompts, 14)
+    door = _serve(model, multi_quantum=2)
+    got = _drain(door, prompts, 14)
+    for a, b in zip(got, want):
+        assert np.array_equal(a, b)
+    reg = door.engine.obs.registry
+    assert reg.get("serving_moe_layer_steps_total").value() \
+        == door.engine.stats["decode_quanta"] * 4 * 2

@@ -400,8 +400,10 @@ def test_chunk_attention_streams_to_the_same_answer(monkeypatch, budget,
         vp, vs = quantize_kv_rows(vp)
         pool_k = np.asarray(kp, np.float32) * np.asarray(ks)[..., None]
         pool_v = np.asarray(vp, np.float32) * np.asarray(vs)[..., None]
-    monkeypatch.setattr(engine_mod, "_CHUNK_SCORE_BYTES", budget)
-    got = engine_mod._paged_chunk_attn(
+    from paddle_tpu.nlp import paged_attention
+
+    monkeypatch.setattr(paged_attention, "_CHUNK_SCORE_BYTES", budget)
+    got = paged_attention._paged_chunk_attn(
         jnp.asarray(q), kp, vp, jnp.asarray(tables), jnp.asarray(base),
         ks=ks, vs=vs)
     want = dense_chunk_attention(
