@@ -74,9 +74,8 @@ def _fused_paged_decode_attn(q, kp, vp, tables, lens, ks=None, vs=None):
         per row and folds it into running (m, l, acc) f32 statistics,
         so temp residency is per-block, not per-context.
       * DMA elision analog — a row whose context ended before block
-        ``ki`` re-points its gather at pool block 0 (the Pallas
-        kernel's clamped ``pool_idx`` map) and masks the whole block,
-        so dead steps never touch cold pool memory.
+        ``ki`` re-points its gather at pool block 0 and masks the
+        whole block, so dead steps never touch cold pool memory.
 
     Same f32 compute dtype, same -1e30 mask, same trailing cast as the
     oracle; the online rescale chain reorders the softmax reductions,
@@ -211,8 +210,10 @@ def _paged_chunk_attn(q, kp, vp, tables, base_lens, ks=None, vs=None):
 
 def _paged_attn(q, kp, vp, tables, lens, ks=None, vs=None,
                 impl="gather"):
-    """Route decode attention: Pallas paged kernel on TPU (block tables
-    dereferenced in SMEM, one pool block DMA per grid step), XLA gather
+    """Route decode attention: Pallas paged kernel on TPU (it takes the
+    pool arrays as they are stored — no relayout on the way in — and
+    DMAs only the live blocks of each row's table, every kv head of a
+    block at once), XLA gather
     fallback elsewhere. Per-row scale pools (int8 engine) always take
     an XLA path: the Pallas kernel only supports STATIC per-head
     scales, not per-(block, position, head) pools. ``impl="fused"``

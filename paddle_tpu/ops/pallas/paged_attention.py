@@ -7,14 +7,24 @@ kernels under paddle/fluid/operators/fused/ — unverified, SURVEY.md
 sequences, with a per-sequence block table — memory scales with live
 tokens, not batch × max_seq.
 
-TPU-native mechanics: the pool rides in HBM as (HK, num_blocks,
-block_size, D); the per-sequence block tables and lengths ride in
-scalar-prefetch SMEM, and the BlockSpec index map dereferences the table
-directly — each grid step DMAs exactly one pool block, so the gather is
-zero-copy (no jnp.take materialization of the cache). Query heads
-sharing a KV head (the GQA group) form the rows of the score matmul, as
-in the contiguous-cache decode kernel. Blocks past a sequence's length
-re-point at pool block 0 (the DMA is elided) and are predicated off.
+TPU-native mechanics: the pool stays in HBM exactly as it is stored,
+(num_blocks, block_size, HK, D), and is never relaid out: the kernel
+sees it as (num_blocks, block_size * HK, D), which in the stored tiling
+is the same bytes (a bitcast — ``tests/test_tpu_aot_compile.py`` holds
+the compiled program to it; (num_blocks, block_size, HK * D) would be
+a physical copy of the whole pool). The per-sequence block tables and
+lengths ride in scalar-prefetch SMEM. One grid step is one sequence:
+it walks the LIVE entries of its table only, a chunk of blocks at a
+time — one DMA a block brings its rows for every kv head (contiguous
+in HBM), all of a chunk's DMAs in flight at once, the next chunk's (the
+next sequence's first, at a row's end) started before this one is
+waited for. Table entries past a sequence's length are never read. A
+chunk is attended in ONE pair of products: its (rows x heads, D) tile
+against all query heads, the scores of a query head against another kv
+head's rows masked off with the positions past the length (the
+products run on the MXU at the rate K and V stream through it whatever
+the number of query rows, so the masked part costs nothing and the
+kv heads need no loop and no strided read).
 """
 from __future__ import annotations
 
@@ -35,56 +45,142 @@ from ._utils import (
 
 NEG_INF = -1e30
 
+# a chunk holds this many K (and V) rows — block_size * HK a block — or
+# one block if a block has more: what one pair of products attends, and
+# what is in flight while it does (a shape rule: no knob)
+_CHUNK_ROWS = 1024
+
 
 def _paged_kernel(tables_ref, lens_ref, kscale_ref, vscale_ref, q_ref,
-                  k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *, sm_scale,
-                  block_size, steps, group, has_scales):
+                  k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, state, *, sm_scale,
+                  block_size, kv_heads, group, chunk, has_scales):
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    ki = pl.program_id(2)
+    rows = pl.num_programs(0)
+    steps = tables_ref.shape[1]
+    heads = kv_heads * group
+    d = q_ref.shape[-1]
+    width = chunk * block_size * kv_heads      # K rows of one chunk
+
+    def live_blocks(row):
+        return jnp.minimum(pl.cdiv(lens_ref[row], block_size), steps)
+
     length = lens_ref[b]
+    n = live_blocks(b)
+    chunks = pl.cdiv(n, chunk)
+    nxt = jnp.minimum(b + 1, rows - 1)
+    next_live = (b + 1 < rows) & (lens_ref[nxt] > 0)
 
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    @pl.when(b == 0)
+    def _reset():
+        # state: the buffer half the next chunk lands in, and whether
+        # the previous row already started this row's first chunk. The
+        # halves start as zeros: a chunk's unused tail then only ever
+        # holds zeros or older pool rows, which the mask multiplies by 0
+        state[0] = 0
+        state[1] = 0
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
 
-    @pl.when(ki * block_size < length)
-    def _body():
-        q = q_ref[0, 0].astype(jnp.float32)   # (G, D)
-        k = k_ref[0, 0].astype(jnp.float32)   # (BS, D)
-        v = v_ref[0, 0].astype(jnp.float32)
+    def block_copies(blk, half, t):
+        return (pltpu.make_async_copy(k_hbm.at[blk], kbuf.at[half, t],
+                                      sem.at[0, half]),
+                pltpu.make_async_copy(v_hbm.at[blk], vbuf.at[half, t],
+                                      sem.at[1, half]))
+
+    def start_chunk(row, j, half):
+        def start(t, _):
+            for copy in block_copies(tables_ref[row, j * chunk + t],
+                                     half, t):
+                copy.start()
+            return 0
+
+        jax.lax.fori_loop(
+            0, jnp.minimum(chunk, live_blocks(row) - j * chunk), start, 0)
+
+    def wait_chunk(count, half):
+        def wait(t, _):
+            # a wait only needs the copy's shape: any block stands in
+            for copy in block_copies(0, half, t):
+                copy.wait()
+            return 0
+
+        jax.lax.fori_loop(0, count, wait, 0)
+
+    @pl.when(n > 0)
+    def _row():
+        half0 = state[0]
+
+        @pl.when(state[1] == 0)
+        def _first():
+            start_chunk(b, 0, half0)
+
+        q = q_ref[0].astype(jnp.float32)                    # (H, D)
+        # column c of a chunk's scores is K row c: token c // HK of the
+        # chunk, kv head c % HK; query head r belongs to kv head r // G
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+        col_head, tok = col % kv_heads, col // kv_heads
+        row_head = jax.lax.broadcasted_iota(
+            jnp.int32, (heads, 1), 0) // group
+        own = col_head == row_head                          # (H, width)
+        score_scale = sm_scale
         if has_scales:
             # int8 KV pools dequantize HERE, in VMEM — the cache stays
             # int8 in HBM (half the residency of a bf16 pool); static
-            # flag so float pools keep the multiply-free hot loop
-            k = k * kscale_ref[h]
-            v = v * vscale_ref[h]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale                           # (G, BS)
-        pos = ki * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (group, block_size), 1
-        )
-        mask = pos < length
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = m_new
+            # flag so float pools keep the multiply-free hot loop. A
+            # head's K scale multiplies its score columns, its V scale
+            # its output rows (once, after the last chunk)
+            ksc = jnp.zeros((1, width), jnp.float32)
+            vsc = jnp.zeros((heads, 1), jnp.float32)
+            for h in range(kv_heads):
+                ksc = jnp.where(col_head == h, kscale_ref[h], ksc)
+                vsc = jnp.where(row_head == h, vscale_ref[h], vsc)
+            score_scale = sm_scale * ksc
 
-    @pl.when(ki == steps - 1)
-    def _finalize():
-        l = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0, 0] = (acc_scr[:] / l).astype(o_ref.dtype)
+        def attend(j, carry):
+            m_prev, l_prev, acc = carry
+            half = (half0 + j) % 2
+
+            # the other half is free: fill it while this one is used
+            @pl.when(j + 1 < chunks)
+            def _next_chunk():
+                start_chunk(b, j + 1, 1 - half)
+
+            @pl.when((j + 1 == chunks) & next_live)
+            def _next_row():
+                start_chunk(nxt, 0, 1 - half)
+
+            wait_chunk(jnp.minimum(chunk, n - j * chunk), half)
+            k = kbuf[half].astype(jnp.float32).reshape(width, d)
+            v = vbuf[half].astype(jnp.float32).reshape(width, d)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * score_scale                                 # (H, width)
+            mask = own & (j * (chunk * block_size) + tok < length)
+            s = jnp.where(mask, s, NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            return m_new, l_new, acc * alpha + pv
+
+        _, l, acc = jax.lax.fori_loop(0, chunks, attend, (
+            jnp.full((heads, 1), NEG_INF, jnp.float32),
+            jnp.zeros((heads, 1), jnp.float32),
+            jnp.zeros((heads, d), jnp.float32)))
+        if has_scales:
+            acc = acc * vsc
+        o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        state[0] = (half0 + chunks) % 2
+        state[1] = next_live.astype(jnp.int32)
+
+    @pl.when(n == 0)
+    def _empty():
+        o_ref[0] = jnp.zeros_like(o_ref[0])
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens,
@@ -141,11 +237,7 @@ def _paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens,
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     steps = block_tables.shape[1]
-
-    qg = q.reshape(b, hk, group, d)
-    # (HK, NB, BS, D): head-major so one grid step pulls one (BS, D) tile
-    kp = jnp.moveaxis(k_pool, 2, 0)
-    vp = jnp.moveaxis(v_pool, 2, 0)
+    chunk = max(1, min(steps, _CHUNK_ROWS // (block_size * hk)))
 
     lens = seq_lens.astype(jnp.int32)
     tables = block_tables.astype(jnp.int32)
@@ -154,44 +246,43 @@ def _paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens,
     vs = (jnp.ones((hk,), jnp.float32) if v_scale is None
           else jnp.asarray(v_scale, jnp.float32).reshape(hk))
 
-    def pool_idx(b_, h_, ki, tables_ref, lens_ref, ks_ref, vs_ref):
-        # dead step (past this sequence's blocks) → re-point at block 0;
-        # the repeated DMA is elided and the body is predicated off
-        live = ki * block_size < lens_ref[b_]
-        blk = jax.lax.select(live, tables_ref[b_, ki], 0)
-        return (h_, blk, 0, 0)
+    def slot_idx(b_, tables_ref, lens_ref, ks_ref, vs_ref):
+        return (b_, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(b, hk, steps),
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, 1, group, d),
-                         lambda b_, h_, ki, t, ln, ks_, vs_: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, block_size, d), pool_idx),
-            pl.BlockSpec((1, 1, block_size, d), pool_idx),
+            pl.BlockSpec((1, h, d), slot_idx),
+            # the pools stay in HBM; the kernel DMAs the live blocks
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, group, d),
-            lambda b_, h_, ki, t, ln, ks_, vs_: (b_, h_, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, h, d), slot_idx),
         scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, d), jnp.float32),
+            pltpu.VMEM((2, chunk, block_size * hk, d), k_pool.dtype),
+            pltpu.VMEM((2, chunk, block_size * hk, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
             _paged_kernel, sm_scale=sm_scale, block_size=block_size,
-            steps=steps, group=group,
+            kv_heads=hk, group=group, chunk=chunk,
             has_scales=k_scale is not None or v_scale is not None,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hk, group, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        # rows run in order: each starts the next one's first chunk
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=_interpret_mode(),
         name="paged_decode_attention",
-    )(tables, lens, ks, vs, qg, kp, vp)
-    out = out.reshape(b, h, d)
+    )(tables, lens, ks, vs, q,
+      # the stored pool seen as (NB, BS * HK, D): a bitcast, no relayout
+      k_pool.reshape(num_blocks, block_size * hk, d),
+      v_pool.reshape(num_blocks, block_size * hk, d))
     return out[:, None] if squeeze else out
 
 

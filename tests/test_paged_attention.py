@@ -51,6 +51,58 @@ def test_paged_matches_contiguous_decode():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("pool", ["float", "int8"])
+@pytest.mark.parametrize("block_size", [16, 32])
+@pytest.mark.parametrize("group,hk", [(7, 4), (4, 8), (1, 8)],
+                         ids=["qwen2-g7-hk4", "mistral-g4-hk8", "mha-g1"])
+def test_paged_kernel_matches_xla_gather(group, hk, block_size, pool):
+    """The kernel (interpret mode) against `_xla_paged_decode_attn` over
+    the shapes that decide it: the GQA groupings served, both block
+    sizes, lengths at every edge of a block, a chunk and the table, and
+    table tails holding block ids far outside the pool (never read)."""
+    from paddle_tpu.nlp.paged_attention import _xla_paged_decode_attn
+    from paddle_tpu.ops.pallas import paged_attention as kernel_mod
+
+    rng = np.random.RandomState(hk * block_size + group)
+    d, w = 128, 12
+    h = group * hk
+    chunk = min(w, kernel_mod._CHUNK_ROWS // (block_size * hk))
+    lens = np.asarray(
+        [1, block_size - 1, block_size, block_size + 1,
+         chunk * block_size, min(w, chunk + 1) * block_size - 3,
+         w * block_size], np.int32)
+    b = len(lens)
+    nb = b * w + 1
+    tables = (rng.permutation(nb - 1)[: b * w].reshape(b, w) + 1).astype(
+        np.int32)
+    for i, ln in enumerate(lens):
+        tables[i, -(-ln // block_size):] = 10 ** 6
+    q = jnp.asarray(rng.randn(b, h, d), jnp.float32)
+    scales = {}
+    if pool == "int8":
+        kp = jnp.asarray(rng.randint(-127, 128, (nb, block_size, hk, d)),
+                         jnp.int8)
+        vp = jnp.asarray(rng.randint(-127, 128, (nb, block_size, hk, d)),
+                         jnp.int8)
+        ksc = jnp.asarray(rng.uniform(0.002, 0.02, hk), jnp.float32)
+        vsc = jnp.asarray(rng.uniform(0.002, 0.02, hk), jnp.float32)
+        scales = {"k_scale": ksc, "v_scale": vsc}
+        k_ref = kp.astype(jnp.float32) * ksc[:, None]
+        v_ref = vp.astype(jnp.float32) * vsc[:, None]
+    else:
+        kp = k_ref = jnp.asarray(rng.randn(nb, block_size, hk, d),
+                                 jnp.float32)
+        vp = v_ref = jnp.asarray(rng.randn(nb, block_size, hk, d),
+                                 jnp.float32)
+    out = paged_decode_attention(q, kp, vp, jnp.asarray(tables),
+                                 jnp.asarray(lens), **scales)
+    ref = _xla_paged_decode_attn(
+        q, k_ref, v_ref, jnp.asarray(np.where(tables >= nb, 0, tables)),
+        jnp.asarray(lens))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
 def test_paged_cache_write_then_attend():
     rng = np.random.RandomState(1)
     lens = [15, 40]
@@ -635,3 +687,37 @@ def test_block_multihead_attention_fused_rope_bias_parity():
         np.testing.assert_allclose(
             np.asarray(out_fd._value), np.asarray(out_rd._value),
             rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("tp", [None, 2], ids=["one-chip", "tp2"])
+def test_engine_decodes_through_the_kernel(tp):
+    """The serving engine's decode quantum with the kernel routed in
+    (interpret mode; per shard under ``tp``): greedy streams are what
+    one request generated alone gives — ragged contexts, rows that
+    retire early, the donated pools written in place between steps."""
+    from paddle_tpu.nlp import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.nlp.generation import generate_on_device
+    from paddle_tpu.serving import ServingEngine
+
+    paddle.seed(0)
+    cfg = LlamaConfig.tiny(tensor_parallel=tp is not None)
+    model = LlamaForCausalLM(cfg)
+    model.eval()
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(1, cfg.vocab_size, n).astype(np.int32)
+               for n in (9, 5, 3)]
+    paddle.set_flags({"FLAGS_pallas_force": True})
+    try:
+        engine = ServingEngine(model, tp=tp, num_slots=3, block_size=4,
+                               prefill_chunk=4, decode_quantum=3)
+        reqs = [engine.submit(p, max_new_tokens=n)
+                for p, n in zip(prompts, (7, 4, 9))]
+        engine.run()
+        got = [engine.output_tokens(r) for r in reqs]
+    finally:
+        paddle.set_flags({"FLAGS_pallas_force": False})
+    for req, tokens in zip(reqs, got):
+        alone = generate_on_device(
+            model, paddle.to_tensor(req.prompt[None, :]),
+            max_new_tokens=req.max_new_tokens)
+        np.testing.assert_array_equal(tokens, np.asarray(alone._value)[0])

@@ -6,7 +6,9 @@ times and is never reported as a chip run (``chip_smoke.py`` is that).
 Skipped where the topology cannot be described.
 """
 import importlib
+import math
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs to /tmp
 
@@ -117,6 +119,60 @@ def test_paged_decode_attention(chip, pool_dtype):
         fn = paged_decode_attention
     assert _compiled_kernels(chip, fn, *shapes) == {
         "paged_decode_attention"}
+
+
+def test_paged_decode_reads_the_pool_as_stored(chip):
+    """The decode cell's shapes (Qwen2-7B: 28/4 heads x 128, 16 slots,
+    1,153 blocks of 32, table width 16): `_paged_write`'s scatter + the
+    kernel inside a scan with the pools donated, as `jit_quantum` runs
+    them. No instruction of the loop body but the scatter itself may
+    have a pool-sized result — no copy, transpose or prefetch of a whole
+    layer pool a step, in whatever layout."""
+    from paddle_tpu.nlp.llama import _paged_write
+    from paddle_tpu.ops.pallas._utils import compiled_kernel_names
+    from paddle_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention,
+    )
+
+    blocks, bs, hk, h, slots, width, steps = 1153, 32, 4, 28, 16, 16, 8
+
+    def quantum(kp, vp, q, kv_new, tables, lens, blk, off):
+        def body(carry, x):
+            q_t, lens_t = x
+            kp, vp, _, _ = _paged_write(kv_new, kv_new, blk, off,
+                                        (*carry, None, None))
+            return (kp, vp), paged_decode_attention(q_t, kp, vp, tables,
+                                                    lens_t)
+
+        (kp, vp), out = jax.lax.scan(body, (kp, vp), (q, lens))
+        return kp, vp, out
+
+    def shape(s, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(s, dt, sharding=chip)
+
+    pool = shape((blocks, bs, hk, D))
+    text = jax.jit(quantum, donate_argnums=(0, 1)).lower(
+        pool, pool, shape((steps, slots, h, D)), shape((slots, hk, D)),
+        shape((slots, width), jnp.int32), shape((steps, slots), jnp.int32),
+        shape((slots,), jnp.int32), shape((slots,), jnp.int32),
+    ).compile().as_text()
+    assert compiled_kernel_names(text) == {"paged_decode_attention"}
+    computations = text.split("\n\n")
+    body = next(c for c in computations if "tpu_custom_call" in c)
+    scatters = {c.split(" ", 1)[0] for c in computations
+                if " scatter(" in c}
+    moved, written = [], 0
+    for line in body.splitlines():
+        m = re.match(r"\s*%\S+ = \(?\w+\[([\d,]+)\]\S* ([\w\-]+)\(", line)
+        if not m or math.prod(map(int, m.group(1).split(","))) != (
+                blocks * bs * hk * D):
+            continue
+        if m.group(2) == "fusion" and re.search(
+                r"calls=(%\S+?),", line).group(1) in scatters:
+            written += 1
+        elif m.group(2) not in ("get-tuple-element", "bitcast"):
+            moved.append(line.split(", metadata")[0].strip()[:200])
+    assert written == 2 and not moved, (written, moved)
 
 
 def test_varlen_flash_attention_prefill(chip):
