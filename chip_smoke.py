@@ -4,7 +4,7 @@ user calls, at the published widths of Llama-2-7B (hidden 4096, 32 heads
 x d128, FFN 11008, vocab 32000) with DEPTH cut to the L=4 one 16 GB v5e
 holds, bf16, weights random from a seed:
 
-  train   bench.build_step -> JittedTrainStep.run_steps, B1 x S4096
+  train   build_step -> JittedTrainStep.run_steps, B1 x S4096
   serve   paddle.inference.serve(model, policy=no_shed_policy()) with the
           engine's defaults, a handful of ragged requests submitted together
 
@@ -22,6 +22,7 @@ from __future__ import annotations
 import argparse
 import gc
 import json
+import os
 import sys
 import time
 import traceback
@@ -136,16 +137,70 @@ def _config_report(cfg):
             "dtype": "bfloat16"}
 
 
+# ------------------------------------------------------------------ set-up
+def enable_compile_cache():
+    """Turn on JAX's persistent compilation cache. The directory comes
+    from ``JAX_COMPILATION_CACHE_DIR`` when that is set (JAX reads it
+    itself; no other directory is set in code), else it is the fixed
+    ``.jax_cache/`` of this checkout: the path is part of the cache key,
+    so it is never built from a temp name, a pid or the time. Every
+    program is kept, small ones included."""
+    import jax
+
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update(
+            "jax_compilation_cache_dir",
+            os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
+def headline_config(**overrides):
+    """Llama-2-7B at its published widths (h4096, 32 heads x d128, FFN
+    11008, vocab 32000) with DEPTH cut to the L=4 one v5e-16G holds
+    (~1.07B params; bf16 params + f32 master + bf16 Adam moments)."""
+    from paddle_tpu.nlp import LlamaConfig
+
+    kw = dict(num_hidden_layers=4, tensor_parallel=False,
+              use_recompute=False)
+    kw.update(overrides)
+    return LlamaConfig.llama2_7b(**kw)
+
+
+def build_step(cfg, batch, seq, **step_kw):
+    """The trainer's defaults around ``cfg``: bf16 weights, AdamW 1e-4
+    with decay 0.01, f32 master weights, bf16 moments, the criterion on
+    f32 logits; one seeded batch of ids."""
+    import paddle_tpu as paddle
+    from paddle_tpu.jit.train import JittedTrainStep
+    from paddle_tpu.nlp import LlamaForCausalLM, LlamaPretrainingCriterion
+
+    paddle.seed(0)
+    model = LlamaForCausalLM(cfg)
+    model.astype("bfloat16")
+    crit = LlamaPretrainingCriterion(cfg)
+
+    def criterion(out, labels):
+        return crit(out.astype("float32"), labels)
+
+    opt = paddle.optimizer.AdamW(
+        1e-4, parameters=model.parameters(), weight_decay=0.01,
+        multi_precision=True, moment_dtype="bfloat16")
+    step = JittedTrainStep(model, criterion, opt, **step_kw)
+    ids = paddle.to_tensor(
+        np.random.RandomState(0).randint(0, cfg.vocab_size, (batch, seq)))
+    return step, ids
+
+
 # ------------------------------------------------------------------ train
 def _train_losses(cfg, batch, seq, steps, **step_kw):
     """``steps`` steps on ONE repeated batch through run_steps; returns
     (losses, kernel names in the compiled K-step program)."""
     import paddle_tpu as paddle
-    from bench import build_step
     from paddle_tpu.ops.pallas._utils import compiled_kernel_names
 
-    _, step, ids = build_step(cfg, batch, seq, moment_dtype="bfloat16",
-                              **step_kw)
+    step, ids = build_step(cfg, batch, seq, **step_kw)
     stacked = paddle.to_tensor(
         np.repeat(np.asarray(ids._value)[None], steps, axis=0))
     # compiled ahead of the dispatch so its text can be read; run_steps
@@ -356,7 +411,6 @@ def fft_phase():
 # ------------------------------------------------------------------- main
 def run(chips, device):
     import jax
-    from bench import enable_compile_cache, headline_config
 
     if device["platform"] != "tpu":
         raise RuntimeError(

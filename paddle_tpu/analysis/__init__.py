@@ -3,7 +3,7 @@ framework source (the CINN-style compiler-level verification layer of
 PAPER.md's blueprint, grown from tests/test_zero_ir.py's one-off IR
 string checks into a first-class subsystem).
 
-Six layers:
+Five layers:
 
 1. **IR audit passes** over any jitted callable's jaxpr / StableHLO /
    compiled HLO: collective-communication census
@@ -30,21 +30,17 @@ Six layers:
    scripts/`` flags tracer hazards in the framework source itself
    (host syncs in jit-reachable code, Python control flow on traced
    values, np.* on tensors, mutable default args).
-5. **Perf sentinel**: :mod:`.perf_budget` — declarative
-   :class:`PerfBudget` floors/ceilings (explicit noise bands) over the
-   checked-in ``BENCH_*.json`` trajectory, a deterministic
-   ``BENCH_INDEX.json`` (:func:`build_index` / :func:`compare_index`
-   staleness diffs) and the :func:`check_perf` gate run pre-merge by
-   ``scripts/check_perf.sh`` via ``scripts/validate_bench.py``.
-6. **Static cost model & roofline**: :mod:`.cost` — per-program
+5. **Static cost model & roofline**: :mod:`.cost` — per-program
    FLOP/byte accounting from BOTH XLA's ``cost_analysis()`` and a
    backend-independent jaxpr walker (:func:`analyze_cost` cross-checks
    them against the pinned agreement band), chip rooflines
    (:func:`roofline` — arithmetic intensity, memory/compute-bound,
-   the ``max(flops/peak, bytes/bw)`` device-time floor) and
-   :func:`host_gap_seconds` against measured walls. ``--cost`` gates
-   every recipe's cross-source agreement; the per-recipe caps ride the
-   budgets and the exact numbers ride the golden fingerprints.
+   the ``max(flops/peak, bytes/bw)`` device-time floor). ``--cost``
+   gates every recipe's cross-source agreement; the per-recipe caps
+   ride the budgets and the exact numbers ride the golden fingerprints.
+
+None of this measures speed: times, rates and idle shares come from
+``benchmark/run.py`` on the chip (``PERF_LEDGER.jsonl``, ``PERF.md``).
 
 CLI: ``python -m paddle_tpu.analysis`` audits the registered recipes
 (``--check`` enforces budgets, ``--fingerprint`` compares goldens,
@@ -75,14 +71,9 @@ from .budget import (
 from .recipes import RECIPES, Recipe, build as build_recipe, \
     run as run_recipe
 from .lint import LintViolation, lint_paths, lint_source
-from .perf_budget import (
-    INDEX_VERSION, PerfBudget, PerfBudgetViolation, build_index,
-    check_perf, compare_index, default_perf_budgets, normalize_artifact,
-)
 from .cost import (
     AGREEMENT_BAND, CHIP_SPECS, ChipSpec, CostReport, CostStats,
-    RooflineReport, analyze_cost, host_gap_seconds, jaxpr_cost,
-    quantum_flops_per_token, roofline, xla_cost_stats,
+    RooflineReport, analyze_cost, jaxpr_cost, roofline, xla_cost_stats,
 )
 
 __all__ = [
@@ -106,13 +97,8 @@ __all__ = [
     "RECIPES", "Recipe", "build_recipe", "run_recipe",
     # linter
     "LintViolation", "lint_paths", "lint_source",
-    # perf sentinel
-    "INDEX_VERSION", "PerfBudget", "PerfBudgetViolation", "build_index",
-    "check_perf", "compare_index", "default_perf_budgets",
-    "normalize_artifact",
     # cost model & roofline
     "AGREEMENT_BAND", "CHIP_SPECS", "ChipSpec", "CostReport",
-    "CostStats", "RooflineReport", "analyze_cost", "host_gap_seconds",
-    "jaxpr_cost", "quantum_flops_per_token", "roofline",
-    "xla_cost_stats",
+    "CostStats", "RooflineReport", "analyze_cost", "jaxpr_cost",
+    "roofline", "xla_cost_stats",
 ]

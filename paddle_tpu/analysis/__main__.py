@@ -15,11 +15,11 @@ findings, donation coverage, memory estimate, and sharding layout.
 ``--fingerprint`` compares each live fingerprint against its golden
 (tests/goldens/<recipe>.json, or ``--goldens-dir``), and ``--cost``
 prints the static cost table (FLOPs, bytes, intensity, roofline floor
-on ``--chip``, host gap vs the checked-in bench walls) while gating
-that both cost sources populated and agree within the pinned band; any
-of the three exits non-zero on a violation/drift (the bench-suite / CI
-entry point — scripts/check_graphs.sh runs all of them plus the
-linter). After an
+on ``--chip``) while gating that both cost sources populated and agree
+within the pinned band; any of the three exits non-zero on a
+violation/drift (scripts/check_graphs.sh runs all of them plus the
+linter). What a dispatch takes on the chip is the benchmark's to
+measure (``benchmark/run.py``, ``PERF.md``), not this table's. After an
 INTENTIONAL graph change run ``--update-goldens`` and review the
 goldens' git diff. Source linting is the sibling CLI:
 ``python -m paddle_tpu.analysis.lint paddle_tpu/ scripts/``.
@@ -33,69 +33,14 @@ import sys
 
 from . import recipes
 from .budget import BudgetViolation
-from .cost import (
-    AGREEMENT_BAND, CHIP_SPECS, DEFAULT_CHIP, host_gap_seconds,
-    roofline,
-)
+from .cost import AGREEMENT_BAND, CHIP_SPECS, DEFAULT_CHIP, roofline
 from .fingerprint import (
     FingerprintMismatch, check_recipe_fingerprint, fingerprint_report,
     save_golden,
 )
 
-#: repo root (three levels above this file) — where the checked-in
-#: BENCH_*.json artifacts that carry measured quantum walls live
-_REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 
-# where a measured per-dispatch wall for a recipe can be read from the
-# checked-in artifacts: recipe -> (artifact, row metric, tokens/s field)
-_MEASURED_WALL_SOURCES = {
-    "serving_decode_step": (
-        "BENCH_SERVING_r06.json",
-        "serving_engine_ragged_tokens_per_sec_cpu_smoke",
-        "quantum_decode_tokens_per_sec"),
-    "serving_multiquantum_step": (
-        "BENCH_HOSTGAP_r18.json",
-        "serving_hostgap_k16_over_k1_host_us_per_token_cpu_smoke",
-        "fused_quantum_tokens_per_sec"),
-}
-
-
-def _measured_wall_s(name, tokens):
-    """Measured wall seconds for ONE dispatch of recipe ``name``, from
-    the checked-in bench artifacts: BENCH_COST_r17.json's in-process
-    quantum timings when present (it measures several recipes), else
-    the serving smoke row's quantum throughput. None when nothing has
-    measured this recipe — the host-gap column then reads n/a."""
-    cost_art = os.path.join(_REPO_ROOT, "BENCH_COST_r17.json")
-    try:
-        with open(cost_art) as f:
-            for row in json.load(f).get("rows", []):
-                if row.get("recipe") == name and isinstance(
-                        row.get("measured_us_per_dispatch"),
-                        (int, float)):
-                    return row["measured_us_per_dispatch"] / 1e6
-    except (OSError, ValueError):
-        pass
-    src = _MEASURED_WALL_SOURCES.get(name)
-    if src is None or not tokens:
-        return None
-    artifact, metric, field = src
-    try:
-        with open(os.path.join(_REPO_ROOT, artifact)) as f:
-            doc = json.load(f)
-        # rows-style artifact or a flat single-row bench line
-        rows = doc.get("rows", [doc] if "metric" in doc else [])
-        for row in rows:
-            if row.get("metric") == metric and isinstance(
-                    row.get(field), (int, float)) and row[field] > 0:
-                return tokens / row[field]
-    except (OSError, ValueError):
-        pass
-    return None
-
-
-def _cost_gate(name, report, budget, chip):
+def _cost_gate(report, chip):
     """Roofline/table lines + gate verdict for one audited recipe.
     ``"ok"`` requires BOTH cost sources populated and the cross-source
     flops ratio inside :data:`AGREEMENT_BAND`; anything else is the
@@ -106,23 +51,11 @@ def _cost_gate(name, report, budget, chip):
         return ("no cost view (neither cost_analysis nor a jaxpr)",
                 lines)
     rl = roofline(c.flops, c.bytes_accessed, chip=chip)
-    tokens = budget.cost_tokens_per_dispatch
     lines.append(
         f"  roofline [{rl.chip.name}]: intensity {rl.intensity:.2f} "
         f"FLOP/B ({rl.bound}-bound, ridge "
         f"{rl.chip.ridge_intensity:.0f}), device floor "
         f"{rl.device_floor_s * 1e6:.2f} us/dispatch")
-    wall = _measured_wall_s(name, tokens)
-    if wall is not None:
-        gap = host_gap_seconds(wall, rl.device_floor_s)
-        lines.append(
-            f"  host gap: measured {wall * 1e6:.1f} us - floor "
-            f"{rl.device_floor_s * 1e6:.2f} us = {gap * 1e6:.1f} us "
-            f"(CPU-smoke wall vs {rl.chip.name} floor: an upper "
-            f"bound, not the TPU gap)")
-    else:
-        lines.append("  host gap: n/a (no measured wall for this "
-                     "recipe in the checked-in bench artifacts)")
     if c.xla is None:
         return "cost source missing: no XLA cost_analysis", lines
     if c.jaxpr is None:
@@ -258,8 +191,7 @@ def main(argv=None):
 
             cost_status, cost_lines = None, []
             if args.cost:
-                cost_status, cost_lines = _cost_gate(
-                    name, report, recipe.budget, args.chip)
+                cost_status, cost_lines = _cost_gate(report, args.chip)
                 if cost_status != "ok":
                     failures += 1
 

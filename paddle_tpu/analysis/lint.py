@@ -508,7 +508,7 @@ class _TaintChecker:
 def _bench_exempt(path):
     """True for measurement/test code where explicit device syncs are
     the point: a ``tests`` path segment, or a ``bench*`` / ``test*`` /
-    ``conftest*`` basename (scripts/bench_*.py, repo-root bench.py)."""
+    ``conftest*`` basename."""
     parts = path.replace(os.sep, "/").split("/")
     base = parts[-1]
     return ("tests" in parts[:-1]

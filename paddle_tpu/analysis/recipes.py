@@ -1,10 +1,9 @@
-"""Real-recipe budget registry: the compiled programs the bench history
-actually protects, each paired with the budget that pins its current
+"""Real-recipe budget registry: the compiled programs the system
+actually dispatches, each paired with the budget that pins its current
 known-good graph shape.
 
 - ``llama_tp_zero_fused_lce``: the TP(mp=2) x ZeRO(sharding=4)
-  fused-LCE train step — the round-5 hybrid recipe whose zero-remat
-  invariant guards the 67% MFU B2 result (BENCH_NOTES.md). Budget: 0
+  fused-LCE train step, the hybrid recipe. Budget: 0
   involuntary remats, the stage-2 reduce-scatter decision present,
   every param/state/buffer leaf donated, and a hard cap on per-step
   all-gather traffic.
@@ -24,11 +23,10 @@ known-good graph shape.
   with the engine's live post-prefill state. Budget: same caps as the
   plain quantum, with BOTH the draft and target KV pool leaves
   donated.
-- ``serving_frontdoor_step``: the FRONT DOOR's quantum variant
-  (``per_request_sampling=True`` — per-slot temperature rides the
-  per-slot state as one extra (S,) f32 input; sampling selection
-  in-graph), built through an engine carrying the whole policy tier
-  (priorities, a forced preemption, SLOs, flight recorder, full
+- ``serving_frontdoor_step``: the FRONT DOOR's sampling quantum
+  (per-slot temperature rides the per-slot state as one extra (S,) f32
+  input; sampling selection in-graph), built through an engine carrying
+  the whole policy tier (priorities, a forced preemption, SLOs, flight recorder, full
   instrumentation). Budget: the same zero-host-callback /
   pools-donated caps — the machine proof that streaming, preemption,
   shedding and drain are ALL host-side policy that never enters the
@@ -64,7 +62,7 @@ fingerprint (``tests/goldens/<name>.json``, see :mod:`.fingerprint`)
 compared against the live audit in tier-1 and by ``--fingerprint`` /
 ``scripts/check_graphs.sh``. Used by tests/test_zero_ir.py,
 tests/test_analysis.py, tests/test_serving.py, the
-``python -m paddle_tpu.analysis`` CLI, and scripts/bench_suite.py.
+and the ``python -m paddle_tpu.analysis`` CLI.
 """
 from __future__ import annotations
 
@@ -324,15 +322,14 @@ def _build_serving_frontdoor_step():
     paddle.seed(0)
     cfg = LlamaConfig.tiny(tensor_parallel=False, dtype="bfloat16")
     model = LlamaForCausalLM(cfg)
-    # the front-door engine: per-request sampling (the quantum variant
-    # whose per-slot temperature input this recipe's golden pins) with
+    # the front-door engine: a sampling engine (the quantum whose
+    # per-slot temperature input this recipe's golden pins) with
     # the FULL policy + observability tier on — and a forced
     # preemption before the audit, so the audited state is one a real
     # overloaded front door reaches (evict, resume, re-prefill)
     engine = ServingEngine(model, num_slots=2, block_size=4,
                            prefill_chunk=8, decode_quantum=4,
                            decode_strategy="sampling", top_k=8,
-                           per_request_sampling=True,
                            trace=True, slo=True, flight=True,
                            faults=FaultInjector(seed=0),
                            resilience=True)
@@ -569,18 +566,14 @@ def _build_serving_multiquantum_step():
     paddle.seed(0)
     cfg = LlamaConfig.tiny(tensor_parallel=False, dtype="bfloat16")
     model = LlamaForCausalLM(cfg)
-    # the MULTI-QUANTUM while_loop driver (K=4 quanta per dispatch)
-    # with the FUSED online-softmax paged-attention inner loop — the
-    # PR-18 host-gap variant, audited under the same full
-    # instrumentation + disarmed-injector + resilience build as
-    # serving_decode_step: 0 host callbacks proves the whole K-quantum
-    # loop (retirement masks, early all-done exit, token buffer) stays
-    # on device, and the golden pins BOTH the while_loop driver and
-    # the fused attention graph. The gather-path recipes above are the
-    # parity oracle and must stay byte-identical.
+    # the MULTI-QUANTUM while_loop driver (K=4 quanta per dispatch),
+    # audited under the same full instrumentation + disarmed-injector +
+    # resilience build as serving_decode_step: 0 host callbacks proves
+    # the whole K-quantum loop (retirement masks, early all-done exit,
+    # token buffer) stays on device, and the golden pins the driver.
     engine = ServingEngine(model, num_slots=2, block_size=4,
                            prefill_chunk=8, decode_quantum=4,
-                           multi_quantum=4, attn_impl="fused",
+                           multi_quantum=4,
                            trace=True, slo=True, flight=True,
                            faults=FaultInjector(seed=0),
                            resilience=True)
@@ -590,34 +583,26 @@ def _build_serving_multiquantum_step():
     engine.step()  # admit + prefill so the audited state is live
     target, args = engine.multiquantum_step_target()
     budget = Budget(
-        name="serving multi-quantum driver (K=4, fused attn, bf16)",
+        name="serving multi-quantum driver (K=4, bf16, single chip)",
         max_remat=0,
         max_total_collectives=0,  # single-chip serving program
         max_f32_matmuls=0,        # bf16 pool/params stay bf16
         max_host_callbacks=0,     # K quanta, ZERO host re-entries
         require_donated=True,     # the 2L KV pool leaves
-        # audited 7.4 KB temp / 891 KB trace peak: the fused attention
-        # streams pool blocks through running (m, l, acc) statistics
-        # instead of materializing the gathered context — the gather
-        # quantum audits 207 KB temp, so this cap IS the fused win's
-        # structural pin (a fallback to the gather path blows it 17x)
-        max_temp_bytes=12_000,
+        # audited 330 KB temp / 891 KB trace peak: the quantum's own
+        # buffers plus the (K, T, S) token buffer; same caps, same ~30%
+        # headroom as serving_decode_step, whose scan this loop wraps
+        max_temp_bytes=430_000,
         max_peak_live_bytes=1_300_000,
         # cost model: both walkers count the while_loop body ONCE, so
-        # per-token FLOPs stay comparable to serving_decode_step's
-        # one-quantum dispatch (2 slots x 4 steps = 8 tokens; audited
-        # 329k flops/token — the online softmax adds rescale
-        # elementwise + transcendentals over the one-shot softmax).
-        # The BYTES number is a known jaxpr-walker artifact: the
-        # block-scan charges every step its whole gathered operands
-        # (pool + weights re-counted per block step — 10.7 MB/token
-        # audited), while XLA's compiled report reads 717 KB for the
-        # whole dispatch; the cap pins the walker's shape, not real
-        # HBM traffic (BENCH_NOTES dispatch-decomposition section)
+        # the numbers are serving_decode_step's one-quantum dispatch
+        # (2 slots x 4 steps = 8 tokens; audited 2.49M flops / 18.8 MB:
+        # 311k flops / 2.36 MB per token, 0.13 FLOP/B) and so are the
+        # caps
         cost_tokens_per_dispatch=8,
         max_flops_per_token=420_000,
-        max_hbm_bytes_per_token=13_000_000,
-        min_arithmetic_intensity=0.025,
+        max_hbm_bytes_per_token=3_100_000,
+        min_arithmetic_intensity=0.09,
     )
     recipe = Recipe("serving_multiquantum_step", target, args, budget)
     recipe.engine = engine  # obs CLI asserts the instrumented engine

@@ -3,7 +3,7 @@
 When GSPMD cannot reshard a tensor between two incompatible layouts it
 falls back to replicate-then-repartition — "Involuntary full
 rematerialization" — the bandwidth cliff the zero-remat invariant (the
-fused-LCE hybrid recipe's protected property, see BENCH_NOTES.md)
+fused-LCE hybrid recipe's protected property)
 forbids. XLA only reports it as an error line on fd 2 during SPMD
 partitioning, so the detector greps the stderr captured while THIS
 target compiled (ir.LoweredTarget records it) and returns one

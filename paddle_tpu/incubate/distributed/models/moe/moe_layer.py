@@ -175,7 +175,7 @@ class MoELayer(Layer):
         (4) scatter-add into a (T, M) partial and ``psum_scatter`` back
         to the token owners. Per-device matmul rows scale as T*k*cf/P —
         the EP compute win the dense (T, E, C) einsum tier lacks at long
-        T (its cost ∝ T², BENCH_NOTES MoE table). Wire is one all-gather
+        T (its cost ∝ T²). Wire is one all-gather
         + one reduce-scatter of (T, M); swapping the gather/scatter pair
         for ``lax.ragged_all_to_all`` (row exchange ∝ routed tokens) is
         the upgrade path once XLA:CPU implements the op — today it would

@@ -6,7 +6,7 @@ At pretrain shapes the unfused loss path materializes the full
 ``(B*S, V)`` logits THREE times over — bf16 forward logits, the f32
 log-softmax, and the f32 logits gradient (≈2.6 GB at B2/S4096/V32k) —
 which is exactly the HBM-pressure regime where XLA's scheduler starts
-serializing (the measured B2 MFU cliff, BENCH_NOTES round 4).
+serializing (PERF.md section 6, "before the ledger").
 
 TPU-native fix: ``lax.scan`` over row chunks computing the loss AND the
 (unscaled) gradients in the same pass — cross-entropy's logits gradient

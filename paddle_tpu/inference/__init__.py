@@ -155,10 +155,9 @@ def create_serving_engine(model, **kwargs):
     decode_quantum, decode_strategy, eos_token_id, ...; pass
     ``spec_draft=<draft LM>`` (and ``spec_gamma``) to switch the
     quantum to the one-dispatch SPECULATIVE drafter/verifier round,
-    ``per_request_sampling=True`` (with
-    ``decode_strategy="sampling"``) for the front-door quantum variant
-    whose per-slot temperature input carries each request's
-    ``temperature``, and ``trace=True`` (or ``obs=<ServingObs>``) for
+    ``decode_strategy="sampling"`` for the quantum whose per-slot
+    temperature input carries each request's ``temperature``, and
+    ``trace=True`` (or ``obs=<ServingObs>``) for
     the runtime observability layer — metrics registry + Chrome-trace
     request spans via :mod:`paddle_tpu.obs`, all recorded at host
     scheduler boundaries (the jitted quantum's fingerprint is
@@ -249,10 +248,9 @@ def serve(model, policy=None, slo=True, flight=True, **kwargs):
     graceful ``drain()``.
 
     ``slo`` / ``flight`` default ON (shedding needs the health report;
-    drain flushes the journals); ``decode_strategy="sampling"``
-    auto-enables ``per_request_sampling`` so ``submit(...,
-    temperature=)`` works per request. ``prefix_cache=True`` (DEFAULT
-    OFF this release) enables content-addressed prefix caching —
+    drain flushes the journals); with ``decode_strategy="sampling"``
+    ``submit(..., temperature=)`` works per request.
+    ``prefix_cache=True`` (DEFAULT OFF this release) enables content-addressed prefix caching —
     shared system prompts alias cached KV blocks instead of
     re-prefilling, ``TokenStream.cached_prefix_tokens`` reports the
     per-request win. ``tp=2`` / ``mesh=`` shard the engine's quantum
@@ -294,8 +292,6 @@ def serve(model, policy=None, slo=True, flight=True, **kwargs):
     """
     from ..serving import ServingEngine, ServingFrontDoor
 
-    if kwargs.get("decode_strategy") == "sampling":
-        kwargs.setdefault("per_request_sampling", True)
     engine = ServingEngine(model, slo=slo, flight=flight, **kwargs)
     return ServingFrontDoor(engine, policy=policy)
 

@@ -348,13 +348,12 @@ class DeepseekV3Attention(Layer):
 
     # -- the serving engine's attention protocol ----------------------------
     def paged_decode(self, x, rope, tables, lens, write_blk, write_off,
-                     cache, attn_impl="gather"):
+                     cache):
         """One token a slot over the latent pool, absorbed: the step's
         ``[c | k_rope]`` row is written at ``(write_blk, write_off)``,
         the pool is read once for scores and values, and no per-head key
         or value of the context is built. ``cache`` is ``(latent pool,
-        None, None, None)``; ``attn_impl`` has one path here (the XLA
-        gather) and is not read."""
+        None, None, None)``."""
         with jax.named_scope("mla"):
             pool = self._write(cache[0], self._latent_rows(x, rope)[:, 0],
                                write_blk, write_off)

@@ -321,7 +321,7 @@ class LlamaAttention(Layer):
         return jnp.cos(freqs), jnp.sin(freqs)
 
     def paged_decode(self, x, rope, tables, lens, write_blk, write_off,
-                     cache, attn_impl="gather"):
+                     cache):
         """One token a slot over the paged pool. ``x`` (S, 1, E) is the
         normed input; ``cache`` this layer's pool arrays ``(k, v,
         k_scale, v_scale)`` (the scales None on a float pool). Writes
@@ -342,8 +342,7 @@ class LlamaAttention(Layer):
         vv = v._value[:, 0]
         kci, vci, ksi, vsi = new = _paged_write(kv, vv, write_blk,
                                                 write_off, cache)
-        att = _paged_attn(qv, kci, vci, tables, lens, ks=ksi, vs=vsi,
-                          impl=attn_impl)
+        att = _paged_attn(qv, kci, vci, tables, lens, ks=ksi, vs=vsi)
         att_t = Tensor(att.reshape(s, 1, h * d), stop_gradient=True)
         return self.o_proj(att_t), new
 

@@ -61,72 +61,6 @@ def _xla_paged_decode_attn(q, kp, vp, tables, lens, ks=None, vs=None):
     return out.astype(q.dtype)
 
 
-def _fused_paged_decode_attn(q, kp, vp, tables, lens, ks=None, vs=None):
-    """Fused (flash-style) decode attention over the paged pool: an
-    online-softmax scan over the BLOCK-TABLE entries, porting the two
-    tricks the Pallas paged kernel and the d128 varlen retune already
-    won (BENCH_NOTES "Paged KV-cache decode" / "flash/varlen kernel
-    retune") to the portable XLA level:
-
-      * no gathered copy — the oracle (`_xla_paged_decode_attn`)
-        materializes the whole (S, W*BS, HK, D) context twice before a
-        full-width softmax; here each scan step touches ONE pool block
-        per row and folds it into running (m, l, acc) f32 statistics,
-        so temp residency is per-block, not per-context.
-      * DMA elision analog — a row whose context ended before block
-        ``ki`` re-points its gather at pool block 0 and masks the
-        whole block, so dead steps never touch cold pool memory.
-
-    Same f32 compute dtype, same -1e30 mask, same trailing cast as the
-    oracle; the online rescale chain reorders the softmax reductions,
-    which is exactly why the gather path stays wired in as the parity
-    oracle (streams compare bit-exact on the tiny recipe shapes — the
-    bf16 output cast absorbs the ulp-level reassociation).
-    ``ks``/``vs`` are the int8 pool's per-row scale pools: blocks
-    dequantize in f32 as they stream through, never all at once."""
-    s_, h, d = q.shape
-    w = tables.shape[1]
-    bs, hk = kp.shape[1], kp.shape[2]
-    rep = h // hk
-    sc = 1.0 / math.sqrt(d)
-    qf = q.astype(jnp.float32)                        # (S, H, D)
-    neg = jnp.float32(-1e30)
-
-    def body(carry, ki):
-        m, l, acc = carry
-        start = ki * bs
-        alive = start < lens                          # (S,)
-        blk = jnp.where(alive, tables[:, ki], 0)      # elision clamp
-        k = kp[blk].astype(jnp.float32)               # (S, BS, HK, D)
-        v = vp[blk].astype(jnp.float32)
-        if ks is not None:
-            k = k * ks[blk][..., None]
-            v = v * vs[blk][..., None]
-        if rep > 1:
-            k = jnp.repeat(k, rep, axis=2)
-            v = jnp.repeat(v, rep, axis=2)
-        logits = jnp.einsum("bhd,bkhd->bhk", qf, k) * sc   # (S, H, BS)
-        mask = alive[:, None] & (
-            (start + jnp.arange(bs))[None, :] < lens[:, None])
-        logits = jnp.where(mask[:, None, :], logits, neg)
-        m2 = jnp.maximum(m, jnp.max(logits, axis=-1))
-        alpha = jnp.exp(m - m2)                       # (S, H)
-        p = jnp.exp(logits - m2[..., None])           # (S, H, BS)
-        l2 = l * alpha + jnp.sum(p, axis=-1)
-        acc2 = acc * alpha[..., None] + jnp.einsum("bhk,bkhd->bhd", p, v)
-        return (m2, l2, acc2), None
-
-    m0 = jnp.full((s_, h), neg, jnp.float32)
-    l0 = jnp.zeros((s_, h), jnp.float32)
-    a0 = jnp.zeros((s_, h, d), jnp.float32)
-    # every row attends >= 1 position (masked rows carry lens == 1), so
-    # the first live block always lifts m above the -1e30 init before
-    # any dead block's exp(neg - m) underflows to an exact 0
-    (m, l, acc), _ = jax.lax.scan(body, (m0, l0, a0), jnp.arange(w))
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.astype(q.dtype)
-
-
 # the f32 score tile of `_paged_chunk_attn` may take this many bytes; a
 # chunk whose scores over its whole block table would take more streams
 # over the table in tiles of key blocks (a shape rule: no knob)
@@ -145,9 +79,9 @@ def _paged_chunk_attn(q, kp, vp, tables, base_lens, ks=None, vs=None):
     and the softmax is f32 with the same -1e30 mask as the decode
     paths. The (S, H, C, keys) f32 scores are built for
     ``_CHUNK_SCORE_BYTES`` worth of key blocks at a time and folded
-    into running (m, l, acc) statistics — the online softmax of
-    `_fused_paged_decode_attn` with a chunk dimension; a table whose
-    scores fit that size is one tile and no loop. ``ks``/``vs`` are the
+    into running (m, l, acc) statistics (an online softmax with a chunk
+    dimension); a table whose scores fit that size is one tile and no
+    loop. ``ks``/``vs`` are the
     int8 pool's per-row scale pools: a tile dequantizes in f32 as it
     streams through. No Pallas analog yet: this runs on every
     backend."""
@@ -208,19 +142,15 @@ def _paged_chunk_attn(q, kp, vp, tables, base_lens, ks=None, vs=None):
         q.dtype)
 
 
-def _paged_attn(q, kp, vp, tables, lens, ks=None, vs=None,
-                impl="gather"):
+def _paged_attn(q, kp, vp, tables, lens, ks=None, vs=None):
     """Route decode attention: Pallas paged kernel on TPU (it takes the
     pool arrays as they are stored — no relayout on the way in — and
     DMAs only the live blocks of each row's table, every kv head of a
-    block at once), XLA gather
-    fallback elsewhere. Per-row scale pools (int8 engine) always take
-    an XLA path: the Pallas kernel only supports STATIC per-head
-    scales, not per-(block, position, head) pools. ``impl="fused"``
-    selects the online-softmax block-streaming path
-    (`_fused_paged_decode_attn`) for the XLA tier — the engine's
-    ``attn_impl=`` knob; the default keeps every existing graph (and
-    golden fingerprint) byte-identical."""
+    block at once), the XLA gather (`_xla_paged_decode_attn`, the
+    reference the parity tests compare with) elsewhere. Per-row scale
+    pools (int8 engine) always take the XLA path: the Pallas kernel only
+    supports STATIC per-head scales, not per-(block, position, head)
+    pools."""
     from ..core.flags import get_flags
 
     if ks is None:
@@ -232,9 +162,6 @@ def _paged_attn(q, kp, vp, tables, lens, ks=None, vs=None,
             from ..ops.pallas.paged_attention import paged_decode_attention
 
             return paged_decode_attention(q, kp, vp, tables, lens)
-    if impl == "fused":
-        return _fused_paged_decode_attn(q, kp, vp, tables, lens,
-                                        ks=ks, vs=vs)
     return _xla_paged_decode_attn(q, kp, vp, tables, lens, ks=ks, vs=vs)
 
 

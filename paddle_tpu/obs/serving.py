@@ -620,8 +620,7 @@ class ServingObs:
         recompute work tokens) for the cost ledger's phase
         attribution. ``device_s`` (decode quanta) is the measured
         device-side share of this quantum's wall — dispatch-return to
-        sync-complete, the same decomposition analysis.cost's
-        ``host_gap_seconds`` estimates statically — and refreshes the
+        sync-complete — and refreshes the
         ``serving_host_gap_fraction`` gauge (this module never imports
         jax, so the split is measured by the engine and handed in)."""
         if not self.enabled:

@@ -54,10 +54,8 @@ snapshot/restore. Streams stay bit-identical to a single-replica run.
 The compiled programs are pinned by the ``serving_decode_step`` /
 ``speculative_verify_step`` / ``serving_frontdoor_step`` /
 ``serving_prefix_step`` analysis Budgets (zero involuntary remat,
-zero host callbacks, KV pools donated). Benched by
-``scripts/bench_serving.py`` (ragged Poisson arrivals, speculative
-serving vs the plain quantum, the ``serving_overload`` shed/no-shed
-burst rows, and the ``shared_prefix`` cached/unshared arms).
+zero host callbacks, KV pools donated). Measured by ``benchmark/run.py``
+on the chip (``PERF.md``).
 """
 from .scheduler import Request, Scheduler, SchedulerConfig
 from .engine import ServingEngine

@@ -83,15 +83,14 @@ recompute-on-resume), SLO-burn-rate load shedding through
 Every one of those mechanisms is host-side policy at the same
 scheduler boundaries: the compiled quantum's ``max_host_callbacks=0``
 budget and golden fingerprint are unchanged (the
-``serving_frontdoor_step`` recipe pins the per-request-sampling
-variant with its own golden).
+``serving_frontdoor_step`` recipe pins the sampling quantum, per-slot
+temperature input and all, with its own golden).
 
 TENSOR-PARALLEL SERVING (``mesh=`` / ``tp=``): the whole quantum
-family — default greedy/sampling, the per-request-sampling front-door
-variant, the speculative draft+verify round, and the mixed chunked-
-prefill batches — runs head/ffn-sharded over a 1-axis ``("mp",)``
-mesh. Params are re-placed at engine build with the same tp2 layouts
-the training recipes pin (column: out-dim, row: in-dim, vocab-parallel
+family — greedy and sampling, the speculative draft+verify round, and
+the mixed chunked-prefill batches — runs head/ffn-sharded over a 1-axis
+``("mp",)`` mesh. Params are re-placed at engine build with the same tp2
+layouts the training recipes pin (column: out-dim, row: in-dim, vocab-parallel
 embedding), the paged pools go head-sharded (each chip holds every
 block for ITS KV heads, so refcounted prefix sharing and COW stay pure
 host bookkeeping), and each quantum remains ONE jitted dispatch whose
@@ -238,12 +237,6 @@ def _tp_shard_params(model):
     return n_sharded
 
 
-# the pool-attention math lives beside the pool (nlp/paged_attention.py);
-# the two decode paths the parity tests compare stay importable from here
-from ..nlp.paged_attention import (  # noqa: E402,F401
-    _fused_paged_decode_attn, _xla_paged_decode_attn)
-
-
 def _layer_cache(pools, i):
     """Layer ``i``'s pool arrays ``(k, v, k_scale, v_scale)``; a side
     the pool does not have (the scales of a float pool, the V side of a
@@ -285,7 +278,7 @@ def _moe_rows_buffer(model, *lead):
 
 
 def paged_decode_math(model, scratch_block, ids_t, seq_lens, tables,
-                      kc, vc, live, ks=(), vs=(), attn_impl="gather"):
+                      kc, vc, live, ks=(), vs=()):
     """One token for every slot over a paged pool (the quantum's
     per-step body; mirrors generation._manual_decode with block-table
     writes instead of dense-cache slice updates). Parameterized by
@@ -323,8 +316,7 @@ def paged_decode_math(model, scratch_block, ids_t, seq_lens, tables,
     for i, layer in enumerate(core.layers):
         att, new = layer.self_attn.paged_decode(
             layer.input_layernorm(hidden), rope, tables, lens,
-            write_blk, write_off, _layer_cache((kc, vc, ks, vs), i),
-            attn_impl=attn_impl)
+            write_blk, write_off, _layer_cache((kc, vc, ks, vs), i))
         _collect_cache(new, out)
         hidden = hidden + att
         hidden = hidden + layer.mlp(
@@ -440,7 +432,11 @@ class ServingEngine:
             the model's max_position_embeddings).
         prefill_chunk / decode_quantum: see SchedulerConfig.
         decode_strategy: "greedy" | "sampling" (engine-wide; sampling
-            knobs via top_k/top_p/temperature, per-request seeds).
+            knobs via top_k/top_p/temperature, per-request seeds). A
+            sampling engine without ``spec_draft`` takes each slot's
+            temperature as an (S,) f32 input of both programs, so
+            ``submit(..., temperature=)`` works per request and a
+            request that names none gets the engine-wide one.
         eos_token_id: retire a slot the step after it emits this id.
         spec_draft: optional DRAFT causal LM (same vocab) switching the
             decode quantum to the speculative drafter/verifier round
@@ -465,13 +461,6 @@ class ServingEngine:
             the compiled quantum, its golden fingerprint, and the
             emitted streams are bit-identical either way (the
             ``serving_prefix_step`` recipe gates this).
-        per_request_sampling: build the FRONT-DOOR quantum variant
-            (requires ``decode_strategy="sampling"``): each slot's
-            temperature rides the per-slot state as one extra (S,)
-            f32 quantum input, so ``submit(..., temperature=)`` works
-            per request. The default engine's quantum signature — and
-            its golden fingerprint — are untouched; the variant is
-            pinned by its own ``serving_frontdoor_step`` recipe.
         obs: observability sink — ``None`` builds a fresh
             :class:`~paddle_tpu.obs.serving.ServingObs` (metrics
             registry always on), ``"off"`` disables the rich hooks
@@ -571,14 +560,6 @@ class ServingEngine:
             2-byte floats). Default ``None``: float pools, every
             existing golden byte-identical (the scale tuples are empty
             pytrees — zero extra avals in the quantum signature).
-        cost_model: ``True`` sizes the cost ledger's MFU numerator from
-            the static cost model (:mod:`paddle_tpu.analysis.cost`):
-            the decode quantum's jaxpr-walked FLOPs per token — which
-            counts attention over live context and the lm-head that
-            the ``2N`` weight-matmul floor deliberately excludes —
-            clamped to never fall below that floor. Host-side
-            accounting only; the compiled quantum and its golden are
-            untouched. Default ``False``: the 2N floor, as before.
         multi_quantum: MULTI-QUANTUM DECODE DRIVER. ``K > 1`` builds a
             second quantum-family variant that runs UP TO K decode
             quanta per dispatch under ``lax.while_loop``, re-entering
@@ -601,27 +582,16 @@ class ServingEngine:
             the pool. A speculative engine ignores K: each spec round
             needs its acceptance counts on the host. Default ``1``: the
             variant isn't built, nothing changes.
-        attn_impl: ``"fused"`` switches the decode quantum's inner loop
-            to the online-softmax block-streaming attention
-            (`_fused_paged_decode_attn` — flash-style m/l/acc over
-            block-table entries, no (S, W*BS, HK, D) gathered copy,
-            dead blocks clamped to pool block 0), the XLA-level port of
-            the Pallas paged kernel's DMA-elision trick. The gather
-            path stays the parity oracle; the ``serving_multiquantum_
-            step`` recipe pins the fused graph's own golden. Default
-            ``"gather"``: every existing graph byte-identical.
     """
 
     def __init__(self, model, num_slots=8, block_size=32, num_blocks=None,
                  max_context=None, prefill_chunk=64, decode_quantum=8,
                  decode_strategy="greedy", top_k=0, top_p=1.0,
                  temperature=1.0, eos_token_id=None, spec_draft=None,
-                 spec_gamma=4, prefix_cache=False,
-                 per_request_sampling=False, obs=None,
+                 spec_gamma=4, prefix_cache=False, obs=None,
                  trace=False, slo=None, flight=None, mesh=None, tp=None,
                  faults=None, resilience=None, quantize=None,
-                 kv_dtype=None, cost_model=False, multi_quantum=1,
-                 attn_impl="gather"):
+                 kv_dtype=None, multi_quantum=1):
         cfg = model.config
         if getattr(cfg, "sliding_window", None):
             raise NotImplementedError(
@@ -632,21 +602,6 @@ class ServingEngine:
             raise ValueError(
                 f"decode_strategy must be greedy|sampling, got "
                 f"{decode_strategy!r}")
-        self._per_request_sampling = bool(per_request_sampling)
-        if self._per_request_sampling and decode_strategy != "sampling":
-            raise ValueError(
-                "per_request_sampling=True requires "
-                "decode_strategy='sampling' (per-slot temperature only "
-                "changes the sampling quantum)")
-        if self._per_request_sampling and spec_draft is not None:
-            raise NotImplementedError(
-                "per_request_sampling does not compose with spec_draft "
-                "yet: the speculative round's acceptance math takes the "
-                "engine-wide temperature")
-        if attn_impl not in ("gather", "fused"):
-            raise ValueError(
-                f"attn_impl must be gather|fused, got {attn_impl!r}")
-        self.attn_impl = attn_impl
         self._mq_max = int(multi_quantum)
         if self._mq_max < 1:
             raise ValueError(
@@ -791,10 +746,12 @@ class ServingEngine:
         self._done = np.ones(s, bool)
         self._max_new = np.zeros(s, np.int32)
         self._keys = np.zeros((s, 2), np.uint32)
-        # per-slot temperature: an input of the front-door quantum
-        # variant (per_request_sampling=True); the default engine's
-        # quantum signature — and golden fingerprint — never sees it
-        self._temps = np.ones(s, np.float32)
+        # per-slot temperature: an input of both programs of a sampling
+        # engine; a greedy engine's programs never see it, and the
+        # speculative round's acceptance math takes the engine-wide one
+        self._temps = (np.ones(s, np.float32)
+                       if decode_strategy == "sampling"
+                       and spec_draft is None else None)
         # front-door streaming hook: called (req, token) for EVERY
         # token appended to a request's stream, at the same host
         # boundary obs.on_token fires on
@@ -928,17 +885,9 @@ class ServingEngine:
         # int8 flops model: a quantized stack feeds the MXU's int8 path,
         # whose peak is 2x the bf16 peak — the MFU denominator doubles
         # (flops per token is unchanged: same 2N contraction count)
-        flops_tok = decode_flops_per_token(
-            n_params, n_embedding_params=embed)
-        if cost_model:
-            # opt-in: count the ACTUAL decode quantum's jaxpr (attention
-            # over live context + lm-head, which 2N excludes) and take
-            # the larger
-            from ..analysis.cost import quantum_flops_per_token
-
-            flops_tok = max(quantum_flops_per_token(self), flops_tok)
         self.obs.ledger.configure(
-            flops_per_token=flops_tok,
+            flops_per_token=decode_flops_per_token(
+                n_params, n_embedding_params=embed),
             peak_flops=peak_flops_per_chip()
             * (2.0 if quantize is not None else 1.0))
         # SLO + flight recorder (the operability tier over the obs
@@ -994,16 +943,21 @@ class ServingEngine:
         """Queue one request; returns the :class:`Request` handle.
 
         Per-request knobs: ``priority`` (admission class, see
-        serving/policy.py), ``temperature`` (needs an engine built with
-        ``per_request_sampling=True``), ``stop_token_ids`` /
+        serving/policy.py), ``temperature`` (a sampling engine without
+        ``spec_draft``), ``stop_token_ids`` /
         ``stop_sequences`` (host-side stop rules; ``finish_reason``
         becomes ``"stop"``), plus the existing ``max_new_tokens`` /
         ``seed``."""
-        if temperature is not None and not self._per_request_sampling:
-            raise ValueError(
-                "per-request temperature needs an engine built with "
-                "per_request_sampling=True (and "
-                "decode_strategy='sampling')")
+        if temperature is not None and self._temps is None:
+            if self.decode_strategy != "sampling":
+                raise ValueError(
+                    "submit(temperature=) needs an engine built with "
+                    "decode_strategy='sampling': a greedy engine's "
+                    "programs take no temperature")
+            raise NotImplementedError(
+                "submit(temperature=) does not compose with spec_draft "
+                "yet: the speculative round's acceptance math takes the "
+                "engine-wide temperature")
         req = Request(prompt, max_new_tokens=max_new_tokens,
                       req_id=req_id, seed=seed, priority=priority,
                       temperature=temperature,
@@ -1530,7 +1484,6 @@ class ServingEngine:
             "eos_token_id": self.eos_token_id,
             "spec_gamma": self.spec_gamma,
             "prefix_cache": self.prefix_cache,
-            "per_request_sampling": self._per_request_sampling,
             "quantize": self.quantize,
             "kv_dtype": self.kv_dtype,
             "submitted_total": self.scheduler._submitted_total,
@@ -1549,7 +1502,9 @@ class ServingEngine:
         part of the snapshot; ``overrides`` adjust any constructor
         kwarg (e.g. ``resilience=True``, ``flight=True``). Completed
         summaries ride the snapshot for audit but are not
-        re-materialized."""
+        re-materialized. A key that an older snapshot carries and the
+        constructor no longer takes (``per_request_sampling``) is not
+        read."""
         if snap.get("kind") != "serving_engine_snapshot":
             raise ValueError(
                 "not a serving engine snapshot (kind="
@@ -1565,7 +1520,6 @@ class ServingEngine:
             eos_token_id=snap["eos_token_id"],
             spec_gamma=snap["spec_gamma"],
             prefix_cache=snap["prefix_cache"],
-            per_request_sampling=snap["per_request_sampling"],
             quantize=snap.get("quantize"),
             kv_dtype=snap.get("kv_dtype"))
         kwargs.update(overrides)
@@ -1644,9 +1598,10 @@ class ServingEngine:
             self._done[slot] = True  # not decodable until prefill ends
             self._max_new[slot] = req.max_new_tokens
             self._keys[slot] = np.asarray(jax.random.PRNGKey(req.seed))
-            self._temps[slot] = (self.temperature
-                                 if req.temperature is None
-                                 else req.temperature)
+            if self._temps is not None:
+                self._temps[slot] = (self.temperature
+                                     if req.temperature is None
+                                     else req.temperature)
 
     def _make_mixed(self, model, scratch, select):
         """Build the mixed step's callable for ``model``: one chunk of
@@ -1714,9 +1669,8 @@ class ServingEngine:
                     # (sequence or prefix index) still maps
                     pool.make_writable(req.req_id, seq, seq + n)
         small = [self._dev(a) for a in (
-            ids, self._seq_lens, counts, self._keys, self._n_gen)]
-        if self._per_request_sampling:
-            small.append(self._dev(self._temps))
+            ids, self._seq_lens, counts, self._keys, self._n_gen,
+            *self._temps_arg())]
 
         def args_of(pool, p_vals):
             # the scale tuples are EMPTY on a float pool (no avals)
@@ -1871,10 +1825,10 @@ class ServingEngine:
         if self.decode_strategy == "greedy":
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
         if temps is not None:
-            # per-slot temperature (the front-door quantum variant):
-            # same scale-then-filter order — and the same f32 division
-            # — as the engine-wide path, so a uniform temps row
-            # replays the engine-wide engine bit-for-bit
+            # per-slot temperature: same scale-then-filter order — and
+            # the same f32 division — as the engine-wide path (the
+            # speculative round's), so a uniform temps row replays it
+            # bit-for-bit
             filt = _filter_logits(
                 logits.astype(jnp.float32)
                 / jnp.maximum(temps, 1e-6)[:, None],
@@ -1900,7 +1854,6 @@ class ServingEngine:
         scratch = self._scratch_block
         t_steps = self.config.decode_quantum
         n_slots = self.config.num_slots
-        attn_impl = self.attn_impl
         has_eos = self.eos_token_id is not None
         eos = -1 if self.eos_token_id is None else int(self.eos_token_id)
 
@@ -1916,8 +1869,7 @@ class ServingEngine:
                     def fwd(tok_t):
                         return paged_decode_math(
                             model, scratch, tok_t, seq_lens, tables,
-                            kc, vc, live, ks=ks, vs=vs,
-                            attn_impl=attn_impl), moe_rows(model)
+                            kc, vc, live, ks=ks, vs=vs), moe_rows(model)
 
                     tok_t = Tensor(last_tok[:, None], stop_gradient=True)
                     ((logits, kc2, vc2, ks2, vs2), rows), _ = \
@@ -1984,24 +1936,21 @@ class ServingEngine:
                     buf, rbuf, qi)
 
         inner = scan_steps if multi is None else multi_steps
-        if self._per_request_sampling:
-            # the front-door variant: per-slot temperature rides the
-            # existing per-slot state as ONE extra (S,) f32 input —
-            # its own recipe (serving_frontdoor_step) and golden pin
-            # this signature; the default quantum below is untouched
-            def quantum(kc, vc, ks, vs, p_vals, tables, seq_lens,
-                        last_tok, n_gen, done, max_new, keys, temps):
-                return inner(kc, vc, ks, vs, p_vals, tables,
-                             seq_lens, last_tok, n_gen, done,
-                             max_new, keys, temps)
-        else:
-            def quantum(kc, vc, ks, vs, p_vals, tables, seq_lens,
-                        last_tok, n_gen, done, max_new, keys):
-                return inner(kc, vc, ks, vs, p_vals, tables,
-                             seq_lens, last_tok, n_gen, done,
-                             max_new, keys, None)
+
+        # ``temps`` is the per-slot temperature row of a sampling engine
+        # (`_temps_arg`); every other engine calls with twelve arguments
+        def quantum(kc, vc, ks, vs, p_vals, tables, seq_lens,
+                    last_tok, n_gen, done, max_new, keys, temps=None):
+            return inner(kc, vc, ks, vs, p_vals, tables, seq_lens,
+                         last_tok, n_gen, done, max_new, keys, temps)
 
         return quantum
+
+    def _temps_arg(self):
+        """The trailing argument of the quantum and of the mixed program:
+        the per-slot temperatures where the engine has them, nothing
+        otherwise."""
+        return () if self._temps is None else (self._dev(self._temps),)
 
     def _dev(self, a):
         """Device view of one host mirror: plain uncommitted transfer on
@@ -2036,16 +1985,13 @@ class ServingEngine:
                         self._dev(self._n_gen), self._dev(self._done),
                         self._dev(self._max_new),
                         self._dev(self._keys))
-            args = (list(self.pool.k_pools), list(self.pool.v_pools),
+            return (list(self.pool.k_pools), list(self.pool.v_pools),
                     tuple(self.pool.k_scales), tuple(self.pool.v_scales),
                     self._p_vals, self._dev(self._tables),
                     self._dev(self._seq_lens),
                     self._dev(self._last_tok), self._dev(self._n_gen),
                     self._dev(self._done), self._dev(self._max_new),
-                    self._dev(self._keys))
-            if self._per_request_sampling:
-                args = args + (self._dev(self._temps),)
-            return args
+                    self._dev(self._keys), *self._temps_arg())
 
     def _dispatch_quantum(self, quanta=1):
         """Run ONE quantum dispatch. Single chip: the jitted callable,

@@ -8,8 +8,8 @@ into a product; entry point ``paddle.inference.serve()``).
 What the front door adds, all as HOST-SIDE policy at the engine's
 existing scheduler boundaries (the compiled quantum's
 ``max_host_callbacks=0`` budget and golden fingerprint are unchanged —
-the ``serving_frontdoor_step`` analysis recipe pins the
-per-request-sampling quantum variant with its own golden):
+the ``serving_frontdoor_step`` analysis recipe pins the sampling
+quantum, per-slot temperature input and all, with its own golden):
 
 - **token-by-token streaming**: :meth:`ServingFrontDoor.submit`
   returns a :class:`TokenStream` — iterate it synchronously (each pull
@@ -17,9 +17,8 @@ per-request-sampling quantum variant with its own golden):
   engine's ``token_sink`` hook pushes every emitted token the moment
   the host sees it.
 - **per-request generation params**: ``max_new_tokens`` / ``seed``
-  ride the existing per-slot state; ``temperature`` rides the
-  front-door quantum variant's per-slot temps input
-  (``per_request_sampling=True``); ``stop_token_ids`` /
+  ride the existing per-slot state; ``temperature`` is a sampling
+  engine's per-slot temps input; ``stop_token_ids`` /
   ``stop_sequences`` are host-side stop rules (``finish_reason ==
   "stop"``, truncate-at-stop convention like eos).
 - **priority preemption**: under pool pressure the pump evicts a
@@ -49,10 +48,6 @@ per-request-sampling quantum variant with its own golden):
   this request aliased from the content-addressed prefix index
   (prefill skipped them — the shared-system-prompt TTFT win), and
   :meth:`stats` carries the engine's ``prefix_cache`` counter block.
-
-Benched by ``scripts/bench_serving.py serving_overload`` (p95 TTFT +
-shed rate under a >capacity Poisson burst, shed vs no-shed arms;
-artifact BENCH_FRONTDOOR_r10.json).
 """
 from __future__ import annotations
 

@@ -69,9 +69,9 @@ class Request:
 
     - ``seed``: per-slot PRNG key for the sampling arm (existing).
     - ``max_new_tokens``: per-slot retirement bound (existing).
-    - ``temperature``: per-slot logits scale — requires an engine built
-      with ``per_request_sampling=True`` (the per-slot temperature
-      array is an input of the front-door quantum variant).
+    - ``temperature``: per-slot logits scale — requires a sampling
+      engine without ``spec_draft`` (the per-slot temperature array is
+      an input of its quantum and of its mixed program).
     - ``stop_token_ids`` / ``stop_sequences``: host-side stop rules
       checked as tokens are appended (``finish_reason == "stop"``; the
       device mask keeps the slot riding until the quantum boundary,

@@ -3,11 +3,9 @@
 stack — unverified, SURVEY.md §0; algorithm: speculative sampling à la
 Leviathan et al. / Chen et al.).
 
-PR 2's bench recorded the host-driven ``speculative_greedy_search``
-losing ~1000x to the fused on-device loop (BENCH_NOTES "Speculative
-decode perf"): per proposal round it paid γ draft dispatches, one
-verify dispatch, and a host sync. Here the ENTIRE round is one jitted
-program batched over the serving slot dimension:
+The host-driven ``speculative_greedy_search`` pays γ draft dispatches,
+one verify dispatch and a host sync per proposal round. Here the ENTIRE
+round is one jitted program batched over the serving slot dimension:
 
 - **draft phase**: a ``lax.scan`` of γ+1 single-token draft steps over
   the draft's own paged pool (``engine.paged_decode_math`` — the same

@@ -1,8 +1,10 @@
 #!/usr/bin/env bash
 # Pre-merge static gate: tracer-hazard lint + graph-budget audit +
-# golden-fingerprint compare over every registered recipe. Exits
-# non-zero on any hazard, budget violation, stale allowlist entry, or
-# fingerprint drift. Run from anywhere; ~1 min on the CPU backend.
+# golden-fingerprint compare over every registered recipe, then the
+# observability smoke checks. Exits non-zero on any hazard, budget
+# violation, stale allowlist entry, or fingerprint drift. Run from
+# anywhere; ~1 min on the CPU backend. It measures no speed: that is
+# `python3 benchmark/run.py` on the chip (PERF.md).
 #
 #     scripts/check_graphs.sh
 #
@@ -29,8 +31,8 @@ python -m paddle_tpu.analysis --check --fingerprint --cost
 # journal.
 #
 # Front-door gate (ISSUE 7): the `--check --fingerprint` pass above
-# also audits `serving_frontdoor_step` (the per-request-sampling
-# quantum variant built through the full policy tier after a forced
+# also audits `serving_frontdoor_step` (the sampling quantum, whose
+# per-slot temperature is an input, built through the full policy tier after a forced
 # preemption: 0 host callbacks, pools donated, its own golden), and
 # `obs check` runs the front-door smoke — a forced priority preemption
 # must fire the preempted/resumed/recomputed counters, resume must
@@ -91,8 +93,7 @@ python -m paddle_tpu.analysis --check --fingerprint --cost
 # placement and device-time floor on the default chip, and gates that
 # BOTH cost sources (XLA cost_analysis + the jaxpr walker) populated
 # and agree within the pinned band. The per-recipe FLOP/byte/intensity
-# caps ride `--check`; the exact counts ride the goldens; the
-# cross-source ratio is also budget-guarded in BENCH_COST_r17.json.
+# caps ride `--check`; the exact counts ride the goldens.
 #
 # Mixed-step gate (ISSUE 27): `--check --fingerprint` above also audits
 # `serving_mixed_step` — the ONE jitted program a step with prefilling
@@ -108,17 +109,11 @@ python -m paddle_tpu.analysis --check --fingerprint --cost
 # Multi-quantum gate (ISSUE 17): `--check --fingerprint` above also
 # audits `serving_multiquantum_step` — the K=4 on-device decode driver
 # (lax.while_loop over the scanned quantum, retiring rows against the
-# eos/max-len masks WITHOUT re-entering the host) with the fused
-# online-softmax paged-attention inner loop. Its budget keeps 0 host
-# callbacks + full pool donation and pins the fused path's structural
-# win: temp bytes <=12 KB per dispatch (the gather path audits
-# ~207 KB — the w*bs-wide gathered K/V staging the fused loop elides).
-# The single-quantum recipes' goldens must stay byte-identical: K=1
-# engines build the exact same scanned quantum, and the XLA-gather
-# attention stays the default parity oracle. Note the jaxpr-walker
-# HBM cap is loose (13 MB/token): the walker charges the block-scan's
-# gathered operands once PER BLOCK STEP while XLA's compiled report
-# reads ~717 KB/dispatch; the flops agreement band still gates.
+# eos/max-len masks WITHOUT re-entering the host). Its budget pins the
+# driver: 0 host callbacks over K quanta, full pool donation, no f32
+# matmul, and the quantum's own temp/FLOP/byte caps (both cost walkers
+# count the loop body once). The single-quantum recipes' goldens are
+# those of K=1 engines, which build the same scanned quantum.
 #
 # Cluster gate (ISSUE 15): the router is pure host code riding the
 # same engines, so `--check --fingerprint` above (0 host callbacks,
@@ -129,10 +124,4 @@ python -m paddle_tpu.analysis --check --fingerprint --cost
 # serving_router_* counters), stream bit-identical to a cluster-of-1
 # run, and render the merged ClusterExporter dashboard's cluster line.
 python -m paddle_tpu.obs check
-# Perf sentinel (ISSUE 10): the runtime twin of the graph gate —
-# validate/index the BENCH_*.json trajectory and enforce the declared
-# PerfBudget bands (spec >=1.1x, shed-arm p95 bound >=1.5x, prefix
-# prefill-token ratio >=2x, tp per-chip pool residency 2.0x,
-# obs/SLO/attribution overhead <3%, ...).
-scripts/check_perf.sh
-echo "check_graphs: lint + budgets + fingerprints (+obs +perf) all green"
+echo "check_graphs: lint + budgets + fingerprints + cost agreement + obs all green"

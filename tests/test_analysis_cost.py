@@ -14,25 +14,18 @@ absent, raises, or returns partial/odd shapes yields ``source="jaxpr"``
 
 Roofline level: classification flips exactly at the chip's ridge
 intensity across a synthetic sweep, the device floor is
-``max(flops/peak, bytes/bw)``, and the host gap is wall minus floor
-against a doctored bench artifact.
-
-Engine level (satellite): ``ServingEngine(cost_model=True)`` sizes the
-cost ledger's MFU numerator from the quantum's jaxpr — never below the
-2N weight-matmul floor.
+``max(flops/peak, bytes/bw)``, and the `--cost` CLI reads no record
+file.
 """
-import json
 import os
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-import paddle_tpu as paddle
 from paddle_tpu.analysis.cost import (
     AGREEMENT_BAND, CHIP_SPECS, CostReport, CostStats, DEFAULT_CHIP,
-    analyze_cost, host_gap_seconds, jaxpr_cost,
-    quantum_flops_per_token, roofline, xla_cost_stats,
+    analyze_cost, jaxpr_cost, roofline, xla_cost_stats,
 )
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -227,64 +220,17 @@ def test_chip_table_sane():
             spec.peak_flops / spec.hbm_bytes_per_sec)
 
 
-def test_host_gap_arithmetic():
-    assert host_gap_seconds(5e-6, 2e-6) == pytest.approx(3e-6)
-    # a TPU floor above a measured wall goes negative, not clamped:
-    # the sign carries the "different machines" signal
-    assert host_gap_seconds(1e-6, 2e-6) == pytest.approx(-1e-6)
+def test_cost_cli_needs_no_record_file(capsys):
+    """`--cost` reads nothing but the recipe: in a tree with no
+    ``BENCH_*.json`` it prints the static roofline line, gates the
+    cross-source agreement, exits 0 and prints no host-gap line (what a
+    dispatch takes above its floor is the benchmark's to measure)."""
+    import glob
 
-
-def test_measured_wall_reads_doctored_artifact(tmp_path, monkeypatch):
-    """The `--cost` CLI's host-gap column: per-recipe measured walls
-    come from BENCH_COST_r17.json when present, else the serving smoke
-    row's throughput, else n/a."""
     from paddle_tpu.analysis import __main__ as cli
 
-    monkeypatch.setattr(cli, "_REPO_ROOT", str(tmp_path))
-    # nothing on disk -> None for everyone
-    assert cli._measured_wall_s("serving_decode_step", 8) is None
-
-    (tmp_path / "BENCH_COST_r17.json").write_text(json.dumps({
-        "rows": [{"metric": "cost_model_floor_vs_measured_cpu_smoke",
-                  "recipe": "llama_decode_greedy",
-                  "measured_us_per_dispatch": 450.0}]}))
-    assert cli._measured_wall_s("llama_decode_greedy", 8) \
-        == pytest.approx(450.0 / 1e6)
-    # recipe not in the cost artifact falls through to the serving row
-    (tmp_path / "BENCH_SERVING_r06.json").write_text(json.dumps({
-        "rows": [{
-            "metric": "serving_engine_ragged_tokens_per_sec_cpu_smoke",
-            "quantum_decode_tokens_per_sec": 16000.0}]}))
-    assert cli._measured_wall_s("serving_decode_step", 8) \
-        == pytest.approx(8 / 16000.0)
-    # no fallback mapping for other recipes
-    assert cli._measured_wall_s("speculative_verify_step", 6) is None
-
-
-# ----------------------------------------------- engine MFU numerator
-
-def test_engine_cost_model_numerator_at_least_2n_floor():
-    """Satellite: cost_model=True prefers the quantum's jaxpr-walked
-    FLOPs per token — which counts attention + lm-head on top of the
-    2N weight-matmul floor, so it can never read below it."""
-    from paddle_tpu.nlp import LlamaConfig, LlamaForCausalLM
-    from paddle_tpu.obs.attribution import decode_flops_per_token
-    from paddle_tpu.serving import ServingEngine
-
-    paddle.seed(0)
-    cfg = LlamaConfig.tiny(tensor_parallel=False)
-    model = LlamaForCausalLM(cfg)
-    engine = ServingEngine(model, num_slots=2, decode_quantum=4,
-                           cost_model=True)
-    n_params = sum(int(v.size) for v in engine._p_vals)
-    embed = int(cfg.vocab_size) * int(cfg.hidden_size)
-    floor = decode_flops_per_token(n_params, n_embedding_params=embed)
-    assert engine.obs.ledger.flops_per_token >= floor
-    # and the walker itself sees the quantum
-    assert quantum_flops_per_token(engine) > 0
-
-    # default engine keeps the exact 2N floor (no behavior change)
-    paddle.seed(0)
-    engine2 = ServingEngine(LlamaForCausalLM(cfg), num_slots=2,
-                            decode_quantum=4)
-    assert engine2.obs.ledger.flops_per_token == floor
+    assert glob.glob(os.path.join(REPO, "BENCH_*.json")) == []
+    assert cli.main(["--recipe", "serving_decode_step", "--cost"]) == 0
+    out = capsys.readouterr().out
+    assert "roofline [" in out and "cost gate: OK" in out
+    assert "host gap" not in out and "measured" not in out

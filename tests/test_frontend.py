@@ -207,9 +207,9 @@ def test_frontdoor_async_streaming(tiny_model):
 
 
 def test_serve_facade_wiring(tiny_model):
-    """inference.serve(): SLOs + flight recorder default ON, sampling
-    auto-enables the per-request quantum variant, one front door per
-    engine enforced."""
+    """inference.serve(): SLOs + flight recorder default ON, a sampling
+    door takes a per-request temperature, one front door per engine
+    enforced."""
     cfg, model = tiny_model
     fd = inference.serve(model, num_slots=2, block_size=4)
     assert fd.engine.slo is not None and fd.engine.flight is not None
@@ -218,7 +218,8 @@ def test_serve_facade_wiring(tiny_model):
         ServingFrontDoor(fd.engine)
     fd2 = inference.serve(model, num_slots=2, block_size=4,
                           decode_strategy="sampling", top_k=4)
-    assert fd2.engine._per_request_sampling is True
+    assert fd2.submit(np.arange(1, 5, dtype=np.int32), max_new_tokens=2,
+                      temperature=0.7).request.temperature == 0.7
     # engine without SLOs: health reads vacuous ok, shedding rests on
     # backpressure alone
     eng = ServingEngine(model, num_slots=2, block_size=4)

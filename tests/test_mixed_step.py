@@ -208,8 +208,7 @@ def arm_per_request_temperatures():
     kw = dict(decode_strategy="sampling", top_k=20, top_p=0.9)
     prompts = prompts_of(cfg, (9, 5, 6), seed=4)
     temps, seeds = (0.3, 1.0, 1.7), (11, 12, 13)
-    engine = ServingEngine(model, per_request_sampling=True, **kw,
-                           **ENGINE_KW)
+    engine = ServingEngine(model, **kw, **ENGINE_KW)
     reqs = [engine.submit(p, max_new_tokens=6, seed=sd, temperature=t)
             for p, t, sd in zip(prompts, temps, seeds)]
     engine.run()

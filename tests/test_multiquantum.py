@@ -6,9 +6,7 @@ live on the host), prefix-cache hits with copy-on-write, int8
 weights + int8 KV, and mid-run preemption — because between
 steady-state quanta the host only round-trips device state through
 untouched int32 mirrors, so folding K round-trips on-device changes no
-math. The fused online-softmax paged-attention path gets the same
-oracle treatment (engine-level stream equality plus a tensor-level
-unit parity check vs the XLA-gather reference), the
+math. The
 ``Scheduler.steady_state`` predicate that gates K is unit-tested, the
 K-token dispatch must account K quanta (token attribution conserved),
 the ``serving_host_gap_fraction`` gauge must be live, and the
@@ -49,20 +47,17 @@ def _run_streams(engine, requests, seeds=None):
 # ------------------------------------------------- bit-exactness matrix
 def test_multiquantum_greedy_matrix(tiny_model):
     """Greedy ragged requests over 2 slots (retirement + slot reuse
-    mid-run): K=4 and K=4+fused streams bit-exact vs the per-quantum
-    gather engine, and the fused path alone (K=1) as well — the driver
-    and the attention rewrite are independently stream-preserving."""
+    mid-run): the K=4 streams are bit-exact vs the per-quantum
+    engine's."""
     cfg, model = tiny_model
     rng = np.random.RandomState(0)
     requests = _ragged(cfg, rng)
     kw = dict(num_slots=2, block_size=4, prefill_chunk=4,
               decode_quantum=3)
     base = _run_streams(ServingEngine(model, **kw), requests)
-    for mq, attn in ((4, "gather"), (1, "fused"), (4, "fused")):
-        got = _run_streams(
-            ServingEngine(model, multi_quantum=mq, attn_impl=attn,
-                          **kw), requests)
-        assert got == base, f"stream drift at K={mq} attn={attn}"
+    got = _run_streams(ServingEngine(model, multi_quantum=4, **kw),
+                       requests)
+    assert got == base
 
 
 def test_multiquantum_sampling_fixed_seed(tiny_model):
@@ -131,10 +126,9 @@ def test_multiquantum_prefix_hit_cow(tiny_model):
 
 
 def test_multiquantum_int8(tiny_model):
-    """int8 weights + int8 KV pool under the K driver and the fused
-    dequant attention: streams bit-exact vs the per-quantum int8
-    gather engine (fresh models per arm — quantization sweeps the
-    params in place)."""
+    """int8 weights + int8 KV pool under the K driver: streams
+    bit-exact vs the per-quantum int8 engine (fresh models per arm —
+    quantization sweeps the params in place)."""
     cfg, _ = tiny_model
     rng = np.random.RandomState(4)
     requests = _ragged(cfg, rng, n=4)
@@ -142,17 +136,14 @@ def test_multiquantum_int8(tiny_model):
               decode_quantum=3, quantize="weight_only_int8",
               kv_dtype="int8")
 
-    def arm(mq, attn):
+    def arm(mq):
         paddle.seed(0)
         model = LlamaForCausalLM(LlamaConfig.tiny(
             tensor_parallel=False))
         return _run_streams(
-            ServingEngine(model, multi_quantum=mq, attn_impl=attn,
-                          **kw), requests)
+            ServingEngine(model, multi_quantum=mq, **kw), requests)
 
-    base = arm(1, "gather")
-    assert arm(4, "gather") == base
-    assert arm(4, "fused") == base
+    assert arm(4) == arm(1)
 
 
 def test_multiquantum_preemption(tiny_model):
@@ -250,75 +241,10 @@ def test_host_gap_gauge_live(tiny_model):
     assert "serving_host_gap_fraction" in text
 
 
-# ---------------------------------------------- fused attention unit
-def test_fused_attention_matches_gather_unit():
-    """Tensor-level parity: the online-softmax block-streaming
-    attention equals the XLA-gather reference on random pools with
-    ragged lengths and dead rows (lens carries the alive mask), in
-    f32 to tight tolerance and bit-exactly after the bf16 output cast
-    the decode quantum applies."""
-    import jax.numpy as jnp
-
-    from paddle_tpu.serving.engine import (
-        _fused_paged_decode_attn, _xla_paged_decode_attn)
-
-    rng = np.random.RandomState(9)
-    S, w, bs, hq, hk, d, B = 4, 5, 4, 4, 2, 16, 24
-    q = jnp.asarray(rng.randn(S, hq, d).astype(np.float32))
-    kp = jnp.asarray(rng.randn(B, bs, hk, d).astype(np.float32))
-    vp = jnp.asarray(rng.randn(B, bs, hk, d).astype(np.float32))
-    tables = jnp.asarray(
-        rng.randint(0, B, (S, w)).astype(np.int32))
-    lens = jnp.asarray(np.array([7, 20, 1, 13], dtype=np.int32))
-    ref = _xla_paged_decode_attn(q, kp, vp, tables, lens)
-    got = _fused_paged_decode_attn(q, kp, vp, tables, lens)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-6, atol=2e-6)
-    qb = q.astype(jnp.bfloat16)
-    ref_b = _xla_paged_decode_attn(qb, kp.astype(jnp.bfloat16),
-                                   vp.astype(jnp.bfloat16), tables,
-                                   lens)
-    got_b = _fused_paged_decode_attn(qb, kp.astype(jnp.bfloat16),
-                                     vp.astype(jnp.bfloat16), tables,
-                                     lens)
-    assert np.array_equal(
-        np.asarray(got_b).view(np.uint16),
-        np.asarray(ref_b).view(np.uint16)), \
-        "bf16 outputs must be bit-identical"
-
-
-def test_fused_attention_int8_pools_unit():
-    """Same parity with int8 K/V pools + per-row f32 scale pools (the
-    fused path dequantizes per streamed block)."""
-    import jax.numpy as jnp
-
-    from paddle_tpu.serving.engine import (
-        _fused_paged_decode_attn, _xla_paged_decode_attn)
-
-    rng = np.random.RandomState(10)
-    S, w, bs, hq, hk, d, B = 3, 4, 4, 4, 2, 8, 16
-    q = jnp.asarray(rng.randn(S, hq, d).astype(np.float32))
-    kq = jnp.asarray(rng.randint(-127, 128, (B, bs, hk, d))
-                     .astype(np.int8))
-    vq = jnp.asarray(rng.randint(-127, 128, (B, bs, hk, d))
-                     .astype(np.int8))
-    ks = jnp.asarray((rng.rand(B, bs, hk) * 0.02 + 1e-3)
-                     .astype(np.float32))
-    vs = jnp.asarray((rng.rand(B, bs, hk) * 0.02 + 1e-3)
-                     .astype(np.float32))
-    tables = jnp.asarray(rng.randint(0, B, (S, w)).astype(np.int32))
-    lens = jnp.asarray(np.array([5, 16, 2], dtype=np.int32))
-    ref = _xla_paged_decode_attn(q, kq, vq, tables, lens, ks=ks, vs=vs)
-    got = _fused_paged_decode_attn(q, kq, vq, tables, lens,
-                                   ks=ks, vs=vs)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-6, atol=2e-6)
-
-
 # -------------------------------------------------- recipe budget gate
 def test_serving_multiquantum_step_budget():
     """ISSUE 17 acceptance: the EXACT K=4 while-loop driver the
-    multi-quantum engine dispatches (fused attention live) has zero
+    multi-quantum engine dispatches has zero
     host callbacks, zero involuntary remat, no collectives, every KV
     pool leaf donated — and its golden fingerprint matches, while the
     K=1 engines' goldens stay untouched (their tests compare against
@@ -339,8 +265,6 @@ def test_multiquantum_rejects_bad_args(tiny_model):
     cfg, model = tiny_model
     with pytest.raises(ValueError):
         ServingEngine(model, multi_quantum=0)
-    with pytest.raises(ValueError):
-        ServingEngine(model, attn_impl="flash")
     eng = ServingEngine(model, num_slots=2, block_size=4)
     with pytest.raises(ValueError):
         eng.multiquantum_step_target()  # K=1 engine has no mq program

@@ -103,6 +103,67 @@ def test_paged_kernel_matches_xla_gather(group, hk, block_size, pool):
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
+def test_gather_decode_matches_dense_reference(kind):
+    """`_xla_paged_decode_attn` (the decode attention wherever the Pallas
+    kernel is not) against a plain softmax in numpy over the same rows
+    laid out contiguously: ragged lengths (one token, mid-block, a block's
+    edge, the whole table), grouped heads, and dead table entries that
+    point at a block of huge values which a missing mask would let in.
+    For an int8 pool with per-row scale pools the reference runs over the
+    dequantised rows."""
+    from paddle_tpu.nlp.paged_attention import _xla_paged_decode_attn
+
+    rng = np.random.RandomState(9)
+    s, w, bs, h, hk, d, nb = 4, 5, 4, 4, 2, 16, 24
+    lens = np.asarray([7, 20, 1, 12], np.int32)
+    tables = (rng.permutation(nb - 1)[: s * w].reshape(s, w) + 1).astype(
+        np.int32)
+    for i, ln in enumerate(lens):
+        tables[i, -(-ln // bs):] = 0          # dead entries: block 0
+    q = rng.randn(s, h, d).astype(np.float32)
+    scales = {}
+    if kind == "int8":
+        kp = rng.randint(-127, 128, (nb, bs, hk, d)).astype(np.int8)
+        vp = rng.randint(-127, 128, (nb, bs, hk, d)).astype(np.int8)
+        kp[0], vp[0] = 127, 127
+        ksc = rng.uniform(0.001, 0.021, (nb, bs, hk)).astype(np.float32)
+        vsc = rng.uniform(0.001, 0.021, (nb, bs, hk)).astype(np.float32)
+        scales = {"ks": jnp.asarray(ksc), "vs": jnp.asarray(vsc)}
+        k_rows = kp.astype(np.float64) * ksc[..., None]
+        v_rows = vp.astype(np.float64) * vsc[..., None]
+    else:
+        kp = rng.randn(nb, bs, hk, d).astype(np.float32)
+        vp = rng.randn(nb, bs, hk, d).astype(np.float32)
+        kp[0], vp[0] = 1e4, 1e4
+        if kind == "bf16":
+            q, kp, vp = (np.asarray(jnp.asarray(a, jnp.bfloat16)
+                                    .astype(jnp.float32))
+                         for a in (q, kp, vp))
+        k_rows, v_rows = kp.astype(np.float64), vp.astype(np.float64)
+    dtype = jnp.bfloat16 if kind == "bf16" else jnp.float32
+    pool_dtype = jnp.int8 if kind == "int8" else dtype
+    out = _xla_paged_decode_attn(
+        jnp.asarray(q, dtype), jnp.asarray(kp, pool_dtype),
+        jnp.asarray(vp, pool_dtype), jnp.asarray(tables),
+        jnp.asarray(lens), **scales)
+    assert out.dtype == dtype and out.shape == (s, h, d)
+
+    want = np.zeros((s, h, d))
+    for i, ln in enumerate(lens):
+        # the row's live tokens, contiguous: (ln, HK, D)
+        k = k_rows[tables[i]].reshape(w * bs, hk, d)[:ln]
+        v = v_rows[tables[i]].reshape(w * bs, hk, d)[:ln]
+        for head in range(h):
+            kv = head // (h // hk)
+            sc = k[:, kv] @ q[i, head].astype(np.float64) / np.sqrt(d)
+            p = np.exp(sc - sc.max())
+            want[i, head] = (p / p.sum()) @ v[:, kv]
+    tol = 2e-2 if kind == "bf16" else 2e-5      # bf16: the output's cast
+    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)), want,
+                               rtol=tol, atol=tol)
+
+
 def test_paged_cache_write_then_attend():
     rng = np.random.RandomState(1)
     lens = [15, 40]

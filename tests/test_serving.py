@@ -21,13 +21,13 @@ The FRONT DOOR's engine tier (ISSUE 7): the preemption correctness
 oracle — a preempted-then-resumed request's stream is BIT-EXACT vs an
 undisturbed run in both the greedy and fixed-seed sampling arms, with
 TTFT observed exactly once despite the re-prefill — plus per-request
-temperature threading (a uniform-temps front-door engine replays the
-engine-wide sampling engine bit-for-bit), host-side stop rules,
+temperature threading (a request that names the engine-wide temperature
+replays one that names none bit-for-bit), host-side stop rules,
 refcount-safe pool release (shared blocks survive one holder's
 eviction), a 100-round ragged preempt/resume leak hunt at the
 scheduler level, priority admission ordering, and the
-``serving_frontdoor_step`` budget + golden pinning the
-per-slot-temperature quantum variant."""
+``serving_frontdoor_step`` budget + golden pinning the sampling
+quantum with its per-slot temperature input."""
 import numpy as np
 import pytest
 import jax.numpy as jnp
@@ -188,18 +188,16 @@ def test_engine_rejects_oversize_and_bad_strategy(tiny_model):
 # ------------------------------------------------ preemption oracle
 def test_preemption_and_temperature_sampling_bit_exact(
         tiny_model, sampling_prompts, plain_sampling_outputs):
-    """ISSUE 7 oracle, fixed-seed sampling arm — one front-door engine
-    (per_request_sampling=True) proves two bit-exactness claims against
-    the module-shared plain sampling run at once: (a) per-request
-    TEMPERATURE threads through the per-slot temps input of the
-    front-door quantum variant (every request passes the temperature
-    the engine-wide fixture used — uniform temps must replay it
-    bit-for-bit), and (b) the fold_in(key, n_emitted) token-stream
+    """ISSUE 7 oracle, fixed-seed sampling arm — one sampling engine
+    proves two bit-exactness claims against the module-shared plain
+    sampling run at once: (a) per-request TEMPERATURE threads through
+    the per-slot temps input of the quantum (every request names the
+    temperature the fixture's requests took from the engine — it must
+    replay them bit-for-bit), and (b) the fold_in(key, n_emitted) token-stream
     discipline survives EVICTION — a preempted request re-prefills and
     continues the SAME sample stream, with TTFT observed once."""
     cfg, model = tiny_model
-    engine = ServingEngine(model, decode_quantum=3,
-                           per_request_sampling=True, **_SAMPLING_KW)
+    engine = ServingEngine(model, decode_quantum=3, **_SAMPLING_KW)
     reqs = [engine.submit(p, max_new_tokens=5, seed=i,
                           temperature=_SAMPLING_KW["temperature"])
             for i, p in enumerate(sampling_prompts)]
@@ -215,18 +213,62 @@ def test_preemption_and_temperature_sampling_bit_exact(
     assert engine.obs.registry.get("serving_ttft_seconds").count() == 3
 
 
-def test_per_request_param_validation(tiny_model):
-    """Temperature needs the front-door quantum variant; the variant
-    needs the sampling strategy; stop rules are pure host checks."""
+@pytest.mark.parametrize("kind", ["sampling", "greedy", "spec_draft"])
+def test_request_temperature(tiny_model, sampling_prompts,
+                             plain_sampling_outputs, kind):
+    """``submit(temperature=)`` with no flag at build: a sampling engine
+    takes it and it changes the stream; a greedy engine and a
+    speculative one refuse it by name (their programs have no per-slot
+    temperature input)."""
     cfg, model = tiny_model
-    engine = ServingEngine(model, num_slots=2, block_size=4)
-    with pytest.raises(ValueError, match="per_request_sampling"):
-        engine.submit(np.arange(1, 5, dtype=np.int32), temperature=0.7)
-    with pytest.raises(ValueError, match="sampling"):
-        ServingEngine(model, per_request_sampling=True)
-    with pytest.raises(NotImplementedError, match="spec_draft"):
-        ServingEngine(model, decode_strategy="sampling",
-                      per_request_sampling=True, spec_draft=model)
+    prompt = np.arange(1, 5, dtype=np.int32)
+    if kind == "greedy":
+        engine = ServingEngine(model, num_slots=2, block_size=4)
+        with pytest.raises(ValueError, match="decode_strategy='sampling'"):
+            engine.submit(prompt, temperature=0.7)
+        return
+    if kind == "spec_draft":
+        engine = ServingEngine(model, spec_draft=model, spec_gamma=2,
+                               **_SAMPLING_KW)
+        with pytest.raises(NotImplementedError, match="spec_draft"):
+            engine.submit(prompt, temperature=0.7)
+        assert engine.submit(prompt, max_new_tokens=2).temperature is None
+        return
+    # the fixture's engine, with one request at a far higher temperature:
+    # its stream leaves the fixture's, its neighbours' do not
+    engine = ServingEngine(model, decode_quantum=3, **_SAMPLING_KW)
+    hot = 0
+    reqs = [engine.submit(p, max_new_tokens=5, seed=i,
+                          temperature=50.0 if i == hot else None)
+            for i, p in enumerate(sampling_prompts)]
+    engine.run()
+    for i, (req, want) in enumerate(zip(reqs, plain_sampling_outputs)):
+        same = np.array_equal(engine.output_tokens(req), want)
+        assert same == (i != hot), (i, engine.output_tokens(req), want)
+
+
+def test_restore_ignores_retired_snapshot_keys(tiny_model):
+    """A snapshot written before ``per_request_sampling`` was retired
+    carries the key; ``restore()`` does not read it, and the restored
+    sampling engine still takes a per-request temperature."""
+    cfg, model = tiny_model
+    engine = ServingEngine(model, **_SAMPLING_KW)
+    engine.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=4,
+                  seed=3, temperature=0.5)
+    snap = engine.snapshot()
+    assert "per_request_sampling" not in snap
+    old = dict(snap, per_request_sampling=True)
+    restored = ServingEngine.restore(old, model)
+    assert restored.decode_strategy == "sampling"
+    (req,) = restored.scheduler.waiting
+    assert req.temperature == 0.5
+    restored.run()
+    assert len(restored.completed) == 1
+    assert len(restored.completed[0].tokens) == 4
+
+
+def test_per_request_param_validation(tiny_model):
+    """Stop rules are pure host checks."""
     # stop-sequence rule, host-side (no engine run needed)
     req = Request(np.arange(1, 5), max_new_tokens=10,
                   stop_sequences=[[7, 8]])

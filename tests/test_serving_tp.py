@@ -14,7 +14,7 @@ pure host allocator work, no compiles) and the mesh-kwarg error paths
 (all raise before any tracing).
 
 The expensive engine-vs-engine parities (fixed-seed sampling with
-``per_request_sampling``, the speculative draft+verify round) are
+per-request temperatures, the speculative draft+verify round) are
 ``slow``: each builds two engines. Run them with ``-m slow``.
 """
 import numpy as np
@@ -124,7 +124,7 @@ def test_tp2_greedy_prefix_stream_parity(tp_model):
 # ----------------------------------------------- slow engine parities
 @pytest.mark.slow
 def test_tp2_per_request_sampling_parity(tp_model):
-    """Fixed-seed sampling through the front-door quantum variant:
+    """Fixed-seed sampling through the sampling quantum:
     per-slot temperatures + per-request seeds, tp1 vs tp2 engines on
     the SAME weights — streams must match bit-for-bit (the collectives
     change where the math runs, not what it computes)."""
@@ -137,7 +137,6 @@ def test_tp2_per_request_sampling_parity(tp_model):
         eng = ServingEngine(model, num_slots=3, block_size=4,
                             prefill_chunk=4, decode_quantum=3,
                             decode_strategy="sampling", temperature=0.8,
-                            per_request_sampling=True,
                             **({"tp": tp} if tp else {}))
         reqs = [eng.submit(p, max_new_tokens=5, seed=i,
                            temperature=0.7 if i % 2 else 1.2)
