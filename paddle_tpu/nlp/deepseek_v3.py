@@ -21,7 +21,8 @@ Layer equations (``x`` the layer's input, pre-norm residual as in Llama:
   q_nope_h``, ``score_h = (q~_h . c + q_rope_h . k_rope) / sqrt(...)``,
   ``out_h = (sum p c) W_uv_h``; per-head keys and values of the context
   are never built. The chunk (prefill) step up-projects one tile of
-  cached rows at a time instead: fewer operations at long chunks.
+  cached rows at a time instead: fewer operations at long chunks; on a
+  TPU inside a Pallas kernel whose scores never leave VMEM.
 - Dense layers: SwiGLU of ``intermediate_size``. Expert layers:
   ``s = sigmoid(x W_r)`` in float32, selection by the top k of ``s + b``
   (``e_score_correction_bias``), weights ``s[sel] / sum s[sel]`` times
@@ -312,6 +313,26 @@ class DeepseekV3Attention(Layer):
             cfg.qk_nope_head_dim + cfg.v_head_dim)
         return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
 
+    def _chunk_attn(self, q_nope, q_rope, pool, tables, base_lens):
+        """Route the chunk attention as ``paged_attention._paged_attn``
+        routes decode: the Pallas kernel on a TPU for a float pool and
+        widths it takes (``ops/pallas/chunk_attention.py``: keys and
+        values up-projected in VMEM, no score ever stored; it takes
+        ``kv_b_proj``'s weight as stored), ``_latent_chunk_attn`` (the
+        reference the parity tests compare with) elsewhere. The path is
+        counted once a traced program."""
+        from ..ops.pallas import chunk_attention as kernel
+
+        w_kvb = self.kv_b_proj.weight._value
+        if PA.use_pallas_kernels() and kernel.supports(
+                q_nope, q_rope, pool, w_kvb):
+            PA.count_chunk_attention_program("kernel")
+            return kernel.latent_chunk_attention(
+                q_nope, q_rope, pool, tables, base_lens, w_kvb, self.scale)
+        PA.count_chunk_attention_program("xla")
+        return _latent_chunk_attn(q_nope, q_rope, pool, tables, base_lens,
+                                  *self._up_weights(), self.scale)
+
     def _write(self, pool, rows, write_blk, write_off):
         return pool.at[write_blk, write_off].set(
             rows[..., None, :].astype(pool.dtype))
@@ -378,9 +399,7 @@ class DeepseekV3Attention(Layer):
             pool = self._write(cache[0], self._latent_rows(x, rope),
                                write_blk, write_off)
             q_nope, q_rope = self._queries(x, rope)      # (S, C, H, .)
-            out = _latent_chunk_attn(q_nope, q_rope, pool, tables,
-                                     base_lens, *self._up_weights(),
-                                     self.scale)
+            out = self._chunk_attn(q_nope, q_rope, pool, tables, base_lens)
             att = self._project_out(out, x.shape[:2])
         return att, (pool, None, None, None)
 

@@ -142,6 +142,18 @@ def _paged_chunk_attn(q, kp, vp, tables, base_lens, ks=None, vs=None):
         q.dtype)
 
 
+def use_pallas_kernels():
+    """The rule every attention route over the pool follows: the Pallas
+    kernels where the backend is a TPU (or ``FLAGS_pallas_force`` sends
+    a CPU test through the interpreter), unless
+    ``FLAGS_use_pallas_kernels`` is off."""
+    from ..core.flags import get_flags
+
+    flags = get_flags(["FLAGS_use_pallas_kernels", "FLAGS_pallas_force"])
+    return flags["FLAGS_use_pallas_kernels"] and (
+        jax.default_backend() == "tpu" or flags["FLAGS_pallas_force"])
+
+
 def _paged_attn(q, kp, vp, tables, lens, ks=None, vs=None):
     """Route decode attention: Pallas paged kernel on TPU (it takes the
     pool arrays as they are stored — no relayout on the way in — and
@@ -151,18 +163,37 @@ def _paged_attn(q, kp, vp, tables, lens, ks=None, vs=None):
     pools (int8 engine) always take the XLA path: the Pallas kernel only
     supports STATIC per-head scales, not per-(block, position, head)
     pools."""
-    from ..core.flags import get_flags
+    if ks is None and use_pallas_kernels():
+        from ..ops.pallas.paged_attention import paged_decode_attention
 
-    if ks is None:
-        flags = get_flags(
-            ["FLAGS_use_pallas_kernels", "FLAGS_pallas_force"])
-        use_pallas = flags["FLAGS_use_pallas_kernels"] and (
-            jax.default_backend() == "tpu" or flags["FLAGS_pallas_force"])
-        if use_pallas:
-            from ..ops.pallas.paged_attention import paged_decode_attention
-
-            return paged_decode_attention(q, kp, vp, tables, lens)
+        return paged_decode_attention(q, kp, vp, tables, lens)
     return _xla_paged_decode_attn(q, kp, vp, tables, lens, ks=ks, vs=vs)
+
+
+_chunk_programs_counted = None
+
+
+def count_chunk_attention_program(path):
+    """Raise ``serving_chunk_attention_programs_total{path}`` (``kernel``
+    | ``xla``) on the process's registry ONCE for the program being
+    traced, however many layers ask: the route is static per compiled
+    program, so a scrape says which path the mixed programs of this
+    process were built with."""
+    global _chunk_programs_counted
+    trace = jax.core.get_opaque_trace_state()
+    if trace != _chunk_programs_counted:
+        _chunk_programs_counted = trace
+        chunk_attention_programs().inc(path=path)
+
+
+def chunk_attention_programs():
+    """The counter itself (an engine's registry shares it)."""
+    from ..obs.registry import MetricsRegistry
+
+    return MetricsRegistry.process().counter(
+        "serving_chunk_attention_programs_total",
+        "mixed-step programs traced, by the path their latent chunk "
+        "attention takes (kernel | xla)")
 
 
 def _pin_kv(arr):

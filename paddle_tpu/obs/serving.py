@@ -181,6 +181,11 @@ class ServingObs:
                 ("experts_touched", "experts that got at least one row"),
                 ("expert_rows_max", "the fullest expert's rows"),
                 ("layer_steps", "(expert layer, decode step) pairs"))}
+        # counted where a mixed program is traced (the route of the
+        # latent chunk attention is static per program); shown here too
+        from ..nlp.paged_attention import chunk_attention_programs
+
+        r.share(chunk_attention_programs())
         # on the process's registry too: a reader outside the program
         # finds it after the engine is gone
         self._g_pool_token_bytes = r.share(MetricsRegistry.process().gauge(

@@ -140,6 +140,34 @@ def _mask_for_block(qi, ki, block_q, block_k, causal, causal_offset, kv_len,
     return mask
 
 
+def _online_softmax_fold(s, v, m_ref, l_ref, acc_ref, mask=None):
+    """Fold one (BQ, BK) tile of f32 scores ``s`` and its values ``v``
+    (BK, Dv) into the running statistics ``m_ref`` / ``l_ref`` (BQ, 1)
+    and ``acc_ref`` (BQ, Dv), all f32 refs: the online-softmax body of
+    every forward attention kernel here. ``mask`` (BQ, BK) marks the
+    valid pairs of a boundary tile; an interior tile passes None and
+    builds none. Matmul INPUTS stay in the storage dtype (bf16 on TPU)
+    with f32 ACCUMULATION via preferred_element_type: an .astype(f32)
+    before a dot forces quarter-rate f32 MXU passes, so ``p`` is cast to
+    the values' dtype and the caller hands ``s`` from such a product."""
+    if mask is not None:
+        s = jnp.where(mask, s, NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    if mask is not None:
+        # fully-masked rows keep m=NEG_INF; mask p explicitly so
+        # exp(NEG_INF - NEG_INF) = 1 cannot leak in
+        p = jnp.where(mask, p, 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    m_ref[...] = m_new
+
+
 # --------------------------------------------------------------------------
 # forward
 # --------------------------------------------------------------------------
@@ -163,38 +191,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                           kv_len, window)
 
     def _accumulate(masked):
-        # matmul INPUTS stay in the storage dtype (bf16 on TPU) with f32
-        # ACCUMULATION via preferred_element_type — an .astype(f32) on
-        # q/k/v before the dot forces quarter-rate f32 MXU passes
-        # (round-5 fix: this was the "attention at ~50% of the matmul
-        # tier" cost in the round-4 long-context rows)
         q = q_ref[0, 0]  # (BQ, D)
         k = k_ref[0, 0]  # (BK, D)
-        v = v_ref[0, 0]  # (BK, D)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * sm_scale  # (BQ, BK) f32
-        if masked:
-            mask = _mask_for_block(qi, ki, block_q, block_k, causal,
-                                   causal_offset, kv_len, window)
-            s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_scr[:]  # (BQ, 1)
-        m_cur = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)
-        if masked:
-            # fully-masked rows keep m=NEG_INF; mask p explicitly so
-            # exp(NEG_INF - NEG_INF) = 1 cannot leak in
-            p = jnp.where(mask, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_scr[:] + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = m_new
-        l_scr[:] = l_new
+        mask = _mask_for_block(qi, ki, block_q, block_k, causal,
+                               causal_offset, kv_len, window) \
+            if masked else None
+        _online_softmax_fold(s, v_ref[0, 0], m_scr, l_scr, acc_scr, mask)
 
     @pl.when(run & full)
     def _interior():

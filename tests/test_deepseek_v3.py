@@ -420,3 +420,155 @@ def test_multi_quantum_carries_the_rows(toy):
     reg = door.engine.obs.registry
     assert reg.get("serving_moe_layer_steps_total").value() \
         == door.engine.stats["decode_quanta"] * 4 * 2
+
+
+# ------------------------------------- the chunk-attention kernel (PR 31)
+_KW = dict(h=4, dn=16, dr=8, dv=16, r=32, bs=8)   # 192 : 128 as 24 : 16
+
+
+def _chunk_case(name, dtype):
+    """(q_nope, q_rope, pool, tables, base_lens, w_kvb, kernel keywords)
+    of one case: toy widths with the real ratio of key to value width,
+    every slot's blocks its own, shuffled over the pool."""
+    h, dn, dr, dv, r, bs = (_KW[k] for k in ("h", "dn", "dr", "dv", "r",
+                                             "bs"))
+    c, w, base, kw, short = {
+        # a base of 0, one in the middle of a block, one whose chunk
+        # ends in the table's last block
+        "uneven_base_lens": (16, 6, [0, 13, 32], {}, None),
+        # 24 queries in tiles of 16, keys in tiles of two blocks
+        "chunk_not_a_multiple_of_the_query_tile": (
+            24, 7, [5, 0, 30], {"block_q": 16, "block_k": 16}, None),
+        # entries past a row's need are block 0, as the engine pads them
+        "table_with_padding_entries": (
+            16, 9, [3, 17, 0], {"block_k": 24}, [3, 5, 2]),
+        # an idle slot: base 0, every entry the scratch block
+        "padding_row": (16, 6, [9, 0, 20], {"block_q": 8}, [4, 0, 5]),
+    }[name]
+    s_ = len(base)
+    nb = s_ * w + 2
+    ks = jax.random.split(jax.random.PRNGKey(len(name)), 4)
+    q_nope = jax.random.normal(ks[0], (s_, c, h, dn)).astype(dtype)
+    q_rope = jax.random.normal(ks[1], (s_, c, h, dr)).astype(dtype)
+    pool = jax.random.normal(ks[2], (nb, bs, 1, r + dr)).astype(dtype)
+    w_kvb = (jax.random.normal(ks[3], (r, h * (dn + dv))) * 0.3).astype(dtype)
+    tables = np.random.default_rng(0).permutation(
+        np.arange(2, nb))[:s_ * w].reshape(s_, w).astype(np.int32)
+    for row, n in enumerate(short or ()):
+        tables[row, n:] = 1 if n == 0 else 0
+    return (q_nope, q_rope, pool, jnp.asarray(tables),
+            jnp.asarray(base, jnp.int32), w_kvb, kw)
+
+
+def _plain_chunk_attention(q_nope, q_rope, pool, tables, base_lens, w_kvb,
+                           scale):
+    """A masked softmax over the whole table's up-projected keys and
+    values, in float32: nothing tiled, nothing folded."""
+    f32 = jnp.float32
+    s_, c, h, dn = q_nope.shape
+    rows = pool[tables].reshape(s_, -1, pool.shape[-1]).astype(f32)
+    r = rows.shape[-1] - q_rope.shape[-1]
+    w = w_kvb.astype(f32).reshape(r, h, -1)
+    k_nope = jnp.einsum("skr,rhd->skhd", rows[..., :r], w[..., :dn])
+    v = jnp.einsum("skr,rhd->skhd", rows[..., :r], w[..., dn:])
+    logits = (jnp.einsum("schd,skhd->shck", q_nope.astype(f32), k_nope)
+              + jnp.einsum("schd,skd->shck", q_rope.astype(f32),
+                           rows[..., r:])) * scale
+    seen = (jnp.arange(rows.shape[1])[None, None, :]
+            <= (base_lens[:, None] + jnp.arange(c)[None, :])[..., None])
+    p = jax.nn.softmax(jnp.where(seen[:, None], logits, -jnp.inf), axis=-1)
+    return jnp.einsum("shck,skhd->schd", p, v)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("name", [
+    "uneven_base_lens", "chunk_not_a_multiple_of_the_query_tile",
+    "table_with_padding_entries", "padding_row"])
+def test_chunk_attention_kernel_matches_the_xla_loop_and_a_plain_softmax(
+        name, dtype):
+    """The kernel (interpreted here) against ``_latent_chunk_attn`` at the
+    same precision and against a plain float32 softmax; bf16 operands
+    round the keys, values and ``p`` to 2**-8, float32 only reorders."""
+    from paddle_tpu.nlp.deepseek_v3 import _latent_chunk_attn
+    from paddle_tpu.ops.pallas.chunk_attention import (
+        latent_chunk_attention)
+
+    *args, w_kvb, kw = _chunk_case(name, dtype)
+    scale = 1.0 / np.sqrt(_KW["dn"] + _KW["dr"])
+    got = latent_chunk_attention(*args, w_kvb, scale, **kw)
+    w3 = w_kvb.reshape(_KW["r"], _KW["h"], -1)
+    loop = _latent_chunk_attn(*args, w3[..., :_KW["dn"]],
+                              w3[..., _KW["dn"]:], scale)
+    plain = _plain_chunk_attention(*args, w_kvb, scale)
+    assert got.shape == loop.shape and got.dtype == loop.dtype == dtype
+    assert np.isfinite(_host(got.astype(jnp.float32))).all()
+    assert _max_abs(plain) > 0.5
+    tol = 2e-5 if dtype == jnp.float32 else 4e-2
+    assert _max_abs(got.astype(jnp.float32), loop.astype(jnp.float32)) < tol
+    assert _max_abs(got.astype(jnp.float32), plain) < tol
+
+
+@pytest.fixture
+def pallas_forced():
+    paddle.set_flags({"FLAGS_pallas_force": True})
+    yield
+    paddle.set_flags({"FLAGS_pallas_force": False})
+
+
+def _chunk_programs(path):
+    from paddle_tpu.nlp.paged_attention import chunk_attention_programs
+
+    return chunk_attention_programs().value(path=path)
+
+
+def test_mixed_step_through_the_kernel_serves_the_xla_routes_tokens(
+        toy, request):
+    """The engine's mixed step with the kernel route forced (prompts of
+    several chunks, rows of uneven length, idle slots) picks the tokens
+    of the XLA route, and each engine's programs are counted under their
+    own path, on the engine's registry too."""
+    cfg, model, _ = toy
+    prompts = _prompts(cfg, (37, 20, 9), seed=31)
+    xla0, kernel0 = _chunk_programs("xla"), _chunk_programs("kernel")
+    want = _drain(_serve(model), prompts, 6)
+    assert _chunk_programs("xla") > xla0
+    assert _chunk_programs("kernel") == kernel0
+    xla1 = _chunk_programs("xla")
+    request.getfixturevalue("pallas_forced")
+    door = _serve(model)
+    got = _drain(door, prompts, 6)
+    for a, b in zip(got, want):
+        assert np.array_equal(a, b)
+    assert _chunk_programs("kernel") > kernel0
+    assert _chunk_programs("xla") == xla1
+    assert door.engine.obs.registry.get(
+        "serving_chunk_attention_programs_total").value(
+            path="kernel") == _chunk_programs("kernel")
+
+
+@pytest.mark.parametrize("path", ["xla", "kernel"])
+def test_a_traced_mixed_program_counts_its_chunk_attention_path(
+        toy, path, request):
+    """Tracing one mixed program raises the counter by ONE on its path's
+    label, however many layers the model has (three here)."""
+    _, model, _ = toy
+    if path == "kernel":
+        request.getfixturevalue("pallas_forced")
+    before = {p: _chunk_programs(p) for p in ("xla", "kernel")}
+    step, args = _serve(model).engine.mixed_step_target()
+    step.lower(*args)
+    other = "xla" if path == "kernel" else "kernel"
+    assert _chunk_programs(path) == before[path] + 1
+    assert _chunk_programs(other) == before[other]
+
+
+def test_a_dense_models_mixed_program_counts_no_chunk_attention_path(
+        pallas_forced):
+    paddle.seed(0)
+    model = LlamaForCausalLM(LlamaConfig.tiny(tensor_parallel=False))
+    eng = ServingEngine(model, num_slots=2, block_size=8, max_context=32)
+    before = {p: _chunk_programs(p) for p in ("xla", "kernel")}
+    step, args = eng.mixed_step_target()
+    step.lower(*args)
+    assert before == {p: _chunk_programs(p) for p in ("xla", "kernel")}

@@ -23,7 +23,8 @@ SLOTS, BLOCK, TABLE_W = 8, 32, 64         # serving engine defaults @ 2048
 POOL_BLOCKS = SLOTS * TABLE_W + 1
 
 _KERNEL_MODULES = ("flash_attention", "rms_norm", "decode_attention",
-                   "paged_attention", "varlen_flash_attention")
+                   "paged_attention", "varlen_flash_attention",
+                   "chunk_attention")
 
 
 @pytest.fixture(scope="module")
@@ -189,3 +190,73 @@ def test_varlen_flash_attention_prefill(chip):
         chip, fn, ((320, H, D), jnp.bfloat16), ((960, H, D), jnp.bfloat16),
         ((960, H, D), jnp.bfloat16), ((6,), jnp.int32), ((6,), jnp.int32))
     assert names == {"varlen_flash_attention_fwd"}
+
+
+@pytest.mark.parametrize("route", ["kernel", "xla"])
+def test_latent_paged_chunk_leaves_no_score_in_memory(chip, route):
+    """The latent cell's ``paged_chunk`` (kanana-2-30b-a3b widths: 32
+    heads of 128 + 64 / 128 over a 512-wide latent; 32 slots x 512
+    tokens, table 136 x 32, bf16) for the described v5e. Through the
+    kernel the compiled layer holds the kernel by name (so it fits
+    VMEM) and no float32 array as large as ONE tile of the XLA loop's
+    (S, H, C, 128 keys) scores, which is also its (S, H, C, Dv) carry;
+    the same reading of the XLA route finds them, so the check can
+    fail."""
+    import paddle_tpu as paddle
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.jit import functional_call
+    from paddle_tpu.nlp.deepseek_v3 import (
+        DeepseekV3Attention, DeepseekV3Config)
+    from paddle_tpu.ops.pallas._utils import compiled_kernel_names
+
+    slots, chunk, width, blocks = 32, 512, 136, 5120
+    paddle.set_default_dtype("bfloat16")
+    try:
+        cfg = DeepseekV3Config.kanana_2_30b_a3b(dtype="bfloat16")
+        attn = DeepseekV3Attention(cfg)
+    finally:
+        paddle.set_default_dtype("float32")
+    p_vals = [p._value for _, p in attn.named_parameters()]
+
+    def layer(p_vals, x, cos, sin, tables, base_lens, blk, off, pool):
+        def fwd(x_t):
+            att, new = attn.paged_chunk(x_t, (cos, sin), tables, base_lens,
+                                        blk, off, (pool, None, None, None))
+            return att._value, new[0]
+
+        return functional_call(attn, fwd, [Tensor(x, stop_gradient=True)],
+                               {}, p_vals, [])[0]
+
+    def shape(s, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(s), dt, sharding=chip)
+
+    half = cfg.qk_rope_head_dim // 2
+    args = ([shape(v.shape, v.dtype) for v in p_vals],
+            shape((slots, chunk, cfg.hidden_size)),
+            shape((slots, chunk, half), jnp.float32),
+            shape((slots, chunk, half), jnp.float32),
+            shape((slots, width), jnp.int32), shape((slots,), jnp.int32),
+            shape((slots, chunk), jnp.int32),
+            shape((slots, chunk), jnp.int32),
+            shape((blocks, BLOCK, 1, cfg.latent_dim)))
+    paddle.set_flags({"FLAGS_pallas_force": route == "kernel"})
+    try:
+        compiled = jax.jit(layer, donate_argnums=(8,)).lower(
+            *args).compile()
+    finally:
+        paddle.set_flags({"FLAGS_pallas_force": False})
+    text = compiled.as_text()
+    tile = slots * cfg.num_attention_heads * chunk * 128
+    # (slot, head, query, key | value lane) in whatever order; the
+    # projections' own float32 results have no head axis
+    big = [m.group(0)
+           for m in re.finditer(r"f32\[(\d+,\d+,\d+,\d+)\]", text)
+           if math.prod(map(int, m.group(1).split(","))) >= tile]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if route == "kernel":
+        assert "chunk_attention" in compiled_kernel_names(text)
+        assert not big, sorted(set(big))
+        assert temp < 3 * tile * 4, temp
+    else:
+        assert "chunk_attention" not in compiled_kernel_names(text)
+        assert big and temp > 3 * tile * 4, temp
