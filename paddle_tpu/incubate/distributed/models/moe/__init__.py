@@ -9,8 +9,8 @@ masks, so GSPMD lowers the dispatch to the same all-to-all the reference
 issues explicitly via GlobalScatter — no hand-written collectives.
 """
 from .gate import (  # noqa: F401
-    TopKGate, GShardGate, SwitchGate, SigmoidTopKGate)
+    TopKGate, GShardGate, SwitchGate, SigmoidTopKGate, SoftmaxTopKGate)
 from .moe_layer import MoELayer  # noqa: F401
 
 __all__ = ["MoELayer", "TopKGate", "GShardGate", "SwitchGate",
-           "SigmoidTopKGate"]
+           "SigmoidTopKGate", "SoftmaxTopKGate"]

@@ -11,7 +11,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-__all__ = ["TopKGate", "GShardGate", "SwitchGate", "SigmoidTopKGate"]
+__all__ = ["TopKGate", "GShardGate", "SwitchGate", "SigmoidTopKGate",
+           "SoftmaxTopKGate"]
 
 
 def _capacity(num_tokens, num_experts, capacity_factor, top_k):
@@ -147,3 +148,22 @@ class SigmoidTopKGate:
         if self.norm_topk_prob:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
         return topi, w * self.routed_scaling_factor, None
+
+
+class SoftmaxTopKGate:
+    """The Granite / Mixtral-style router's decision: the ``top_k``
+    largest LOGITS are chosen and the weights are the softmax over those
+    chosen values alone (not over all experts). No capacity, no bias, no
+    auxiliary loss: every token keeps all ``top_k`` experts."""
+
+    def __init__(self, top_k):
+        self.top_k = int(top_k)
+
+    def topk_assignments(self, logits, bias=None):
+        """logits (T, E) -> (expert_ids (T, k), weights (T, k) float32,
+        None)."""
+        if bias is not None:
+            raise NotImplementedError(
+                "SoftmaxTopKGate takes no selection bias")
+        topv, topi = jax.lax.top_k(logits.astype(jnp.float32), self.top_k)
+        return topi, jax.nn.softmax(topv, axis=-1), None

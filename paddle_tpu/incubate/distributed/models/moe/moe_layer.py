@@ -21,8 +21,15 @@ from .gate import TopKGate, SwitchGate
 __all__ = ["MoELayer", "grouped_expert_ffn"]
 
 
+# the sorted-rows buffer of `grouped_expert_ffn` (T*k rows of the model
+# width) may take this many bytes; more tokens than that go through in
+# equal tiles of positions, one after another (a shape rule: no knob, and
+# no row is ever capped)
+_SORTED_ROWS_BYTES = 512 << 20
+
+
 def grouped_expert_ffn(xt, expert_ids, gate_vals, w1, w2, act, b1=None,
-                       b2=None):
+                       b2=None, held=None):
     """The routed experts' FFN as two grouped matrix products
     (megablocks-style): the T*k routed rows are sorted by expert and fed
     to ``jax.lax.ragged_dot`` with the per-expert group sizes, so the
@@ -36,10 +43,36 @@ def grouped_expert_ffn(xt, expert_ids, gate_vals, w1, w2, act, b1=None,
     combined output (T, M) in ``xt``'s dtype and the rows each expert
     got, (E,) int32. The k expert outputs of a token are gathered back
     and summed in float32 in the order of ``expert_ids`` (a gather, not
-    a scatter-add: TPUs serialise scatters)."""
+    a scatter-add: TPUs serialise scatters).
+
+    ``held=(lo, n)``: this chip HOLDS experts ``lo .. lo + n - 1`` of
+    the ones the ids range over (expert parallelism: the router keeps
+    its published width); ``w1`` / ``w2`` are those ``n`` slabs. A choice
+    that fell on an absent expert sorts past every group, takes part in
+    no product and adds exactly zero; the returned rows are the held
+    experts', (n,). The sorted-rows buffer is sized for the worst case
+    (every choice held). Default: every expert is held."""
     t, k = expert_ids.shape
+    n_tiles = -(-(t * k * xt.shape[-1] * xt.dtype.itemsize)
+                // _SORTED_ROWS_BYTES)
+    while t % n_tiles:      # whole tiles of positions
+        n_tiles += 1
+    if n_tiles > 1:
+        ys, rows = jax.lax.map(
+            lambda a: grouped_expert_ffn(*a, w1, w2, act, b1, b2, held),
+            tuple(a.reshape(n_tiles, t // n_tiles, a.shape[-1])
+                  for a in (xt, expert_ids, gate_vals)))
+        return ys.reshape(t, -1), jnp.sum(rows, axis=0)
     e = w1.shape[0]
     expert_flat = expert_ids.reshape(-1)                  # (T*k,)
+    if held is not None:
+        # absent experts share the id ``e``: last in the sort, in no group
+        lo, n = held
+        if n != e:
+            raise ValueError(f"held={held} but {e} weight slabs")
+        local = expert_flat - lo
+        present = (local >= 0) & (local < e)
+        expert_flat = jnp.where(present, local, e)
     order = jnp.argsort(expert_flat)                      # stable
     sorted_exp = expert_flat[order]
     group_sizes = jnp.bincount(expert_flat, length=e).astype(jnp.int32)
@@ -52,6 +85,18 @@ def grouped_expert_ffn(xt, expert_ids, gate_vals, w1, w2, act, b1=None,
     out = jax.lax.ragged_dot(h, w2.astype(xt.dtype), group_sizes)
     if b2 is not None:
         out = out + b2[sorted_exp].astype(xt.dtype)
+    if held is not None:
+        # rows past the last group belong to no product: exactly zero,
+        # whatever the backend leaves there. The k outputs of a token are
+        # gathered back one choice at a time into a float32 sum: no
+        # (T, k, M) float32 buffer beside the worst-case sorted rows
+        out = jnp.where((sorted_exp < e)[:, None], out, 0)
+        gate_vals = jnp.where(present.reshape(t, k), gate_vals, 0)
+        back = jnp.argsort(order).reshape(t, k)
+        y = sum(out[back[:, j]].astype(jnp.float32)
+                * gate_vals.astype(jnp.float32)[:, j, None]
+                for j in range(k))
+        return y.astype(xt.dtype), group_sizes
     back = out[jnp.argsort(order)].reshape(t, k, -1)      # token-major
     y = jnp.sum(back.astype(jnp.float32)
                 * gate_vals.astype(jnp.float32)[..., None], axis=1)

@@ -18,6 +18,11 @@ from .deepseek_v3 import (  # noqa: F401
     DeepseekV3Config, DeepseekV3Attention, DeepseekV3MLP, DeepseekV3MoE,
     DeepseekV3DecoderLayer, DeepseekV3Model, DeepseekV3ForCausalLM,
 )
+from .granitemoehybrid import (  # noqa: F401
+    GraniteMoeHybridConfig, GraniteMoeHybridMamba, GraniteMoeHybridAttention,
+    GraniteMoeHybridMoE, GraniteMoeHybridDecoderLayer, GraniteMoeHybridModel,
+    GraniteMoeHybridForCausalLM,
+)
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForPretraining,

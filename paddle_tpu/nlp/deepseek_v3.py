@@ -367,7 +367,7 @@ class DeepseekV3Attention(Layer):
             return self._project_out(out.astype(x._value.dtype),
                                      x.shape[:2])
 
-    # -- the serving engine's attention protocol ----------------------------
+    # -- the attention half of the serving engine's layer protocol ----------
     def paged_decode(self, x, rope, tables, lens, write_blk, write_off,
                      cache):
         """One token a slot over the latent pool, absorbed: the step's
@@ -503,7 +503,7 @@ class DeepseekV3MoE(Layer):
             return routed + self.shared_experts(x)
 
 
-class DeepseekV3DecoderLayer(Layer):
+class DeepseekV3DecoderLayer(PA.PagedResidualLayer, Layer):
     def __init__(self, config: DeepseekV3Config, layer_idx):
         super().__init__()
         self.input_layernorm = RMSNorm(config.hidden_size,
@@ -539,6 +539,9 @@ class DeepseekV3Model(Layer):
             hidden = layer(hidden)
         return self.norm(hidden)
 
+    def paged_rope(self, positions):
+        return self.layers[0].self_attn.paged_rope(positions)
+
 
 class DeepseekV3ForCausalLM(Layer):
     def __init__(self, config: DeepseekV3Config):
@@ -566,4 +569,5 @@ class DeepseekV3ForCausalLM(Layer):
         """One array a layer, one row a token: ``[c | rope(k_rope)]``,
         shared by every head."""
         return {"layout": "latent", "num_kv_heads": 1,
-                "head_dim": self.config.latent_dim}
+                "head_dim": self.config.latent_dim,
+                "layers": ("latent",) * self.config.num_hidden_layers}

@@ -28,6 +28,7 @@ from ..nn import functional as F
 from ..nn.functional.rope import build_rope_cache, apply_rotary_emb
 from ..tensor._helpers import apply, ensure_tensor
 from ..parallel import mesh as mesh_state
+from .paged_attention import PagedResidualLayer
 
 __all__ = [
     "LlamaConfig", "LlamaAttention", "LlamaMLP", "LlamaDecoderLayer",
@@ -305,9 +306,20 @@ class LlamaAttention(Layer):
         out = out.reshape([b, s, self.num_heads * self.head_dim])
         return self.o_proj(out), cache
 
-    # -- the serving engine's attention protocol --------------------------
+    # -- the attention half of the serving engine's layer protocol --------
     # (serving/engine.py: paged_decode_math / paged_chunk_math keep the
-    # layer loop and ask the layer's attention module for the rest)
+    # layer loop and ask each LAYER for the rest; a decoder layer with
+    # K/V attention hands its normed input on to these)
+    # what multiplies the scores over the pool; None is 1 / sqrt(D)
+    softmax_scale = None
+
+    def _rotate(self, x, rope):
+        """Rotary embedding of (..., H, D) rows at their own positions
+        (a model without positions overrides this with the identity)."""
+        from .paged_attention import _rope_rows
+
+        return _rope_rows(x, *rope)
+
     def paged_rope(self, positions):
         """What the rotary embedding needs at ``positions`` (float32, any
         shape): ``(cos, sin)`` with a trailing D/2. The engine's bodies
@@ -329,20 +341,20 @@ class LlamaAttention(Layer):
         ``lens`` live positions of each row's ``tables`` and returns the
         attention output (S, 1, E) and the layer's new pool arrays."""
         from ..core.tensor import Tensor
-        from .paged_attention import _paged_attn, _rope_rows
+        from .paged_attention import _paged_attn
 
         s = x.shape[0]
         h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
-        cos, sin = rope
         q = self.q_proj(x).reshape([s, 1, h, d])
         k = self.k_proj(x).reshape([s, 1, hk, d])
         v = self.v_proj(x).reshape([s, 1, hk, d])
-        qv = _rope_rows(q._value[:, 0], cos, sin)    # (S, H, D)
-        kv = _rope_rows(k._value[:, 0], cos, sin)
+        qv = self._rotate(q._value[:, 0], rope)      # (S, H, D)
+        kv = self._rotate(k._value[:, 0], rope)
         vv = v._value[:, 0]
         kci, vci, ksi, vsi = new = _paged_write(kv, vv, write_blk,
                                                 write_off, cache)
-        att = _paged_attn(qv, kci, vci, tables, lens, ks=ksi, vs=vsi)
+        att = _paged_attn(qv, kci, vci, tables, lens, ks=ksi, vs=vsi,
+                          scale=self.softmax_scale)
         att_t = Tensor(att.reshape(s, 1, h * d), stop_gradient=True)
         return self.o_proj(att_t), new
 
@@ -354,21 +366,20 @@ class LlamaAttention(Layer):
         ``base_lens`` cached positions and the chunk's own up to j.
         Same contract as :meth:`paged_decode` with a chunk axis."""
         from ..core.tensor import Tensor
-        from .paged_attention import _paged_chunk_attn, _rope_rows
+        from .paged_attention import _paged_chunk_attn
 
         s, c = x.shape[0], x.shape[1]
         h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
-        cos, sin = rope
         q = self.q_proj(x).reshape([s, c, h, d])
         k = self.k_proj(x).reshape([s, c, hk, d])
         v = self.v_proj(x).reshape([s, c, hk, d])
-        qv = _rope_rows(q._value, cos, sin)          # (S, C, H, D)
-        kv = _rope_rows(k._value, cos, sin)
+        qv = self._rotate(q._value, rope)            # (S, C, H, D)
+        kv = self._rotate(k._value, rope)
         vv = v._value
         kci, vci, ksi, vsi = new = _paged_write(kv, vv, write_blk,
                                                 write_off, cache)
         att = _paged_chunk_attn(qv, kci, vci, tables, base_lens,
-                                ks=ksi, vs=vsi)
+                                ks=ksi, vs=vsi, scale=self.softmax_scale)
         att_t = Tensor(att.reshape(s, c, h * d), stop_gradient=True)
         return self.o_proj(att_t), new
 
@@ -504,7 +515,7 @@ class LlamaMLP(Layer):
         return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
 
 
-class LlamaDecoderLayer(Layer):
+class LlamaDecoderLayer(PagedResidualLayer, Layer):
     def __init__(self, config: LlamaConfig):
         super().__init__()
         self.config = config
@@ -625,6 +636,11 @@ class LlamaModel(Layer):
                 new_caches.append(cache_i)
         return self.norm(hidden), new_caches
 
+    def paged_rope(self, positions):
+        """What every layer's rotary embedding needs at ``positions``
+        (the serving engine's bodies ask once a step)."""
+        return self.layers[0].self_attn.paged_rope(positions)
+
 
 class LlamaForCausalLM(Layer):
     def __init__(self, config: LlamaConfig):
@@ -661,16 +677,18 @@ class LlamaForCausalLM(Layer):
     @property
     def decoder(self):
         """The stack the engine's bodies loop over: ``embed_tokens``,
-        ``layers`` (each with ``input_layernorm``, ``self_attn``,
-        ``post_attention_layernorm``, ``mlp``) and ``norm``."""
+        ``paged_rope``, ``layers`` (each with ``paged_decode`` /
+        ``paged_chunk``) and ``norm``."""
         return self.llama
 
     def paged_cache_layout(self):
         """The pool geometry this model's attention caches: a K and a V
-        array a layer, each row ``num_key_value_heads x head_dim``."""
+        array a layer, each row ``num_key_value_heads x head_dim``;
+        ``layers`` says it of every layer (``"kv"``: block arrays)."""
         cfg = self.config
         return {"layout": "kv", "num_kv_heads": cfg.num_key_value_heads,
-                "head_dim": cfg.head_dim}
+                "head_dim": cfg.head_dim,
+                "layers": ("kv",) * cfg.num_hidden_layers}
 
     def generate(self, input_ids, max_new_tokens=32,
                  decode_strategy="greedy_search", **kwargs):

@@ -31,13 +31,15 @@ def _rope_rows(x, cos, sin):
     return out.astype(x.dtype)
 
 
-def _xla_paged_decode_attn(q, kp, vp, tables, lens, ks=None, vs=None):
+def _xla_paged_decode_attn(q, kp, vp, tables, lens, ks=None, vs=None,
+                           scale=None):
     """Off-TPU decode attention over the paged pool: gather the table's
     blocks and run the same f32 masked softmax as the contiguous-cache
     fallback (`_masked_decode_attn`). ``ks``/``vs`` are the optional
     per-row scale pools of an int8 pool ((NB, BS, HK) f32): the gathered
     rows dequantize in f32 before the softmax, so the math matches the
-    float path up to the quantization rounding itself."""
+    float path up to the quantization rounding itself. ``scale``
+    multiplies the scores (default ``1 / sqrt(D)``)."""
     s_, h, d = q.shape
     w = tables.shape[1]
     bs, hk = kp.shape[1], kp.shape[2]
@@ -51,7 +53,7 @@ def _xla_paged_decode_attn(q, kp, vp, tables, lens, ks=None, vs=None):
     rep = h // hk
     kr = jnp.repeat(k, rep, axis=2) if rep > 1 else k
     vr = jnp.repeat(v, rep, axis=2) if rep > 1 else v
-    sc = 1.0 / math.sqrt(d)
+    sc = 1.0 / math.sqrt(d) if scale is None else scale
     logits = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32),
                         kr.astype(jnp.float32)) * sc
     mask = jnp.arange(w * bs)[None, :] < lens[:, None]
@@ -67,7 +69,8 @@ def _xla_paged_decode_attn(q, kp, vp, tables, lens, ks=None, vs=None):
 _CHUNK_SCORE_BYTES = 256 << 20
 
 
-def _paged_chunk_attn(q, kp, vp, tables, base_lens, ks=None, vs=None):
+def _paged_chunk_attn(q, kp, vp, tables, base_lens, ks=None, vs=None,
+                      scale=None):
     """Chunk attention over the paged pool, shared by the speculative
     VERIFY pass and the mixed prefill step: query position j of each
     slot attends pool positions < base+j+1 (the row's cached context
@@ -83,13 +86,14 @@ def _paged_chunk_attn(q, kp, vp, tables, base_lens, ks=None, vs=None):
     dimension); a table whose scores fit that size is one tile and no
     loop. ``ks``/``vs`` are the
     int8 pool's per-row scale pools: a tile dequantizes in f32 as it
-    streams through. No Pallas analog yet: this runs on every
+    streams through. ``scale`` multiplies the scores (default
+    ``1 / sqrt(D)``). No Pallas analog yet: this runs on every
     backend."""
     s_, c, h, d = q.shape
     w = tables.shape[1]
     bs, hk = kp.shape[1], kp.shape[2]
     g = h // hk
-    sc = 1.0 / math.sqrt(d)
+    sc = 1.0 / math.sqrt(d) if scale is None else scale
     tile = max(1, min(w, _CHUNK_SCORE_BYTES // (s_ * h * c * bs * 4)))
     n_tiles = -(-w // tile)
     # whole tiles: the padding columns point at pool block 0 and lie
@@ -142,6 +146,32 @@ def _paged_chunk_attn(q, kp, vp, tables, base_lens, ks=None, vs=None):
         q.dtype)
 
 
+class PagedResidualLayer:
+    """The serving engine's LAYER protocol (``serving/engine.py``) for a
+    pre-norm residual decoder layer with ``input_layernorm``,
+    ``self_attn``, ``post_attention_layernorm`` and ``mlp`` (a mix-in):
+    ``x += Attn(norm(x)); x += MLP(norm(x))``, the attention asked of
+    ``self_attn.paged_decode`` / ``paged_chunk``. ``step`` is the step's
+    addressing (``rope``, ``tables``, ``lens``, ``write_blk``,
+    ``write_off``, ...), ``cache`` this layer's pool arrays ``(k, v,
+    k_scale, v_scale)``, a side the pool lacks None. Each returns the new
+    hidden stream and the layer's new pool arrays."""
+
+    def _paged(self, attend, hidden, step, cache):
+        att, new = attend(
+            self.input_layernorm(hidden), step["rope"], step["tables"],
+            step["lens"], step["write_blk"], step["write_off"], cache)
+        hidden = hidden + att
+        hidden = hidden + self.mlp(self.post_attention_layernorm(hidden))
+        return hidden, new
+
+    def paged_decode(self, hidden, step, cache):
+        return self._paged(self.self_attn.paged_decode, hidden, step, cache)
+
+    def paged_chunk(self, hidden, step, cache):
+        return self._paged(self.self_attn.paged_chunk, hidden, step, cache)
+
+
 def use_pallas_kernels():
     """The rule every attention route over the pool follows: the Pallas
     kernels where the backend is a TPU (or ``FLAGS_pallas_force`` sends
@@ -154,7 +184,7 @@ def use_pallas_kernels():
         jax.default_backend() == "tpu" or flags["FLAGS_pallas_force"])
 
 
-def _paged_attn(q, kp, vp, tables, lens, ks=None, vs=None):
+def _paged_attn(q, kp, vp, tables, lens, ks=None, vs=None, scale=None):
     """Route decode attention: Pallas paged kernel on TPU (it takes the
     pool arrays as they are stored — no relayout on the way in — and
     DMAs only the live blocks of each row's table, every kv head of a
@@ -166,8 +196,10 @@ def _paged_attn(q, kp, vp, tables, lens, ks=None, vs=None):
     if ks is None and use_pallas_kernels():
         from ..ops.pallas.paged_attention import paged_decode_attention
 
-        return paged_decode_attention(q, kp, vp, tables, lens)
-    return _xla_paged_decode_attn(q, kp, vp, tables, lens, ks=ks, vs=vs)
+        return paged_decode_attention(q, kp, vp, tables, lens,
+                                      sm_scale=scale)
+    return _xla_paged_decode_attn(q, kp, vp, tables, lens, ks=ks, vs=vs,
+                                  scale=scale)
 
 
 _chunk_programs_counted = None

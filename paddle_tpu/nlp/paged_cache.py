@@ -116,6 +116,18 @@ class PagedKVCachePool:
             ``commit_like``, copy-on-write, the accounting and the
             donation audit hold for both. A model says which it caches
             (``model.paged_cache_layout()``).
+        state: the SLOT side, for a model with recurrent (state-space)
+            layers: ``{"slots": S, "layers": n, "arrays": [(shape,
+            dtype), ...]}`` gives ``n`` state layers, each a tuple of
+            arrays ``(S, *shape)`` whose row ``s`` is what the request in
+            slot ``s`` carries from token to token, whatever its context
+            (``dtype`` None: the pool's ``dtype``). ``num_layers`` then
+            counts only the layers that hold block arrays. The side is
+            EMPTY for a model without such layers (zero avals, as the V
+            side of a latent pool), and is donated, adopted, committed
+            and accounted with the blocks. Rows are never reset from the
+            host: the program starts a row whose base length is 0 from
+            zeros.
         dtype: cache dtype (bf16 for serving).
         kv_dtype: ``"int8"`` switches the block buffers to int8 and
             grows per-layer SCALE POOLS ``k_scales``/``v_scales`` of
@@ -140,7 +152,7 @@ class PagedKVCachePool:
 
     def __init__(self, num_blocks, block_size, num_kv_heads, head_dim,
                  num_layers=1, dtype=jnp.bfloat16, prefix_cache=False,
-                 mesh=None, kv_dtype=None, layout="kv"):
+                 mesh=None, kv_dtype=None, layout="kv", state=None):
         if layout not in ("kv", "latent"):
             raise ValueError(
                 f"unsupported pool layout {layout!r} (kv or latent)")
@@ -154,6 +166,12 @@ class PagedKVCachePool:
                 "a latent pool under a mesh is not supported: its one "
                 "row a token is shared by every head, so there is no "
                 "head axis to shard")
+        if state and (kv_dtype is not None or mesh is not None
+                      or prefix_cache):
+            raise NotImplementedError(
+                "a pool with slot state does not compose with "
+                "kv_dtype='int8', a mesh or the prefix cache: a cached "
+                "block says nothing of the state its prefix left")
         self.layout = layout
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
@@ -209,6 +227,11 @@ class PagedKVCachePool:
         else:
             self.k_scales = []
             self.v_scales = []
+        # the slot side: per state layer a tuple of (slots, ...) arrays
+        self.state = tuple(
+            tuple(jnp.zeros((int(state["slots"]), *shape), dt or dtype)
+                  for shape, dt in state["arrays"])
+            for _ in range(int(state["layers"]))) if state else ()
         self._free = list(range(self.num_blocks - 1, -1, -1))
         self._tables: dict = {}   # seq_id -> list[int] block ids
         self._lens: dict = {}     # seq_id -> int tokens
@@ -290,14 +313,18 @@ class PagedKVCachePool:
         range ``[written_tokens, need_tokens)`` when ``cow`` (prefix-
         cache engines must never write into a block another holder
         still maps), and return the padded host int32 table row the
-        quantum dispatch feeds the device."""
+        quantum dispatch feeds the device. The row is written on the
+        host: a device array built and read back was one round trip a
+        slot and quantum."""
         if need_tokens > self.seq_len(seq_id):
             self.ensure(seq_id, need_tokens)
         if cow:
             self.make_writable(seq_id, int(written_tokens),
                                int(need_tokens))
-        return np.asarray(self.block_table_array(
-            [seq_id], pad_to=pad_to))[0]
+        table = self._tables.get(seq_id, [])
+        row = np.zeros(max(len(table), pad_to or 1), np.int32)
+        row[:len(table)] = table
+        return row
 
     def share(self, src_seq_id, dst_seq_id):
         """Alias ``src``'s blocks into a new table for ``dst`` with the
@@ -796,6 +823,8 @@ class PagedKVCachePool:
             "cached_blocks": len(self._cached_blocks),
             "kv_dtype": str(self.k_pools[0].dtype),
             "bytes_per_token": self.bytes_per_token(),
+            "state_bytes_per_slot": self.state_bytes_per_slot(),
+            "state_slots": self.state_slots,
             "bytes_in_use": self.bytes_in_use(),
             "per_chip_bytes_in_use": self.per_chip_bytes_in_use(),
         }
@@ -833,7 +862,27 @@ class PagedKVCachePool:
         bf16 pool's bytes) plus the scale-pool rows that travel with
         each quantized block."""
         return (self.bytes_per_token() * self.block_size
-                * self.blocks_in_use)
+                * self.blocks_in_use
+                + self.state_bytes_per_slot() * self._state_rows_in_use())
+
+    @property
+    def state_slots(self):
+        """Rows of the slot side (0 without state layers)."""
+        return self.state[0][0].shape[0] if self.state else 0
+
+    def state_bytes_per_slot(self):
+        """Bytes of slot state one request holds over all state layers,
+        whatever its context (0 without state layers)."""
+        return sum(a.dtype.itemsize * int(np.prod(a.shape[1:]))
+                   for layer in self.state for a in layer)
+
+    def _state_rows_in_use(self):
+        """Sequences that hold a slot's state: every one that holds
+        blocks but the engine's own (ids that begin with ``__``, the
+        scratch sequence)."""
+        if not self.state:
+            return 0
+        return sum(1 for s in self._tables if not str(s).startswith("__"))
 
     @property
     def arrays_per_layer(self):
@@ -858,12 +907,21 @@ class PagedKVCachePool:
         divide for the pool to shard at all)."""
         return self.bytes_in_use() // self.tp_shards
 
-    def adopt(self, k_pools, v_pools, k_scales=(), v_scales=()):
+    def adopt(self, k_pools, v_pools, k_scales=(), v_scales=(), state=()):
         """Take a jitted step's donated-and-returned buffers as the
         pool's new truth (async handles: no sync). The scale pools of a
-        float pool are empty and stay so."""
+        float pool and the slot side of a pool without state layers are
+        empty and stay so."""
         self.k_pools, self.v_pools = list(k_pools), list(v_pools)
         self.k_scales, self.v_scales = list(k_scales), list(v_scales)
+        self.state = tuple(tuple(layer) for layer in state)
+
+    def arrays(self):
+        """The pool's sides as a jitted step takes (and donates) them:
+        ``(k_pools, v_pools, k_scales, v_scales, state)``; a side the
+        pool lacks is an empty pytree."""
+        return (list(self.k_pools), list(self.v_pools),
+                tuple(self.k_scales), tuple(self.v_scales), self.state)
 
     def commit_like(self, ref):
         """Give the buffers the commitment of ``ref``, a weight of the
@@ -875,9 +933,8 @@ class PagedKVCachePool:
         Under a mesh the buffers are committed to their layout from the
         start."""
         if self.mesh is None and ref.committed:
-            self.adopt(*([jax.device_put(a, ref.sharding) for a in leaves]
-                         for leaves in (self.k_pools, self.v_pools,
-                                        self.k_scales, self.v_scales)))
+            self.adopt(*jax.tree_util.tree_map(
+                lambda a: jax.device_put(a, ref.sharding), self.arrays()))
 
     # -- device views ------------------------------------------------------
     def block_table_array(self, seq_ids, pad_to=None):

@@ -180,7 +180,17 @@ class ServingObs:
                 ("routed_rows", "rows the decode steps handed to experts"),
                 ("experts_touched", "experts that got at least one row"),
                 ("expert_rows_max", "the fullest expert's rows"),
-                ("layer_steps", "(expert layer, decode step) pairs"))}
+                ("layer_steps", "(expert layer, decode step) pairs"),
+                ("offshare_rows", "choices that fell on experts this chip "
+                                  "does not hold"))}
+        self._c_state_resets = r.counter(
+            "serving_state_resets_total",
+            "rows that began at position 0: their slot state started "
+            "from zeros inside the mixed step")
+        self._g_state_slot_bytes = r.share(MetricsRegistry.process().gauge(
+            "serving_state_bytes_per_slot",
+            "bytes of recurrent state one slot holds over all state "
+            "layers, whatever its context"))
         # counted where a mixed program is traced (the route of the
         # latent chunk attention is static per program); shown here too
         from ..nlp.paged_attention import chunk_attention_programs
@@ -587,6 +597,13 @@ class ServingObs:
         """Published once at engine build (the pool's geometry)."""
         self._g_pool_token_bytes.set(float(nbytes), pool=pool)
 
+    def set_state_bytes_per_slot(self, nbytes, pool="target"):
+        """Published once at engine build (0 without state layers)."""
+        self._g_state_slot_bytes.set(float(nbytes), pool=pool)
+
+    def on_state_reset(self):
+        self._c_state_resets.inc(1)
+
     def on_mixed_dispatch(self, bucket, padded_tokens, built):
         """One mixed step is about to dispatch the program of chunk
         length ``bucket``: count its padding beside
@@ -597,23 +614,27 @@ class ServingObs:
         if built:
             self._c_mixed_programs.inc(built, bucket=str(bucket))
 
-    def on_moe_rows(self, rows):
+    def on_moe_rows(self, rows, choices):
         """The decode steps just read back handed ``rows`` (an int array
-        ``(steps, expert layers, experts)``) to the experts. Returns what
-        the counters were raised by, which the step's span carries too
-        (``moe_rows``, ``moe_experts_touched``, ``moe_rows_max``,
-        ``moe_layer_steps``): a reader outside the program bounds a
-        window by its spans."""
+        ``(steps, expert layers, experts HELD here)``) to the experts, of
+        the ``choices`` every (layer, step) routed (slots x experts a
+        token); what is not among the rows fell on experts another chip
+        holds. Returns what the counters were raised by, which the
+        step's span carries too (``moe_rows``, ``moe_experts_touched``,
+        ``moe_rows_max``, ``moe_layer_steps``, ``moe_offshare_rows``): a
+        reader outside the program bounds a window by its spans."""
         by = {"routed_rows": int(rows.sum()),
               "experts_touched": int((rows > 0).sum()),
               "expert_rows_max": int(rows.max(axis=-1).sum()),
               "layer_steps": rows.shape[0] * rows.shape[1]}
+        by["offshare_rows"] = by["layer_steps"] * choices - by["routed_rows"]
         for key, n in by.items():
             self._c_moe[key].inc(n)
         return {"moe_rows": by["routed_rows"],
                 "moe_experts_touched": by["experts_touched"],
                 "moe_rows_max": by["expert_rows_max"],
-                "moe_layer_steps": by["layer_steps"]}
+                "moe_layer_steps": by["layer_steps"],
+                "moe_offshare_rows": by["offshare_rows"]}
 
     def on_quantum(self, kind, t0, t1, tokens, rows, breakdown=None,
                    device_s=None):

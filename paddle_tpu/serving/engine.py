@@ -29,19 +29,42 @@ shape of that loop:
   list for immediate reuse; admission is gated on worst-case demand so
   the pool cannot exhaust mid-flight (scheduler.py).
 
-WHAT IS ASKED OF A MODEL. The two jitted bodies (``paged_decode_math``,
-``paged_chunk_math``) keep the layer loop, the embedding, the positions,
-the write addresses, the norms, the head and ``layer.mlp``; everything
-else belongs to the model: ``model.decoder`` (``embed_tokens``,
-``layers``, ``norm``), ``model.paged_cache_layout()`` (what a layer
-caches: K and V rows, or ONE latent row a token) and each layer's
-``self_attn.paged_rope`` / ``paged_decode`` / ``paged_chunk`` (the
-attention protocol: given the normed input, positions, tables, lengths,
-write block/offset and that layer's pool arrays, the attention output and
-the new pool arrays). ``nlp/llama.py`` has the K/V form,
-``nlp/deepseek_v3.py`` latent attention's two forms and routed experts in
-``layer.mlp``; both programs hand back the rows the experts got beside the
-tokens (``moe_rows``; an empty tuple, no aval, without experts).
+WHAT IS ASKED OF A MODEL (the layer protocol). The two jitted bodies
+(``paged_decode_math``, ``paged_chunk_math``) keep the layer loop, the
+embedding call, the positions, the write addresses, the final norm and the
+head call, and know nothing of what a layer computes. Of the MODEL they ask
+
+- ``model.decoder``: ``embed_tokens`` (a call: ids -> the hidden stream,
+  any multiplier inside), ``paged_rope(positions)`` (what the layers'
+  rotary embedding needs, once a step; None for a model without
+  positions), ``layers`` and ``norm``; ``model.lm_head`` (a call);
+- ``model.paged_cache_layout()``: the block pool's geometry (``layout``
+  ``"kv"``: K and V rows; ``"latent"``: ONE row a token) and, per layer,
+  WHAT IT CACHES (``layers``: ``"kv"`` | ``"latent"``: block arrays, in
+  the order of such layers; ``"state"``: a row of the pool's SLOT side,
+  whose per-slot arrays ``state`` lists as ``(shape, dtype)``). A model
+  whose layers all cache blocks has an empty slot side: no aval.
+
+Of each LAYER they ask ``paged_decode(hidden, step, cache)`` and
+``paged_chunk(hidden, step, cache)``: given the hidden stream, the step's
+addressing and that layer's cache arrays, the new hidden stream and the
+layer's new cache arrays. ``step`` holds ``rope``, ``tables``, ``lens``
+(decode: each live row's length with this token; chunk: each row's base
+length), ``write_blk`` / ``write_off``, ``live`` and, in a chunk, ``valid``
+(S, C): the positions that bring a token. ``cache`` is ``(k, v, k_scale,
+v_scale)`` for a block layer (a side the pool lacks None) and the tuple of
+``(num_slots, ...)`` arrays for a state layer, row ``s`` slot ``s``'s. A
+state layer leaves a row's state where the row's last valid position put
+it, starts a row whose base length is 0 from zeros (no host-side reset),
+and keeps the state of a row that is not live; preemption frees the slot
+and recompute-on-resume rebuilds the state from ``prompt + tokens``.
+``nlp/paged_attention.PagedResidualLayer`` is the pre-norm residual
+layer over K/V or latent attention (``nlp/llama.py``,
+``nlp/deepseek_v3.py``: ``self_attn.paged_decode`` / ``paged_chunk``);
+``nlp/granitemoehybrid.py`` has state-space layers beside attention. A
+feed-forward that routes rows to experts shows ``rows_per_expert``; both
+programs hand back the rows the experts HELD here got beside the tokens
+(``moe_rows``; an empty tuple, no aval, without experts).
 
 Token selection reuses the generation tier's ``_filter_logits``
 (greedy argmax or temperature/top-k/top-p sampling with per-slot key
@@ -237,23 +260,44 @@ def _tp_shard_params(model):
     return n_sharded
 
 
-def _layer_cache(pools, i):
-    """Layer ``i``'s pool arrays ``(k, v, k_scale, v_scale)``; a side
-    the pool does not have (the scales of a float pool, the V side of a
-    latent pool) is an empty tuple and reads None."""
-    return tuple(p[i] if len(p) else None for p in pools)
+def _layer_caches(model, pools, state):
+    """Per layer of ``model``, the cache arrays the step hands it, by
+    what ``model.paged_cache_layout()["layers"]`` says it caches: a layer
+    with block arrays gets ``(k, v, k_scale, v_scale)`` of its place among
+    such layers (a side the pool lacks, the scales of a float pool or the
+    V side of a latent pool, is an empty tuple and reads None); a
+    ``"state"`` layer gets its tuple of per-slot arrays."""
+    out, n_block, n_state = [], 0, 0
+    for kind in model.paged_cache_layout()["layers"]:
+        if kind == "state":
+            out.append(state[n_state])
+            n_state += 1
+        else:
+            out.append(tuple(p[n_block] if len(p) else None
+                             for p in pools))
+            n_block += 1
+    return out
 
 
-def _collect_cache(new, out):
-    """Append a layer's new pool arrays to the per-side lists (a None
-    side stays empty)."""
-    for side, arr in zip(out, new):
-        if arr is not None:
-            side.append(arr)
+def _collect_caches(model, new):
+    """The layers' new cache arrays back in the step's order: the four
+    block sides (a None side stays empty) and the state side."""
+    sides, state = ([], [], [], []), []
+    for kind, arrays in zip(model.paged_cache_layout()["layers"], new):
+        if kind == "state":
+            state.append(tuple(arrays))
+            continue
+        for side, arr in zip(sides, arrays):
+            if arr is not None:
+                side.append(arr)
+    return (sides[0], sides[1], tuple(sides[2]), tuple(sides[3]),
+            tuple(state))
 
 
 def _expert_blocks(model):
-    """The feed-forward blocks of ``model`` that route rows to experts."""
+    """The feed-forward blocks of ``model`` that route rows to experts
+    (each with ``rows_per_expert`` over the ``num_experts`` it holds,
+    ``router.top_k`` and ``inactive_params_per_token()``)."""
     return [layer.mlp for layer in model.decoder.layers
             if hasattr(layer.mlp, "rows_per_expert")]
 
@@ -278,65 +322,70 @@ def _moe_rows_buffer(model, *lead):
 
 
 def paged_decode_math(model, scratch_block, ids_t, seq_lens, tables,
-                      kc, vc, live, ks=(), vs=()):
+                      kc, vc, live, ks=(), vs=(), st=()):
     """One token for every slot over a paged pool (the quantum's
     per-step body; mirrors generation._manual_decode with block-table
     writes instead of dense-cache slice updates). Parameterized by
     ``model`` so the plain quantum (target) and the speculative DRAFT
     scan (serving/speculative.py) share one decode-step definition.
 
-    The body keeps the layer loop, the embedding, the positions, the
-    write addresses, the norms, the head and ``layer.mlp``; the rest is
-    asked of each layer's attention module (``paged_rope`` once,
-    ``paged_decode`` a layer: ``nlp/llama.py`` has the K/V form,
-    ``nlp/deepseek_v3.py`` the latent one).
+    The body keeps the layer loop, the embedding call, the positions,
+    the write addresses, the final norm and the head call; the rest is
+    asked of each LAYER (``layer.paged_decode(hidden, step, cache)``:
+    the new hidden stream and the layer's new cache arrays; see the
+    module docstring). ``step`` is the step's addressing: ``rope``,
+    ``tables``, ``lens`` (each live row's length with this token),
+    ``write_blk`` / ``write_off`` and ``live``.
 
     ``ks``/``vs`` are the per-layer per-row scale pools of an int8
     pool (empty tuples on a float pool — zero extra avals, so the
-    unquantized quantum graph and its golden are byte-identical), and
-    ``vc`` is empty on a latent pool. Returns
-    ``(logits, new_kc, new_vc, new_ks, new_vs)``; a side that came in
-    empty goes out empty."""
+    unquantized quantum graph and its golden are byte-identical),
+    ``vc`` is empty on a latent pool and ``st``, the slot side (per
+    state layer a tuple of arrays whose row ``s`` is slot ``s``'s), for
+    a model without state layers. Returns
+    ``(logits, new_kc, new_vc, new_ks, new_vs, new_st)``; a side that
+    came in empty goes out empty."""
     core = model.decoder
     bs = kc[0].shape[1]
     w = tables.shape[1]
 
     hidden = core.embed_tokens(ids_t)                # (S, 1, E)
-    rope = core.layers[0].self_attn.paged_rope(
-        seq_lens.astype(jnp.float32))
+    rope = core.paged_rope(seq_lens.astype(jnp.float32))
 
     blk_idx = jnp.clip(seq_lens // bs, 0, w - 1)
     own_blk = jnp.take_along_axis(tables, blk_idx[:, None],
                                   axis=1)[:, 0]
-    write_blk = jnp.where(live, own_blk, scratch_block)
-    write_off = jnp.where(live, seq_lens % bs, 0)
-    lens = jnp.where(live, seq_lens + 1, 1)
+    step = {"rope": rope, "tables": tables, "live": live,
+            "write_blk": jnp.where(live, own_blk, scratch_block),
+            "write_off": jnp.where(live, seq_lens % bs, 0),
+            "lens": jnp.where(live, seq_lens + 1, 1)}
 
-    out = ([], [], [], [])
-    for i, layer in enumerate(core.layers):
-        att, new = layer.self_attn.paged_decode(
-            layer.input_layernorm(hidden), rope, tables, lens,
-            write_blk, write_off, _layer_cache((kc, vc, ks, vs), i))
-        _collect_cache(new, out)
-        hidden = hidden + att
-        hidden = hidden + layer.mlp(
-            layer.post_attention_layernorm(hidden))
+    new = []
+    for layer, cache in zip(core.layers, _layer_caches(
+            model, (kc, vc, ks, vs), st)):
+        hidden, arrays = layer.paged_decode(hidden, step, cache)
+        new.append(arrays)
     hidden = core.norm(hidden)
     logits = model.lm_head(hidden)
-    return (logits._value[:, 0], out[0], out[1],
-            tuple(out[2]), tuple(out[3]))
+    return (logits._value[:, 0], *_collect_caches(model, new))
 
 
 def paged_chunk_math(model, scratch_block, ids_t, seq_lens, tables,
-                     kc, vc, live, ks=(), vs=(), counts=None):
+                     kc, vc, live, ks=(), vs=(), counts=None, st=()):
     """C tokens for every slot over a paged pool — ONE body for the
     speculative round's TARGET verify pass (reference: the speculative
     verify forward of the reference's serving stack — unverified,
     SURVEY.md §0) and for the engine's mixed prefill step. Chunk
     position j writes its KV at ``seq_lens + j`` (masked rows go to the
     scratch block) and attends its own prefix; one batched forward
-    covers all slots and all C positions. The attention itself is each
-    layer's ``self_attn.paged_chunk`` (see ``paged_decode_math``).
+    covers all slots and all C positions. Everything between the
+    embedding and the final norm is each layer's ``paged_chunk`` (see
+    ``paged_decode_math``); ``step`` carries ``lens`` (each row's BASE
+    length: what it had cached before this chunk) and ``valid``, (S, C),
+    the positions that bring a token. A state layer leaves a row's state
+    where the row's last valid position put it, starts a row whose base
+    length is 0 from zeros, and keeps the state of a row that is not
+    live.
 
     ``counts=None`` is the verify pass: every position of a live row is
     valid and the logits of all of them come back, (S, C, V). Stale
@@ -354,7 +403,7 @@ def paged_chunk_math(model, scratch_block, ids_t, seq_lens, tables,
     w = tables.shape[1]
 
     hidden = core.embed_tokens(ids_t)                # (S, C, E)
-    rope = core.layers[0].self_attn.paged_rope(
+    rope = core.paged_rope(
         (seq_lens[:, None]
          + jnp.arange(c)[None, :]).astype(jnp.float32))
 
@@ -364,19 +413,16 @@ def paged_chunk_math(model, scratch_block, ids_t, seq_lens, tables,
     wpos = seq_lens[:, None] + jnp.arange(c)[None, :]
     blk_idx = jnp.clip(wpos // bs, 0, w - 1)
     own_blk = jnp.take_along_axis(tables, blk_idx, axis=1)
-    write_blk = jnp.where(valid, own_blk, scratch_block)
-    write_off = jnp.where(valid, wpos % bs, 0)
-    base_lens = jnp.where(live, seq_lens, 0)
+    step = {"rope": rope, "tables": tables, "live": live, "valid": valid,
+            "write_blk": jnp.where(valid, own_blk, scratch_block),
+            "write_off": jnp.where(valid, wpos % bs, 0),
+            "lens": jnp.where(live, seq_lens, 0)}
 
-    out = ([], [], [], [])
-    for i, layer in enumerate(core.layers):
-        att, new = layer.self_attn.paged_chunk(
-            layer.input_layernorm(hidden), rope, tables, base_lens,
-            write_blk, write_off, _layer_cache((kc, vc, ks, vs), i))
-        _collect_cache(new, out)
-        hidden = hidden + att
-        hidden = hidden + layer.mlp(
-            layer.post_attention_layernorm(hidden))
+    new = []
+    for layer, cache in zip(core.layers, _layer_caches(
+            model, (kc, vc, ks, vs), st)):
+        hidden, arrays = layer.paged_chunk(hidden, step, cache)
+        new.append(arrays)
     if counts is None:
         logits = model.lm_head(core.norm(hidden))._value
     else:
@@ -384,7 +430,7 @@ def paged_chunk_math(model, scratch_block, ids_t, seq_lens, tables,
         logits = model.lm_head(core.norm(Tensor(
             jnp.take_along_axis(hidden._value, last, axis=1),
             stop_gradient=True)))._value[:, 0]
-    return logits, out[0], out[1], tuple(out[2]), tuple(out[3])
+    return (logits, *_collect_caches(model, new))
 
 
 class _AuditedStep:
@@ -608,22 +654,31 @@ class ServingEngine:
                 f"multi_quantum must be >= 1, got {multi_quantum}")
         self.mesh, self.tp = _resolve_tp_mesh(mesh, tp)
         layout = model.paged_cache_layout()
-        if layout["layout"] == "latent":
-            # nothing is silently ignored: what the latent pool and the
-            # latent attention cannot do yet is refused by name
-            for name, asked in (("kv_dtype='int8'", kv_dtype == "int8"),
-                                ("tp > 1", self.tp > 1),
-                                ("spec_draft", spec_draft is not None)):
-                if asked:
-                    raise NotImplementedError(
-                        f"ServingEngine does not compose {name} with a "
-                        f"latent-attention model "
-                        f"({type(model).__name__}) yet")
-        elif spec_draft is not None and spec_draft.paged_cache_layout()[
-                "layout"] == "latent":
+        kinds = layout["layers"]
+        # nothing is silently ignored: what a latent pool, latent
+        # attention or a slot's recurrent state cannot do yet is refused
+        # by name
+        refused = {"kv_dtype='int8'": kv_dtype == "int8",
+                   "tp > 1": self.tp > 1,
+                   "spec_draft": spec_draft is not None}
+        if "state" in kinds:
+            what = "a state-space"
+            refused["prefix_cache=True"] = bool(prefix_cache)
+        elif "latent" in kinds:
+            what = "a latent-attention"
+        else:
+            refused = {}
+        for name, asked in refused.items():
+            if asked:
+                raise NotImplementedError(
+                    f"ServingEngine does not compose {name} with {what} "
+                    f"model ({type(model).__name__}) yet")
+        if spec_draft is not None and set(
+                spec_draft.paged_cache_layout()["layers"]) != {"kv"}:
             raise NotImplementedError(
-                "ServingEngine does not take a latent-attention model "
-                f"({type(spec_draft).__name__}) as spec_draft yet")
+                "ServingEngine does not take a latent-attention or "
+                f"state-space model ({type(spec_draft).__name__}) as "
+                "spec_draft yet")
         if self.tp > 1:
             _check_tp_divisible(cfg, self.tp, "target")
             if spec_draft is not None:
@@ -695,11 +750,17 @@ class ServingEngine:
         if num_blocks is None:
             num_blocks = s * w + 1  # +1: the masked-write scratch block
         self.prefix_cache = bool(prefix_cache)
+        # block arrays for the layers that cache keys, a slot side for
+        # the layers that carry a state (none: an empty side, no aval)
+        n_state = kinds.count("state")
+        n_block = len(kinds) - n_state
         self.pool = PagedKVCachePool(
             num_blocks, bs, layout["num_kv_heads"], layout["head_dim"],
-            num_layers=cfg.num_hidden_layers, dtype=cache_dtype,
+            num_layers=n_block, dtype=cache_dtype,
             prefix_cache=self.prefix_cache, mesh=self.mesh,
-            kv_dtype=kv_dtype, layout=layout["layout"])
+            kv_dtype=kv_dtype, layout=layout["layout"],
+            state={"slots": s, "layers": n_state,
+                   "arrays": layout["state"]} if n_state else None)
         self.pool.commit_like(self._p_vals[0])
         # masked (retired/empty) rows dump their KV writes here
         self._scratch_block = self.pool.ensure("__scratch__", 1)[0]
@@ -759,13 +820,15 @@ class ServingEngine:
 
         n_pool = (4 if self.pool.quantized
                   else self.pool.arrays_per_layer)
+        # every pool leaf is donated: the block arrays and the slot side
+        n_donated = len(jax.tree_util.tree_leaves(self.pool.arrays()))
         # the mixed step: ONE jitted, pool-donating program per model
         # (the draft's writes its KV only); jit keeps an executable per
         # chunk-length bucket, built on the bucket's first use
         self._mixed = _AuditedStep(
             jax.jit(self._make_mixed(model, self._scratch_block, True),
-                    donate_argnums=(0, 1, 2, 3)),
-            n_donatable=n_pool * cfg.num_hidden_layers,
+                    donate_argnums=(0, 1, 2, 3, 4)),
+            n_donatable=n_donated,
             name="serving_mixed_step", mesh=self.mesh)
         self._mixed_buckets = set()
         if spec_draft is not None:
@@ -775,7 +838,7 @@ class ServingEngine:
             self._d_mixed = _AuditedStep(
                 jax.jit(self._make_mixed(spec_draft,
                                          self._d_scratch_block, False),
-                        donate_argnums=(0, 1, 2, 3)),
+                        donate_argnums=(0, 1, 2, 3, 4)),
                 n_donatable=n_pool * d_cfg.num_hidden_layers,
                 name="serving_mixed_step_draft", mesh=self.mesh)
             # argnums 0..7 = target kc/vc/ks/vs + draft kc/vc/ks/vs; on
@@ -792,11 +855,9 @@ class ServingEngine:
                 name="speculative_verify_step", mesh=self.mesh)
         else:
             self._quantum = jax.jit(self._make_quantum(),
-                                    donate_argnums=(0, 1, 2, 3))
+                                    donate_argnums=(0, 1, 2, 3, 4))
             self._audited = _AuditedStep(
-                self._quantum,
-                n_donatable=n_pool * cfg.num_hidden_layers,
-                mesh=self.mesh)
+                self._quantum, n_donatable=n_donated, mesh=self.mesh)
         # the multi-quantum while_loop variant: built ONLY when asked
         # for (K > 1, non-speculative) — same signature as the plain
         # quantum, so `_quantum_args()` feeds both; the default
@@ -806,10 +867,9 @@ class ServingEngine:
         if self._mq_max > 1 and spec_draft is None:
             self._mq_quantum = jax.jit(
                 self._make_quantum(multi=self._mq_max),
-                donate_argnums=(0, 1, 2, 3))
+                donate_argnums=(0, 1, 2, 3, 4))
             self._mq_audited = _AuditedStep(
-                self._mq_quantum,
-                n_donatable=n_pool * cfg.num_hidden_layers,
+                self._mq_quantum, n_donatable=n_donated,
                 name="serving_multiquantum_step", mesh=self.mesh)
         # under tp the small per-slot state rides every dispatch
         # committed replicated, so the compiled quantum's input layouts
@@ -868,6 +928,7 @@ class ServingEngine:
         # suppressed; a tp=1 engine leaves the series empty)
         self.obs.set_quantum_collectives(self.quantum_collectives)
         self.obs.set_pool_bytes_per_token(self.pool.bytes_per_token())
+        self.obs.set_state_bytes_per_slot(self.pool.state_bytes_per_slot())
         # cost-ledger MFU constants (obs/attribution.py): target-model
         # FLOPs per decoded token (2N weight-matmul floor, embedding
         # gathers excluded) and the chip peak (0.0 on the CPU backend —
@@ -878,8 +939,10 @@ class ServingEngine:
 
         # "params actually multiplied per token": of an expert layer's
         # routed experts a token multiplies its top k, not all of them
+        blocks = _expert_blocks(model)
+        self._moe_top_k = blocks[0].router.top_k if blocks else 0
         n_params = sum(int(v.size) for v in self._p_vals) - sum(
-            b.inactive_params_per_token() for b in _expert_blocks(model))
+            b.inactive_params_per_token() for b in blocks)
         embed = (int(getattr(cfg, "vocab_size", 0))
                  * int(getattr(cfg, "hidden_size", 0)))
         # int8 flops model: a quantized stack feeds the MXU's int8 path,
@@ -1375,7 +1438,7 @@ class ServingEngine:
         self._spec_disabled = True
         cfg = self.model.config
         self._plain_quantum = jax.jit(self._make_quantum(),
-                                      donate_argnums=(0, 1, 2, 3))
+                                      donate_argnums=(0, 1, 2, 3, 4))
         self._plain_audited = _AuditedStep(
             self._plain_quantum,
             n_donatable=(4 if self.pool.quantized else 2)
@@ -1611,25 +1674,25 @@ class ServingEngine:
         tokens with one definition. The speculative arm's DRAFT ingests
         the same rows through a program of its own that returns its
         pools only (``select=False``: the forward exists for its KV
-        writes). Weights are arguments (``p_vals``), the pools the
-        leading, donated ones."""
-        def mixed(kc, vc, ks, vs, p_vals, tables, ids, seq_lens, counts,
-                  keys, n_gen, temps=None):
+        writes). Weights are arguments (``p_vals``), the pool's five
+        sides (``PagedKVCachePool.arrays``) the leading, donated ones."""
+        def mixed(kc, vc, ks, vs, st, p_vals, tables, ids, seq_lens,
+                  counts, keys, n_gen, temps=None):
             with autograd.no_grad():
                 def fwd(ids_t):
                     return paged_chunk_math(
                         model, scratch, ids_t, seq_lens, tables, kc, vc,
-                        counts > 0, ks=ks, vs=vs,
-                        counts=counts), moe_rows(model)
+                        counts > 0, ks=ks, vs=vs, counts=counts,
+                        st=st), moe_rows(model)
 
-                ((logits, kc2, vc2, ks2, vs2), rows), _ = functional_call(
+                ((logits, *pools), rows), _ = functional_call(
                     model, fwd, [Tensor(ids, stop_gradient=True)], {},
                     p_vals, [])
             if not select:
-                return kc2, vc2, ks2, vs2
+                return tuple(pools)
             # ``rows`` is () for a model without experts: no aval; the
             # tokens stay the last output
-            return (kc2, vc2, ks2, vs2, rows,
+            return (*pools, rows,
                     self._select_device(logits, keys, n_gen, temps))
 
         return mixed
@@ -1661,6 +1724,9 @@ class ServingEngine:
                              if req.prefilling else self._last_tok[slot])
             counts[slot] = n
             seq_ids[slot] = req.req_id
+            if seq == 0 and self.pool.state:
+                # the program starts this row's state from zeros
+                self.obs.on_state_reset()
             for pool in pools:
                 pool.ensure(req.req_id, seq + n)
                 if self.prefix_cache:
@@ -1673,9 +1739,9 @@ class ServingEngine:
             *self._temps_arg())]
 
         def args_of(pool, p_vals):
-            # the scale tuples are EMPTY on a float pool (no avals)
-            return (list(pool.k_pools), list(pool.v_pools),
-                    tuple(pool.k_scales), tuple(pool.v_scales), p_vals,
+            # the scale tuples are EMPTY on a float pool (no avals), the
+            # slot side without state layers
+            return (*pool.arrays(), p_vals,
                     self._dev(pool.block_table_array(
                         seq_ids, pad_to=self._table_width)), *small)
 
@@ -1749,8 +1815,13 @@ class ServingEngine:
             with RecordEvent("engine.mixed.select"):
                 nxt = np.asarray(toks)               # (S,) int32
                 if not isinstance(rows, tuple):
-                    # (expert layers, experts): the step's routed rows
-                    span.args["moe_rows"] = int(np.asarray(rows).sum())
+                    # (expert layers, experts held): the step's routed
+                    # rows, and the choices that fell on absent experts
+                    got = np.asarray(rows)
+                    span.args["moe_rows"] = int(got.sum())
+                    span.args["moe_offshare_rows"] = (
+                        got.shape[0] * self.config.num_slots * bucket
+                        * self._moe_top_k - int(got.sum()))
             now = self._now()  # the stamp of every token of the step
             with RecordEvent("engine.mixed.emit"):
                 prefill_emitted = 0
@@ -1857,22 +1928,25 @@ class ServingEngine:
         has_eos = self.eos_token_id is not None
         eos = -1 if self.eos_token_id is None else int(self.eos_token_id)
 
-        def scan_steps(kc, vc, ks, vs, p_vals, tables, seq_lens,
+        def scan_steps(kc, vc, ks, vs, st, p_vals, tables, seq_lens,
                        last_tok, n_gen, done, max_new, keys, temps):
-            # ks/vs are the int8 pool's per-row scale pools; on a float
-            # engine they are EMPTY tuples — zero avals in the carry,
-            # so the compiled graph (and golden) is byte-identical
+            # ks/vs are the int8 pool's per-row scale pools and st the
+            # slot side; on a float engine without state layers they are
+            # EMPTY tuples — zero avals in the carry, so the compiled
+            # graph (and golden) is byte-identical
             def body(carry, _):
-                kc, vc, ks, vs, seq_lens, last_tok, n_gen, done = carry
+                (kc, vc, ks, vs, st, seq_lens, last_tok, n_gen,
+                 done) = carry
                 live = ~done
                 with autograd.no_grad():
                     def fwd(tok_t):
                         return paged_decode_math(
                             model, scratch, tok_t, seq_lens, tables,
-                            kc, vc, live, ks=ks, vs=vs), moe_rows(model)
+                            kc, vc, live, ks=ks, vs=vs,
+                            st=st), moe_rows(model)
 
                     tok_t = Tensor(last_tok[:, None], stop_gradient=True)
-                    ((logits, kc2, vc2, ks2, vs2), rows), _ = \
+                    ((logits, kc2, vc2, ks2, vs2, st2), rows), _ = \
                         functional_call(model, fwd, [tok_t], {}, p_vals,
                                         [])
                 nxt = self._select_device(logits, keys, n_gen, temps)
@@ -1882,21 +1956,21 @@ class ServingEngine:
                 if has_eos:
                     done2 = done2 | (live & (nxt == eos))
                 seq_lens2 = seq_lens + live.astype(jnp.int32)
-                return (kc2, vc2, ks2, vs2, seq_lens2, nxt, n_gen2,
+                return (kc2, vc2, ks2, vs2, st2, seq_lens2, nxt, n_gen2,
                         done2), (nxt, rows)
 
             # ``rows``: (T, expert layers, experts) int32, or () for a
             # model without experts (no aval: its graph is what it was)
-            (kc, vc, ks, vs, seq_lens, last_tok, n_gen, done), \
+            (kc, vc, ks, vs, st, seq_lens, last_tok, n_gen, done), \
                 (toks, rows) = jax.lax.scan(
                     body,
-                    (kc, vc, tuple(ks), tuple(vs), seq_lens, last_tok,
-                     n_gen, done),
+                    (kc, vc, tuple(ks), tuple(vs), tuple(st), seq_lens,
+                     last_tok, n_gen, done),
                     None, length=t_steps)
-            return (kc, vc, ks, vs, seq_lens, last_tok, n_gen, done,
+            return (kc, vc, ks, vs, st, seq_lens, last_tok, n_gen, done,
                     toks, rows)
 
-        def multi_steps(kc, vc, ks, vs, p_vals, tables, seq_lens,
+        def multi_steps(kc, vc, ks, vs, st, p_vals, tables, seq_lens,
                         last_tok, n_gen, done, max_new, keys, temps):
             # K quanta per dispatch: the host round-trips device state
             # untouched between steady-state quanta, so folding the
@@ -1909,39 +1983,40 @@ class ServingEngine:
             rbuf0 = _moe_rows_buffer(model, k_max, t_steps)
 
             def cond(carry):
-                qi, done = carry[0], carry[8]
+                qi, done = carry[0], carry[9]
                 return (qi < k_max) & ~jnp.all(done)
 
             def body(carry):
-                (qi, kc, vc, ks, vs, seq_lens, last_tok, n_gen, done,
+                (qi, kc, vc, ks, vs, st, seq_lens, last_tok, n_gen, done,
                  buf, rbuf) = carry
-                (kc, vc, ks, vs, seq_lens, last_tok, n_gen, done,
-                 toks, rows) = scan_steps(kc, vc, ks, vs, p_vals, tables,
-                                          seq_lens, last_tok, n_gen, done,
-                                          max_new, keys, temps)
+                (kc, vc, ks, vs, st, seq_lens, last_tok, n_gen, done,
+                 toks, rows) = scan_steps(kc, vc, ks, vs, st, p_vals,
+                                          tables, seq_lens, last_tok,
+                                          n_gen, done, max_new, keys,
+                                          temps)
                 buf = jax.lax.dynamic_update_slice(
                     buf, toks[None], (qi, 0, 0))
                 rbuf = jax.tree_util.tree_map(
                     lambda b, r: jax.lax.dynamic_update_slice(
                         b, r[None], (qi, 0, 0, 0)), rbuf, rows)
-                return (qi + 1, kc, vc, ks, vs, seq_lens, last_tok,
+                return (qi + 1, kc, vc, ks, vs, st, seq_lens, last_tok,
                         n_gen, done, buf, rbuf)
 
-            (qi, kc, vc, ks, vs, seq_lens, last_tok, n_gen, done,
+            (qi, kc, vc, ks, vs, st, seq_lens, last_tok, n_gen, done,
              buf, rbuf) = jax.lax.while_loop(
                 cond, body,
-                (jnp.int32(0), kc, vc, tuple(ks), tuple(vs), seq_lens,
-                 last_tok, n_gen, done, buf0, rbuf0))
-            return (kc, vc, ks, vs, seq_lens, last_tok, n_gen, done,
+                (jnp.int32(0), kc, vc, tuple(ks), tuple(vs), tuple(st),
+                 seq_lens, last_tok, n_gen, done, buf0, rbuf0))
+            return (kc, vc, ks, vs, st, seq_lens, last_tok, n_gen, done,
                     buf, rbuf, qi)
 
         inner = scan_steps if multi is None else multi_steps
 
         # ``temps`` is the per-slot temperature row of a sampling engine
-        # (`_temps_arg`); every other engine calls with twelve arguments
-        def quantum(kc, vc, ks, vs, p_vals, tables, seq_lens,
+        # (`_temps_arg`); every other engine calls with thirteen arguments
+        def quantum(kc, vc, ks, vs, st, p_vals, tables, seq_lens,
                     last_tok, n_gen, done, max_new, keys, temps=None):
-            return inner(kc, vc, ks, vs, p_vals, tables, seq_lens,
+            return inner(kc, vc, ks, vs, st, p_vals, tables, seq_lens,
                          last_tok, n_gen, done, max_new, keys, temps)
 
         return quantum
@@ -1966,9 +2041,10 @@ class ServingEngine:
         """The quantum's argument tuple; its uploads (the ``_dev``
         calls) are the span ``engine.decode.args``."""
         with RecordEvent("engine.decode.args"):
-            # the scale tuples ride right after their pool's v_pools (empty
-            # on a float engine — no avals, goldens untouched); donation
-            # covers all leading pool pytrees
+            # the scale tuples ride right after their pool's v_pools and the
+            # slot side after them (empty on a float engine without state
+            # layers — no avals, goldens untouched); donation covers all
+            # leading pool pytrees
             if self.spec_draft is not None and not self._spec_disabled:
                 return (list(self.pool.k_pools), list(self.pool.v_pools),
                         tuple(self.pool.k_scales),
@@ -1985,8 +2061,7 @@ class ServingEngine:
                         self._dev(self._n_gen), self._dev(self._done),
                         self._dev(self._max_new),
                         self._dev(self._keys))
-            return (list(self.pool.k_pools), list(self.pool.v_pools),
-                    tuple(self.pool.k_scales), tuple(self.pool.v_scales),
+            return (*self.pool.arrays(),
                     self._p_vals, self._dev(self._tables),
                     self._dev(self._seq_lens),
                     self._dev(self._last_tok), self._dev(self._n_gen),
@@ -2189,7 +2264,9 @@ class ServingEngine:
                 raise
             # adopt the donated pool outputs NOW (async handles — no
             # sync): the pre-dispatch buffers were consumed by donation
-            self.pool.adopt(kc, vc, ks, vs)
+            # (the slot side leads ``out``: no name of its own, see the
+            # docstring's note on this frame's words)
+            self.pool.adopt(kc, vc, ks, vs, out.pop(0))
             # out: seq_lens, last_tok, n_gen, done, toks, the experts'
             # rows (() without experts), and the count of quanta that
             # ran where the dispatch was of several. The
@@ -2228,7 +2305,8 @@ class ServingEngine:
                     if k > 1:
                         moe = moe[:max(int(np.asarray(nq)), 1)]
                     step.args.update(self.obs.on_moe_rows(
-                        moe.reshape(-1, *moe.shape[-2:])))
+                        moe.reshape(-1, *moe.shape[-2:]),
+                        self.config.num_slots * self._moe_top_k))
             # the device's share of the wall: from the jitted call's return
             # (the enqueue span's end) to the sync span's end
             now = sync.t1

@@ -124,7 +124,7 @@ def make_spec_round(engine):
                         draft, d_scratch, tok_t, seq_lens + j,
                         d_tables, kcs, vcs, live, ks=kss, vs=vss)
 
-                (logits, kcs2, vcs2, kss2, vss2), _ = functional_call(
+                (logits, kcs2, vcs2, kss2, vss2, _), _ = functional_call(
                     draft, fwd,
                     [Tensor(cur[:, None], stop_gradient=True)], {},
                     d_pv, [])
@@ -154,7 +154,7 @@ def make_spec_round(engine):
                     target, t_scratch, ids_t, seq_lens, t_tables,
                     t_kc, t_vc, live, ks=t_ks, vs=t_vs)
 
-            (t_logits, t_kc2, t_vc2, t_ks2, t_vs2), _ = functional_call(
+            (t_logits, t_kc2, t_vc2, t_ks2, t_vs2, _), _ = functional_call(
                 target, tfwd, [Tensor(chunk, stop_gradient=True)], {},
                 t_pv, [])
 

@@ -298,7 +298,7 @@ def test_the_bucket_is_a_function_of_the_rows(tiny, longest, chunk, bucket):
     args, d_args, got, lens = engine._mixed_args(
         engine.scheduler.prefilling(), [], False)
     assert got == bucket and lens == [longest, 1] and d_args is None
-    ids, counts = np.asarray(args[6]), np.asarray(args[8])
+    ids, counts = np.asarray(args[7]), np.asarray(args[9])
     assert ids.shape == (2, bucket) and list(counts) == [longest, 1]
 
 
@@ -432,8 +432,8 @@ def test_the_head_runs_at_the_last_valid_position_only(tiny):
             return engine_mod.paged_chunk_math(
                 model, 0, paddle.Tensor(ids), seq_lens, tables, kc, vc,
                 seq_lens >= 0, counts=counts)[0]
-        return jax.eval_shape(fwd, args[0], args[1], args[6], args[7],
-                              args[5]).shape
+        return jax.eval_shape(fwd, args[0], args[1], args[7], args[8],
+                              args[6]).shape
 
     assert logits_shape(None) == (3, 4, cfg.vocab_size)
     assert logits_shape(jnp.asarray([4, 0, 1])) == (3, cfg.vocab_size)

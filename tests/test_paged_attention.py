@@ -211,6 +211,24 @@ def test_pool_allocator_reuse_and_memory_claim():
         pool.ensure("d", 16 * 32)
 
 
+def test_grow_decode_table_row_is_written_on_the_host(monkeypatch):
+    """The quantum's table row: grown to cover the dispatch, padded, equal
+    to the pool's own table, and a host array that was never on the
+    device (one call a slot and quantum: a device round trip each was
+    most of the decode step's host time)."""
+    pool = PagedKVCachePool(num_blocks=16, block_size=32, num_kv_heads=2,
+                            head_dim=64, num_layers=1)
+    pool.ensure("a", 40)    # 2 blocks
+    monkeypatch.setattr(pool, "block_table_array", None)
+    monkeypatch.setattr(jnp, "asarray", None)
+    row = pool.grow_decode_table("a", 70, 40, pad_to=6)
+    assert isinstance(row, np.ndarray) and row.dtype == np.int32
+    assert row.tolist() == pool._tables["a"] + [0, 0, 0]
+    assert len(pool._tables["a"]) == 3 and pool.seq_len("a") == 70
+    # no growth needed, no padding asked for: the table as it stands
+    assert pool.grow_decode_table("a", 64, 40).tolist() == pool._tables["a"]
+
+
 def test_block_multihead_attention_prefill_then_decode():
     """The incubate functional: prefill writes the pool + varlen flash;
     decode steps match a full-context reference."""
