@@ -171,10 +171,11 @@ def _latent_decode_attn(q_lat, q_rope, pool, tables, lens, scale):
     table's blocks are gathered ONCE and serve as keys (all lanes) and
     as values (the first ``R`` lanes, the compressed part). ``q_lat``
     (S, H, R) is the query carried into latent space, ``q_rope``
-    (S, H, Dr); ``pool`` (NB, BS, 1, R + Dr). Operands stay in the
+    (S, H, Dr); ``pool`` (NB, BS, R + Dr). Operands stay in the
     pool's dtype with float32 accumulation; softmax in float32 with the
     -1e30 mask of the other decode paths. Returns the context in latent
-    space, (S, H, R) float32."""
+    space, (S, H, R) float32. The CPU's path, and the reference the
+    kernel's parity tests compare with."""
     s_, _, r = q_lat.shape
     w, bs = tables.shape[1], pool.shape[1]
     rows = pool[tables].reshape(s_, w * bs, pool.shape[-1])
@@ -333,9 +334,28 @@ class DeepseekV3Attention(Layer):
         return _latent_chunk_attn(q_nope, q_rope, pool, tables, base_lens,
                                   *self._up_weights(), self.scale)
 
+    def _decode_attn(self, q_lat, q_rope, pool, tables, lens):
+        """Route the absorbed decode attention by the rule of
+        ``_chunk_attn``: on a TPU, for a float pool of whole-lane widths,
+        the kernel (``ops/pallas/paged_attention.py``: each live block
+        read once from the pool as it is stored, a row ``[c | k_rope]``
+        the key of ONE product with ``[q_lat | q_rope]`` and its first R
+        lanes the value), ``_latent_decode_attn`` elsewhere. The path is
+        counted once a traced program."""
+        from ..ops.pallas import paged_attention as kernel
+
+        r = q_lat.shape[-1]
+        if PA.use_pallas_kernels() and kernel.supports_latent(pool, r):
+            PA.count_latent_decode_program("kernel")
+            return kernel.latent_decode_attention(
+                jnp.concatenate([q_lat, q_rope.astype(q_lat.dtype)], -1),
+                pool, tables, lens, self.scale, r)
+        PA.count_latent_decode_program("xla")
+        return _latent_decode_attn(q_lat, q_rope, pool, tables, lens,
+                                   self.scale)
+
     def _write(self, pool, rows, write_blk, write_off):
-        return pool.at[write_blk, write_off].set(
-            rows[..., None, :].astype(pool.dtype))
+        return pool.at[write_blk, write_off].set(rows.astype(pool.dtype))
 
     def _project_out(self, out, lead):
         return self.o_proj(Tensor(
@@ -382,8 +402,8 @@ class DeepseekV3Attention(Layer):
             w_uk, w_uv = self._up_weights()
             q_lat = jnp.einsum("shd,rhd->shr", q_nope[:, 0], w_uk,
                                preferred_element_type=jnp.float32)
-            ctx = _latent_decode_attn(q_lat, q_rope[:, 0], pool, tables,
-                                      lens, self.scale)  # (S, H, R) f32
+            ctx = self._decode_attn(q_lat, q_rope[:, 0], pool, tables,
+                                    lens)                # (S, H, R) f32
             out = jnp.einsum("shr,rhd->shd", ctx.astype(w_uv.dtype), w_uv,
                              preferred_element_type=jnp.float32)
             att = self._project_out(out.astype(x._value.dtype)[:, None],

@@ -202,20 +202,30 @@ def _paged_attn(q, kp, vp, tables, lens, ks=None, vs=None, scale=None):
                                   scale=scale)
 
 
-_chunk_programs_counted = None
+_programs_counted = {}   # counter name -> the trace it last counted
+
+
+def _count_traced_program(counter, path):
+    """Raise ``counter{path}`` ONCE for the program being traced, however
+    many layers ask: a route is static per compiled program."""
+    trace = jax.core.get_opaque_trace_state()
+    if _programs_counted.get(counter.name) != trace:
+        _programs_counted[counter.name] = trace
+        counter.inc(path=path)
 
 
 def count_chunk_attention_program(path):
     """Raise ``serving_chunk_attention_programs_total{path}`` (``kernel``
-    | ``xla``) on the process's registry ONCE for the program being
-    traced, however many layers ask: the route is static per compiled
-    program, so a scrape says which path the mixed programs of this
+    | ``xla``) on the process's registry once for the mixed program being
+    traced, so a scrape says which path the mixed programs of this
     process were built with."""
-    global _chunk_programs_counted
-    trace = jax.core.get_opaque_trace_state()
-    if trace != _chunk_programs_counted:
-        _chunk_programs_counted = trace
-        chunk_attention_programs().inc(path=path)
+    _count_traced_program(chunk_attention_programs(), path)
+
+
+def count_latent_decode_program(path):
+    """The same for the decode quantum of a latent model:
+    ``serving_latent_decode_programs_total{path}``."""
+    _count_traced_program(latent_decode_programs(), path)
 
 
 def chunk_attention_programs():
@@ -226,6 +236,16 @@ def chunk_attention_programs():
         "serving_chunk_attention_programs_total",
         "mixed-step programs traced, by the path their latent chunk "
         "attention takes (kernel | xla)")
+
+
+def latent_decode_programs():
+    """The counter itself (an engine's registry shares it)."""
+    from ..obs.registry import MetricsRegistry
+
+    return MetricsRegistry.process().counter(
+        "serving_latent_decode_programs_total",
+        "decode programs traced, by the path their attention over the "
+        "latent pool takes (kernel | xla)")
 
 
 def _pin_kv(arr):

@@ -110,7 +110,12 @@ class PagedKVCachePool:
         layout: ``"kv"`` (a K and a V array a layer) or ``"latent"``:
             ONE array a layer whose row is the token's compressed
             key/value (latent attention: ``num_kv_heads`` 1,
-            ``head_dim`` the latent width plus the rotary key's). The V
+            ``head_dim`` the latent width plus the rotary key's),
+            stored ``(num_blocks, block_size, head_dim)``: there is no
+            head axis. (A TPU keeps such an array block-index-minor
+            while ``head_dim`` is not whole 128-lane tiles, as 576 is
+            not, and re-lays it out round a program that reads blocks:
+            PERF.md section 6, PR 33.) The V
             side is then an EMPTY list exactly as the scale pools are
             empty on a float pool (zero avals), so ``adopt``,
             ``commit_like``, copy-on-write, the accounting and the
@@ -184,6 +189,12 @@ class PagedKVCachePool:
         self.kv_dtype = kv_dtype
         shape = (self.num_blocks, self.block_size, self.num_kv_heads,
                  self.head_dim)
+        if layout == "latent":
+            if self.num_kv_heads != 1:
+                raise ValueError(
+                    "a latent pool holds one row a token: num_kv_heads "
+                    f"must be 1, not {self.num_kv_heads}")
+            shape = (self.num_blocks, self.block_size, self.head_dim)
         self.mesh = mesh
         self._pool_sharding = None
         self._scale_sharding = None

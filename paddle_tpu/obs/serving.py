@@ -191,11 +191,13 @@ class ServingObs:
             "serving_state_bytes_per_slot",
             "bytes of recurrent state one slot holds over all state "
             "layers, whatever its context"))
-        # counted where a mixed program is traced (the route of the
-        # latent chunk attention is static per program); shown here too
-        from ..nlp.paged_attention import chunk_attention_programs
+        # counted where a program is traced (the routes of the latent
+        # chunk and decode attention are static per program); shown here
+        from ..nlp.paged_attention import (
+            chunk_attention_programs, latent_decode_programs)
 
         r.share(chunk_attention_programs())
+        r.share(latent_decode_programs())
         # on the process's registry too: a reader outside the program
         # finds it after the engine is gone
         self._g_pool_token_bytes = r.share(MetricsRegistry.process().gauge(

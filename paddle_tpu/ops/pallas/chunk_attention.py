@@ -154,7 +154,7 @@ def latent_chunk_attention(q_nope, q_rope, pool, tables, base_lens, w_kvb,
 
     Args:
         q_nope: (S, C, H, Dn); q_rope: (S, C, H, Dr), already rotated.
-        pool: (num_blocks, block_size, 1, R + Dr), a float pool; the
+        pool: (num_blocks, block_size, R + Dr), a float pool; the
             chunk's own rows are already written.
         tables: (S, W) int32 pool block ids per slot, in order; entries
             past a slot's ``base + C`` are never read by a live query

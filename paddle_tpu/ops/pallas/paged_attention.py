@@ -25,6 +25,23 @@ head's rows masked off with the positions past the length (the
 products run on the MXU at the rate K and V stream through it whatever
 the number of query rows, so the masked part costs nothing and the
 kv heads need no loop and no strided read).
+
+The LATENT pool (``nlp/deepseek_v3.py``: one row ``[c | rope(k_rope)]``
+a token for all heads, stored (num_blocks, block_size, R + Dr)) has its
+own entry, ``latent_decode_attention``: the same online softmax (the
+fold ``chunk_attention`` shares, ``flash_attention._online_softmax_fold``),
+one kv head and NO value pool — a row is head h's key against ``[q_lat_h
+| q_rope_h]`` in ONE product, and its first R lanes are the value, out
+of the SAME buffer: one DMA a block. Its blocks arrive another way:
+R + Dr = 576 is not a whole number of 128-lane tiles, and Mosaic slices
+an HBM array for a hand-made DMA only along whole tiles ("Slice shape
+along dimension 2 must be aligned to tiling (128), but is 576"), while a
+BlockSpec may take a dimension whole. So a grid step there is (sequence,
+chunk): the chunk's blocks are as many BlockSpecs over the stored pool,
+each indexed through a table of fetches in scalar prefetch
+(``_latent_fetches``), all fetched while the step before computes; an
+entry past a sequence's length names the block its BlockSpec fetched
+last, which elides the copy.
 """
 from __future__ import annotations
 
@@ -42,6 +59,7 @@ from ._utils import (
     head_axis as _head_axis, interpret_mode as _interpret_mode,
     per_shard as _per_shard,
 )
+from .flash_attention import _online_softmax_fold
 
 NEG_INF = -1e30
 
@@ -284,6 +302,141 @@ def _paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens,
       k_pool.reshape(num_blocks, block_size * hk, d),
       v_pool.reshape(num_blocks, block_size * hk, d))
     return out[:, None] if squeeze else out
+
+
+def _latent_kernel(fetch_ref, lens_ref, q_ref, *refs, sm_scale, block_size,
+                   chunk, v_width):
+    blocks = refs[:chunk]
+    o_ref, m_scr, l_scr, acc_scr = refs[chunk:]
+    j = pl.program_id(1)
+    length = lens_ref[pl.program_id(0)]
+    width = chunk * block_size                 # rows of one chunk
+
+    @pl.when(j == 0)
+    def _reset():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j * width < length)
+    def _attend():
+        # the chunk's rows, keys AND values: a dead entry's rows are an
+        # older fetch of the pool, which the mask multiplies by 0
+        k = jnp.concatenate([blk[0] for blk in blocks], axis=0)
+        s = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * sm_scale                                        # (H, width)
+        tok = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+        _online_softmax_fold(s, k[:, :v_width], m_scr, l_scr, acc_scr,
+                             j * width + tok < length)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finish():
+        o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[...], 1e-30)).astype(
+            o_ref.dtype)
+
+
+def supports_latent(pool, v_width):
+    """Whether the compiled (Mosaic) kernel takes this latent pool: a
+    float pool, values of whole lanes and blocks of whole sublane tiles
+    (the interpreter, off-TPU, takes any)."""
+    if not jnp.issubdtype(pool.dtype, jnp.floating):
+        return False
+    if _interpret_mode():
+        return True
+    return v_width % 128 == 0 and pool.shape[1] % 16 == 0
+
+
+def latent_decode_attention(q, pool, block_tables, seq_lens, sm_scale,
+                            v_width):
+    """One-step decode attention over a LATENT paged pool, absorbed.
+
+    Args:
+        q: (B, H, D) — per head ``[q_lat | q_rope]``: the query carried
+            into latent space beside its rotated rope part.
+        pool: (num_blocks, block_size, D), a float pool, as stored: one
+            row ``[c | rope(k_rope)]`` a token for all heads. A row is
+            every head's key; its first ``v_width`` lanes are its value.
+        block_tables: (B, max_blocks) int32; entries past a sequence's
+            length are never read (any value).
+        seq_lens: (B,) int32 valid tokens (the one being decoded too);
+            a row of length 0 comes out as zeros.
+    Operands are taken in the pool's dtype, products accumulate in
+    float32, the softmax is float32 with the -1e30 mask, ``p`` is cast to
+    the pool's dtype before the value product:
+    ``deepseek_v3._latent_decode_attn``'s precision. Returns the context
+    in latent space, (B, H, v_width) float32.
+    """
+    # heads are independent, the pool has none: it is replicated
+    q_spec = P(None, _head_axis(q.shape[1]), None)
+    return _per_shard(
+        functools.partial(_latent_decode_attention, sm_scale=sm_scale,
+                          v_width=v_width),
+        (q_spec, P(), P(), P()), q_spec,
+    )(q, pool, block_tables, seq_lens)
+
+
+def _latent_fetches(block_tables, seq_lens, block_size, chunk):
+    """(B, grid steps x chunk) int32: the pool block each of a grid
+    step's BlockSpecs fetches. A position past the sequence's last block
+    names the block its BlockSpec fetched last (an equal index elides
+    the DMA), a sequence of length 0 block 0: no table entry past a
+    length is ever read."""
+    steps = block_tables.shape[1]
+    n = jnp.minimum(-(-seq_lens // block_size), steps)[:, None]
+    pos = jnp.arange(-(-steps // chunk) * chunk, dtype=jnp.int32)[None, :]
+    i = pos % chunk
+    last = jnp.maximum(n - 1 - i, 0) // chunk * chunk + i
+    pos = jnp.where(pos < n, pos, jnp.where(i < n, last, 0))
+    return jnp.where(n > 0, jnp.take_along_axis(block_tables, pos, axis=1),
+                     0)
+
+
+def _latent_decode_attention(q, pool, block_tables, seq_lens, *, sm_scale,
+                             v_width):
+    b, h, d = q.shape
+    block_size = pool.shape[1]
+    steps = block_tables.shape[1]
+    chunk = max(1, min(steps, _CHUNK_ROWS // block_size))
+    lens = seq_lens.astype(jnp.int32)
+    fetches = _latent_fetches(block_tables.astype(jnp.int32), lens,
+                              block_size, chunk)
+
+    def block_idx(i):
+        return lambda b_, j, fetch_ref, lens_ref: (
+            fetch_ref[b_, j * chunk + i], 0, 0)
+
+    def slot_idx(b_, j, fetch_ref, lens_ref):
+        return (b_, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, fetches.shape[1] // chunk),
+        in_specs=[
+            pl.BlockSpec((1, h, d), slot_idx),
+            # the stored pool, once a block of the chunk
+            *[pl.BlockSpec((1, block_size, d), block_idx(i))
+              for i in range(chunk)],
+        ],
+        out_specs=pl.BlockSpec((1, h, v_width), slot_idx),
+        scratch_shapes=[
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, v_width), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _latent_kernel, sm_scale=sm_scale, block_size=block_size,
+            chunk=chunk, v_width=v_width),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, v_width), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=_interpret_mode(),
+        name="latent_decode_attention",
+    )(fetches, lens, q.astype(pool.dtype), *[pool] * chunk)
 
 
 def paged_cache_write(k_pool, v_pool, k_new, v_new, block_tables, positions):

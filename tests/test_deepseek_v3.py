@@ -115,7 +115,7 @@ def test_served_tokens_are_the_references_best(toy, chunk, quantum):
         assert np.array_equal(_host(jnp.argmax(lg, -1)), toks)
     pool = door.engine.pool
     assert pool.layout == "latent" and pool.v_pools == []
-    assert pool.k_pools[0].shape[2:] == (1, 64 + 16)
+    assert pool.k_pools[0].shape == (64, 8, 64 + 16)
 
 
 def test_the_int8_control_fails_the_gap_tolerance(toy):
@@ -141,7 +141,7 @@ def test_absorbed_decode_equals_the_unabsorbed_form(toy):
     x = jnp.asarray(rng.standard_normal((s, n, cfg["hidden_size"])),
                     jnp.float32)
     want = attn(paddle.to_tensor(x))._value[:, -1]
-    pool = jnp.zeros((8, bs, 1, 64 + 16), jnp.float32)
+    pool = jnp.zeros((8, bs, 64 + 16), jnp.float32)
     tables = jnp.asarray(1 + np.arange(s * 3).reshape(s, 3), jnp.int32)
     pos = jnp.arange(n - 1)
     rope = attn.paged_rope(jnp.broadcast_to(pos, (s, n - 1)).astype(
@@ -259,7 +259,7 @@ def _latent_pool(**kw):
 def test_latent_pool_accounting():
     pool = _latent_pool()
     assert pool.v_pools == [] and pool.k_scales == [] == pool.v_scales
-    assert [a.shape for a in pool.k_pools] == [(8, 4, 1, 12)] * 3
+    assert [a.shape for a in pool.k_pools] == [(8, 4, 12)] * 3
     assert pool.arrays_per_layer == 1
     assert pool.bytes_per_token() == 3 * 12 * 4
     pool.ensure("a", 9)                       # three blocks
@@ -450,7 +450,7 @@ def _chunk_case(name, dtype):
     ks = jax.random.split(jax.random.PRNGKey(len(name)), 4)
     q_nope = jax.random.normal(ks[0], (s_, c, h, dn)).astype(dtype)
     q_rope = jax.random.normal(ks[1], (s_, c, h, dr)).astype(dtype)
-    pool = jax.random.normal(ks[2], (nb, bs, 1, r + dr)).astype(dtype)
+    pool = jax.random.normal(ks[2], (nb, bs, r + dr)).astype(dtype)
     w_kvb = (jax.random.normal(ks[3], (r, h * (dn + dv))) * 0.3).astype(dtype)
     tables = np.random.default_rng(0).permutation(
         np.arange(2, nb))[:s_ * w].reshape(s_, w).astype(np.int32)
@@ -572,3 +572,139 @@ def test_a_dense_models_mixed_program_counts_no_chunk_attention_path(
     step, args = eng.mixed_step_target()
     step.lower(*args)
     assert before == {p: _chunk_programs(p) for p in ("xla", "kernel")}
+
+
+# ---------------------------- the paged decode kernel over the latent pool
+_DECODE_WIDTHS = {"kanana": dict(h=32, r=512, dr=64, bs=32, w=36, rows=1024),
+                  "toy": dict(h=4, r=32, dr=8, bs=8, w=11, rows=32)}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("widths", list(_DECODE_WIDTHS))
+def test_latent_decode_kernel_matches_the_xla_gather(widths, dtype,
+                                                     monkeypatch):
+    """The latent pool's decode kernel (interpreted here) against ``_latent_decode_attn`` at the same precision, ragged lengths
+    in ONE call: one token, a block's edge and the next, one past it, a
+    partial last chunk, the table's full width, and an idle slot (zeros
+    out, never a NaN). Table tails name blocks far outside the pool:
+    never read."""
+    from paddle_tpu.nlp.deepseek_v3 import _latent_decode_attn
+    from paddle_tpu.ops.pallas import paged_attention as kernel
+
+    h, r, dr, bs, w, rows = (_DECODE_WIDTHS[widths][k] for k in (
+        "h", "r", "dr", "bs", "w", "rows"))
+    monkeypatch.setattr(kernel, "_CHUNK_ROWS", rows)
+    chunk = rows // bs
+    assert chunk < w < 3 * chunk      # a table of whole chunks and a part
+    lens = np.asarray([1, bs, 2 * bs, 2 * bs + 1, chunk * bs + bs + 5,
+                       w * bs, 0], np.int32)
+    s_ = len(lens)
+    nb = s_ * w + 1
+    rng = np.random.default_rng(h + bs)
+    tables = (rng.permutation(nb - 1)[:s_ * w].reshape(s_, w) + 1).astype(
+        np.int32)
+    for i, n in enumerate(lens):
+        tables[i, -(-n // bs):] = 10 ** 6
+    ks = jax.random.split(jax.random.PRNGKey(r), 3)
+    q_lat = jax.random.normal(ks[0], (s_, h, r), jnp.float32) * 0.3
+    q_rope = jax.random.normal(ks[1], (s_, h, dr), jnp.float32)
+    pool = jax.random.normal(ks[2], (nb, bs, r + dr)).astype(dtype)
+    scale = 1.0 / np.sqrt(24.0)
+    assert kernel.supports_latent(pool, r)
+    got = kernel.latent_decode_attention(
+        jnp.concatenate([q_lat, q_rope], -1), pool, jnp.asarray(tables),
+        jnp.asarray(lens), scale, r)
+    want = _latent_decode_attn(
+        q_lat, q_rope, pool, jnp.asarray(np.where(tables >= nb, 0, tables)),
+        jnp.asarray(lens), scale)
+    assert got.shape == want.shape == (s_, h, r)
+    assert got.dtype == want.dtype == jnp.float32
+    got, want = _host(got), _host(want)
+    assert np.isfinite(got).all() and not got[-1].any()
+    assert np.abs(want[:-1]).max() > 0.5
+    tol = 2e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got[:-1], want[:-1], rtol=tol, atol=tol)
+
+
+def _decode_programs(path):
+    from paddle_tpu.nlp.paged_attention import latent_decode_programs
+
+    return latent_decode_programs().value(path=path)
+
+
+def _serve_with_a_shared_prefix_and_a_preemption(cfg, model):
+    """A request whose prompt is four whole blocks of an earlier one's
+    (prefix reuse, and copy-on-write where it recomputes its last token
+    into a shared block) beside one that is preempted in mid-decode and
+    resumed: every stream's tokens."""
+    base = _prompts(cfg, (32,), seed=5)[0]
+    prompts = [np.concatenate([base, _prompts(cfg, (5,), seed=6)[0]]), base,
+               _prompts(cfg, (21,))[0]]
+    door = _serve(model, prefix_cache=True)
+    first = door.submit(prompts[0], max_new_tokens=10)
+    while door.engine.has_work:
+        door.pump()
+    rest = [door.submit(p, max_new_tokens=10) for p in prompts[1:]]
+    while len(rest[1].request.tokens) < 3:
+        door.pump()
+    door.engine.preempt(rest[1].request)
+    while door.engine.has_work:
+        door.pump()
+    pool = door.engine.pool
+    assert pool.prefix_hits >= 4 and pool.cow_copies >= 1
+    assert door.engine.scheduler.preempted_total >= 1
+    return [_host(s.request.tokens, np.int32) for s in [first] + rest]
+
+
+def test_engine_decodes_the_latent_pool_through_the_kernel(toy, request):
+    """The decode quantum with the kernel routed in (interpreted) serves
+    the greedy tokens of the XLA route over the reshaped latent pool:
+    prefix reuse, copy-on-write and a preempted request resumed. Each
+    engine's quantum is counted under its own path, on the engine's
+    registry too."""
+    cfg, model, _ = toy
+    xla0, kernel0 = _decode_programs("xla"), _decode_programs("kernel")
+    want = _serve_with_a_shared_prefix_and_a_preemption(cfg, model)
+    assert _decode_programs("xla") > xla0
+    assert _decode_programs("kernel") == kernel0
+    xla1 = _decode_programs("xla")
+    request.getfixturevalue("pallas_forced")
+    got = _serve_with_a_shared_prefix_and_a_preemption(cfg, model)
+    assert all(len(t) == 10 for t in want)
+    for a, b in zip(got, want):
+        assert np.array_equal(a, b)
+    assert _decode_programs("kernel") > kernel0
+    assert _decode_programs("xla") == xla1
+
+
+@pytest.mark.parametrize("path", ["xla", "kernel"])
+def test_a_traced_quantum_counts_its_latent_decode_path(toy, path, request):
+    """Tracing one quantum raises the counter by ONE on its path's label,
+    however many layers and scanned steps it has, and ``/metrics`` of the
+    engine shows the process's count."""
+    _, model, _ = toy
+    if path == "kernel":
+        request.getfixturevalue("pallas_forced")
+    before = {p: _decode_programs(p) for p in ("xla", "kernel")}
+    engine = _serve(model).engine
+    step, args = engine.decode_step_target()
+    step.lower(*args)
+    other = "xla" if path == "kernel" else "kernel"
+    assert _decode_programs(path) == before[path] + 1
+    assert _decode_programs(other) == before[other]
+    assert engine.obs.registry.get(
+        "serving_latent_decode_programs_total").value(
+            path=path) == _decode_programs(path)
+    assert (f'serving_latent_decode_programs_total{{path="{path}"}}'
+            in engine.obs.registry.prometheus())
+
+
+def test_a_dense_models_quantum_counts_no_latent_decode_path(pallas_forced):
+    paddle.seed(0)
+    model = LlamaForCausalLM(LlamaConfig.tiny(tensor_parallel=False))
+    eng = ServingEngine(model, num_slots=2, block_size=8, max_context=32)
+    before = {p: _decode_programs(p) for p in ("xla", "kernel")}
+    step, args = eng.decode_step_target()
+    step.lower(*args)
+    assert before == {p: _decode_programs(p) for p in ("xla", "kernel")}

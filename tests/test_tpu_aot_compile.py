@@ -122,6 +122,35 @@ def test_paged_decode_attention(chip, pool_dtype):
         "paged_decode_attention"}
 
 
+def _pool_sized(text, elements, skip=("get-tuple-element", "bitcast")):
+    """``(opcode, line)`` of every instruction of a computation's text
+    whose result has ``elements`` elements, pass-throughs left out."""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \(?\w+\[([\d,]+)\]\S* ([\w\-]+)\(",
+                     line)
+        if m and math.prod(map(int, m.group(1).split(","))) == elements \
+                and m.group(2) not in skip:
+            found.append((m.group(2), line.strip()))
+    return found
+
+
+def _scattered_and_moved(text, body, elements):
+    """Of a loop body's instructions with a pool-sized result: the
+    scatter fusions (the in-place writes), and every other one, which
+    moves a whole pool a step."""
+    scatters = {c.split(" ", 1)[0] for c in text.split("\n\n")
+                if " scatter(" in c}
+    written, moved = [], []
+    for op, line in _pool_sized(body, elements):
+        if op == "fusion" and re.search(
+                r"calls=(%\S+?),", line).group(1) in scatters:
+            written.append(line)
+        else:
+            moved.append(line.split(", metadata")[0][:200])
+    return written, moved
+
+
 def test_paged_decode_reads_the_pool_as_stored(chip):
     """The decode cell's shapes (Qwen2-7B: 28/4 heads x 128, 16 slots,
     1,153 blocks of 32, table width 16): `_paged_write`'s scatter + the
@@ -158,22 +187,9 @@ def test_paged_decode_reads_the_pool_as_stored(chip):
         shape((slots,), jnp.int32), shape((slots,), jnp.int32),
     ).compile().as_text()
     assert compiled_kernel_names(text) == {"paged_decode_attention"}
-    computations = text.split("\n\n")
-    body = next(c for c in computations if "tpu_custom_call" in c)
-    scatters = {c.split(" ", 1)[0] for c in computations
-                if " scatter(" in c}
-    moved, written = [], 0
-    for line in body.splitlines():
-        m = re.match(r"\s*%\S+ = \(?\w+\[([\d,]+)\]\S* ([\w\-]+)\(", line)
-        if not m or math.prod(map(int, m.group(1).split(","))) != (
-                blocks * bs * hk * D):
-            continue
-        if m.group(2) == "fusion" and re.search(
-                r"calls=(%\S+?),", line).group(1) in scatters:
-            written += 1
-        elif m.group(2) not in ("get-tuple-element", "bitcast"):
-            moved.append(line.split(", metadata")[0].strip()[:200])
-    assert written == 2 and not moved, (written, moved)
+    body = next(c for c in text.split("\n\n") if "tpu_custom_call" in c)
+    written, moved = _scattered_and_moved(text, body, blocks * bs * hk * D)
+    assert len(written) == 2 and not moved, (written, moved)
 
 
 def test_varlen_flash_attention_prefill(chip):
@@ -192,6 +208,90 @@ def test_varlen_flash_attention_prefill(chip):
     assert names == {"varlen_flash_attention_fwd"}
 
 
+def _kanana_attention():
+    """One latent attention layer at kanana-2-30b-a3b's widths (32 heads
+    of 128 + 64 / 128 over a 512-wide latent), bf16: the layer, its
+    configuration and its parameter values."""
+    import paddle_tpu as paddle
+    from paddle_tpu.nlp.deepseek_v3 import (
+        DeepseekV3Attention, DeepseekV3Config)
+
+    paddle.set_default_dtype("bfloat16")
+    try:
+        cfg = DeepseekV3Config.kanana_2_30b_a3b(dtype="bfloat16")
+        attn = DeepseekV3Attention(cfg)
+    finally:
+        paddle.set_default_dtype("float32")
+    return attn, cfg, [p._value for _, p in attn.named_parameters()]
+
+
+def test_latent_paged_decode_reads_the_pool_as_stored(chip):
+    """The latent cell's shapes (kanana-2-30b-a3b: 5,120 blocks x 32 x
+    576 bf16, 32 slots, table 136): the layer's ``paged_decode`` (write,
+    then the kernel) scanned over 8 steps with the pool donated, as
+    `jit_quantum` runs it. The kernel is in the program by name and
+    reads the pool the scatter wrote: no instruction of the loop body
+    but the scatter has a pool-sized result — no copy, transpose or
+    gather of a layer's pool a step. ROUND the scan the compiler still
+    re-lays the pool out, once in and once out: 576 lanes are not whole
+    128-lane tiles, so the device stores the array block-index-minor
+    ({0,2,1}) whatever the number of its axes (PERF.md section 6, PR
+    33)."""
+    import paddle_tpu as paddle
+    from paddle_tpu.core.tensor import Tensor
+    from paddle_tpu.jit import functional_call
+    from paddle_tpu.ops.pallas._utils import compiled_kernel_names
+
+    attn, cfg, p_vals = _kanana_attention()
+    blocks, slots, width, steps = 5120, 32, 136, 8
+    half = cfg.qk_rope_head_dim // 2
+
+    def quantum(pool, p_vals, x, cos, sin, tables, lens, blk, off):
+        def layer(pool, x_t, cos_t, sin_t, lens_t):
+            def fwd(x_in):
+                att, new = attn.paged_decode(
+                    x_in, (cos_t, sin_t), tables, lens_t, blk, off,
+                    (pool, None, None, None))
+                return new[0], att._value
+
+            return functional_call(
+                attn, fwd, [Tensor(x_t, stop_gradient=True)], {}, p_vals,
+                [])[0]
+
+        return jax.lax.scan(lambda pool, xs: layer(pool, *xs), pool,
+                            (x, cos, sin, lens))
+
+    def shape(s, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(s), dt, sharding=chip)
+
+    paddle.set_flags({"FLAGS_pallas_force": True})
+    try:
+        text = jax.jit(quantum, donate_argnums=(0,)).lower(
+            shape((blocks, BLOCK, cfg.latent_dim)),
+            [shape(v.shape, v.dtype) for v in p_vals],
+            shape((steps, slots, 1, cfg.hidden_size)),
+            shape((steps, slots, 1, half), jnp.float32),
+            shape((steps, slots, 1, half), jnp.float32),
+            shape((slots, width), jnp.int32),
+            shape((steps, slots), jnp.int32),
+            shape((slots,), jnp.int32), shape((slots,), jnp.int32),
+        ).compile().as_text()
+    finally:
+        paddle.set_flags({"FLAGS_pallas_force": False})
+    assert "latent_decode_attention" in compiled_kernel_names(text)
+    pool_size = blocks * BLOCK * cfg.latent_dim
+    computations = text.split("\n\n")
+    body = next(c for c in computations
+                if "tpu_custom_call" in c and "latent_decode_attention" in c)
+    written, moved = _scattered_and_moved(text, body, pool_size)
+    assert len(written) == 1 and not moved, (written, moved)
+    entry = next(c for c in computations if c.startswith("ENTRY"))
+    round_it = [op for op, _ in _pool_sized(
+        entry, pool_size, skip=("get-tuple-element", "bitcast", "parameter",
+                                "while", "tuple"))]
+    assert round_it in (["copy"], ["copy", "copy"]), round_it
+
+
 @pytest.mark.parametrize("route", ["kernel", "xla"])
 def test_latent_paged_chunk_leaves_no_score_in_memory(chip, route):
     """The latent cell's ``paged_chunk`` (kanana-2-30b-a3b widths: 32
@@ -201,22 +301,16 @@ def test_latent_paged_chunk_leaves_no_score_in_memory(chip, route):
     VMEM) and no float32 array as large as ONE tile of the XLA loop's
     (S, H, C, 128 keys) scores, which is also its (S, H, C, Dv) carry;
     the same reading of the XLA route finds them, so the check can
-    fail."""
+    fail. The layer's pool is re-laid out once on the way in and once on
+    the way out (the device stores it block-index-minor: see the decode
+    test above) and nowhere else: no copy of it a key tile."""
     import paddle_tpu as paddle
     from paddle_tpu.core.tensor import Tensor
     from paddle_tpu.jit import functional_call
-    from paddle_tpu.nlp.deepseek_v3 import (
-        DeepseekV3Attention, DeepseekV3Config)
     from paddle_tpu.ops.pallas._utils import compiled_kernel_names
 
     slots, chunk, width, blocks = 32, 512, 136, 5120
-    paddle.set_default_dtype("bfloat16")
-    try:
-        cfg = DeepseekV3Config.kanana_2_30b_a3b(dtype="bfloat16")
-        attn = DeepseekV3Attention(cfg)
-    finally:
-        paddle.set_default_dtype("float32")
-    p_vals = [p._value for _, p in attn.named_parameters()]
+    attn, cfg, p_vals = _kanana_attention()
 
     def layer(p_vals, x, cos, sin, tables, base_lens, blk, off, pool):
         def fwd(x_t):
@@ -238,7 +332,7 @@ def test_latent_paged_chunk_leaves_no_score_in_memory(chip, route):
             shape((slots, width), jnp.int32), shape((slots,), jnp.int32),
             shape((slots, chunk), jnp.int32),
             shape((slots, chunk), jnp.int32),
-            shape((blocks, BLOCK, 1, cfg.latent_dim)))
+            shape((blocks, BLOCK, cfg.latent_dim)))
     paddle.set_flags({"FLAGS_pallas_force": route == "kernel"})
     try:
         compiled = jax.jit(layer, donate_argnums=(8,)).lower(
@@ -257,6 +351,9 @@ def test_latent_paged_chunk_leaves_no_score_in_memory(chip, route):
         assert "chunk_attention" in compiled_kernel_names(text)
         assert not big, sorted(set(big))
         assert temp < 3 * tile * 4, temp
+        copies = [line for op, line in _pool_sized(
+            text, blocks * BLOCK * cfg.latent_dim) if op == "copy"]
+        assert len(copies) <= 2, copies
     else:
         assert "chunk_attention" not in compiled_kernel_names(text)
         assert big and temp > 3 * tile * 4, temp
