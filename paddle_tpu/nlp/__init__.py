@@ -23,6 +23,10 @@ from .granitemoehybrid import (  # noqa: F401
     GraniteMoeHybridMoE, GraniteMoeHybridDecoderLayer, GraniteMoeHybridModel,
     GraniteMoeHybridForCausalLM,
 )
+from .afmoe import (  # noqa: F401
+    AfmoeConfig, AfmoeAttention, AfmoeMoE, AfmoeDecoderLayer, AfmoeModel,
+    AfmoeForCausalLM,
+)
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForPretraining,

@@ -43,15 +43,12 @@ import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
-from ..incubate.distributed.models.moe.gate import SigmoidTopKGate
-from ..incubate.distributed.models.moe.moe_layer import grouped_expert_ffn
-from ..nn import functional as F
 from ..nn import initializer as I
 from ..nn.layer.common import Embedding, Linear
 from ..nn.layer.layers import Layer
 from ..nn.layer.norm import RMSNorm
-from ..tensor._helpers import apply
 from . import paged_attention as PA
+from .routed_experts import SigmoidRoutedExperts, SwiGLUMLP
 
 __all__ = ["DeepseekV3Config", "DeepseekV3Attention", "DeepseekV3MLP",
            "DeepseekV3MoE", "DeepseekV3DecoderLayer", "DeepseekV3Model",
@@ -424,103 +421,47 @@ class DeepseekV3Attention(Layer):
         return att, (pool, None, None, None)
 
 
-class DeepseekV3MLP(Layer):
-    """SwiGLU: down(silu(gate(x)) * up(x))."""
-
-    def __init__(self, hidden_size, intermediate_size):
-        super().__init__()
-        self.gate_proj = Linear(hidden_size, intermediate_size,
-                                bias_attr=False)
-        self.up_proj = Linear(hidden_size, intermediate_size,
-                              bias_attr=False)
-        self.down_proj = Linear(intermediate_size, hidden_size,
-                                bias_attr=False)
-
-    def forward(self, x):
-        return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
+# SwiGLU: down(silu(gate(x)) * up(x)); the dense layers' feed-forward and
+# the shared experts'
+DeepseekV3MLP = SwiGLUMLP
 
 
 class DeepseekV3Gate(Layer):
     """The router's parameters: ``weight`` (E_model, experts) and the
     selection bias ``e_score_correction_bias`` (experts,)."""
 
-    def __init__(self, config: DeepseekV3Config):
+    def __init__(self, hidden_size, num_experts):
         super().__init__()
         self.weight = self.create_parameter(
-            (config.hidden_size, config.n_routed_experts),
+            (hidden_size, num_experts),
             default_initializer=I.XavierNormal())
         self.e_score_correction_bias = self.create_parameter(
-            (config.n_routed_experts,), is_bias=True)
+            (num_experts,), is_bias=True)
 
 
-class DeepseekV3Experts(Layer):
-    """The routed experts, stacked: ``gate_up_proj`` (experts, E_model,
-    2 x width) holds each expert's gate then up projection, ``down_proj``
-    (experts, width, E_model)."""
+class DeepseekV3MoE(SigmoidRoutedExperts):
+    """An expert layer's feed-forward (``nlp/routed_experts.py``: routed
+    experts beside shared ones, no capacity) under this source's names:
+    ``gate.weight``, ``gate.e_score_correction_bias``,
+    ``experts.{gate_up_proj,down_proj}``, ``shared_experts``; ``router``
+    is the decision (:class:`SigmoidTopKGate`)."""
 
-    def __init__(self, config: DeepseekV3Config):
-        super().__init__()
-        e, f = config.n_routed_experts, config.moe_intermediate_size
-        self.gate_up_proj = self.create_parameter(
-            (e, config.hidden_size, 2 * f),
-            default_initializer=I.XavierNormal())
-        self.down_proj = self.create_parameter(
-            (e, f, config.hidden_size),
-            default_initializer=I.XavierNormal())
-
-
-def _swiglu(h):
-    g, u = jnp.split(h, 2, axis=-1)
-    return jax.nn.silu(g.astype(jnp.float32)).astype(u.dtype) * u
-
-
-class DeepseekV3MoE(Layer):
-    """An expert layer's feed-forward: routed experts (the sort +
-    ``ragged_dot`` core ``MoELayer`` uses, no capacity: nothing is ever
-    dropped) beside the shared experts. After a forward,
-    ``rows_per_expert`` holds the rows each expert was handed, (experts,)
-    int32, a value of the same trace (as ``MoELayer.l_aux`` is)."""
+    op_name = "deepseek_v3_routed_experts"
 
     def __init__(self, config: DeepseekV3Config):
-        super().__init__()
-        self.num_experts = config.n_routed_experts
-        self.router = SigmoidTopKGate(
-            config.num_experts_per_tok, config.norm_topk_prob,
-            config.routed_scaling_factor, config.n_group,
-            config.topk_group)
-        self.gate = DeepseekV3Gate(config)
-        self.experts = DeepseekV3Experts(config)
-        self.shared_experts = DeepseekV3MLP(
-            config.hidden_size,
-            config.n_shared_experts * config.moe_intermediate_size)
-        self.rows_per_expert = None
+        super().__init__(
+            config.hidden_size, config.moe_intermediate_size,
+            config.n_routed_experts, config.num_experts_per_tok,
+            config.n_shared_experts * config.moe_intermediate_size,
+            config.norm_topk_prob, config.routed_scaling_factor,
+            config.n_group, config.topk_group)
+        self.router = self.decision
 
-    def inactive_params_per_token(self):
-        """Routed-expert weights a token does NOT multiply: all but its
-        top k experts' (what a 2N operations count must leave out)."""
-        per_expert = (self.experts.gate_up_proj._value.size
-                      + self.experts.down_proj._value.size
-                      ) // self.num_experts
-        return (self.num_experts - self.router.top_k) * per_expert
+    def _build_router(self, hidden_size, num_experts):
+        self.gate = DeepseekV3Gate(hidden_size, num_experts)
 
-    def _routed(self, xv, gw, gb, w1, w2):
-        xt = xv.reshape(-1, xv.shape[-1])
-        with jax.named_scope("moe.router"):
-            logits = jnp.matmul(xt.astype(jnp.float32),
-                                gw.astype(jnp.float32))
-            topi, weights, _ = self.router.topk_assignments(logits, gb)
-        with jax.named_scope("moe.experts"):
-            y, rows = grouped_expert_ffn(xt, topi, weights, w1, w2, _swiglu)
-        return y.reshape(xv.shape), rows
-
-    def forward(self, x):
-        routed, rows = apply(
-            self._routed, x, self.gate.weight,
-            self.gate.e_score_correction_bias, self.experts.gate_up_proj,
-            self.experts.down_proj, op_name="deepseek_v3_routed_experts")
-        self.rows_per_expert = rows._value
-        with jax.named_scope("moe.shared"):
-            return routed + self.shared_experts(x)
+    def _router_leaves(self):
+        return self.gate.weight, self.gate.e_score_correction_bias
 
 
 class DeepseekV3DecoderLayer(PA.PagedResidualLayer, Layer):
@@ -588,6 +529,10 @@ class DeepseekV3ForCausalLM(Layer):
     def paged_cache_layout(self):
         """One array a layer, one row a token: ``[c | rope(k_rope)]``,
         shared by every head."""
+        if self.config.sliding_window:
+            raise NotImplementedError(
+                "DeepseekV3: sliding_window over the latent pool is not "
+                "implemented")
         return {"layout": "latent", "num_kv_heads": 1,
                 "head_dim": self.config.latent_dim,
                 "layers": ("latent",) * self.config.num_hidden_layers}

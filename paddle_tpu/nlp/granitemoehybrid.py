@@ -654,6 +654,9 @@ class GraniteMoeHybridForCausalLM(Layer):
         (``"kv"``), a state-space layer a row of the pool's slot side
         (``"state"``: the arrays of ``state``, per slot)."""
         cfg = self.config
+        if cfg.sliding_window:
+            raise NotImplementedError(
+                "GraniteMoeHybrid: sliding_window is not implemented")
         mamba = next((layer.mamba for layer in self.model.layers
                       if layer.kind == "mamba"), None)
         return {"layout": "kv", "num_kv_heads": cfg.num_key_value_heads,

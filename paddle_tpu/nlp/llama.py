@@ -684,8 +684,16 @@ class LlamaForCausalLM(Layer):
     def paged_cache_layout(self):
         """The pool geometry this model's attention caches: a K and a V
         array a layer, each row ``num_key_value_heads x head_dim``;
-        ``layers`` says it of every layer (``"kv"``: block arrays)."""
+        ``layers`` says it of every layer (``"kv"``: block arrays). A
+        ``sliding_window`` is refused here: what a model's layers cannot
+        serve, the model says."""
         cfg = self.config
+        if cfg.sliding_window:
+            raise NotImplementedError(
+                "LlamaForCausalLM cannot be served with sliding_window: "
+                "one uniform window over the K/V block path is not built "
+                "(its paged attention has no lower bound, and blocks that "
+                "left the window are never released)")
         return {"layout": "kv", "num_kv_heads": cfg.num_key_value_heads,
                 "head_dim": cfg.head_dim,
                 "layers": ("kv",) * cfg.num_hidden_layers}

@@ -146,6 +146,159 @@ def _paged_chunk_attn(q, kp, vp, tables, base_lens, ks=None, vs=None,
         q.dtype)
 
 
+# -- a window layer's keys: a per-slot RING on the pool's slot side ----------
+# A layer that attends only the last W positions keeps, per slot, a K and a V
+# array (slots, R, HK x D): position p of the request in slot s lives at row
+# p mod R, its KV heads side by side. R >= W + the longest chunk
+# (`ring_tokens`), so a chunk may write all its positions before it attends
+# and no key a valid query needs is overwritten. What a row may see is
+# decided by positions alone, so a reused slot needs no zeroing and nothing
+# is reset from the host.
+#
+# Why the heads lie side by side in ONE minor axis: a scatter wants the row
+# it writes minor, a product batched over KV heads wants the head axis
+# outside the rows, and with a head axis of its own, on either side of the
+# rows, the TPU compiler copied every ring whole into the other order and
+# back, each layer and decode step (PERF.md section 6, PR 35). The decode
+# product therefore contracts over ALL of a row (`_per_kv_head`): a plain
+# batched matrix product over the ring as it is stored.
+
+
+def ring_tokens(window, prefill_chunk, block_size):
+    """Rows of a window layer's ring: the ``window - 1`` keys before a
+    chunk and the chunk's own ``prefill_chunk``, in whole blocks."""
+    return -(-(int(window) + int(prefill_chunk)) // int(block_size)) \
+        * int(block_size)
+
+
+def ring_write(ring_k, ring_v, k, v, pos, ok):
+    """Write rows ``k`` / ``v`` (S, C, HK, D) of positions ``pos`` (S, C)
+    into each slot's ring at ``pos mod R``; a position whose ``ok`` is
+    false (past a row's count, a row that is not live, an idle slot)
+    writes nothing: its index lies outside the ring and the scatter drops
+    it."""
+    r = ring_k.shape[1]
+    row = jnp.where(ok, pos % r, r)
+    slot = jnp.arange(ring_k.shape[0])[:, None]
+    flat = (*k.shape[:2], ring_k.shape[2])
+    return (ring_k.at[slot, row].set(
+                k.reshape(flat).astype(ring_k.dtype), mode="drop"),
+            ring_v.at[slot, row].set(
+                v.reshape(flat).astype(ring_v.dtype), mode="drop"))
+
+
+def _ring_positions(top, r, lo=0, n=None):
+    """The position each ring row ``lo .. lo + n - 1`` holds when the last
+    position written is ``top`` (S,): the largest ``p <= top`` with ``p mod
+    R == row``; negative where the request has not come that far (the row
+    then holds what an earlier request left, which no one may see)."""
+    rows = lo + jnp.arange(r if n is None else n)
+    return top[:, None] - (top[:, None] - rows[None, :]) % r
+
+
+def _per_kv_head(q, hk):
+    """q (S, H, D), query heads grouped over ``hk`` KV heads -> (S, H, HK x
+    D): each query head's values in its own KV head's place of a ring row
+    and zeros in the others', so that a product over the whole row is the
+    head's own score."""
+    s_, h, d = q.shape
+    own = jnp.eye(hk, dtype=q.dtype)[:, None, :, None]       # (HK,1,HK,1)
+    return (q.reshape(s_, hk, h // hk, 1, d) * own).reshape(s_, h, hk * d)
+
+
+def ring_decode_attn(q, ring_k, ring_v, lens, window, scale=None):
+    """One query a slot over its ring: the row of length ``lens`` (with
+    this token, already written) attends positions ``lens - window <= s <
+    lens``. q (S, H, D). Plain XLA over the whole ring as it is stored,
+    with a mask by position; the (S, H, R) float32 scores are small. The
+    products run over whole ring rows (HK x the operations a head needs,
+    which one query a slot makes cheap) so that no head axis has to be
+    brought outside the rows."""
+    s_, h, d = q.shape
+    r, hk = ring_k.shape[1], ring_k.shape[2] // d
+    sc = 1.0 / math.sqrt(d) if scale is None else scale
+    pos = _ring_positions(lens - 1, r)                       # (S, R)
+    seen = (pos >= 0) & (pos > lens[:, None] - 1 - window)
+    ct = jnp.promote_types(q.dtype, ring_k.dtype)
+    logits = jnp.einsum("bhc,bkc->bhk", _per_kv_head(q, hk).astype(ct),
+                        ring_k.astype(ct),
+                        preferred_element_type=jnp.float32) * sc
+    logits = jnp.where(seen[:, None], logits, jnp.float32(-1e30))
+    p = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bhk,bkc->bhc", p.astype(ct), ring_v.astype(ct),
+                     preferred_element_type=jnp.float32)     # (S, H, HK x D)
+    # each query head keeps its own KV head's D values
+    out = out.reshape(s_, hk, h // hk, hk, d)
+    own = jnp.arange(hk)[None, :, None, None, None]
+    out = jnp.take_along_axis(out, own, axis=3)[:, :, :, 0]
+    return out.reshape(s_, h, d).astype(q.dtype)
+
+
+def ring_chunk_attn(q, ring_k, ring_v, base_lens, counts, window,
+                    scale=None):
+    """C queries a slot over its ring, the chunk's own positions already
+    written (`ring_write`): query j of a row with base ``b`` attends
+    positions ``b + j - window < s <= b + j``. q (S, C, H, D); ``counts``
+    (S,) each row's valid positions (the ring holds positions up to ``b +
+    counts - 1``; a query past the count reads nothing anyone keeps).
+
+    The same fold as :func:`_paged_chunk_attn`: the (S, H, C, keys) float32
+    scores are built for ``_CHUNK_SCORE_BYTES`` worth of ring rows at a
+    time and folded into running (m, l, acc); query heads are grouped over
+    their KV head and operands keep the ring's dtype. A tile of rows is
+    given its head axis as it is sliced out (a tile-sized copy)."""
+    s_, c, h, d = q.shape
+    r, hk = ring_k.shape[1], ring_k.shape[2] // d
+    g = h // hk
+    sc = 1.0 / math.sqrt(d) if scale is None else scale
+    tile = max(1, min(r, _CHUNK_SCORE_BYTES // (s_ * h * c * 4)))
+    while r % tile:          # whole tiles of ring rows
+        tile -= 1
+    n_tiles = r // tile
+    qpos = base_lens[:, None] + jnp.arange(c)[None, :]       # (S, C)
+    top = base_lens + counts - 1
+    neg = jnp.float32(-1e30)
+    qg = q.reshape(s_, c, hk, g, d)
+    ct = jnp.promote_types(q.dtype, ring_k.dtype)
+
+    def fold(carry, ti):
+        m, l, acc = carry
+        k = jax.lax.dynamic_slice_in_dim(ring_k, ti * tile, tile, 1)
+        v = jax.lax.dynamic_slice_in_dim(ring_v, ti * tile, tile, 1)
+        logits = jnp.einsum(
+            "bchgd,bkhd->bhgck", qg.astype(ct),
+            k.reshape(s_, tile, hk, d).astype(ct),
+            preferred_element_type=jnp.float32) * sc         # (S,HK,G,C,K)
+        kpos = _ring_positions(top, r, ti * tile, tile)[:, None, :]
+        seen = ((kpos >= 0) & (kpos <= qpos[:, :, None])
+                & (kpos > qpos[:, :, None] - window))        # (S, C, K)
+        logits = jnp.where(seen[:, None, None], logits, neg)
+        m2 = jnp.maximum(m, jnp.max(logits, axis=-1))
+        alpha = jnp.exp(m - m2)                              # (S, HK, G, C)
+        # a tile may hold no key a query sees (m still at its init): its
+        # exp(neg - neg) must not count
+        p = jnp.where(seen[:, None, None], jnp.exp(logits - m2[..., None]),
+                      0.0)
+        l2 = l * alpha + jnp.sum(p, axis=-1)
+        acc2 = acc * alpha[..., None] + jnp.einsum(
+            "bhgck,bkhd->bhgcd", p.astype(ct),
+            v.reshape(s_, tile, hk, d).astype(ct),
+            preferred_element_type=jnp.float32)
+        return (m2, l2, acc2), None
+
+    carry = (jnp.full((s_, hk, g, c), neg, jnp.float32),
+             jnp.zeros((s_, hk, g, c), jnp.float32),
+             jnp.zeros((s_, hk, g, c, d), jnp.float32))
+    if n_tiles == 1:
+        carry, _ = fold(carry, 0)
+    else:
+        carry, _ = jax.lax.scan(fold, carry, jnp.arange(n_tiles))
+    _, l, acc = carry
+    out = acc / jnp.maximum(l, 1e-30)[..., None]             # (S,HK,G,C,D)
+    return out.transpose(0, 3, 1, 2, 4).reshape(s_, c, h, d).astype(
+        q.dtype)
+
+
 class PagedResidualLayer:
     """The serving engine's LAYER protocol (``serving/engine.py``) for a
     pre-norm residual decoder layer with ``input_layernorm``,

@@ -121,12 +121,19 @@ class PagedKVCachePool:
             ``commit_like``, copy-on-write, the accounting and the
             donation audit hold for both. A model says which it caches
             (``model.paged_cache_layout()``).
-        state: the SLOT side, for a model with recurrent (state-space)
-            layers: ``{"slots": S, "layers": n, "arrays": [(shape,
-            dtype), ...]}`` gives ``n`` state layers, each a tuple of
-            arrays ``(S, *shape)`` whose row ``s`` is what the request in
-            slot ``s`` carries from token to token, whatever its context
-            (``dtype`` None: the pool's ``dtype``). ``num_layers`` then
+        state: the SLOT side, for a model with layers whose cache does
+            not grow with the context (a state-space layer's recurrence,
+            a window-attention layer's ring of keys): ``{"slots": S,
+            "layers": n, "arrays": [(shape, dtype), ...]}`` gives ``n``
+            state layers, each a tuple of arrays ``(S, *shape)`` whose
+            row ``s`` is what the request in slot ``s`` carries from
+            token to token, whatever its context (``dtype`` None: the
+            pool's ``dtype``). A None in a ``shape`` is a RING's length,
+            ``"ring_tokens"`` of the same dict (what the engine works out
+            from the model's window, its ``prefill_chunk`` and
+            ``block_size``): that array holds a window layer's last
+            positions, position ``p`` at row ``p mod ring_tokens``.
+            ``num_layers`` then
             counts only the layers that hold block arrays. The side is
             EMPTY for a model without such layers (zero avals, as the V
             side of a latent pool), and is donated, adopted, committed
@@ -174,9 +181,11 @@ class PagedKVCachePool:
         if state and (kv_dtype is not None or mesh is not None
                       or prefix_cache):
             raise NotImplementedError(
-                "a pool with slot state does not compose with "
+                "a pool with slot state (a state-space layer's "
+                "recurrence, a window layer's ring) does not compose with "
                 "kv_dtype='int8', a mesh or the prefix cache: a cached "
-                "block says nothing of the state its prefix left")
+                "block says nothing of the state or the ring its prefix "
+                "left")
         self.layout = layout
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
@@ -238,10 +247,15 @@ class PagedKVCachePool:
         else:
             self.k_scales = []
             self.v_scales = []
-        # the slot side: per state layer a tuple of (slots, ...) arrays
+        # the slot side: per state layer a tuple of (slots, ...) arrays;
+        # a None in a shape is the ring's length
+        self.ring_tokens = int(state.get("ring_tokens", 0)) if state else 0
+        self._ring_arrays = tuple(
+            None in shape for shape, _ in state["arrays"]) if state else ()
         self.state = tuple(
-            tuple(jnp.zeros((int(state["slots"]), *shape), dt or dtype)
-                  for shape, dt in state["arrays"])
+            tuple(jnp.zeros((int(state["slots"]), *(
+                self.ring_tokens if n is None else n for n in shape)),
+                dt or dtype) for shape, dt in state["arrays"])
             for _ in range(int(state["layers"]))) if state else ()
         self._free = list(range(self.num_blocks - 1, -1, -1))
         self._tables: dict = {}   # seq_id -> list[int] block ids
@@ -836,6 +850,8 @@ class PagedKVCachePool:
             "bytes_per_token": self.bytes_per_token(),
             "state_bytes_per_slot": self.state_bytes_per_slot(),
             "state_slots": self.state_slots,
+            "window_bytes_per_slot": self.window_bytes_per_slot(),
+            "window_ring_tokens": self.ring_tokens,
             "bytes_in_use": self.bytes_in_use(),
             "per_chip_bytes_in_use": self.per_chip_bytes_in_use(),
         }
@@ -886,6 +902,13 @@ class PagedKVCachePool:
         whatever its context (0 without state layers)."""
         return sum(a.dtype.itemsize * int(np.prod(a.shape[1:]))
                    for layer in self.state for a in layer)
+
+    def window_bytes_per_slot(self):
+        """The part of ``state_bytes_per_slot`` that is window layers'
+        rings (0 without such layers)."""
+        return sum(a.dtype.itemsize * int(np.prod(a.shape[1:]))
+                   for layer in self.state
+                   for a, ring in zip(layer, self._ring_arrays) if ring)
 
     def _state_rows_in_use(self):
         """Sequences that hold a slot's state: every one that holds

@@ -83,6 +83,8 @@ import time
 from collections import deque
 from collections.abc import MutableMapping
 
+import numpy as np
+
 from .attribution import CostLedger
 from .registry import LATENCY_BUCKETS, MetricsRegistry
 from .trace import TraceRecorder
@@ -191,6 +193,19 @@ class ServingObs:
             "serving_state_bytes_per_slot",
             "bytes of recurrent state one slot holds over all state "
             "layers, whatever its context"))
+        self._g_window_slot_bytes = r.share(MetricsRegistry.process().gauge(
+            "serving_window_bytes_per_slot",
+            "bytes of window-attention rings one slot holds over all "
+            "window layers, whatever its context"))
+        # keys a window layer / a full layer attended, per live row and
+        # position, from the host's length mirrors (min(len, W) / len);
+        # both zero for a model without window layers
+        self._c_keys = {
+            kind: r.counter(
+                f"serving_{kind}_keys_attended_total",
+                f"keys one {kind}-attention layer attended, over live "
+                "rows and positions")
+            for kind in ("window", "full")}
         # counted where a program is traced (the routes of the latent
         # chunk and decode attention are static per program); shown here
         from ..nlp.paged_attention import (
@@ -603,8 +618,30 @@ class ServingObs:
         """Published once at engine build (0 without state layers)."""
         self._g_state_slot_bytes.set(float(nbytes), pool=pool)
 
+    def set_window_bytes_per_slot(self, nbytes, pool="target"):
+        """Published once at engine build (0 without window layers)."""
+        self._g_window_slot_bytes.set(float(nbytes), pool=pool)
+
     def on_state_reset(self):
         self._c_state_resets.inc(1)
+
+    def on_keys_attended(self, before, after, window):
+        """Live rows went from lengths ``before`` to ``after`` (int
+        arrays): each new position ``p`` attended ``p + 1`` keys in a full
+        layer and ``min(p + 1, window)`` in a window layer. Returns what
+        the counters were raised by, which the step's span carries too
+        (``window_keys``, ``full_keys``)."""
+        def keys(n, w):     # sum of min(L, w) for L = 1 .. n
+            m = np.minimum(n, w)
+            return m * (m + 1) // 2 + (n - m) * w
+
+        before, after = (np.asarray(a, np.int64) for a in (before, after))
+        by = {"full": int((keys(after, after) - keys(before, before)).sum()),
+              "window": int((keys(after, window)
+                             - keys(before, window)).sum())}
+        for kind, n in by.items():
+            self._c_keys[kind].inc(n)
+        return {"window_keys": by["window"], "full_keys": by["full"]}
 
     def on_mixed_dispatch(self, bucket, padded_tokens, built):
         """One mixed step is about to dispatch the program of chunk

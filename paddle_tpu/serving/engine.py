@@ -42,8 +42,13 @@ head call, and know nothing of what a layer computes. Of the MODEL they ask
   ``"kv"``: K and V rows; ``"latent"``: ONE row a token) and, per layer,
   WHAT IT CACHES (``layers``: ``"kv"`` | ``"latent"``: block arrays, in
   the order of such layers; ``"state"``: a row of the pool's SLOT side,
-  whose per-slot arrays ``state`` lists as ``(shape, dtype)``). A model
-  whose layers all cache blocks has an empty slot side: no aval.
+  whose per-slot arrays ``state`` lists as ``(shape, dtype)``; a None in a
+  shape is the length of a window layer's RING of keys, ``window`` (the
+  layout's, 0 or absent without such layers) plus ``prefill_chunk`` in
+  whole blocks). A model whose layers all cache blocks has an empty slot
+  side: no aval. What a model's layers cannot serve (one uniform window
+  over the block path) the layout refuses, by raising; the engine reads no
+  attribute of a model's config to decide it.
 
 Of each LAYER they ask ``paged_decode(hidden, step, cache)`` and
 ``paged_chunk(hidden, step, cache)``: given the hidden stream, the step's
@@ -61,7 +66,9 @@ and recompute-on-resume rebuilds the state from ``prompt + tokens``.
 ``nlp/paged_attention.PagedResidualLayer`` is the pre-norm residual
 layer over K/V or latent attention (``nlp/llama.py``,
 ``nlp/deepseek_v3.py``: ``self_attn.paged_decode`` / ``paged_chunk``);
-``nlp/granitemoehybrid.py`` has state-space layers beside attention. A
+``nlp/granitemoehybrid.py`` has state-space layers beside attention,
+``nlp/afmoe.py`` window layers (rings: position ``p`` at row ``p mod R``,
+what a row may see decided by positions alone) beside full ones. A
 feed-forward that routes rows to experts shows ``rows_per_expert``; both
 programs hand back the rows the experts HELD here got beside the tokens
 (``moe_rows``; an empty tuple, no aval, without experts).
@@ -138,6 +145,7 @@ from ..core.tensor import Tensor
 from ..core import autograd
 from ..jit import functional_call
 from ..nlp.generation import _filter_logits
+from ..nlp.paged_attention import ring_tokens
 from ..nlp.paged_cache import PagedKVCachePool
 from ..nn.quant import quantize_for_serving
 from ..obs.flight import FlightRecorder
@@ -639,11 +647,6 @@ class ServingEngine:
                  faults=None, resilience=None, quantize=None,
                  kv_dtype=None, multi_quantum=1):
         cfg = model.config
-        if getattr(cfg, "sliding_window", None):
-            raise NotImplementedError(
-                "ServingEngine does not compose with sliding_window: a "
-                "rolling buffer wrap-writes over pool slots the block "
-                "tables still map")
         if decode_strategy not in ("greedy", "sampling"):
             raise ValueError(
                 f"decode_strategy must be greedy|sampling, got "
@@ -656,13 +659,15 @@ class ServingEngine:
         layout = model.paged_cache_layout()
         kinds = layout["layers"]
         # nothing is silently ignored: what a latent pool, latent
-        # attention or a slot's recurrent state cannot do yet is refused
-        # by name
+        # attention, a slot's recurrent state or a window layer's ring
+        # cannot do yet is refused by name (what can be served at all is
+        # the layout's to say: a model whose layers cannot raises there)
         refused = {"kv_dtype='int8'": kv_dtype == "int8",
                    "tp > 1": self.tp > 1,
                    "spec_draft": spec_draft is not None}
+        self._window = int(layout.get("window", 0))
         if "state" in kinds:
-            what = "a state-space"
+            what = "a window-ring" if self._window else "a state-space"
             refused["prefix_cache=True"] = bool(prefix_cache)
         elif "latent" in kinds:
             what = "a latent-attention"
@@ -676,21 +681,15 @@ class ServingEngine:
         if spec_draft is not None and set(
                 spec_draft.paged_cache_layout()["layers"]) != {"kv"}:
             raise NotImplementedError(
-                "ServingEngine does not take a latent-attention or "
-                f"state-space model ({type(spec_draft).__name__}) as "
-                "spec_draft yet")
+                "ServingEngine does not take a latent-attention, "
+                "state-space or window-ring model "
+                f"({type(spec_draft).__name__}) as spec_draft yet")
         if self.tp > 1:
             _check_tp_divisible(cfg, self.tp, "target")
             if spec_draft is not None:
                 _check_tp_divisible(spec_draft.config, self.tp, "draft")
         if spec_draft is not None:
             d_cfg = spec_draft.config
-            if getattr(d_cfg, "sliding_window", None):
-                raise NotImplementedError(
-                    "speculative serving with a sliding-window draft is "
-                    "not supported: rollback-by-length-mask cannot "
-                    "restore rolling-buffer slots rejected proposals "
-                    "wrapped over")
             if d_cfg.vocab_size != cfg.vocab_size:
                 raise ValueError(
                     f"draft vocab {d_cfg.vocab_size} != target vocab "
@@ -750,8 +749,10 @@ class ServingEngine:
         if num_blocks is None:
             num_blocks = s * w + 1  # +1: the masked-write scratch block
         self.prefix_cache = bool(prefix_cache)
-        # block arrays for the layers that cache keys, a slot side for
-        # the layers that carry a state (none: an empty side, no aval)
+        # block arrays for the layers that cache every key, a slot side
+        # for the layers that carry a state or a window's ring of keys
+        # (none: an empty side, no aval); a ring holds the window and one
+        # chunk, in whole blocks
         n_state = kinds.count("state")
         n_block = len(kinds) - n_state
         self.pool = PagedKVCachePool(
@@ -760,7 +761,10 @@ class ServingEngine:
             prefix_cache=self.prefix_cache, mesh=self.mesh,
             kv_dtype=kv_dtype, layout=layout["layout"],
             state={"slots": s, "layers": n_state,
-                   "arrays": layout["state"]} if n_state else None)
+                   "arrays": layout["state"],
+                   "ring_tokens": ring_tokens(
+                       self._window, self.config.prefill_chunk, bs)
+                   if self._window else 0} if n_state else None)
         self.pool.commit_like(self._p_vals[0])
         # masked (retired/empty) rows dump their KV writes here
         self._scratch_block = self.pool.ensure("__scratch__", 1)[0]
@@ -929,6 +933,8 @@ class ServingEngine:
         self.obs.set_quantum_collectives(self.quantum_collectives)
         self.obs.set_pool_bytes_per_token(self.pool.bytes_per_token())
         self.obs.set_state_bytes_per_slot(self.pool.state_bytes_per_slot())
+        self.obs.set_window_bytes_per_slot(
+            self.pool.window_bytes_per_slot())
         # cost-ledger MFU constants (obs/attribution.py): target-model
         # FLOPs per decoded token (2N weight-matmul floor, embedding
         # gathers excluded) and the chip peak (0.0 on the CPU backend —
@@ -1822,6 +1828,12 @@ class ServingEngine:
                     span.args["moe_offshare_rows"] = (
                         got.shape[0] * self.config.num_slots * bucket
                         * self._moe_top_k - int(got.sum()))
+                if self._window:
+                    base = self._seq_lens[[r.slot for r in pre + dec]]
+                    span.args.update(self.obs.on_keys_attended(
+                        base, base + np.asarray(
+                            chunk_lens + [1] * len(dec), base.dtype),
+                        self._window))
             now = self._now()  # the stamp of every token of the step
             with RecordEvent("engine.mixed.emit"):
                 prefill_emitted = 0
@@ -2294,6 +2306,7 @@ class ServingEngine:
             t_steps = self.config.decode_quantum
             with RecordEvent("engine.decode.sync") as sync:
                 toks = np.asarray(toks)                      # sync
+                before = self._seq_lens
                 self._seq_lens = np.asarray(seq_lens).copy()
                 self._last_tok = np.asarray(last_tok).copy()
                 self._n_gen = np.asarray(n_gen).copy()
@@ -2307,6 +2320,10 @@ class ServingEngine:
                     step.args.update(self.obs.on_moe_rows(
                         moe.reshape(-1, *moe.shape[-2:]),
                         self.config.num_slots * self._moe_top_k))
+                if self._window:
+                    at = [r.slot for r in rows]
+                    step.args.update(self.obs.on_keys_attended(
+                        before[at], self._seq_lens[at], self._window))
             # the device's share of the wall: from the jitted call's return
             # (the enqueue span's end) to the sync span's end
             now = sync.t1

@@ -357,3 +357,112 @@ def test_latent_paged_chunk_leaves_no_score_in_memory(chip, route):
     else:
         assert "chunk_attention" not in compiled_kernel_names(text)
         assert big and temp > 3 * tile * 4, temp
+
+
+def _layouts(text, elements, rows):
+    """``(opcode, minor-to-major order)`` of every instruction of a compiled
+    program whose result has ``elements`` elements in rows of one of the
+    widths ``rows`` (a weight may have as many elements as a ring: the
+    dense feed-forward's 2048 x 6144 has), pass-throughs left out."""
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \(?\w+\[([\d,]+)\]\{([\d,]+)\S* "
+                     r"([\w\-]+)\(", line)
+        if not m or m.group(3) in ("get-tuple-element", "bitcast",
+                                   "parameter"):
+            continue
+        dims = list(map(int, m.group(1).split(",")))
+        if math.prod(dims) == elements and dims[-1] in rows:
+            found.append((m.group(3), m.group(2)))
+    return found
+
+
+def test_the_window_cells_programs_move_no_ring_and_no_pool(chip,
+                                                            monkeypatch):
+    """``trinity-mini.doc16k-o128``'s REAL ``jit_quantum`` and ``jit_mixed``
+    (the family's model from the cell's configuration, 4.24 B parameters
+    as zeros; the engine with the cell's options; a batch of 8 x 16,384
+    admitted) compiled for the described v5e. A window layer's ring
+    ``(8, 3072, 512)`` and the full layer's block arrays ``(4224, 32, 4,
+    128)`` are written by scatters in place and read as they are stored:
+    no instruction of either program, in any loop body or outside, has a
+    ring-sized or pool-sized result in another order than the stored one
+    (no ``copy`` or ``transpose`` of a whole ring or pool a layer and
+    step; stored with a head axis of its own on either side of the rows a
+    ring was copied whole 8 to 24 times a decode step: PERF.md section 6,
+    PR 35); what the compiler itself moves is a prefetch in the stored
+    order. The programs fit the chip beside their arguments, and the full
+    layer's decode attention is the dense cell's kernel."""
+    import json
+
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.nn import initializer as I
+    from paddle_tpu.ops.pallas._utils import compiled_kernel_names
+    from paddle_tpu.serving import ServingEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from benchmark.families import afmoe as family
+    from benchmark.harness import counts_afmoe as counts
+
+    class Zeros(I.Constant):        # 8.5 GB of weights nobody reads
+        def __init__(self, *a, **k):
+            super().__init__(0.0)
+
+    for name in ("XavierNormal", "XavierUniform", "Normal"):
+        monkeypatch.setattr(I, name, Zeros)
+    with open(os.path.join(root, "benchmark", "configs",
+                           "trinity-mini-l5.json")) as f:
+        cfg = json.load(f)
+    dtype_was = paddle.get_default_dtype()
+    paddle.set_flags({"FLAGS_pallas_force": True})
+    try:
+        model = family.build_model(cfg)
+        model.eval()
+        eng = ServingEngine(model, **cfg["engine"])
+        for _ in range(8):
+            eng.submit(np.ones(16384, np.int32), max_new_tokens=128)
+        eng._admit()
+
+        def shapes(args):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                               sharding=chip), args)
+
+        compiled = {}
+        for name, (step, args) in (("quantum", eng.decode_step_target()),
+                                   ("mixed", eng.mixed_step_target())):
+            compiled[name] = step.lower(*shapes(args)).compile()
+    finally:
+        paddle.set_flags({"FLAGS_pallas_force": False})
+        paddle.set_default_dtype(dtype_was)
+    n_params = sum(int(p._value.size) for _, p in model.named_parameters())
+    assert n_params == counts.total_params(cfg) == 4_241_534_720
+    ring = 8 * 3072 * 512
+    pool = 4224 * 32 * 4 * 128
+    resident = 2 * n_params + 2 * 2 * pool + 8 * 25_165_824
+    for name, program in compiled.items():
+        text = program.as_text()
+        mem = program.memory_analysis()
+        assert 0 <= mem.argument_size_in_bytes - resident < 1 << 20, name
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < 12e9, (name, mem.temp_size_in_bytes)
+        rings = _layouts(text, ring, (512,))
+        pools = _layouts(text, pool, (128, 512))
+        # every window layer's K and V ring and the full layer's K and V
+        # arrays are scattered into, in the stored (row-major) order
+        assert [op for op, _ in rings].count("scatter") == 8, rings
+        assert [op for op, _ in pools].count("scatter") == 2, pools
+        # row-major as stored (a scatter may see its array with the
+        # leading axes folded, (slots x rows, 512): the same bytes)
+        for _, order in rings + pools:
+            n = order.count(",") + 1
+            assert order == ",".join(map(str, reversed(range(n)))), (
+                name, rings, pools)
+        moved = {op for op, _ in rings + pools} & {"copy", "transpose"}
+        assert not moved, (name, moved)
+    assert "paged_decode_attention" in compiled_kernel_names(
+        compiled["quantum"].as_text())
+    assert compiled["quantum"].memory_analysis().temp_size_in_bytes < 1 << 28
