@@ -63,7 +63,7 @@ def _xla_paged_decode_attn(q, kp, vp, tables, lens, ks=None, vs=None,
     return out.astype(q.dtype)
 
 
-# the f32 score tile of `_paged_chunk_attn` may take this many bytes; a
+# the f32 score tile of `_xla_paged_chunk_attn` may take this many bytes; a
 # chunk whose scores over its whole block table would take more streams
 # over the table in tiles of key blocks (a shape rule: no knob)
 _CHUNK_SCORE_BYTES = 256 << 20
@@ -75,7 +75,31 @@ def _paged_chunk_attn(q, kp, vp, tables, base_lens, ks=None, vs=None,
     VERIFY pass and the mixed prefill step: query position j of each
     slot attends pool positions < base+j+1 (the row's cached context
     and the chunk's own positions up to j, which the caller has already
-    written). q is (S, C, H, D).
+    written). q is (S, C, H, D); ``scale`` multiplies the scores
+    (default ``1 / sqrt(D)``).
+
+    The route: the Pallas kernel (``ops/pallas/chunk_attention.py``:
+    the score tile and the running statistics stay in VMEM) where
+    :func:`use_pallas_kernels` holds, the pool is a float pool and the
+    head width is whole lanes; the XLA fold
+    (:func:`_xla_paged_chunk_attn`, the reference the parity tests
+    compare with) elsewhere — the CPU, the int8 engine's per-row scale
+    pools (``ks`` / ``vs``). Which one a traced program took is
+    ``serving_chunk_attention_programs_total{path}``."""
+    from ..ops.pallas import chunk_attention as kernel
+
+    if ks is None and use_pallas_kernels() and kernel.supports_gqa(q, kp):
+        count_chunk_attention_program("kernel")
+        return kernel.paged_chunk_attention(q, kp, vp, tables, base_lens,
+                                            scale)
+    count_chunk_attention_program("xla")
+    return _xla_paged_chunk_attn(q, kp, vp, tables, base_lens, ks=ks,
+                                 vs=vs, scale=scale)
+
+
+def _xla_paged_chunk_attn(q, kp, vp, tables, base_lens, ks=None, vs=None,
+                          scale=None):
+    """:func:`_paged_chunk_attn` in plain XLA, on every backend.
 
     The query heads are grouped over their KV head (K and V are never
     repeated), operands keep the pool's dtype with f32 accumulation,
@@ -86,9 +110,7 @@ def _paged_chunk_attn(q, kp, vp, tables, base_lens, ks=None, vs=None,
     dimension); a table whose scores fit that size is one tile and no
     loop. ``ks``/``vs`` are the
     int8 pool's per-row scale pools: a tile dequantizes in f32 as it
-    streams through. ``scale`` multiplies the scores (default
-    ``1 / sqrt(D)``). No Pallas analog yet: this runs on every
-    backend."""
+    streams through."""
     s_, c, h, d = q.shape
     w = tables.shape[1]
     bs, hk = kp.shape[1], kp.shape[2]
@@ -242,7 +264,25 @@ def ring_chunk_attn(q, ring_k, ring_v, base_lens, counts, window,
     (S,) each row's valid positions (the ring holds positions up to ``b +
     counts - 1``; a query past the count reads nothing anyone keeps).
 
-    The same fold as :func:`_paged_chunk_attn`: the (S, H, C, keys) float32
+    The route is :func:`_paged_chunk_attn`'s: the same Pallas kernel with
+    ``window`` as its lower bound, over the ring as it is stored, or the
+    XLA fold :func:`_xla_ring_chunk_attn`."""
+    from ..ops.pallas import chunk_attention as kernel
+
+    if use_pallas_kernels() and kernel.supports_gqa(q, ring_k):
+        count_chunk_attention_program("kernel")
+        return kernel.ring_chunk_attention(q, ring_k, ring_v, base_lens,
+                                           counts, window, scale)
+    count_chunk_attention_program("xla")
+    return _xla_ring_chunk_attn(q, ring_k, ring_v, base_lens, counts,
+                                window, scale=scale)
+
+
+def _xla_ring_chunk_attn(q, ring_k, ring_v, base_lens, counts, window,
+                         scale=None):
+    """:func:`ring_chunk_attn` in plain XLA, on every backend.
+
+    The same fold as :func:`_xla_paged_chunk_attn`: the (S, H, C, keys) float32
     scores are built for ``_CHUNK_SCORE_BYTES`` worth of ring rows at a
     time and folded into running (m, l, acc); query heads are grouped over
     their KV head and operands keep the ring's dtype. A tile of rows is
@@ -387,8 +427,9 @@ def chunk_attention_programs():
 
     return MetricsRegistry.process().counter(
         "serving_chunk_attention_programs_total",
-        "mixed-step programs traced, by the path their latent chunk "
-        "attention takes (kernel | xla)")
+        "mixed-step programs traced, by the path their chunk attention "
+        "(over the latent pool, a K/V table or a window ring) takes "
+        "(kernel | xla)")
 
 
 def latent_decode_programs():

@@ -33,3 +33,24 @@ def _no_mesh_leak():
 
     if mesh_state.has_mesh():
         mesh_state.set_mesh(None)
+
+
+@pytest.fixture
+def pallas_forced():
+    """The Pallas kernel routes taken off-TPU (through the interpreter)
+    for one test."""
+    import paddle_tpu as paddle
+
+    paddle.set_flags({"FLAGS_pallas_force": True})
+    yield
+    paddle.set_flags({"FLAGS_pallas_force": False})
+
+
+@pytest.fixture
+def chunk_programs():
+    """A reader of ``serving_chunk_attention_programs_total``: the
+    process's count of traced mixed programs, by ``path``."""
+    from paddle_tpu.nlp.paged_attention import chunk_attention_programs
+
+    counter = chunk_attention_programs()
+    return lambda: {p: counter.value(path=p) for p in ("xla", "kernel")}

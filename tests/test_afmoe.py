@@ -372,6 +372,193 @@ def test_a_query_sees_exactly_its_window_and_a_full_layer_everything():
         q, kc, vc, tables, jnp.asarray([30, 30])))[0] == list(range(30))
 
 
+# -------------------- the Pallas chunk kernel over a ring (interpreted here)
+# by case: (C, HK, G, ring rows, cached tokens a slot, valid positions a
+# slot, key tile, query tile); W = 8 throughout
+_RING_CASES = {
+    "g1": (4, 2, 1, 12, (13, 38), (4, 4), 4, None),
+    "g4": (4, 2, 4, 12, (13, 38), (4, 4), 4, None),
+    "g7": (4, 1, 7, 12, (13, 38), (4, 4), 4, None),
+    "g8": (4, 1, 8, 12, (13, 38), (4, 4), 4, None),
+    "wrapped_three_times_over_stale_rows": (4, 2, 2, 12, (40, 37), (4, 3),
+                                            4, None),
+    "a_base_of_zero": (4, 2, 2, 12, (0, 0), (4, 2), 4, None),
+    "an_idle_slot": (4, 2, 2, 12, (17, 0, 40), (4, 0, 4), 4, None),
+    "bases_off_the_tiles": (4, 2, 2, 12, (5, 9, 22), (4, 4, 1), 4, None),
+    "a_chunk_off_the_sublanes": (5, 2, 2, 16, (7, 30), (5, 4), 4, None),
+    "one_key_tile": (4, 2, 2, 12, (13, 38), (4, 4), None, None),
+    "two_query_tiles": (24, 2, 2, 32, (0, 45), (24, 17), 8, 16),
+}
+
+
+def _written_ring(rng, slots, r, hk, d, upto, dtype):
+    """Rings that an EARLIER request filled with its own rows, then
+    positions ``0 .. upto[s] - 1`` of each slot written in turn, so that a
+    long context wraps the ring over stale rows and over its own; beside
+    them every position's key and value (S, P, HK, D) for a dense
+    reference."""
+    top = max(max(upto), 1)
+    k = jnp.asarray(rng.standard_normal((slots, top, hk, d)), dtype)
+    v = jnp.asarray(rng.standard_normal((slots, top, hk, d)), dtype)
+    ring_k = jnp.asarray(rng.standard_normal((slots, r, hk * d)), dtype)
+    ring_v = jnp.asarray(rng.standard_normal((slots, r, hk * d)), dtype)
+    lens = jnp.asarray(upto)[:, None]
+    for lo in range(0, top, r):
+        pos = jnp.broadcast_to(jnp.arange(lo, min(lo + r, top))[None, :],
+                               (slots, min(lo + r, top) - lo))
+        ring_k, ring_v = PA.ring_write(
+            ring_k, ring_v, k[:, lo:lo + r], v[:, lo:lo + r], pos, pos < lens)
+    return ring_k, ring_v, k, v
+
+
+def _dense_window_attention(q, k, v, base, window):
+    """(S, C, H, D) queries over every position's keys (S, P, HK, D):
+    query j of a row sees ``base + j - window < p <= base + j``; a plain
+    float64 softmax, K and V repeated over the query heads."""
+    q, k, v = (_host(a.astype(jnp.float32)).astype(np.float64)
+               for a in (q, k, v))
+    rep = q.shape[2] // k.shape[2]
+    k, v = np.repeat(k, rep, axis=2), np.repeat(v, rep, axis=2)
+    logits = np.einsum("schd,sphd->shcp", q, k) / np.sqrt(q.shape[-1])
+    t = np.asarray(base)[:, None] + np.arange(q.shape[1])[None, :]
+    p = np.arange(k.shape[1])[None, None, :]
+    seen = (p <= t[..., None]) & (p > t[..., None] - window)
+    logits = np.where(seen[:, None], logits, -np.inf)
+    w = np.exp(logits - logits.max(-1, keepdims=True))
+    return np.einsum("shcp,sphd->schd", w / w.sum(-1, keepdims=True), v)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(_RING_CASES))
+def test_the_chunk_kernel_over_a_ring_matches_the_xla_fold(case, dtype):
+    """``ring_chunk_attention`` over the ring AS STORED against the fold it
+    replaces on a TPU, at the same precision on EVERY query (one past a
+    row's count reads what the fold reads), and on the queries that count
+    against a dense float64 softmax over the positions themselves: float32
+    only reorders sums, bf16 rounds the operands and ``p`` to 2**-8."""
+    from paddle_tpu.ops.pallas.chunk_attention import ring_chunk_attention
+
+    c, hk, g, r, base, counts, bk, bq = _RING_CASES[case]
+    d, slots = 16, len(base)
+    rng = np.random.default_rng(len(case))
+    upto = [b + n for b, n in zip(base, counts)]
+    ring_k, ring_v, k, v = _written_ring(rng, slots, r, hk, d, upto, dtype)
+    q = jnp.asarray(rng.standard_normal((slots, c, hk * g, d)), dtype)
+    base, counts = jnp.asarray(base, jnp.int32), jnp.asarray(counts, jnp.int32)
+    got = ring_chunk_attention(q, ring_k, ring_v, base, counts, WINDOW,
+                               1.0 / np.sqrt(d), block_q=bq, block_k=bk)
+    fold = PA._xla_ring_chunk_attn(q, ring_k, ring_v, base, counts, WINDOW)
+    assert got.shape == fold.shape == q.shape and got.dtype == dtype
+    tol = 2e-5 if dtype == jnp.float32 else 4e-2
+    got = _host(got.astype(jnp.float32))
+    assert np.isfinite(got).all()
+    assert _max_abs(got, fold.astype(jnp.float32)) < tol
+    dense = _dense_window_attention(
+        q, jnp.pad(k, ((0, 0), (0, c), (0, 0), (0, 0))),
+        jnp.pad(v, ((0, 0), (0, c), (0, 0), (0, 0))), base, WINDOW)
+    counted = _host(jnp.arange(c)[None, :] < counts[:, None])
+    assert np.abs(dense[counted]).max() > 0.5
+    assert np.abs(got[counted] - dense[counted]).max() < tol
+
+
+@pytest.mark.parametrize("tiling", ["ring_of_three_tiles", "ring_of_one_tile",
+                                    "ring_of_six_tiles", "table_by_blocks",
+                                    "table_in_one_tile"])
+def test_the_kernel_sees_its_bounds_to_one_key(tiling):
+    """The hand-made edge again, through the kernel: W = 8 over a ring of
+    12 whose old rows another request left, the chunk's queries 26 .. 29
+    see ``t - 7 .. t`` each and not ONE key more on either side, however
+    the ring is cut into key tiles; over a table they see ``0 .. t``. A
+    row that brings nothing (count 0) sees nothing."""
+    from paddle_tpu.ops.pallas import chunk_attention as kernel
+
+    slots, hk, g, d = 2, 2, 2, 32
+    k, v = _one_hot_keys(slots, 30, hk, d)
+    pos = jnp.broadcast_to(jnp.arange(30)[None, :], (slots, 30))
+    q = jnp.ones((slots, 4, hk * g, d), jnp.float32)
+    base = jnp.asarray([26, 1], jnp.int32)
+    if tiling.startswith("ring"):
+        ring_k = jnp.full((slots, RING, hk * d), 9.0)
+        ring_v = jnp.full((slots, RING, hk * d), 9.0)
+        upto = jnp.asarray([30, 0])[:, None]          # row 1: an idle slot
+        for lo in (0, 12, 24):
+            ring_k, ring_v = PA.ring_write(
+                ring_k, ring_v, k[:, lo:lo + 12], v[:, lo:lo + 12],
+                pos[:, lo:lo + 12], pos[:, lo:lo + 12] < upto)
+        out = kernel.ring_chunk_attention(
+            q, ring_k, ring_v, jnp.asarray([26, 0]), jnp.asarray([4, 0]),
+            WINDOW,
+            1.0 / np.sqrt(d), block_k={"three": 4, "one": 12, "six": 2}[
+                tiling.split("_")[2]])
+        for j in range(4):
+            assert _seen(out[:1, j])[0] == list(range(26 + j - 7,
+                                                      26 + j + 1))
+        assert _max_abs(out[1]) == 0.0
+        return
+    pool = PagedKVCachePool(16, BLOCK, hk, d, num_layers=1,
+                            dtype=jnp.float32)
+    pool.ensure("a", 30)
+    pool.ensure("b", 30)
+    tables = pool.block_table_array(["a", "b"], pad_to=8)
+    blk = jnp.take_along_axis(tables, pos // BLOCK, axis=1)
+    kc, vc, _, _ = _paged_write(k, v, blk, pos % BLOCK,
+                                (pool.k_pools[0], pool.v_pools[0], None, None))
+    out = kernel.paged_chunk_attention(
+        q, kc, vc, tables, base, 1.0 / np.sqrt(d),
+        block_k=BLOCK if tiling == "table_by_blocks" else None)
+    for j in range(4):
+        assert _seen(out[:1, j])[0] == list(range(26 + j + 1))
+        assert _seen(out[1:, j])[0] == list(range(1 + j + 1))
+
+
+@pytest.mark.parametrize("route", ["xla", "kernel"])
+def test_a_window_models_mixed_program_counts_its_chunk_attention_route(
+        toy, route, request, chunk_programs):
+    """Tracing one mixed program raises the counter by ONE on its route's
+    label, however many ring and table layers ask (four and one here)."""
+    _, model, _ = toy
+    if route == "kernel":
+        request.getfixturevalue("pallas_forced")
+    before = chunk_programs()
+    step, args = _serve(model).engine.mixed_step_target()
+    step.lower(*args)
+    other = "xla" if route == "kernel" else "kernel"
+    after = chunk_programs()
+    assert after[route] == before[route] + 1
+    assert after[other] == before[other]
+
+
+def test_the_mixed_step_through_the_kernel_serves_the_folds_streams(
+        toy, request, chunk_programs):
+    """The engine's mixed step with the kernel route forced (prompts of
+    several chunks that wrap the rings three times, rows of uneven
+    length, an idle slot, a slot reused over another request's rows)
+    serves, token for token, what the XLA route serves; each engine's
+    programs are counted under their own route."""
+    cfg, model, _ = toy
+    prompts = _prompts(cfg, (41, 22, 9), seed=31)
+
+    def serve():
+        door = _serve(model, num_slots=3)
+        first = _drain(door, prompts, 6)
+        return door, first + _drain(door, prompts[:1], 6)
+
+    before = chunk_programs()
+    _, want = serve()
+    assert chunk_programs()["kernel"] == before["kernel"]
+    request.getfixturevalue("pallas_forced")
+    before = chunk_programs()
+    door, got = serve()
+    for a, b in zip(got, want):
+        assert np.array_equal(a, b)
+    after = chunk_programs()
+    assert after["kernel"] > before["kernel"] and after["xla"] == before["xla"]
+    assert door.engine.obs.registry.get(
+        "serving_chunk_attention_programs_total").value(
+            path="kernel") == after["kernel"]
+
+
 def test_a_dropped_write_leaves_the_ring_bit_for_bit():
     ring = jnp.arange(2 * RING * 2 * 4, dtype=jnp.float32).reshape(
         2, RING, 2 * 4)

@@ -509,13 +509,6 @@ def test_chunk_attention_kernel_matches_the_xla_loop_and_a_plain_softmax(
     assert _max_abs(got.astype(jnp.float32), plain) < tol
 
 
-@pytest.fixture
-def pallas_forced():
-    paddle.set_flags({"FLAGS_pallas_force": True})
-    yield
-    paddle.set_flags({"FLAGS_pallas_force": False})
-
-
 def _chunk_programs(path):
     from paddle_tpu.nlp.paged_attention import chunk_attention_programs
 
@@ -563,15 +556,19 @@ def test_a_traced_mixed_program_counts_its_chunk_attention_path(
     assert _chunk_programs(other) == before[other]
 
 
-def test_a_dense_models_mixed_program_counts_no_chunk_attention_path(
-        pallas_forced):
+def test_a_dense_models_mixed_program_counts_the_same_counter(
+        pallas_forced, chunk_programs):
+    """The counter is the mixed step's, not the latent pool's: since the
+    K/V table has a chunk kernel too (``gqa_chunk_attention``), a dense
+    model's traced program raises it on its own route, once."""
     paddle.seed(0)
     model = LlamaForCausalLM(LlamaConfig.tiny(tensor_parallel=False))
     eng = ServingEngine(model, num_slots=2, block_size=8, max_context=32)
-    before = {p: _chunk_programs(p) for p in ("xla", "kernel")}
+    before = chunk_programs()
     step, args = eng.mixed_step_target()
     step.lower(*args)
-    assert before == {p: _chunk_programs(p) for p in ("xla", "kernel")}
+    assert chunk_programs() == {"kernel": before["kernel"] + 1,
+                                "xla": before["xla"]}
 
 
 # ---------------------------- the paged decode kernel over the latent pool

@@ -415,6 +415,107 @@ def test_chunk_attention_streams_to_the_same_answer(monkeypatch, budget,
     assert -(-w // tile) == {256 << 20: 1, 1024: 3, 1: 5}[budget]
 
 
+# the Pallas kernel over a K/V table (interpreted here), by case: (S, C,
+# HK, G, block table width, cached tokens a slot, key tile, query tile)
+_TABLE_CASES = {
+    "g1": (2, 8, 2, 1, 6, (5, 11), 8, None),
+    "g4": (2, 8, 2, 4, 6, (5, 11), 8, None),
+    "g7": (2, 8, 1, 7, 6, (5, 11), 8, None),
+    "g8": (2, 8, 1, 8, 6, (5, 11), 8, None),
+    "bases_off_the_tiles": (3, 8, 2, 2, 9, (3, 13, 26), 8, None),
+    "a_base_of_zero": (2, 8, 2, 2, 4, (0, 0), 8, None),
+    "an_idle_slot": (3, 8, 2, 2, 6, (9, 0, 14), 8, None),
+    "a_chunk_off_the_sublanes": (2, 5, 2, 2, 6, (7, 18), 8, None),
+    "two_query_tiles": (2, 24, 2, 2, 12, (0, 21), 8, 16),
+    "a_table_longer_than_the_context": (2, 8, 2, 2, 16, (2, 9), 16, None),
+    "one_key_tile": (2, 8, 2, 2, 5, (3, 11), None, None),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(_TABLE_CASES))
+def test_the_chunk_kernel_over_a_table_matches_the_xla_fold(case, dtype):
+    """``paged_chunk_attention`` against the fold it replaces on a TPU at
+    the same precision, and against the dense float64 softmax: float32
+    only reorders sums, bf16 rounds the operands and ``p`` to 2**-8. An
+    idle slot's table names block 0 throughout (its queries are nobody's,
+    but the fold and the kernel read the same rows for them)."""
+    from paddle_tpu.nlp import paged_attention
+    from paddle_tpu.ops.pallas.chunk_attention import paged_chunk_attention
+
+    s, c, hk, g, w, base, bk, bq = _TABLE_CASES[case]
+    d, bs = 16, 4
+    rng = np.random.RandomState(len(case))
+    q = jnp.asarray(rng.randn(s, c, hk * g, d), dtype)
+    kp = jnp.asarray(rng.randn(1 + s * w, bs, hk, d), dtype)
+    vp = jnp.asarray(rng.randn(1 + s * w, bs, hk, d), dtype)
+    tables = 1 + rng.permutation(s * w).astype(np.int32).reshape(s, w)
+    if case == "an_idle_slot":
+        tables[1] = 0
+    base = np.asarray(base, np.int32)
+    got = paged_chunk_attention(q, kp, vp, jnp.asarray(tables),
+                                jnp.asarray(base), 1.0 / np.sqrt(d),
+                                block_q=bq, block_k=bk)
+    fold = paged_attention._xla_paged_chunk_attn(
+        q, kp, vp, jnp.asarray(tables), jnp.asarray(base))
+    assert got.shape == fold.shape == q.shape and got.dtype == dtype
+
+    def host(a):
+        return np.asarray(jax.device_get(a.astype(jnp.float32)))
+
+    dense = dense_chunk_attention(
+        host(q), host(kp[tables]).reshape(s, w * bs, hk, d),
+        host(vp[tables]).reshape(s, w * bs, hk, d), base)
+    tol = 2e-5 if dtype == jnp.float32 else 4e-2
+    got = host(got)
+    assert np.abs(dense).max() > 0.5
+    assert np.abs(got - host(fold)).max() < tol
+    assert np.abs(got - dense).max() < tol
+
+
+@pytest.mark.parametrize("route", ["kernel", "xla", "int8"])
+def test_a_dense_models_mixed_program_counts_its_chunk_attention_route(
+        tiny, route, request, chunk_programs):
+    """Tracing one mixed program of the dense model raises
+    ``serving_chunk_attention_programs_total`` by ONE on its route's
+    label, whatever the model's depth: ``kernel`` where the kernels are
+    forced, ``xla`` where they are not, and ``xla`` for an int8 pool's
+    per-row scales whatever the flag says."""
+    cfg, model = tiny
+    if route == "int8":
+        model = build_model()[1]      # the sweep rewrites a model in place
+    kw = {"kv_dtype": "int8"} if route == "int8" else {}
+    engine = ServingEngine(model, **kw, **ENGINE_KW)
+    engine.submit(prompts_of(cfg, (7,))[0], max_new_tokens=2)
+    engine._admit()
+    step, args = engine.mixed_step_target()
+    before = chunk_programs()
+    if route != "xla":
+        request.getfixturevalue("pallas_forced")
+    step.lower(*args)
+    took = "kernel" if route == "kernel" else "xla"
+    after = chunk_programs()
+    assert after[took] == before[took] + 1
+    other = "xla" if took == "kernel" else "kernel"
+    assert after[other] == before[other]
+    assert engine.obs.registry.get(
+        "serving_chunk_attention_programs_total").value(
+            path=took) == after[took]
+
+
+@pytest.mark.parametrize("arm", ARMS[:-1],
+                         ids=[a.__name__[4:] for a in ARMS[:-1]])
+def test_streams_are_the_sequential_reference_through_the_kernels(
+        arm, pallas_forced):
+    """Every arm again with the kernel routes forced (interpreted here):
+    the mixed step's chunk attention, the verify pass's (draft + 1
+    queries, padded to the sublanes) and, under ``tp=2``, the kernel per
+    shard over the head axis serve the sequential reference's streams;
+    the int8 pool keeps the fold."""
+    arm()
+
+
 def test_the_head_runs_at_the_last_valid_position_only(tiny):
     """(S, V) logits come out of the chunk body when rows bring their
     counts, never (S, C, V); the verify pass still gets every

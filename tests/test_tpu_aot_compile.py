@@ -359,11 +359,61 @@ def test_latent_paged_chunk_leaves_no_score_in_memory(chip, route):
         assert big and temp > 3 * tile * 4, temp
 
 
-def _layouts(text, elements, rows):
+# (slots, chunk, heads, KV heads, table width, pool blocks, ring rows)
+_GQA_CHUNK_SHAPES = {
+    # the dense decode cell's mixed step: Qwen2-7B's SEVEN heads a group
+    "qwen2_7b_table": (16, 128, 28, 4, 16, 1153, None),
+    # the state-space cell's one attention layer in ten
+    "granite_table": (64, 128, 32, 8, 40, 2560, None),
+    # a speculative verify pass: draft + 1 queries, padded to 16
+    "verify_table": (8, 5, 32, 8, 64, 513, None),
+    # a window layer's ring at the window cell's 8 slots and at 16
+    "trinity_ring": (8, 1024, 32, 4, None, None, 3072),
+    "trinity_ring_16_slots": (16, 1024, 32, 4, None, None, 3072),
+}
+
+
+@pytest.mark.parametrize("shapes", sorted(_GQA_CHUNK_SHAPES))
+def test_gqa_chunk_attention(chip, shapes, pallas_forced):
+    """The dense routes of the mixed step compile to the kernel at the
+    shapes the cells (and the verify pass) bring, groups of 4, 7 and 8
+    heads; a ring is read as it is stored, through a bitcast: no
+    instruction has a ring-sized result (but the compiler's own prefetch
+    of a ring, in the stored order, at 16 slots)."""
+    from paddle_tpu.nlp import paged_attention as PA
+    from paddle_tpu.ops.pallas._utils import compiled_kernel_names
+
+    s, c, h, hk, w, nb, r = _GQA_CHUNK_SHAPES[shapes]
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    if r is None:
+        fn = PA._paged_chunk_attn
+        args = [((s, c, h, D), bf16), ((nb, BLOCK, hk, D), bf16),
+                ((nb, BLOCK, hk, D), bf16), ((s, w), i32), ((s,), i32)]
+    else:
+        def fn(q, ring_k, ring_v, base, counts):
+            return PA.ring_chunk_attn(q, ring_k, ring_v, base, counts, 2048)
+
+        args = [((s, c, h, D), bf16), ((s, r, hk * D), bf16),
+                ((s, r, hk * D), bf16), ((s,), i32), ((s,), i32)]
+    text = jax.jit(fn).lower(*[
+        jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+        for shape, dt in args]).compile().as_text()
+    assert compiled_kernel_names(text) == {"gqa_chunk_attention"}
+    if r is not None:
+        assert not _pool_sized(text, s * r * hk * D,
+                               skip=("get-tuple-element", "bitcast",
+                                     "parameter", "copy-start",
+                                     "copy-done"))
+
+
+def _layouts(text, elements, rows, but_leading=None):
     """``(opcode, minor-to-major order)`` of every instruction of a compiled
     program whose result has ``elements`` elements in rows of one of the
     widths ``rows`` (a weight may have as many elements as a ring: the
-    dense feed-forward's 2048 x 6144 has), pass-throughs left out."""
+    dense feed-forward's 2048 x 6144 has), pass-throughs left out, and
+    results whose leading dimension is ``but_leading`` (rows gathered a
+    slot, which are as many as the pool's where the tables name every
+    block)."""
     found = []
     for line in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%\S+ = \(?\w+\[([\d,]+)\]\{([\d,]+)\S* "
@@ -372,7 +422,8 @@ def _layouts(text, elements, rows):
                                    "parameter"):
             continue
         dims = list(map(int, m.group(1).split(",")))
-        if math.prod(dims) == elements and dims[-1] in rows:
+        if math.prod(dims) == elements and dims[-1] in rows \
+                and dims[0] != but_leading:
             found.append((m.group(3), m.group(2)))
     return found
 
@@ -450,7 +501,9 @@ def test_the_window_cells_programs_move_no_ring_and_no_pool(chip,
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
             < 12e9, (name, mem.temp_size_in_bytes)
         rings = _layouts(text, ring, (512,))
-        pools = _layouts(text, pool, (128, 512))
+        # the chunk kernel reads the table's rows gathered a slot, (8, 528
+        # x 32, 4 x 128): 8 x 528 is every block here, but no pool
+        pools = _layouts(text, pool, (128, 512), but_leading=8)
         # every window layer's K and V ring and the full layer's K and V
         # arrays are scattered into, in the stored (row-major) order
         assert [op for op, _ in rings].count("scatter") == 8, rings
@@ -466,3 +519,11 @@ def test_the_window_cells_programs_move_no_ring_and_no_pool(chip,
     assert "paged_decode_attention" in compiled_kernel_names(
         compiled["quantum"].as_text())
     assert compiled["quantum"].memory_analysis().temp_size_in_bytes < 1 << 28
+    # both chunk attentions of the mixed step are the kernel (five calls:
+    # four rings and the table), and nothing the size of a fold's (S, H,
+    # C, 256 keys) float32 score tile is left in the program
+    mixed = compiled["mixed"].as_text()
+    assert "gqa_chunk_attention" in compiled_kernel_names(mixed)
+    assert len(re.findall(r" custom-call\(.*gqa_chunk_attention/pallas_call",
+                          mixed)) == 5
+    assert not re.search(r"f32\[8,4,8,1024,\d+\]", mixed)
