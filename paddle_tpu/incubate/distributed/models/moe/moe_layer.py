@@ -64,43 +64,48 @@ def grouped_expert_ffn(xt, expert_ids, gate_vals, w1, w2, act, b1=None,
                   for a in (xt, expert_ids, gate_vals)))
         return ys.reshape(t, -1), jnp.sum(rows, axis=0)
     e = w1.shape[0]
-    expert_flat = expert_ids.reshape(-1)                  # (T*k,)
-    if held is not None:
-        # absent experts share the id ``e``: last in the sort, in no group
-        lo, n = held
-        if n != e:
-            raise ValueError(f"held={held} but {e} weight slabs")
-        local = expert_flat - lo
-        present = (local >= 0) & (local < e)
-        expert_flat = jnp.where(present, local, e)
-    order = jnp.argsort(expert_flat)                      # stable
-    sorted_exp = expert_flat[order]
-    group_sizes = jnp.bincount(expert_flat, length=e).astype(jnp.int32)
+    with jax.named_scope("moe.dispatch"):
+        expert_flat = expert_ids.reshape(-1)                  # (T*k,)
+        if held is not None:
+            # absent experts share the id ``e``: last in the sort, in no
+            # group
+            lo, n = held
+            if n != e:
+                raise ValueError(f"held={held} but {e} weight slabs")
+            local = expert_flat - lo
+            present = (local >= 0) & (local < e)
+            expert_flat = jnp.where(present, local, e)
+        order = jnp.argsort(expert_flat)                      # stable
+        sorted_exp = expert_flat[order]
+        group_sizes = jnp.bincount(expert_flat, length=e).astype(jnp.int32)
+        xs = xt[order // k]                                   # (T*k, M)
 
-    xs = xt[order // k]                                   # (T*k, M)
-    h = jax.lax.ragged_dot(xs, w1.astype(xt.dtype), group_sizes)
-    if b1 is not None:
-        h = h + b1[sorted_exp].astype(xt.dtype)
-    h = act(h)
-    out = jax.lax.ragged_dot(h, w2.astype(xt.dtype), group_sizes)
-    if b2 is not None:
-        out = out + b2[sorted_exp].astype(xt.dtype)
-    if held is not None:
-        # rows past the last group belong to no product: exactly zero,
-        # whatever the backend leaves there. The k outputs of a token are
-        # gathered back one choice at a time into a float32 sum: no
-        # (T, k, M) float32 buffer beside the worst-case sorted rows
-        out = jnp.where((sorted_exp < e)[:, None], out, 0)
-        gate_vals = jnp.where(present.reshape(t, k), gate_vals, 0)
-        back = jnp.argsort(order).reshape(t, k)
-        y = sum(out[back[:, j]].astype(jnp.float32)
-                * gate_vals.astype(jnp.float32)[:, j, None]
-                for j in range(k))
+    with jax.named_scope("moe.products"):
+        h = jax.lax.ragged_dot(xs, w1.astype(xt.dtype), group_sizes)
+        if b1 is not None:
+            h = h + b1[sorted_exp].astype(xt.dtype)
+        h = act(h)
+        out = jax.lax.ragged_dot(h, w2.astype(xt.dtype), group_sizes)
+        if b2 is not None:
+            out = out + b2[sorted_exp].astype(xt.dtype)
+    with jax.named_scope("moe.combine"):
+        if held is not None:
+            # rows past the last group belong to no product: exactly
+            # zero, whatever the backend leaves there. The k outputs of a
+            # token are gathered back one choice at a time into a float32
+            # sum: no (T, k, M) float32 buffer beside the worst-case
+            # sorted rows
+            out = jnp.where((sorted_exp < e)[:, None], out, 0)
+            gate_vals = jnp.where(present.reshape(t, k), gate_vals, 0)
+            back = jnp.argsort(order).reshape(t, k)
+            y = sum(out[back[:, j]].astype(jnp.float32)
+                    * gate_vals.astype(jnp.float32)[:, j, None]
+                    for j in range(k))
+            return y.astype(xt.dtype), group_sizes
+        back = out[jnp.argsort(order)].reshape(t, k, -1)      # token-major
+        y = jnp.sum(back.astype(jnp.float32)
+                    * gate_vals.astype(jnp.float32)[..., None], axis=1)
         return y.astype(xt.dtype), group_sizes
-    back = out[jnp.argsort(order)].reshape(t, k, -1)      # token-major
-    y = jnp.sum(back.astype(jnp.float32)
-                * gate_vals.astype(jnp.float32)[..., None], axis=1)
-    return y.astype(xt.dtype), group_sizes
 
 
 class MoELayer(Layer):

@@ -104,7 +104,8 @@ class JittedTrainStep:
                     def fwd_and_loss(*args):
                         n_in = len(in_t)
                         out = model_ref(*args[:n_in])
-                        return criterion_ref(out, *args[n_in:])
+                        with jax.named_scope("loss"):
+                            return criterion_ref(out, *args[n_in:])
 
                     loss_t, new_b = functional_call(
                         model_ref, fwd_and_loss, in_t + lb_t, {}, pv, b_vals
@@ -118,8 +119,9 @@ class JittedTrainStep:
                 if g is not None and sh is not None else g
                 for g, sh in zip(grads, grad_pins)
             ]
-            new_p, new_s = opt_ref.functional_apply(
-                p_vals, grads, s_vals, lr, step_no, decay_flags)
+            with jax.named_scope("optimizer"):
+                new_p, new_s = opt_ref.functional_apply(
+                    p_vals, grads, s_vals, lr, step_no, decay_flags)
             return loss, new_p, new_s, new_b
 
         def step_fn(p_vals, s_vals, b_vals, rng, lr, step_no, inputs, labels):

@@ -180,6 +180,7 @@ class AfmoeAttention(LlamaAttention):
         row = (None, self.num_kv_heads * self.head_dim)
         return [(row, None), (row, None)]
 
+    @jax.named_scope("attn.proj")
     def _project(self, x, rope):
         """The normed input (S, C, E) -> q (S, C, H, D) and k (S, C, HK,
         D), normed per head and, in a window layer, rotated; v; and the
@@ -201,6 +202,7 @@ class AfmoeAttention(LlamaAttention):
             att = att.reshape(gate.shape)
             att = (att.astype(F32) * jax.nn.sigmoid(gate.astype(F32))
                    ).astype(gate.dtype)
+        with jax.named_scope("attn.proj"):
             return self.o_proj(Tensor(att, stop_gradient=True))
 
     def forward(self, x):
@@ -236,14 +238,16 @@ class AfmoeAttention(LlamaAttention):
         lens = step["lens"]
         if self.window:
             with jax.named_scope("attn.window"):
-                new = PA.ring_write(*cache, k, v, lens[:, None] - 1,
-                                    step["live"][:, None])
+                with jax.named_scope("cache.write"):
+                    new = PA.ring_write(*cache, k, v, lens[:, None] - 1,
+                                        step["live"][:, None])
                 att = PA.ring_decode_attn(q[:, 0], *new, lens, self.window)
         else:
             with jax.named_scope("attn.full"):
-                kci, vci, ksi, vsi = new = _paged_write(
-                    k[:, 0], v[:, 0], step["write_blk"], step["write_off"],
-                    cache)
+                with jax.named_scope("cache.write"):
+                    kci, vci, ksi, vsi = new = _paged_write(
+                        k[:, 0], v[:, 0], step["write_blk"],
+                        step["write_off"], cache)
                 att = PA._paged_attn(q[:, 0], kci, vci, step["tables"],
                                      lens, ks=ksi, vs=vsi)
         return self._gated_out(att, gate), new
@@ -258,14 +262,16 @@ class AfmoeAttention(LlamaAttention):
         if self.window:
             with jax.named_scope("attn.window"):
                 pos = base[:, None] + jnp.arange(x.shape[1])[None, :]
-                new = PA.ring_write(*cache, k, v, pos, valid)
+                with jax.named_scope("cache.write"):
+                    new = PA.ring_write(*cache, k, v, pos, valid)
                 att = PA.ring_chunk_attn(
                     q, *new, base, jnp.sum(valid, axis=1).astype(base.dtype),
                     self.window)
         else:
             with jax.named_scope("attn.full"):
-                kci, vci, ksi, vsi = new = _paged_write(
-                    k, v, step["write_blk"], step["write_off"], cache)
+                with jax.named_scope("cache.write"):
+                    kci, vci, ksi, vsi = new = _paged_write(
+                        k, v, step["write_blk"], step["write_off"], cache)
                 att = PA._paged_chunk_attn(q, kci, vci, step["tables"],
                                            base, ks=ksi, vs=vsi)
         return self._gated_out(att, gate), new
@@ -326,23 +332,24 @@ class AfmoeDecoderLayer(Layer):
                                    config.intermediate_size))
 
     def _feed_forward(self, hidden, att):
-        hidden = hidden + self.post_attention_layernorm(att)
-        return hidden + self.post_mlp_layernorm(
-            self.mlp(self.pre_mlp_layernorm(hidden)))
+        hidden = hidden + PA.normed(self.post_attention_layernorm, att)
+        return hidden + PA.normed(
+            self.post_mlp_layernorm,
+            self.mlp(PA.normed(self.pre_mlp_layernorm, hidden)))
 
     def forward(self, hidden):
-        return self._feed_forward(
-            hidden, self.self_attn(self.input_layernorm(hidden)))
+        return self._feed_forward(hidden, self.self_attn(
+            PA.normed(self.input_layernorm, hidden)))
 
     # -- the serving engine's layer protocol --------------------------------
     def paged_decode(self, hidden, step, cache):
         att, new = self.self_attn.paged_decode(
-            self.input_layernorm(hidden), step, cache)
+            PA.normed(self.input_layernorm, hidden), step, cache)
         return self._feed_forward(hidden, att), new
 
     def paged_chunk(self, hidden, step, cache):
         att, new = self.self_attn.paged_chunk(
-            self.input_layernorm(hidden), step, cache)
+            PA.normed(self.input_layernorm, hidden), step, cache)
         return self._feed_forward(hidden, att), new
 
 

@@ -283,6 +283,7 @@ class DeepseekV3Attention(Layer):
         freqs = positions[..., None] * inv_freq
         return jnp.cos(freqs), jnp.sin(freqs)
 
+    @jax.named_scope("attn.proj")
     def _queries(self, x, rope):
         """x (..., E) -> q_nope (..., H, Dn), rotated q_rope (..., H, Dr)
         as raw arrays."""
@@ -294,6 +295,7 @@ class DeepseekV3Attention(Layer):
         q_rope = _rope_interleaved(q[..., cfg.qk_nope_head_dim:], *rope)
         return q_nope, q_rope
 
+    @jax.named_scope("attn.proj")
     def _latent_rows(self, x, rope):
         """x (..., E) -> the rows the cache holds, (..., R + Dr):
         ``[RMSNorm(c_raw) | rope(k_rope)]``."""
@@ -351,9 +353,11 @@ class DeepseekV3Attention(Layer):
         return _latent_decode_attn(q_lat, q_rope, pool, tables, lens,
                                    self.scale)
 
+    @jax.named_scope("cache.write")
     def _write(self, pool, rows, write_blk, write_off):
         return pool.at[write_blk, write_off].set(rows.astype(pool.dtype))
 
+    @jax.named_scope("attn.proj")
     def _project_out(self, out, lead):
         return self.o_proj(Tensor(
             out.reshape(*lead, self.num_heads * self.config.v_head_dim),

@@ -58,6 +58,7 @@ from ..nn.layer.layers import Layer
 from ..nn.layer.norm import RMSNorm
 from ..tensor._helpers import apply
 from .llama import LlamaAttention
+from .paged_attention import normed
 
 __all__ = ["GraniteMoeHybridConfig", "GraniteMoeHybridMamba",
            "GraniteMoeHybridAttention", "GraniteMoeHybridMoE",
@@ -285,10 +286,10 @@ class GraniteMoeHybridMamba(Layer):
         cfg = self.config
         with jax.named_scope("ssm.in_proj"):
             zxd = self.in_proj(u)._value
-        d_in, cd = cfg.mamba_d_inner, cfg.mamba_conv_dim
-        dt = jax.nn.softplus(zxd[..., d_in + cd:].astype(F32)
-                             + self.dt_bias._value.astype(F32))
-        return zxd[..., :d_in], zxd[..., d_in:d_in + cd], dt
+            d_in, cd = cfg.mamba_d_inner, cfg.mamba_conv_dim
+            dt = jax.nn.softplus(zxd[..., d_in + cd:].astype(F32)
+                                 + self.dt_bias._value.astype(F32))
+            return zxd[..., :d_in], zxd[..., d_in:d_in + cd], dt
 
     def _conv(self, window):
         """``window`` (S, C + width - 1, D): each position's input behind
@@ -343,10 +344,11 @@ class GraniteMoeHybridMamba(Layer):
                 xs, b, c, dt * dt_mask[..., None],
                 -jnp.exp(self.A_log._value.astype(F32)),
                 self.D._value.astype(F32), state, keep)
-        # the new tail is a few rows of `window`: have it taken before
-        # the stream goes on, or the scheduler keeps every layer's window
-        # (132 MB at the cell's size) alive to the program's end
-        y, new_tail = jax.lax.optimization_barrier((y, new_tail))
+            # the new tail is a few rows of `window`: have it taken
+            # before the stream goes on, or the scheduler keeps every
+            # layer's window (132 MB at the cell's size) alive to the
+            # program's end
+            y, new_tail = jax.lax.optimization_barrier((y, new_tail))
         return self._out(y, z), (state, new_tail)
 
     # -- the whole-sequence pass --------------------------------------------
@@ -380,9 +382,11 @@ class GraniteMoeHybridMamba(Layer):
             u, valid.astype(F32), tail0, state0,
             jnp.sum(valid, axis=1).astype(jnp.int32),
             keep=~(live & (step["lens"] == 0)))
-        return out, (
-            jnp.where(live[:, None, None, None], state, state0),
-            jnp.where(live[:, None, None], tail.astype(tail0.dtype), tail0))
+        with jax.named_scope("cache.write"):
+            return out, (
+                jnp.where(live[:, None, None, None], state, state0),
+                jnp.where(live[:, None, None], tail.astype(tail0.dtype),
+                          tail0))
 
     def paged_decode(self, u, step, cache):
         """One position a slot: the recurrence itself, the state read and
@@ -404,10 +408,11 @@ class GraniteMoeHybridMamba(Layer):
             y = (jnp.sum(state * c[:, None, None, :], axis=-1)
                  + self.D._value.astype(F32)[:, None] * xs)
         out = self._out(y[:, None], z)
-        return out, (
-            jnp.where(live[:, None, None, None], state, state0),
-            jnp.where(live[:, None, None], window[:, 1:].astype(
-                tail0.dtype), tail0))
+        with jax.named_scope("cache.write"):
+            return out, (
+                jnp.where(live[:, None, None, None], state, state0),
+                jnp.where(live[:, None, None], window[:, 1:].astype(
+                    tail0.dtype), tail0))
 
 
 class GraniteMoeHybridAttention(LlamaAttention):
@@ -560,19 +565,19 @@ class GraniteMoeHybridDecoderLayer(Layer):
     def _feed_forward(self, hidden, mixed):
         r = self.residual_multiplier
         hidden = hidden + mixed * r
-        v = self.post_attention_layernorm(hidden)
+        v = normed(self.post_attention_layernorm, hidden)
         routed = self.block_sparse_moe(v)
         with jax.named_scope("moe.shared"):
             return hidden + (routed + self.shared_mlp(v)) * r
 
     def forward(self, hidden):
         mixer = self.mamba if self.kind == "mamba" else self.self_attn
-        return self._feed_forward(
-            hidden, mixer(self.input_layernorm(hidden)))
+        x = normed(self.input_layernorm, hidden)
+        return self._feed_forward(hidden, mixer(x))
 
     # -- the serving engine's layer protocol --------------------------------
     def _paged(self, form, hidden, step, cache):
-        x = self.input_layernorm(hidden)
+        x = normed(self.input_layernorm, hidden)
         if self.kind == "mamba":
             mixed, new = getattr(self.mamba, form)(x, step, cache)
         else:

@@ -21,6 +21,8 @@ from __future__ import annotations
 
 import math
 
+from jax import named_scope
+
 from ..nn.layer.layers import Layer
 from ..nn.layer.common import Linear, Embedding
 from ..nn.layer.norm import RMSNorm
@@ -28,7 +30,7 @@ from ..nn import functional as F
 from ..nn.functional.rope import build_rope_cache, apply_rotary_emb
 from ..tensor._helpers import apply, ensure_tensor
 from ..parallel import mesh as mesh_state
-from .paged_attention import PagedResidualLayer
+from .paged_attention import PagedResidualLayer, normed
 
 __all__ = [
     "LlamaConfig", "LlamaAttention", "LlamaMLP", "LlamaDecoderLayer",
@@ -217,6 +219,20 @@ class LlamaAttention(Layer):
     def forward(self, hidden, position_offset=0, cache=None,
                 cu_seqlens=None, position_ids=None):
         b, s, _ = hidden.shape
+        with named_scope("attn.proj"):
+            q, k, v = self._rotated_qkv(hidden, position_offset,
+                                           position_ids)
+        with named_scope("attn.window" if self.config.sliding_window
+                         else "attn.full"):
+            out, cache = self._attend(q, k, v, position_offset, cache,
+                                      cu_seqlens)
+        out = out.reshape([b, s, self.num_heads * self.head_dim])
+        with named_scope("attn.proj"):
+            return self.o_proj(out), cache
+
+    def _rotated_qkv(self, hidden, position_offset, position_ids):
+        """q, k, v (B, S, heads, D), q and k rotated at their positions."""
+        b, s, _ = hidden.shape
         q = self.q_proj(hidden).reshape([b, s, self.num_heads, self.head_dim])
         k = self.k_proj(hidden).reshape([b, s, self.num_kv_heads, self.head_dim])
         v = self.v_proj(hidden).reshape([b, s, self.num_kv_heads, self.head_dim])
@@ -239,7 +255,13 @@ class LlamaAttention(Layer):
                       op_name="rope_q")
             k = apply(lambda t: apply_rotary_emb(t, cos, sin), k,
                       op_name="rope_k")
+        return q, k, v
 
+    def _attend(self, q, k, v, position_offset, cache, cu_seqlens):
+        """The attention itself, by what the call brings: packed ragged
+        sequences, a dense cache, a window, context parallelism, or
+        plain causal attention. Returns (B, S, H, D) and the cache."""
+        b, s = q.shape[0], q.shape[1]
         if cu_seqlens is not None:
             # packed ragged sequences, (B=1, T) layout: the Pallas varlen
             # kernel skips dead cross-segment tiles AND their KV DMA
@@ -303,8 +325,7 @@ class LlamaAttention(Layer):
             )
         else:
             out = F.scaled_dot_product_attention(q, k, v, is_causal=True)
-        out = out.reshape([b, s, self.num_heads * self.head_dim])
-        return self.o_proj(out), cache
+        return out, cache
 
     # -- the attention half of the serving engine's layer protocol --------
     # (serving/engine.py: paged_decode_math / paged_chunk_math keep the
@@ -345,18 +366,22 @@ class LlamaAttention(Layer):
 
         s = x.shape[0]
         h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
-        q = self.q_proj(x).reshape([s, 1, h, d])
-        k = self.k_proj(x).reshape([s, 1, hk, d])
-        v = self.v_proj(x).reshape([s, 1, hk, d])
-        qv = self._rotate(q._value[:, 0], rope)      # (S, H, D)
-        kv = self._rotate(k._value[:, 0], rope)
-        vv = v._value[:, 0]
-        kci, vci, ksi, vsi = new = _paged_write(kv, vv, write_blk,
-                                                write_off, cache)
-        att = _paged_attn(qv, kci, vci, tables, lens, ks=ksi, vs=vsi,
-                          scale=self.softmax_scale)
+        with named_scope("attn.proj"):
+            q = self.q_proj(x).reshape([s, 1, h, d])
+            k = self.k_proj(x).reshape([s, 1, hk, d])
+            v = self.v_proj(x).reshape([s, 1, hk, d])
+            qv = self._rotate(q._value[:, 0], rope)      # (S, H, D)
+            kv = self._rotate(k._value[:, 0], rope)
+            vv = v._value[:, 0]
+        with named_scope("cache.write"):
+            kci, vci, ksi, vsi = new = _paged_write(kv, vv, write_blk,
+                                                    write_off, cache)
+        with named_scope("attn.full"):
+            att = _paged_attn(qv, kci, vci, tables, lens, ks=ksi, vs=vsi,
+                              scale=self.softmax_scale)
         att_t = Tensor(att.reshape(s, 1, h * d), stop_gradient=True)
-        return self.o_proj(att_t), new
+        with named_scope("attn.proj"):
+            return self.o_proj(att_t), new
 
     def paged_chunk(self, x, rope, tables, base_lens, write_blk,
                     write_off, cache):
@@ -370,18 +395,23 @@ class LlamaAttention(Layer):
 
         s, c = x.shape[0], x.shape[1]
         h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
-        q = self.q_proj(x).reshape([s, c, h, d])
-        k = self.k_proj(x).reshape([s, c, hk, d])
-        v = self.v_proj(x).reshape([s, c, hk, d])
-        qv = self._rotate(q._value, rope)            # (S, C, H, D)
-        kv = self._rotate(k._value, rope)
-        vv = v._value
-        kci, vci, ksi, vsi = new = _paged_write(kv, vv, write_blk,
-                                                write_off, cache)
-        att = _paged_chunk_attn(qv, kci, vci, tables, base_lens,
-                                ks=ksi, vs=vsi, scale=self.softmax_scale)
+        with named_scope("attn.proj"):
+            q = self.q_proj(x).reshape([s, c, h, d])
+            k = self.k_proj(x).reshape([s, c, hk, d])
+            v = self.v_proj(x).reshape([s, c, hk, d])
+            qv = self._rotate(q._value, rope)            # (S, C, H, D)
+            kv = self._rotate(k._value, rope)
+            vv = v._value
+        with named_scope("cache.write"):
+            kci, vci, ksi, vsi = new = _paged_write(kv, vv, write_blk,
+                                                    write_off, cache)
+        with named_scope("attn.full"):
+            att = _paged_chunk_attn(qv, kci, vci, tables, base_lens,
+                                    ks=ksi, vs=vsi,
+                                    scale=self.softmax_scale)
         att_t = Tensor(att.reshape(s, c, h * d), stop_gradient=True)
-        return self.o_proj(att_t), new
+        with named_scope("attn.proj"):
+            return self.o_proj(att_t), new
 
     def forward_no_cache(self, hidden, position_offset=0,
                          cu_seqlens=None, position_ids=None):
@@ -512,7 +542,9 @@ class LlamaMLP(Layer):
                 config.intermediate_size, config.hidden_size, bias_attr=False)
 
     def forward(self, x):
-        return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
+        with named_scope("mlp"):
+            return self.down_proj(
+                F.silu(self.gate_proj(x)) * self.up_proj(x))
 
 
 class LlamaDecoderLayer(PagedResidualLayer, Layer):
@@ -543,16 +575,17 @@ class LlamaDecoderLayer(PagedResidualLayer, Layer):
             # silently freeze q/k/v/o in eager training)
             attn_out = recompute(
                 self.self_attn.forward_no_cache,
-                self.input_layernorm(hidden), position_offset,
-                cu_seqlens, position_ids,
+                normed(self.input_layernorm, hidden),
+                position_offset, cu_seqlens, position_ids,
             )
         else:
             attn_out, cache = self.self_attn(
-                self.input_layernorm(hidden), position_offset, cache,
-                cu_seqlens, position_ids)
+                normed(self.input_layernorm, hidden),
+                position_offset, cache, cu_seqlens, position_ids)
         hidden = residual + attn_out
         hidden = _mark_hidden(hidden, self.config)
-        hidden = hidden + self.mlp(self.post_attention_layernorm(hidden))
+        hidden = hidden + self.mlp(
+            normed(self.post_attention_layernorm, hidden))
         hidden = _mark_hidden(hidden, self.config)
         return hidden, cache
 
@@ -599,7 +632,8 @@ class LlamaModel(Layer):
 
     def forward(self, input_ids, position_offset=0, caches=None,
                 cu_seqlens=None):
-        hidden = self.embed_tokens(input_ids)
+        with named_scope("embed"):
+            hidden = self.embed_tokens(input_ids)
         hidden = _mark_hidden(hidden, self.config)
         position_ids = None
         if cu_seqlens is not None:
@@ -634,7 +668,8 @@ class LlamaModel(Layer):
                                         cu_seqlens, position_ids)
             if new_caches is not None:
                 new_caches.append(cache_i)
-        return self.norm(hidden), new_caches
+        with named_scope("head"):
+            return self.norm(hidden), new_caches
 
     def paged_rope(self, positions):
         """What every layer's rotary embedding needs at ``positions``
@@ -668,7 +703,8 @@ class LlamaForCausalLM(Layer):
             # LlamaPretrainingCriterion's chunked fused op — returning
             # logits here would defeat the point (full (N, V) buffers)
             return hidden
-        logits = self.lm_head(hidden)
+        with named_scope("head"):
+            logits = self.lm_head(hidden)
         if caches is not None:
             return logits, new_caches
         return logits

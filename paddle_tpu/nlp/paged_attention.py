@@ -339,6 +339,13 @@ def _xla_ring_chunk_attn(q, ring_k, ring_v, base_lens, counts, window,
         q.dtype)
 
 
+def normed(norm, hidden):
+    """A layer-level norm under the profiler scope every family gives
+    it (``norm``)."""
+    with jax.named_scope("norm"):
+        return norm(hidden)
+
+
 class PagedResidualLayer:
     """The serving engine's LAYER protocol (``serving/engine.py``) for a
     pre-norm residual decoder layer with ``input_layernorm``,
@@ -352,10 +359,12 @@ class PagedResidualLayer:
 
     def _paged(self, attend, hidden, step, cache):
         att, new = attend(
-            self.input_layernorm(hidden), step["rope"], step["tables"],
-            step["lens"], step["write_blk"], step["write_off"], cache)
+            normed(self.input_layernorm, hidden), step["rope"],
+            step["tables"], step["lens"], step["write_blk"],
+            step["write_off"], cache)
         hidden = hidden + att
-        hidden = hidden + self.mlp(self.post_attention_layernorm(hidden))
+        hidden = hidden + self.mlp(
+            normed(self.post_attention_layernorm, hidden))
         return hidden, new
 
     def paged_decode(self, hidden, step, cache):

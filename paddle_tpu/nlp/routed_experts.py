@@ -22,7 +22,11 @@ __all__ = ["SwiGLUMLP", "StackedExperts", "SigmoidRoutedExperts"]
 
 
 class SwiGLUMLP(Layer):
-    """SwiGLU: down(silu(gate(x)) * up(x))."""
+    """SwiGLU: down(silu(gate(x)) * up(x)). ``scope`` is the profiler
+    scope its operations carry (a dense layer's ``mlp``; an expert
+    block's shared experts say ``moe.shared``)."""
+
+    scope = "mlp"
 
     def __init__(self, hidden_size, intermediate_size):
         super().__init__()
@@ -34,7 +38,9 @@ class SwiGLUMLP(Layer):
                                 bias_attr=False)
 
     def forward(self, x):
-        return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
+        with jax.named_scope(self.scope):
+            return self.down_proj(
+                F.silu(self.gate_proj(x)) * self.up_proj(x))
 
 
 class StackedExperts(Layer):
@@ -82,6 +88,7 @@ class SigmoidRoutedExperts(Layer):
         self._build_router(hidden_size, self.num_experts)
         self.experts = StackedExperts(self.num_experts, hidden_size, width)
         self.shared_experts = SwiGLUMLP(hidden_size, shared_width)
+        self.shared_experts.scope = "moe.shared"
         self.rows_per_expert = None
 
     def _build_router(self, hidden_size, num_experts):

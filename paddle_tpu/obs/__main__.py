@@ -11,6 +11,8 @@
     python -m paddle_tpu.obs watch --url http://127.0.0.1:9100
     python -m paddle_tpu.obs watch --in metrics.json [--slo-in rep.json]
     python -m paddle_tpu.obs check                       # CI gate
+    python -m paddle_tpu.obs profile --in <trace dir or .xplane.pb> \
+        [--mesh-axes axes.json] [--format json]
 
 ``snapshot`` renders a metrics snapshot (live from the ``--demo``
 engine run, or re-rendered offline from a saved ``--in`` JSON dump) as
@@ -18,6 +20,15 @@ Prometheus text or stable-sorted JSON. ``export`` writes/validates the
 Chrome trace-event JSON (open in Perfetto / chrome://tracing); with
 ``--demo`` it drives a tiny CPU serving engine (``--spec`` switches it
 to the speculative arm) so the artifact carries real request spans.
+
+``profile`` reads a saved DEVICE trace (what a
+``paddle_tpu.profiler.Profiler`` session or ``jax.profiler.start_trace``
+wrote: a log directory, the newest trace in it, or one ``.xplane.pb``)
+through ``paddle_tpu.profiler.load_profiler_result`` and prints its four
+tables: programs, device seconds by named scope and phase, collectives
+by mesh axis (``--mesh-axes``: a JSON file ``{axis: groups of partition
+ids}`` as ``parallel.mesh.axis_groups()`` gave them when the trace was
+taken), idle gaps by ``RecordEvent`` span. No chip and no engine needed.
 
 The operability tier (ISSUE 6): ``serve`` runs the live HTTP exporter
 (obs/export.py — ``/metrics`` ``/healthz`` ``/slo`` ``/snapshot``
@@ -756,6 +767,21 @@ def _cmd_check(args):
     return 0
 
 
+def _cmd_profile(args):
+    from paddle_tpu.profiler import load_profiler_result
+
+    axes = None
+    if args.mesh_axes:
+        with open(args.mesh_axes) as f:
+            axes = json.load(f)
+    result = load_profiler_result(args.infile, mesh_axes=axes)
+    if args.format == "json":
+        print(json.dumps(result.to_dict(), sort_keys=True))
+    else:
+        print(result.tables())
+    return 0
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(
         prog="python -m paddle_tpu.obs",
@@ -829,6 +855,15 @@ def main(argv=None):
     p.add_argument("--recipe", action="append", default=None,
                    choices=_CHECK_RECIPES)
     p.set_defaults(fn=_cmd_check)
+
+    p = sub.add_parser("profile",
+                       help="tables of a saved device trace")
+    p.add_argument("--in", dest="infile", required=True,
+                   help="profiler log directory or .xplane.pb")
+    p.add_argument("--mesh-axes", default=None,
+                   help="JSON file {axis: groups of partition ids}")
+    p.add_argument("--format", choices=("text", "json"), default="text")
+    p.set_defaults(fn=_cmd_profile)
 
     args = ap.parse_args(argv)
     if args.cmd == "check":

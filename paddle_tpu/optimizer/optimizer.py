@@ -136,7 +136,8 @@ class Optimizer:
         def upd(p, g, st, d=True):
             return self._apply_one(p, g, st, lr, step, decay=d)
         if self._grad_clip is not None:
-            flat_g = self._grad_clip.clip_values(flat_g)
+            with jax.named_scope("grad.clip"):
+                flat_g = self._grad_clip.clip_values(flat_g)
         new = [upd(p, g, st, d)
                for p, g, st, d in zip(flat_p, flat_g, flat_s, flat_d)]
         new_p = jax.tree_util.tree_unflatten(tdef, [x[0] for x in new])

@@ -35,6 +35,23 @@ def mesh_axis_size(axis: str) -> int:
     return int(_GLOBAL_MESH.shape[axis])
 
 
+def axis_groups(mesh: Mesh | None = None):
+    """``{axis: groups of partition ids}`` of ``mesh`` (default: the
+    installed one): a partition's id is its place in the mesh's flat
+    device order, which is what a compiled collective's
+    ``replica_groups`` count in; a group of ``axis`` holds the ids that
+    differ along that axis alone. What the profiler's reader matches a
+    collective against (``profiler.load_profiler_result(...,
+    mesh_axes=)``); plain lists, so it can be written down beside a saved
+    trace. Axes of size 1 are left out."""
+    mesh = _GLOBAL_MESH if mesh is None else mesh
+    if mesh is None:
+        return {}
+    ids = np.arange(mesh.devices.size).reshape(mesh.devices.shape)
+    return {name: np.moveaxis(ids, k, -1).reshape(-1, ids.shape[k]).tolist()
+            for k, name in enumerate(mesh.axis_names) if ids.shape[k] > 1}
+
+
 class MeshScope:
     """Temporarily install a mesh (used by per-stage pipeline execution)."""
 

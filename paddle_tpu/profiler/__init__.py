@@ -86,11 +86,24 @@ def export_chrome_tracing(dir_name, worker_name=None):
     return handler
 
 
-def load_profiler_result(path):
-    raise NotImplementedError(
-        "load via TensorBoard's profile plugin (XPlane format)"
-    )
+def load_profiler_result(path, mesh_axes=None):
+    """Read a saved device trace (a ``.xplane.pb`` or a profiler log
+    directory; the newest trace in it) into a
+    :class:`~paddle_tpu.profiler.reader.ProfilerResult`: device seconds
+    by program and named scope, collectives by mesh axis (``mesh_axes``:
+    ``parallel.mesh.axis_groups()`` of the mesh that ran, default the
+    installed one), idle gaps by ``RecordEvent`` span. ``print`` it for
+    the tables; ``python -m paddle_tpu.obs profile --in <dir>`` does."""
+    from . import reader  # lazily: the engine's import does not need it
+    from ..parallel import mesh as mesh_state
 
+    if mesh_axes is None and mesh_state.has_mesh():
+        mesh_axes = mesh_state.axis_groups()
+    return reader.load(path, mesh_axes=mesh_axes)
+
+
+# the step-level rows, which carry ``cpu_s``
+_CPU_ROWS = frozenset({"door.pump", "engine.step", "train.run_steps"})
 
 # .stack: this thread's open RecordEvents; .cache_load: seconds of a
 # persistent-cache load whose backend-compile event is still to come
@@ -110,18 +123,23 @@ class RecordEvent:
     ``step_kind`` marks the span as one STEP of the program (the engine
     and the trainer name theirs: mixed, decode, spec_round, train):
     JAX's compile events are charged to the outermost such span open on
-    the thread (:func:`count_compile_events`). Host code only: never
-    inside a jitted body."""
+    the thread (:func:`count_compile_events`). The step-level rows
+    (``door.pump``, ``engine.step``, ``train.run_steps``) also carry
+    ``cpu_s``, this thread's CPU seconds over the span
+    (``time.thread_time``): a slow row whose ``cpu_s`` is small was off
+    the CPU (descheduled, or blocked in the runtime), one whose ``cpu_s``
+    is its duration was the interpreter. Host code only: never inside a
+    jitted body."""
 
     __slots__ = ("name", "args", "step_kind", "id", "parent", "t0", "t1",
-                 "_ann", "_stack")
+                 "_ann", "_stack", "_cpu0")
 
     def __init__(self, name, event_type=None, *, step_kind=None, **ids):
         self.name = name
         self.args = ids
         self.step_kind = step_kind
         self.id = self.parent = self.t0 = self.t1 = None
-        self._ann = self._stack = None
+        self._ann = self._stack = self._cpu0 = None
 
     def begin(self):
         stack = getattr(_open, "stack", None)
@@ -131,6 +149,10 @@ class RecordEvent:
         self.parent = stack[-1].id if stack else None
         self._stack = stack  # its thread's open spans: end() leaves these
         stack.append(self)
+        if self.name in _CPU_ROWS:
+            self._cpu0 = time.thread_time()
+        # nothing between the annotation and the stamp: the two clocks'
+        # durations are held within a millisecond of each other
         self._ann = jax.profiler.TraceAnnotation(self.name)
         self._ann.__enter__()
         self.t0 = time.perf_counter()
@@ -141,6 +163,8 @@ class RecordEvent:
         self.t1 = time.perf_counter()
         self._ann.__exit__(None, None, None)
         self._ann = None
+        if self._cpu0 is not None:
+            self.args["cpu_s"] = time.thread_time() - self._cpu0
         stack = self._stack
         if stack[-1] is self:
             stack.pop()
@@ -297,7 +321,7 @@ class Profiler:
         if on_trace_ready is not None:
             on_trace_ready(self)
         self._step_no = 0
-        self._tracing = False
+        self._tracing = self._traced = False
         self._step_times = []
         self._last_step_t = None
 
@@ -311,7 +335,7 @@ class Profiler:
                 ProfilerState.RECORD, ProfilerState.RECORD_AND_RETURN)
         if want and not self._tracing:
             jax.profiler.start_trace(self._export_dir)
-            self._tracing = True
+            self._tracing = self._traced = True
         elif not want and self._tracing:
             jax.profiler.stop_trace()
             self._tracing = False
@@ -348,7 +372,17 @@ class Profiler:
 
     def summary(self, sorted_by=None, op_detail=True, thread_sep=False,
                 time_unit="ms"):
+        """The step-time line, then (after :meth:`stop`, when a trace was
+        taken) the tables of :func:`load_profiler_result` over it:
+        programs, scopes, collectives, idle gaps. Returns the text."""
         times = self._step_times or [0.0]
         avg = sum(times) / len(times)
-        return (f"steps: {len(times)}  avg: {avg * 1e3:.2f} ms  "
+        text = (f"steps: {len(times)}  avg: {avg * 1e3:.2f} ms  "
                 f"min: {min(times) * 1e3:.2f} ms  max: {max(times) * 1e3:.2f} ms")
+        if self._traced and not self._tracing:
+            try:
+                text += "\n" + load_profiler_result(self._export_dir).tables(
+                    top_ops=4 if op_detail else 0, time_unit=time_unit)
+            except ValueError as e:  # a CPU trace has no device plane
+                text += f"\n(no device tables: {e})"
+        return text

@@ -357,25 +357,28 @@ def paged_decode_math(model, scratch_block, ids_t, seq_lens, tables,
     bs = kc[0].shape[1]
     w = tables.shape[1]
 
-    hidden = core.embed_tokens(ids_t)                # (S, 1, E)
-    rope = core.paged_rope(seq_lens.astype(jnp.float32))
+    with jax.named_scope("embed"):
+        hidden = core.embed_tokens(ids_t)            # (S, 1, E)
+    with jax.named_scope("attn.proj"):
+        rope = core.paged_rope(seq_lens.astype(jnp.float32))
 
-    blk_idx = jnp.clip(seq_lens // bs, 0, w - 1)
-    own_blk = jnp.take_along_axis(tables, blk_idx[:, None],
-                                  axis=1)[:, 0]
-    step = {"rope": rope, "tables": tables, "live": live,
-            "write_blk": jnp.where(live, own_blk, scratch_block),
-            "write_off": jnp.where(live, seq_lens % bs, 0),
-            "lens": jnp.where(live, seq_lens + 1, 1)}
+    with jax.named_scope("cache.write"):     # the step's addressing
+        blk_idx = jnp.clip(seq_lens // bs, 0, w - 1)
+        own_blk = jnp.take_along_axis(tables, blk_idx[:, None],
+                                      axis=1)[:, 0]
+        step = {"rope": rope, "tables": tables, "live": live,
+                "write_blk": jnp.where(live, own_blk, scratch_block),
+                "write_off": jnp.where(live, seq_lens % bs, 0),
+                "lens": jnp.where(live, seq_lens + 1, 1)}
 
     new = []
     for layer, cache in zip(core.layers, _layer_caches(
             model, (kc, vc, ks, vs), st)):
         hidden, arrays = layer.paged_decode(hidden, step, cache)
         new.append(arrays)
-    hidden = core.norm(hidden)
-    logits = model.lm_head(hidden)
-    return (logits._value[:, 0], *_collect_caches(model, new))
+    with jax.named_scope("head"):
+        logits = model.lm_head(core.norm(hidden))._value[:, 0]
+    return (logits, *_collect_caches(model, new))
 
 
 def paged_chunk_math(model, scratch_block, ids_t, seq_lens, tables,
@@ -410,34 +413,39 @@ def paged_chunk_math(model, scratch_block, ids_t, seq_lens, tables,
     bs = kc[0].shape[1]
     w = tables.shape[1]
 
-    hidden = core.embed_tokens(ids_t)                # (S, C, E)
-    rope = core.paged_rope(
-        (seq_lens[:, None]
-         + jnp.arange(c)[None, :]).astype(jnp.float32))
+    with jax.named_scope("embed"):
+        hidden = core.embed_tokens(ids_t)            # (S, C, E)
+    with jax.named_scope("attn.proj"):
+        rope = core.paged_rope(
+            (seq_lens[:, None]
+             + jnp.arange(c)[None, :]).astype(jnp.float32))
 
-    valid = live[:, None]
-    if counts is not None:
-        valid = valid & (jnp.arange(c)[None, :] < counts[:, None])
-    wpos = seq_lens[:, None] + jnp.arange(c)[None, :]
-    blk_idx = jnp.clip(wpos // bs, 0, w - 1)
-    own_blk = jnp.take_along_axis(tables, blk_idx, axis=1)
-    step = {"rope": rope, "tables": tables, "live": live, "valid": valid,
-            "write_blk": jnp.where(valid, own_blk, scratch_block),
-            "write_off": jnp.where(valid, wpos % bs, 0),
-            "lens": jnp.where(live, seq_lens, 0)}
+    with jax.named_scope("cache.write"):     # the step's addressing
+        valid = live[:, None]
+        if counts is not None:
+            valid = valid & (jnp.arange(c)[None, :] < counts[:, None])
+        wpos = seq_lens[:, None] + jnp.arange(c)[None, :]
+        blk_idx = jnp.clip(wpos // bs, 0, w - 1)
+        own_blk = jnp.take_along_axis(tables, blk_idx, axis=1)
+        step = {"rope": rope, "tables": tables, "live": live,
+                "valid": valid,
+                "write_blk": jnp.where(valid, own_blk, scratch_block),
+                "write_off": jnp.where(valid, wpos % bs, 0),
+                "lens": jnp.where(live, seq_lens, 0)}
 
     new = []
     for layer, cache in zip(core.layers, _layer_caches(
             model, (kc, vc, ks, vs), st)):
         hidden, arrays = layer.paged_chunk(hidden, step, cache)
         new.append(arrays)
-    if counts is None:
-        logits = model.lm_head(core.norm(hidden))._value
-    else:
-        last = jnp.maximum(counts - 1, 0)[:, None, None]
-        logits = model.lm_head(core.norm(Tensor(
-            jnp.take_along_axis(hidden._value, last, axis=1),
-            stop_gradient=True)))._value[:, 0]
+    with jax.named_scope("head"):
+        if counts is None:
+            logits = model.lm_head(core.norm(hidden))._value
+        else:
+            last = jnp.maximum(counts - 1, 0)[:, None, None]
+            logits = model.lm_head(core.norm(Tensor(
+                jnp.take_along_axis(hidden._value, last, axis=1),
+                stop_gradient=True)))._value[:, 0]
     return (logits, *_collect_caches(model, new))
 
 
@@ -1114,7 +1122,8 @@ class ServingEngine:
                 self.faults.maybe_corrupt(self.pool)
             pending = None
             try:
-                self._admit()
+                with RecordEvent("engine.admit"):
+                    self._admit()
                 live = self.scheduler.live()
                 self.stats["occupancy_sum"] += (
                     len(live) / self.config.num_slots)
@@ -1904,6 +1913,7 @@ class ServingEngine:
         self._done[slot] = req.finished
 
     # -- the jitted decode quantum ----------------------------------------
+    @jax.named_scope("sample")
     def _select_device(self, logits, keys, n_gen, temps=None):
         if self.decode_strategy == "greedy":
             return jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -1962,12 +1972,13 @@ class ServingEngine:
                         functional_call(model, fwd, [tok_t], {}, p_vals,
                                         [])
                 nxt = self._select_device(logits, keys, n_gen, temps)
-                nxt = jnp.where(done, last_tok, nxt).astype(jnp.int32)
-                n_gen2 = n_gen + live.astype(jnp.int32)
-                done2 = done | (n_gen2 >= max_new)
-                if has_eos:
-                    done2 = done2 | (live & (nxt == eos))
-                seq_lens2 = seq_lens + live.astype(jnp.int32)
+                with jax.named_scope("sample"):   # ... and who retires
+                    nxt = jnp.where(done, last_tok, nxt).astype(jnp.int32)
+                    n_gen2 = n_gen + live.astype(jnp.int32)
+                    done2 = done | (n_gen2 >= max_new)
+                    if has_eos:
+                        done2 = done2 | (live & (nxt == eos))
+                    seq_lens2 = seq_lens + live.astype(jnp.int32)
                 return (kc2, vc2, ks2, vs2, st2, seq_lens2, nxt, n_gen2,
                         done2), (nxt, rows)
 

@@ -35,7 +35,8 @@ SHARED_READERS = {
     "compiles_per_mixed_step", "decode_quantum_ms", "kv_blocks_peak_pct",
     "cache_bytes_per_token", "serve_device_idle_pct", "serve_hbm_peak_gib",
     "queue_wait_ms", "mixed_forward_ms", "mixed_trace_lower_ms",
-    "quantum_host_ms", "quantum_args_ms", "compiles_in_decode"}
+    "quantum_host_ms", "quantum_args_ms", "compiles_in_decode",
+    "mixed_host_ms"}
 
 
 @pytest.fixture(scope="module")

@@ -14,6 +14,7 @@ and the spans change nothing of what the engine does.
 import glob
 import os
 import re
+import statistics
 import threading
 import time
 
@@ -29,7 +30,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # the whole vocabulary (PERF.md section 3 has the same table)
 VOCABULARY = [
-    "door.pump", "engine.step", "request.queued",
+    "door.pump", "engine.step", "engine.admit", "request.queued",
     "engine.mixed", "engine.mixed.prepare", "engine.mixed.forward",
     "engine.mixed.select", "engine.mixed.emit",
     "engine.decode", "engine.decode.prepare", "engine.decode.args",
@@ -43,6 +44,7 @@ BENCHMARK_BASES = ("window", "batch", "submit", "pump", "dispatch", "wait")
 PARENTS = {
     "door.pump": {None},
     "engine.step": {"door.pump"},
+    "engine.admit": {"engine.step"},
     "request.queued": {None},
     "engine.mixed": {"engine.step"},
     "engine.mixed.prepare": {"engine.mixed"},
@@ -210,6 +212,80 @@ def test_one_step_row_per_step_made(run):
                for r in mixed)
     assert sum(r["args"]["padded_tokens"] for r in mixed) \
         == reg.get("serving_mixed_padded_tokens_total").value()
+
+
+def test_admission_is_a_span_of_the_dispatch_half(run):
+    """ISSUE 37: ``_admit`` is spanned every step, under the dispatch
+    half of ``engine.step`` and before the step's own span, so its time
+    (a device round trip a request for its key) is no longer the step's
+    unread self time."""
+    by_id = {r["args"]["id"]: r for r in run["rows"]}
+    admits = [r for r in run["rows"] if r["name"] == "engine.admit"]
+    assert len(admits) == run["door"].engine.stats["steps"]
+    for r in admits:
+        step = by_id[r["args"]["parent"]]
+        assert step["name"] == "engine.step"
+        assert step["args"]["half"] == "dispatch"
+        after = [k for k in run["rows"]
+                 if k["args"]["parent"] == step["args"]["id"]
+                 and k["name"] in ("engine.mixed", "engine.decode")]
+        assert all(r["ts"] + r["dur"] <= k["ts"] + 1e-3 for k in after)
+
+
+def test_step_rows_carry_their_cpu_seconds(run):
+    """The three step-level rows, and only they, carry ``cpu_s``: the
+    thread's CPU seconds over the span, never more than its duration
+    (plus the clocks' grain). A freeze then reads off-CPU or on-CPU from
+    the slowest row alone."""
+    with_cpu = {r["name"] for r in run["rows"] if "cpu_s" in r["args"]}
+    assert with_cpu == {"door.pump", "engine.step", "train.run_steps"}
+    for r in run["rows"]:
+        if r["name"] in with_cpu:
+            assert 0.0 <= r["args"]["cpu_s"] <= r["dur"] * 1e-6 + 2e-3
+    # a span that sleeps is off the CPU; one that spins is on it
+    m0 = mark()
+    with RecordEvent("engine.step"):
+        time.sleep(0.05)
+    with RecordEvent("engine.step"):
+        t0 = time.perf_counter()
+        while time.perf_counter() - t0 < 0.05:
+            pass
+    asleep, spinning = rows_since(m0)
+    assert asleep["args"]["cpu_s"] < 0.02 < spinning["args"]["cpu_s"]
+
+
+def test_mixed_host_ms_is_the_pump_less_the_forward(run, monkeypatch):
+    """The benchmark's ``mixed_host_ms`` (ISSUE 37) on this run's rows:
+    the median over the mixed steps of the ``door.pump`` row that ran the
+    step less its ``engine.mixed.forward``, admission inside it; silent
+    on a program without the rows."""
+    monkeypatch.syspath_prepend(ROOT)
+    from benchmark.harness import program_spans
+    from benchmark.metrics import mixed_host_ms
+
+    rows = program_spans.from_events(run["rows"])
+    by_id = {r["id"]: r for r in rows}
+    want = []
+    for fwd in rows:
+        if fwd["name"] != "engine.mixed.forward":
+            continue
+        step = by_id[by_id[fwd["parent"]]["parent"]]
+        pump = by_id[step["parent"]]
+        assert pump["name"] == "door.pump"
+        admit = [r for r in rows if r["name"] == "engine.admit"
+                 and r["parent"] == step["id"]]
+        assert len(admit) == 1
+        assert pump["seconds"] - fwd["seconds"] >= admit[0]["seconds"]
+        want.append(1e3 * (pump["seconds"] - fwd["seconds"]))
+    stats = run["door"].engine.stats
+    assert len(want) == stats["mixed_steps"]
+    obs = {"engine_steps": {"mixed_steps": stats["mixed_steps"],
+                            "decode_quanta": stats["decode_quanta"]}}
+    monkeypatch.setattr(program_spans, "rows", lambda: rows)
+    assert mixed_host_ms.read(obs) == pytest.approx(
+        statistics.median(want), abs=1e-9)
+    monkeypatch.setattr(program_spans, "rows", lambda: [])
+    assert mixed_host_ms.read(obs) is None
 
 
 def test_the_halves_of_a_quantum_pair_by_their_step(run):
