@@ -14,11 +14,15 @@ import jax.numpy as jnp
 
 from .....nn.layer.layers import Layer
 from .....nn import initializer as I
+from .....ops.pallas import grouped_matmul as _kernel
+from .....ops.pallas.grouped_matmul import swiglu
+from .....ops.pallas._utils import count_traced_program, use_pallas_kernels
 from .....tensor._helpers import apply, ensure_tensor
 from .....parallel import mesh as mesh_state
 from .gate import TopKGate, SwitchGate
 
-__all__ = ["MoELayer", "grouped_expert_ffn"]
+__all__ = ["MoELayer", "grouped_expert_ffn", "moe_products_programs",
+           "swiglu"]
 
 
 # the sorted-rows buffer of `grouped_expert_ffn` (T*k rows of the model
@@ -28,13 +32,25 @@ __all__ = ["MoELayer", "grouped_expert_ffn"]
 _SORTED_ROWS_BYTES = 512 << 20
 
 
+def moe_products_programs():
+    """``moe_products_programs_total{path}`` (``kernel`` | ``ragged_dot``)
+    on the process's registry (an engine's registry shares it): programs
+    traced, by the form their routed experts' two products take."""
+    from .....obs.registry import MetricsRegistry
+
+    return MetricsRegistry.process().counter(
+        "moe_products_programs_total",
+        "programs traced, by the form the routed experts' two products "
+        "take (kernel | ragged_dot)")
+
+
 def grouped_expert_ffn(xt, expert_ids, gate_vals, w1, w2, act, b1=None,
                        b2=None, held=None):
     """The routed experts' FFN as two grouped matrix products
-    (megablocks-style): the T*k routed rows are sorted by expert and fed
-    to ``jax.lax.ragged_dot`` with the per-expert group sizes, so the
-    work is O(T*k) rows whatever the imbalance and no row is ever
-    dropped by this function (a gate that drops hands in weight zero).
+    (megablocks-style): the T*k routed rows are sorted by expert and
+    multiplied with the per-expert group sizes, so the work is O(T*k)
+    rows whatever the imbalance and no row is ever dropped by this
+    function (a gate that drops hands in weight zero).
 
     ``xt`` (T, M) tokens; ``expert_ids`` / ``gate_vals`` (T, k) each
     token's experts and combine weights; ``w1`` (E, M, F1), ``w2``
@@ -44,6 +60,19 @@ def grouped_expert_ffn(xt, expert_ids, gate_vals, w1, w2, act, b1=None,
     got, (E,) int32. The k expert outputs of a token are gathered back
     and summed in float32 in the order of ``expert_ids`` (a gather, not
     a scatter-add: TPUs serialise scatters).
+
+    The two products are ONE algorithm in two forms, chosen by a static
+    rule that reads the inputs (``ops/pallas/grouped_matmul.supports``
+    beside ``use_pallas_kernels``): the Pallas kernel ``grouped_matmul``
+    on a TPU where the mean rows a group is at least its row tile
+    (prefill) and both products' widths are whole lanes,
+    ``jax.lax.ragged_dot`` elsewhere (the CPU, decode's few rows a group,
+    a mesh). Same precision either way, and ``ragged_dot``'s backward.
+    Where ``act`` is :func:`swiglu` itself (no ``b1``) the kernel applies
+    it as the first product's epilogue; any other callable runs after the
+    plain kernel, as it does after ``ragged_dot``.
+    Which form a traced program took is counted once a program:
+    :func:`moe_products_programs`.
 
     ``held=(lo, n)``: this chip HOLDS experts ``lo .. lo + n - 1`` of
     the ones the ids range over (expert parallelism: the router keeps
@@ -57,12 +86,27 @@ def grouped_expert_ffn(xt, expert_ids, gate_vals, w1, w2, act, b1=None,
                 // _SORTED_ROWS_BYTES)
     while t % n_tiles:      # whole tiles of positions
         n_tiles += 1
+    kernel = use_pallas_kernels() and _kernel.supports(
+        t // n_tiles * k, w1, w2)
+    count_traced_program(moe_products_programs(),
+                         "kernel" if kernel else "ragged_dot")
     if n_tiles > 1:
         ys, rows = jax.lax.map(
-            lambda a: grouped_expert_ffn(*a, w1, w2, act, b1, b2, held),
+            lambda a: _expert_ffn_tile(*a, w1, w2, act, b1, b2, held,
+                                       kernel),
             tuple(a.reshape(n_tiles, t // n_tiles, a.shape[-1])
                   for a in (xt, expert_ids, gate_vals)))
         return ys.reshape(t, -1), jnp.sum(rows, axis=0)
+    return _expert_ffn_tile(xt, expert_ids, gate_vals, w1, w2, act, b1, b2,
+                            held, kernel)
+
+
+def _expert_ffn_tile(xt, expert_ids, gate_vals, w1, w2, act, b1, b2, held,
+                     kernel):
+    """One tile of positions of :func:`grouped_expert_ffn`; ``kernel``:
+    its two products take ``grouped_matmul``, not ``ragged_dot``."""
+    product = _kernel.grouped_matmul if kernel else jax.lax.ragged_dot
+    t, k = expert_ids.shape
     e = w1.shape[0]
     with jax.named_scope("moe.dispatch"):
         expert_flat = expert_ids.reshape(-1)                  # (T*k,)
@@ -81,11 +125,19 @@ def grouped_expert_ffn(xt, expert_ids, gate_vals, w1, w2, act, b1=None,
         xs = xt[order // k]                                   # (T*k, M)
 
     with jax.named_scope("moe.products"):
-        h = jax.lax.ragged_dot(xs, w1.astype(xt.dtype), group_sizes)
-        if b1 is not None:
-            h = h + b1[sorted_exp].astype(xt.dtype)
-        h = act(h)
-        out = jax.lax.ragged_dot(h, w2.astype(xt.dtype), group_sizes)
+        w1 = w1.astype(xt.dtype)
+        if kernel and act is swiglu and b1 is None \
+                and _kernel.fuses_swiglu(w1):
+            # the activation as the first product's epilogue: h is never
+            # stored (the same roundings; within one bf16 step of XLA's
+            # fusion on the chip)
+            h = product(xs, w1, group_sizes, swiglu=True)
+        else:
+            h = product(xs, w1, group_sizes)
+            if b1 is not None:
+                h = h + b1[sorted_exp].astype(xt.dtype)
+            h = act(h)
+        out = product(h, w2.astype(xt.dtype), group_sizes)
         if b2 is not None:
             out = out + b2[sorted_exp].astype(xt.dtype)
     with jax.named_scope("moe.combine"):
@@ -190,8 +242,7 @@ class MoELayer(Layer):
 
     def _act(self, h):
         if self.activation == "swiglu":
-            g_, u_ = jnp.split(h, 2, axis=-1)
-            return jax.nn.silu(g_.astype(jnp.float32)).astype(u_.dtype) * u_
+            return swiglu(h)
         return jax.nn.gelu(h.astype(jnp.float32)).astype(h.dtype)
 
     def _grouped_fn(self, xv, gw, w1, b1, w2, b2):

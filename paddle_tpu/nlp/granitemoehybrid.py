@@ -51,7 +51,8 @@ import jax.numpy as jnp
 
 from ..core.tensor import Tensor
 from ..incubate.distributed.models.moe.gate import SoftmaxTopKGate
-from ..incubate.distributed.models.moe.moe_layer import grouped_expert_ffn
+from ..incubate.distributed.models.moe.moe_layer import (
+    grouped_expert_ffn, swiglu)
 from ..nn import initializer as I
 from ..nn.layer.common import Embedding, Linear
 from ..nn.layer.layers import Layer
@@ -448,11 +449,6 @@ class GraniteMoeHybridAttention(LlamaAttention):
             stop_gradient=True))
 
 
-def _swiglu(h):
-    g, u = jnp.split(h, 2, axis=-1)
-    return jax.nn.silu(g.astype(F32)).astype(u.dtype) * u
-
-
 class _Stacked(Layer):
     """A stacked weight ``(experts, in, out)`` under the name ``weight``."""
 
@@ -511,7 +507,7 @@ class GraniteMoeHybridMoE(Layer):
         with jax.named_scope("moe.experts"):
             held = None if self.num_experts == self.published_experts \
                 else self.held
-            y, rows = grouped_expert_ffn(xt, topi, weights, w1, w2, _swiglu,
+            y, rows = grouped_expert_ffn(xt, topi, weights, w1, w2, swiglu,
                                          held=held)
         return y.reshape(xv.shape), rows
 
@@ -536,7 +532,7 @@ class GraniteMoeHybridSharedMLP(Layer):
 
     def forward(self, x):
         h = self.input_linear(x)
-        return self.output_linear(Tensor(_swiglu(h._value),
+        return self.output_linear(Tensor(swiglu(h._value),
                                          stop_gradient=True))
 
 

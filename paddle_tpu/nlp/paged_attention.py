@@ -13,6 +13,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..ops.pallas._utils import count_traced_program, use_pallas_kernels
 from ..parallel import mesh as mesh_state
 
 
@@ -374,18 +375,6 @@ class PagedResidualLayer:
         return self._paged(self.self_attn.paged_chunk, hidden, step, cache)
 
 
-def use_pallas_kernels():
-    """The rule every attention route over the pool follows: the Pallas
-    kernels where the backend is a TPU (or ``FLAGS_pallas_force`` sends
-    a CPU test through the interpreter), unless
-    ``FLAGS_use_pallas_kernels`` is off."""
-    from ..core.flags import get_flags
-
-    flags = get_flags(["FLAGS_use_pallas_kernels", "FLAGS_pallas_force"])
-    return flags["FLAGS_use_pallas_kernels"] and (
-        jax.default_backend() == "tpu" or flags["FLAGS_pallas_force"])
-
-
 def _paged_attn(q, kp, vp, tables, lens, ks=None, vs=None, scale=None):
     """Route decode attention: Pallas paged kernel on TPU (it takes the
     pool arrays as they are stored — no relayout on the way in — and
@@ -404,30 +393,18 @@ def _paged_attn(q, kp, vp, tables, lens, ks=None, vs=None, scale=None):
                                   scale=scale)
 
 
-_programs_counted = {}   # counter name -> the trace it last counted
-
-
-def _count_traced_program(counter, path):
-    """Raise ``counter{path}`` ONCE for the program being traced, however
-    many layers ask: a route is static per compiled program."""
-    trace = jax.core.get_opaque_trace_state()
-    if _programs_counted.get(counter.name) != trace:
-        _programs_counted[counter.name] = trace
-        counter.inc(path=path)
-
-
 def count_chunk_attention_program(path):
     """Raise ``serving_chunk_attention_programs_total{path}`` (``kernel``
     | ``xla``) on the process's registry once for the mixed program being
     traced, so a scrape says which path the mixed programs of this
     process were built with."""
-    _count_traced_program(chunk_attention_programs(), path)
+    count_traced_program(chunk_attention_programs(), path)
 
 
 def count_latent_decode_program(path):
     """The same for the decode quantum of a latent model:
     ``serving_latent_decode_programs_total{path}``."""
-    _count_traced_program(latent_decode_programs(), path)
+    count_traced_program(latent_decode_programs(), path)
 
 
 def chunk_attention_programs():

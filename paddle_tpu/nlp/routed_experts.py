@@ -11,7 +11,8 @@ import jax
 import jax.numpy as jnp
 
 from ..incubate.distributed.models.moe.gate import SigmoidTopKGate
-from ..incubate.distributed.models.moe.moe_layer import grouped_expert_ffn
+from ..incubate.distributed.models.moe.moe_layer import (
+    grouped_expert_ffn, swiglu)
 from ..nn import functional as F
 from ..nn import initializer as I
 from ..nn.layer.common import Linear
@@ -56,11 +57,6 @@ class StackedExperts(Layer):
         self.down_proj = self.create_parameter(
             (num_experts, width, hidden_size),
             default_initializer=I.XavierNormal())
-
-
-def _swiglu(h):
-    g, u = jnp.split(h, 2, axis=-1)
-    return jax.nn.silu(g.astype(jnp.float32)).astype(u.dtype) * u
 
 
 class SigmoidRoutedExperts(Layer):
@@ -112,7 +108,7 @@ class SigmoidRoutedExperts(Layer):
                                 gw.astype(jnp.float32))
             topi, weights, _ = self.decision.topk_assignments(logits, gb)
         with jax.named_scope("moe.experts"):
-            y, rows = grouped_expert_ffn(xt, topi, weights, w1, w2, _swiglu)
+            y, rows = grouped_expert_ffn(xt, topi, weights, w1, w2, swiglu)
         return y.reshape(xv.shape), rows
 
     def forward(self, x):

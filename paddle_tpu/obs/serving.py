@@ -206,13 +206,17 @@ class ServingObs:
                 f"keys one {kind}-attention layer attended, over live "
                 "rows and positions")
             for kind in ("window", "full")}
-        # counted where a program is traced (the routes of the latent
-        # chunk and decode attention are static per program); shown here
+        # counted where a program is traced (the routes of the chunk and
+        # decode attention and the form of the routed experts' products
+        # are static per program); shown here
+        from ..incubate.distributed.models.moe.moe_layer import (
+            moe_products_programs)
         from ..nlp.paged_attention import (
             chunk_attention_programs, latent_decode_programs)
 
         r.share(chunk_attention_programs())
         r.share(latent_decode_programs())
+        r.share(moe_products_programs())
         # on the process's registry too: a reader outside the program
         # finds it after the engine is gone
         self._g_pool_token_bytes = r.share(MetricsRegistry.process().gauge(

@@ -14,6 +14,31 @@ def interpret_mode():
     return jax.default_backend() != "tpu"
 
 
+def use_pallas_kernels():
+    """The rule every route to a Pallas kernel outside training follows
+    (attention over the serving pools, the routed experts' products): the
+    kernels where the backend is a TPU (or ``FLAGS_pallas_force`` sends a
+    CPU test through the interpreter), unless ``FLAGS_use_pallas_kernels``
+    is off."""
+    from ...core.flags import get_flags
+
+    flags = get_flags(["FLAGS_use_pallas_kernels", "FLAGS_pallas_force"])
+    return flags["FLAGS_use_pallas_kernels"] and (
+        jax.default_backend() == "tpu" or flags["FLAGS_pallas_force"])
+
+
+_programs_counted = {}   # counter name -> the trace it last counted
+
+
+def count_traced_program(counter, path):
+    """Raise ``counter{path}`` ONCE for the program being traced, however
+    many layers ask: a route is static per compiled program."""
+    trace = jax.core.get_opaque_trace_state()
+    if _programs_counted.get(counter.name) != trace:
+        _programs_counted[counter.name] = trace
+        counter.inc(path=path)
+
+
 def round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 

@@ -39,7 +39,10 @@ SCOPES = {
     "moe.router": "router logits, top-k, the routing weights",
     "moe.experts": "the routed expert block outside its three parts",
     "moe.dispatch": "sort by expert, gather of the rows, group sizes",
-    "moe.products": "the two ragged_dots and the activation between them",
+    "moe.products": "the routed experts' two grouped products (the "
+                    "grouped_matmul kernel in prefill on a TPU, "
+                    "jax.lax.ragged_dot elsewhere) and the activation "
+                    "between them",
     "moe.combine": "un-sort, the routing weights, the sum over choices",
     "moe.shared": "the shared expert(s)",
     "ssm.in_proj": "a Mamba-2 mixer's input product and split",
@@ -66,5 +69,9 @@ SPANS = (
 # names the TPU compiler writes OVER an operation's op_name where it expands
 # the operation into calls of its own (``ragged-dot-none``,
 # ``ragged-dot-metadata``): a prefix -> the scope the operation sits in.
-# ``jax.lax.ragged_dot`` has one site, the expert block's two products.
+# ``jax.lax.ragged_dot`` has two sites, both the routed experts' two
+# products: ``grouped_expert_ffn`` (under ``moe.products``, where the rule
+# does not take the ``grouped_matmul`` kernel, whose own rows read
+# ``<program>/grouped_matmul``) and ``MoELayer._grouped_ep_fn`` (the
+# expert-parallel schedule inside ``shard_map``).
 COMPILER_NAMES = {"ragged-dot": "moe.products"}

@@ -132,6 +132,41 @@ def test_every_equation_sits_in_a_scope(family, program):
     assert EXPECTED[family] <= seen, EXPECTED[family] - seen
 
 
+@pytest.mark.parametrize("path", ["kernel", "ragged_dot"])
+def test_both_forms_of_the_products_sit_under_moe_products(path,
+                                                           monkeypatch):
+    """The routed experts' two products under either form the rule picks
+    (ISSUE 38): the ``grouped_matmul`` kernel's calls (on a chip trace
+    their rows read ``<program>/grouped_matmul``, as the chunk kernel's
+    do) and the ``ragged_dot``s (``ragged-dot-none`` there, mapped by
+    ``COMPILER_NAMES``) are equations of ``moe.products``."""
+    from paddle_tpu.incubate.distributed.models.moe.moe_layer import (
+        grouped_expert_ffn)
+    from paddle_tpu.ops.pallas import grouped_matmul as kernel
+
+    monkeypatch.setattr(kernel, "_BLOCK_M", 32)
+    paddle.set_flags({"FLAGS_pallas_force": path == "kernel"})
+    try:
+        jaxpr = jax.make_jaxpr(lambda x, i, g, a, b: grouped_expert_ffn(
+            x, i, g, a, b, jax.nn.gelu))(
+                jnp.zeros((64, 128)), jnp.zeros((64, 2), jnp.int32),
+                jnp.ones((64, 2)), jnp.zeros((4, 128, 128)),
+                jnp.zeros((4, 128, 128)))
+    finally:
+        paddle.set_flags({"FLAGS_pallas_force": False})
+    rows = list(leaves(jaxpr.jaxpr))
+    products = [stack for p, stack, eqn in rows
+                if p == ("pallas_call" if path == "kernel"
+                         else "ragged_dot_general")]
+    assert len(products) == 2
+    assert {scope_of(stack)[0] for stack in products} == {"moe.products"}
+    if path == "kernel":
+        assert not [p for p, _, _ in rows if p == "ragged_dot_general"]
+    assert not [(p, stack) for p, stack, eqn in rows
+                if scope_of(stack)[0] == "unscoped"
+                and not allowed_outside(p, eqn)]
+
+
 def test_every_scope_has_a_site():
     """No dead vocabulary: every scope is written by some program above,
     ``moe.experts`` (the block outside its three parts: a tiled call's

@@ -24,7 +24,7 @@ POOL_BLOCKS = SLOTS * TABLE_W + 1
 
 _KERNEL_MODULES = ("flash_attention", "rms_norm", "decode_attention",
                    "paged_attention", "varlen_flash_attention",
-                   "chunk_attention")
+                   "chunk_attention", "grouped_matmul")
 
 
 @pytest.fixture(scope="module")
@@ -406,6 +406,37 @@ def test_gqa_chunk_attention(chip, shapes, pallas_forced):
                                      "copy-done"))
 
 
+# a mixed step's routed rows (a tile's, in the state-space cell), experts
+# held, and the two products' (K, N)
+_EXPERT_PRODUCTS = {
+    "trinity_p1": (65536, 128, 2048, 2048),
+    "trinity_p2": (65536, 128, 1024, 2048),
+    "kanana_p1": (98304, 128, 2048, 1536),
+    "kanana_p2": (98304, 128, 768, 2048),
+    "granite_p1": (40960, 36, 4096, 1536),
+    "granite_p2": (40960, 36, 768, 4096),
+}
+
+
+@pytest.mark.parametrize("shapes", sorted(_EXPERT_PRODUCTS))
+def test_grouped_matmul(chip, shapes):
+    """The routed experts' products of the three expert cells' mixed
+    steps compile to the kernel at its own tiles (the first with the
+    SwiGLU epilogue, as the cells run it): two whole (K, N) weight
+    blocks, a row tile in and one out fit the VMEM limit it asks for."""
+    import functools
+
+    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    rows, e, k, n = _EXPERT_PRODUCTS[shapes]
+    bf16 = jnp.bfloat16
+    assert _compiled_kernels(
+        chip, functools.partial(grouped_matmul,
+                                swiglu=shapes.endswith("p1")),
+        ((rows, k), bf16), ((e, k, n), bf16),
+        ((e,), jnp.int32)) == {"grouped_matmul"}
+
+
 def _layouts(text, elements, rows, but_leading=None):
     """``(opcode, minor-to-major order)`` of every instruction of a compiled
     program whose result has ``elements`` elements in rows of one of the
@@ -527,3 +558,10 @@ def test_the_window_cells_programs_move_no_ring_and_no_pool(chip,
     assert len(re.findall(r" custom-call\(.*gqa_chunk_attention/pallas_call",
                           mixed)) == 5
     assert not re.search(r"f32\[8,4,8,1024,\d+\]", mixed)
+    # the four expert layers' two products are the grouped-matmul kernel
+    # in the mixed step (65,536 routed rows over 128 experts) and
+    # ragged_dot in the quantum (512 rows): ISSUE 38's rule
+    assert len(re.findall(r" custom-call\(.*grouped_matmul/pallas_call",
+                          mixed)) == 8
+    assert "grouped_matmul" not in compiled_kernel_names(
+        compiled["quantum"].as_text())
