@@ -27,6 +27,10 @@ from .afmoe import (  # noqa: F401
     AfmoeConfig, AfmoeAttention, AfmoeMoE, AfmoeDecoderLayer, AfmoeModel,
     AfmoeForCausalLM,
 )
+from .nemotron_h import (  # noqa: F401
+    NemotronHConfig, NemotronHMoE, NemotronHBlock, NemotronHModel,
+    NemotronHForCausalLM,
+)
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForPretraining,

@@ -43,12 +43,11 @@ import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
-from ..nn import initializer as I
 from ..nn.layer.common import Embedding, Linear
 from ..nn.layer.layers import Layer
 from ..nn.layer.norm import RMSNorm
 from . import paged_attention as PA
-from .routed_experts import SigmoidRoutedExperts, SwiGLUMLP
+from .routed_experts import GateLeaves, SigmoidRoutedExperts, SwiGLUMLP
 
 __all__ = ["DeepseekV3Config", "DeepseekV3Attention", "DeepseekV3MLP",
            "DeepseekV3MoE", "DeepseekV3DecoderLayer", "DeepseekV3Model",
@@ -430,17 +429,9 @@ class DeepseekV3Attention(Layer):
 DeepseekV3MLP = SwiGLUMLP
 
 
-class DeepseekV3Gate(Layer):
-    """The router's parameters: ``weight`` (E_model, experts) and the
-    selection bias ``e_score_correction_bias`` (experts,)."""
-
-    def __init__(self, hidden_size, num_experts):
-        super().__init__()
-        self.weight = self.create_parameter(
-            (hidden_size, num_experts),
-            default_initializer=I.XavierNormal())
-        self.e_score_correction_bias = self.create_parameter(
-            (num_experts,), is_bias=True)
+# the router's parameters: ``weight`` (E_model, experts) and the selection
+# bias ``e_score_correction_bias`` (experts,)
+DeepseekV3Gate = GateLeaves
 
 
 class DeepseekV3MoE(SigmoidRoutedExperts):

@@ -8,19 +8,23 @@ Equations (``r`` = ``residual_multiplier``, eps ``rms_norm_eps``):
 Mixer(RMSNorm(x)); v = RMSNorm(x); x += r * (Routed(v) + Shared(v))``;
 ``logits = RMSNorm(x) Embed^T / logits_scaling`` (a tied head).
 
-- State-space mixer (``layer_types[i] == "mamba"``; ``d_in = mamba_expand x
-  hidden = mamba_n_heads x mamba_d_head``, ``N = mamba_d_state``, one group:
-  B and C are shared by all heads): ``[z | xBC | dt_raw] = u W_in``
-  (``d_in | d_in + 2N | heads``); a depthwise causal convolution of width
-  ``mamba_d_conv`` with bias over ``xBC``, zeros before a sequence's first
-  token, then silu; ``[xs | B | C] = xBC``. Per head, in float32: ``dt =
-  softplus(dt_raw + dt_bias)``, ``A = -exp(A_log)``, ``H_t = exp(dt_t A)
-  H_{t-1} + dt_t xs_t (x) B_t`` (a ``d_head x N`` state, ``H_0 = 0``),
-  ``y_t = H_t C_t + D xs_t``. Gate, then norm: ``g = y * silu(z)``; ``g *
-  rsqrt(mean(g^2) + eps) * w_norm``; ``out = g W_out``.
+- State-space mixer (``layer_types[i] == "mamba"``; :class:`Mamba2Mixer`,
+  which ``nlp/nemotron_h.py`` shares: ``d_in = heads x head width`` (here
+  also ``mamba_expand x hidden``), ``N`` the state width, ``G`` groups: B
+  and C are shared by the ``heads / G`` heads of a group, head ``h`` uses
+  group ``h // (heads / G)``; this family has one group): ``[z | xBC |
+  dt_raw] = u W_in`` (``d_in | d_in + 2GN | heads``); a depthwise causal
+  convolution of width ``mamba_d_conv`` with bias over ``xBC``, zeros before
+  a sequence's first token, then silu; ``[xs | B | C] = xBC`` with B, C of
+  shape (G, N). Per head, in float32: ``dt = softplus(dt_raw + dt_bias)``,
+  ``A = -exp(A_log)``, ``H_t = exp(dt_t A) H_{t-1} + dt_t xs_t (x) B_t`` (a
+  ``d_head x N`` state, ``H_0 = 0``), ``y_t = H_t C_t + D xs_t``. Gate, then
+  norm PER GROUP of ``d_in / G`` channels: ``g = y * silu(z)``; ``g *
+  rsqrt(mean_group(g^2) + eps) * w_norm``; ``out = g W_out``.
 - The same sum over a chunk of C positions with incoming state ``H_0``
   (:func:`ssd_chunk`): ``c_t = sum_{s<=t} dt_s A``; ``y_t = sum_{s<=t}
-  exp(c_t - c_s) dt_s (C_t . B_s) xs_s + exp(c_t) H_0 C_t + D xs_t``;
+  exp(c_t - c_s) dt_s (C_t . B_s) xs_s + exp(c_t) H_0 C_t + D xs_t``
+  (``C_t . B_s`` taken within the head's group);
   ``H_C = exp(c_C) H_0 + sum_s exp(c_C - c_s) dt_s xs_s (x) B_s``. A
   position with ``dt = 0`` is the identity. What serving keeps per request
   and state layer is ``H`` (float32) and the convolution's last
@@ -41,8 +45,10 @@ Mixer(RMSNorm(x)); v = RMSNorm(x); x += r * (Routed(v) + Shared(v))``;
 Serving only (``paddle.inference.serve``); ``forward`` is the plain
 whole-sequence pass in chunks of ``mamba_chunk_size`` the tests compare
 with. Not done here: training, ``generate`` over a dense cache, tensor
-parallelism, ``mamba_n_groups`` > 1, biases on the projections,
-``time_step_limit`` other than (0, inf).
+parallelism, biases on the projections, ``time_step_limit`` other than
+(0, inf). The mixer and :func:`ssd_chunk` take any number of groups; this
+family's published configs have one, and ``mamba_expand x hidden`` has to
+be ``heads x head width`` (a config that states otherwise is refused).
 """
 from __future__ import annotations
 
@@ -61,8 +67,9 @@ from ..tensor._helpers import apply
 from .llama import LlamaAttention
 from .paged_attention import normed
 
-__all__ = ["GraniteMoeHybridConfig", "GraniteMoeHybridMamba",
-           "GraniteMoeHybridAttention", "GraniteMoeHybridMoE",
+__all__ = ["GraniteMoeHybridConfig", "Mamba2Mixer", "GraniteMoeHybridMamba",
+           "NoPositionAttention", "GraniteMoeHybridAttention",
+           "GraniteMoeHybridMoE",
            "GraniteMoeHybridDecoderLayer", "GraniteMoeHybridModel",
            "GraniteMoeHybridForCausalLM", "ssd_chunk"]
 
@@ -94,7 +101,8 @@ class GraniteMoeHybridConfig:
             layer_types = (_PERIOD * (-(-num_hidden_layers // 10))
                            )[:num_hidden_layers]
         for what, bad in (
-                ("mamba_n_groups other than 1", mamba_n_groups != 1),
+                ("mamba_n_groups that does not divide mamba_n_heads",
+                 mamba_n_groups < 1 or mamba_n_heads % mamba_n_groups),
                 ("a bias on the projections",
                  mamba_proj_bias or attention_bias),
                 ("a convolution without bias", not mamba_conv_bias),
@@ -131,6 +139,7 @@ class GraniteMoeHybridConfig:
         self.mamba_d_head = mamba_d_head
         self.mamba_d_state = mamba_d_state
         self.mamba_d_conv = mamba_d_conv
+        self.mamba_n_groups = mamba_n_groups
         self.mamba_chunk_size = mamba_chunk_size
         self.attention_multiplier = attention_multiplier
         self.embedding_multiplier = embedding_multiplier
@@ -155,7 +164,8 @@ class GraniteMoeHybridConfig:
     @property
     def mamba_conv_dim(self):
         """What the convolution runs over: ``[xs | B | C]``."""
-        return self.mamba_d_inner + 2 * self.mamba_d_state
+        return (self.mamba_d_inner
+                + 2 * self.mamba_n_groups * self.mamba_d_state)
 
     @staticmethod
     def tiny(**overrides):
@@ -187,43 +197,57 @@ _DECAY_BYTES = 128 << 20
 def ssd_chunk(xs, b, c, dt, a, d, h0, keep=None):
     """The state-space sum over one chunk (the module's second equation
     block), in float32. ``xs`` (S, C, H, P) inputs as heads, ``b`` / ``c``
-    (S, C, N) shared by the heads, ``dt`` (S, C, H) >= 0 (0: that position
-    is the identity), ``a`` (H,) < 0, ``d`` (H,), ``h0`` (S, H, P, N) the
-    incoming state; ``keep`` (S,) 0 | 1 multiplies what ``h0`` adds (0: the
-    row starts from a zero state; the factor rides the decays, so no
-    zeroed copy of the state is ever made). Returns ``y`` (S, C, H, P) and
-    the outgoing state.
+    (S, C, G, N), each group shared by ``H / G`` heads in order, ``dt``
+    (S, C, H) >= 0 (0: that position is the identity), ``a`` (H,) < 0,
+    ``d`` (H,), ``h0`` (S, H, P, N) the incoming state; ``keep`` (S,) 0 | 1
+    multiplies what ``h0`` adds (0: the row starts from a zero state; the
+    factor rides the decays, so no zeroed copy of the state is ever made).
+    Returns ``y`` (S, C, H, P) and the outgoing state.
 
-    The (S, g, C, C) decay matrix is built for a group of ``g`` heads at a
-    time under ``_DECAY_BYTES``; every product contracts over positions
-    or the state width on the matrix unit."""
+    The (S, g, C, C) decay matrix is built for ``g`` heads at a time under
+    ``_DECAY_BYTES``, whole groups or an equal part of one; every product
+    contracts over positions or the state width on the matrix unit."""
     s_, cl, h, p = xs.shape
+    per = h // b.shape[2]                       # heads a group
     xs, b, c = xs.astype(F32), b.astype(F32), c.astype(F32)
     cum = jnp.cumsum(dt * a, axis=1).transpose(0, 2, 1)     # (S, H, C)
     carried = jnp.exp(cum)                    # what of h0 each position sees
     if keep is not None:
         carried = carried * keep.astype(F32)[:, None, None]
     x_dt = xs * dt[..., None]                               # dt_s xs_s
-    cb = jnp.einsum("stn,sun->stu", c, b)                   # C_t . B_u
+    cb = jnp.einsum("stgn,sugn->sgtu", c, b)                # C_t . B_u
     causal = jnp.arange(cl)[:, None] >= jnp.arange(cl)[None, :]
     group = max(1, min(h, _DECAY_BYTES // (s_ * cl * cl * 4)))
-    while h % group:
+    while h % group or (group % per and per % group):
         group -= 1
+    ng = max(1, group // per)       # B / C groups the streamed heads span
+    k = group // ng                 # heads of each
 
     def heads(lo):
         def cut(v, axis):
             return jax.lax.dynamic_slice_in_dim(v, lo, group, axis)
 
+        def of_group(v, axis):      # the streamed heads' B / C groups
+            return jax.lax.dynamic_slice_in_dim(v, lo // per, ng, axis)
+
+        def split(v, axis):         # a heads axis as (groups, heads of each)
+            return v.reshape(*v.shape[:axis], ng, k, *v.shape[axis + 1:])
+
         cg, xg, kg = cut(cum, 1), cut(x_dt, 2), cut(carried, 1)
+        bg, cgr = of_group(b, 2), of_group(c, 2)            # (S, C, ng, N)
         diff = cg[..., :, None] - cg[..., None, :]          # c_t - c_u
-        m = cb[:, None] * jnp.exp(jnp.where(causal, diff, -jnp.inf))
+        m = (of_group(cb, 1)[:, :, None] * split(
+            jnp.exp(jnp.where(causal, diff, -jnp.inf)), 1)
+             ).reshape(s_, group, cl, cl)
         y = (jnp.einsum("shtu,suhp->sthp", m, xg)
-             + jnp.einsum("shpn,stn->sthp", cut(h0, 1), c)
+             + jnp.einsum("sgkpn,stgn->stgkp", split(cut(h0, 1), 1), cgr
+                          ).reshape(s_, cl, group, p)
              * kg.transpose(0, 2, 1)[..., None]
              + cut(d, 0)[:, None] * cut(xs, 2))
         h1 = (cut(h0, 1) * kg[..., -1][..., None, None]
-              + jnp.einsum("shu,suhp,sun->shpn",
-                           jnp.exp(cg[..., -1:] - cg), xg, b))
+              + jnp.einsum("sgku,sugkp,sugn->sgkpn",
+                           split(jnp.exp(cg[..., -1:] - cg), 1),
+                           split(xg, 2), bg).reshape(s_, group, p, -1))
         return y, h1
 
     if group == h:
@@ -253,41 +277,48 @@ class _Conv1d(Layer):
         self.bias = self.create_parameter((channels,), is_bias=True)
 
 
-class GraniteMoeHybridMamba(Layer):
-    """The Mamba-2 mixer; see the module's equations."""
+class Mamba2Mixer(Layer):
+    """The Mamba-2 mixer of the module's equations, by its sizes: ``heads``
+    x ``d_head`` inner channels, ``groups`` of B and C of width ``d_state``,
+    a convolution of width ``d_conv``; ``forward`` sums ``chunk_size``
+    positions at a time. Parameter names are the source's (``in_proj``,
+    ``conv1d``, ``dt_bias``, ``A_log``, ``D``, ``norm``, ``out_proj``)."""
 
-    def __init__(self, config: GraniteMoeHybridConfig):
+    def __init__(self, hidden_size, heads, d_head, d_state, d_conv=4,
+                 groups=1, chunk_size=256, eps=1e-5):
         super().__init__()
-        self.config = config
-        h, d_in = config.mamba_n_heads, config.mamba_d_inner
-        self.in_proj = Linear(config.hidden_size,
-                              d_in + config.mamba_conv_dim + h,
+        if heads % groups:
+            raise ValueError(f"{groups} groups do not divide {heads} heads")
+        self.heads, self.d_head, self.d_state = heads, d_head, d_state
+        self.d_conv, self.groups, self.chunk_size = d_conv, groups, chunk_size
+        self.eps = float(eps)
+        self.d_inner = heads * d_head
+        self.conv_dim = self.d_inner + 2 * groups * d_state
+        self.in_proj = Linear(hidden_size,
+                              self.d_inner + self.conv_dim + heads,
                               bias_attr=False)
-        self.conv1d = _Conv1d(config.mamba_conv_dim, config.mamba_d_conv)
-        self.dt_bias = self.create_parameter((h,), is_bias=True)
-        self.A_log = self.create_parameter((h,), is_bias=True)
+        self.conv1d = _Conv1d(self.conv_dim, d_conv)
+        self.dt_bias = self.create_parameter((heads,), is_bias=True)
+        self.A_log = self.create_parameter((heads,), is_bias=True)
         self.D = self.create_parameter(
-            (h,), default_initializer=I.Constant(1.0))
-        self.norm = RMSNorm(d_in, epsilon=config.rms_norm_eps)
-        self.out_proj = Linear(d_in, config.hidden_size, bias_attr=False)
+            (heads,), default_initializer=I.Constant(1.0))
+        self.norm = RMSNorm(self.d_inner, epsilon=eps)
+        self.out_proj = Linear(self.d_inner, hidden_size, bias_attr=False)
 
     def state_arrays(self):
         """What a slot keeps for this layer, as ``(shape, dtype)``: the
         recurrence's state in float32 and the convolution's last inputs
         in the model's dtype (None)."""
-        cfg = self.config
-        return [((cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state),
-                 "float32"),
-                ((cfg.mamba_d_conv - 1, cfg.mamba_conv_dim), None)]
+        return [((self.heads, self.d_head, self.d_state), "float32"),
+                ((self.d_conv - 1, self.conv_dim), None)]
 
     # -- shared pieces ------------------------------------------------------
     def _project(self, u):
         """u (..., E) -> z (..., d_in), xBC (..., conv_dim) and
         ``softplus(dt_raw + dt_bias)`` (..., H) float32, raw arrays."""
-        cfg = self.config
         with jax.named_scope("ssm.in_proj"):
             zxd = self.in_proj(u)._value
-            d_in, cd = cfg.mamba_d_inner, cfg.mamba_conv_dim
+            d_in, cd = self.d_inner, self.conv_dim
             dt = jax.nn.softplus(zxd[..., d_in + cd:].astype(F32)
                                  + self.dt_bias._value.astype(F32))
             return zxd[..., :d_in], zxd[..., d_in:d_in + cd], dt
@@ -304,21 +335,22 @@ class GraniteMoeHybridMamba(Layer):
                            ).astype(window.dtype)
 
     def _split(self, xbc):
-        """[xs | B | C] -> xs as heads (..., H, P), B, C (..., N)."""
-        cfg = self.config
-        d_in, n = cfg.mamba_d_inner, cfg.mamba_d_state
-        xs = xbc[..., :d_in].reshape(*xbc.shape[:-1], cfg.mamba_n_heads,
-                                     cfg.mamba_d_head)
-        return xs, xbc[..., d_in:d_in + n], xbc[..., d_in + n:]
+        """[xs | B | C] -> xs as heads (..., H, P), B, C (..., G, N)."""
+        d_in, gn = self.d_inner, self.groups * self.d_state
+        lead = xbc.shape[:-1]
+        return (xbc[..., :d_in].reshape(*lead, self.heads, self.d_head),
+                xbc[..., d_in:d_in + gn].reshape(*lead, self.groups, -1),
+                xbc[..., d_in + gn:].reshape(*lead, self.groups, -1))
 
     def _out(self, y, z):
-        """Gate by silu(z), norm over all of ``d_in``, project out.
-        ``y`` (..., H, P) float32, ``z`` (..., d_in)."""
+        """Gate by silu(z), norm over each group's ``d_in / G`` channels,
+        project out. ``y`` (..., H, P) float32, ``z`` (..., d_in)."""
         with jax.named_scope("ssm.out"):
             g = y.reshape(z.shape) * jax.nn.silu(z.astype(F32))
-            g = g * jax.lax.rsqrt(
+            g = g.reshape(*z.shape[:-1], self.groups, -1)
+            g = (g * jax.lax.rsqrt(
                 jnp.mean(jnp.square(g), axis=-1, keepdims=True)
-                + self.config.rms_norm_eps)
+                + self.eps)).reshape(z.shape)
             g = (g * self.norm.weight._value.astype(F32)).astype(z.dtype)
             return self.out_proj(Tensor(g, stop_gradient=True))
 
@@ -354,16 +386,15 @@ class GraniteMoeHybridMamba(Layer):
 
     # -- the whole-sequence pass --------------------------------------------
     def forward(self, u):
-        """u (B, S, E) from zero state, ``mamba_chunk_size`` positions at
-        a time; nothing is kept."""
-        cfg = self.config
+        """u (B, S, E) from zero state, ``chunk_size`` positions at a
+        time; nothing is kept."""
         bsz, s = u.shape[0], u.shape[1]
         (shape, _), (tshape, _) = self.state_arrays()
         state = jnp.zeros((bsz, *shape), F32)
         tail = jnp.zeros((bsz, *tshape), u._value.dtype)
         outs = []
-        for lo in range(0, s, cfg.mamba_chunk_size):
-            part = u[:, lo:lo + cfg.mamba_chunk_size]
+        for lo in range(0, s, self.chunk_size):
+            part = u[:, lo:lo + self.chunk_size]
             n = part.shape[1]
             out, (state, tail) = self._chunk(
                 part, jnp.ones((bsz, n), F32), tail, state,
@@ -392,7 +423,6 @@ class GraniteMoeHybridMamba(Layer):
     def paged_decode(self, u, step, cache):
         """One position a slot: the recurrence itself, the state read and
         written once."""
-        cfg = self.config
         state0, tail0 = cache
         live = step["live"]
         z, xbc, dt = self._project(u)                  # (S, 1, .)
@@ -403,10 +433,16 @@ class GraniteMoeHybridMamba(Layer):
             xs, b, c, dt = xs.astype(F32), b.astype(F32), c.astype(F32), \
                 dt[:, 0]
             a = -jnp.exp(self.A_log._value.astype(F32))
+            # heads as (groups, heads of each): a group's B and C row is
+            # broadcast over its heads, never repeated in memory
+            by_group = (-1, self.groups, self.heads // self.groups,
+                        self.d_head, self.d_state)
             state = (state0 * jnp.exp(dt * a)[..., None, None]
-                     + (xs * dt[..., None])[..., None]
-                     * b[:, None, None, :])
-            y = (jnp.sum(state * c[:, None, None, :], axis=-1)
+                     + ((xs * dt[..., None])[..., None].reshape(
+                         *by_group[:4], 1)
+                        * b[:, :, None, None, :]).reshape(state0.shape))
+            y = (jnp.sum(state.reshape(by_group) * c[:, :, None, None, :],
+                         axis=-1).reshape(xs.shape)
                  + self.D._value.astype(F32)[:, None] * xs)
         out = self._out(y[:, None], z)
         with jax.named_scope("cache.write"):
@@ -416,14 +452,27 @@ class GraniteMoeHybridMamba(Layer):
                     tail0.dtype), tail0))
 
 
-class GraniteMoeHybridAttention(LlamaAttention):
-    """GQA without positions, scores times ``attention_multiplier``:
-    ``LlamaAttention``'s projections and its paged K/V forms, with the
-    rotation an identity and the scale handed in."""
+class GraniteMoeHybridMamba(Mamba2Mixer):
+    """:class:`Mamba2Mixer` at this family's keys."""
 
     def __init__(self, config: GraniteMoeHybridConfig):
+        super().__init__(
+            config.hidden_size, config.mamba_n_heads, config.mamba_d_head,
+            config.mamba_d_state, config.mamba_d_conv,
+            config.mamba_n_groups, config.mamba_chunk_size,
+            config.rms_norm_eps)
+
+
+class NoPositionAttention(LlamaAttention):
+    """GQA without positions, scores times ``softmax_scale``:
+    ``LlamaAttention``'s projections and its paged K/V forms, with the
+    rotation an identity and the scale handed in (None: ``1 /
+    sqrt(head_dim)``)."""
+
+    def __init__(self, config, softmax_scale=None):
         super().__init__(config)
-        self.softmax_scale = float(config.attention_multiplier)
+        self.softmax_scale = (float(softmax_scale) if softmax_scale
+                              else self.head_dim ** -0.5)
 
     def _rotate(self, x, rope):
         return x
@@ -447,6 +496,14 @@ class GraniteMoeHybridAttention(LlamaAttention):
         return self.o_proj(Tensor(
             out.astype(x._value.dtype).reshape(b, s, h * d),
             stop_gradient=True))
+
+
+class GraniteMoeHybridAttention(NoPositionAttention):
+    """:class:`NoPositionAttention` with ``attention_multiplier`` as the
+    scale."""
+
+    def __init__(self, config: GraniteMoeHybridConfig):
+        super().__init__(config, config.attention_multiplier)
 
 
 class _Stacked(Layer):

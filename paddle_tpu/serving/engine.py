@@ -45,8 +45,10 @@ head call, and know nothing of what a layer computes. Of the MODEL they ask
   whose per-slot arrays ``state`` lists as ``(shape, dtype)``; a None in a
   shape is the length of a window layer's RING of keys, ``window`` (the
   layout's, 0 or absent without such layers) plus ``prefill_chunk`` in
-  whole blocks). A model whose layers all cache blocks has an empty slot
-  side: no aval. What a model's layers cannot serve (one uniform window
+  whole blocks; ``"none"``: NOTHING, a layer that is a feed-forward alone,
+  as ``nlp/nemotron_h.py``'s expert layers are: it is handed an empty
+  tuple, hands one back, and takes no place on either side of the pool).
+  A model whose layers all cache blocks has an empty slot side: no aval. What a model's layers cannot serve (one uniform window
   over the block path) the layout refuses, by raising; the engine reads no
   attribute of a model's config to decide it.
 
@@ -68,7 +70,9 @@ layer over K/V or latent attention (``nlp/llama.py``,
 ``nlp/deepseek_v3.py``: ``self_attn.paged_decode`` / ``paged_chunk``);
 ``nlp/granitemoehybrid.py`` has state-space layers beside attention,
 ``nlp/afmoe.py`` window layers (rings: position ``p`` at row ``p mod R``,
-what a row may see decided by positions alone) beside full ones. A
+what a row may see decided by positions alone) beside full ones,
+``nlp/nemotron_h.py`` layers of ONE part each: a state-space mixer, an
+attention or routed experts (``"none"``) under one norm. A
 feed-forward that routes rows to experts shows ``rows_per_expert``; both
 programs hand back the rows the experts HELD here got beside the tokens
 (``moe_rows``; an empty tuple, no aval, without experts).
@@ -274,10 +278,13 @@ def _layer_caches(model, pools, state):
     with block arrays gets ``(k, v, k_scale, v_scale)`` of its place among
     such layers (a side the pool lacks, the scales of a float pool or the
     V side of a latent pool, is an empty tuple and reads None); a
-    ``"state"`` layer gets its tuple of per-slot arrays."""
+    ``"state"`` layer gets its tuple of per-slot arrays, a layer that
+    caches nothing (``"none"``) an empty tuple."""
     out, n_block, n_state = [], 0, 0
     for kind in model.paged_cache_layout()["layers"]:
-        if kind == "state":
+        if kind == "none":
+            out.append(())
+        elif kind == "state":
             out.append(state[n_state])
             n_state += 1
         else:
@@ -289,9 +296,12 @@ def _layer_caches(model, pools, state):
 
 def _collect_caches(model, new):
     """The layers' new cache arrays back in the step's order: the four
-    block sides (a None side stays empty) and the state side."""
+    block sides (a None side stays empty) and the state side; a layer
+    that caches nothing has no place on either."""
     sides, state = ([], [], [], []), []
     for kind, arrays in zip(model.paged_cache_layout()["layers"], new):
+        if kind == "none":
+            continue
         if kind == "state":
             state.append(tuple(arrays))
             continue
@@ -305,9 +315,10 @@ def _collect_caches(model, new):
 def _expert_blocks(model):
     """The feed-forward blocks of ``model`` that route rows to experts
     (each with ``rows_per_expert`` over the ``num_experts`` it holds,
-    ``router.top_k`` and ``inactive_params_per_token()``)."""
+    ``router.top_k`` and ``inactive_params_per_token()``): a layer shows
+    its own as ``mlp`` (None, or no such attribute, without one)."""
     return [layer.mlp for layer in model.decoder.layers
-            if hasattr(layer.mlp, "rows_per_expert")]
+            if hasattr(getattr(layer, "mlp", None), "rows_per_expert")]
 
 
 def moe_rows(model):
@@ -762,7 +773,7 @@ class ServingEngine:
         # (none: an empty side, no aval); a ring holds the window and one
         # chunk, in whole blocks
         n_state = kinds.count("state")
-        n_block = len(kinds) - n_state
+        n_block = len(kinds) - n_state - kinds.count("none")
         self.pool = PagedKVCachePool(
             num_blocks, bs, layout["num_kv_heads"], layout["head_dim"],
             num_layers=n_block, dtype=cache_dtype,

@@ -225,17 +225,18 @@ def test_the_cell_and_its_files(run, real, cfg):
     assert names == NEW_READERS | TRACE_READERS | SHARED_READERS
     assert {m["name"] for m in run.metrics_of(real, cell, "end_to_end")} \
         == {"out_tok_s", "gap_p95_ms", "setup_s"}
-    # the new entries are the lists' last, and the cell the last of the
-    # lists it joined
-    assert real["configs"][-1]["name"] == CONFIG
-    assert real["workloads"][-1]["name"] == CELL
+    # the new entries were appended, and the cell joined its lists at the
+    # end (what later PRs appended follows them)
+    assert [c["name"] for c in real["configs"]].index(CONFIG) == 5
+    assert [w["name"] for w in real["workloads"]].index(CELL) == 5
     # (per-layer metrics that later PRs appended follow them)
     per_layer = [m["name"] for m in real["per_layer"]]
     at = per_layer.index("window_bytes_per_slot")
     assert per_layer[at:at + 4] == [
         "window_bytes_per_slot", "afmoe_decode_hbm_bw_pct",
         "afmoe_mixed_mfu_pct", "afmoe_load_max_over_mean"]
-    assert all(m["workloads"][-1] == CELL
+    later = {w["name"] for w in real["workloads"][6:]}
+    assert all(set(m["workloads"][m["workloads"].index(CELL) + 1:]) <= later
                for m in real["per_layer"] + real["end_to_end"]
                if CELL in m.get("workloads", ()))
     # the pool's peak: 8 requests x ceil(16511 / 32) blocks + the scratch

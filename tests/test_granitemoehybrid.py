@@ -257,30 +257,41 @@ def test_chunked_prefill_then_decode_through_the_slot_state(toy):
 
 
 def _recurrence(xs, b, c, dt, a, d, h0):
-    """The module's first equation block, position by position, float64."""
+    """The module's first equation block, position by position, float64;
+    ``b`` / ``c`` (S, C, G, N), head ``h`` using group ``h // (H / G)``."""
     xs, b, c, dt, a, d, h = (np.asarray(v, np.float64)
                              for v in (xs, b, c, dt, a, d, h0))
+    per = xs.shape[2] // b.shape[2]
+    b, c = np.repeat(b, per, axis=2), np.repeat(c, per, axis=2)
     ys = np.zeros(xs.shape)
     for t in range(xs.shape[1]):
         h = (h * np.exp(dt[:, t] * a)[..., None, None]
              + (dt[:, t, :, None] * xs[:, t])[..., None]
-             * b[:, t, None, None, :])
-        ys[:, t] = (h * c[:, t, None, None, :]).sum(-1) + d[:, None] * xs[:, t]
+             * b[:, t, :, None, :])
+        ys[:, t] = (h * c[:, t, :, None, :]).sum(-1) + d[:, None] * xs[:, t]
     return ys, h
 
 
-@pytest.mark.parametrize("decay_bytes", [None, 4 * 2 * 12 * 12],
-                         ids=["one_group", "head_groups_of_two"])
-def test_chunked_form_equals_the_recurrence(decay_bytes, monkeypatch):
+@pytest.mark.parametrize("groups,decay_bytes", [
+    (1, None), (1, 4 * 2 * 12 * 12), (3, None), (3, 4 * 2 * 12 * 12),
+    (3, 4 * 2 * 12 * 12 // 2), (2, 4 * 3 * 12 * 12), (6, None)],
+    ids=["one_group", "head_groups_of_two", "three_groups",
+         "three_groups_streamed_whole", "three_groups_streamed_in_halves",
+         "two_groups_streamed_whole", "a_group_a_head"])
+def test_chunked_form_equals_the_recurrence(groups, decay_bytes,
+                                            monkeypatch):
     """``ssd_chunk`` over 12 positions with an incoming state, positions
-    of dt = 0 (past a row's count) included, against the recurrence; the
-    head groups stream under a byte bound to the same numbers."""
+    of dt = 0 (past a row's count) included, against the recurrence, for
+    one group of B and C (this family's) and for several (``nemotron_h``'s:
+    head ``h`` reads group ``h // (H / G)``); the heads stream under a byte
+    bound, whole groups or an equal part of one at a time, to the same
+    numbers."""
     if decay_bytes:
         monkeypatch.setattr(G, "_DECAY_BYTES", decay_bytes)
     rng = np.random.default_rng(0)
     s_, cl, h, p, n = 2, 12, 6, 4, 5
     xs = rng.standard_normal((s_, cl, h, p))
-    b, c = rng.standard_normal((2, s_, cl, n))
+    b, c = rng.standard_normal((2, s_, cl, groups, n))
     dt = rng.uniform(0.01, 0.3, (s_, cl, h))
     dt[1, 7:] = 0.0                       # row 1 brings 7 positions
     a = -np.exp(np.linspace(0.0, 2.7, h))
@@ -295,6 +306,12 @@ def test_chunked_form_equals_the_recurrence(decay_bytes, monkeypatch):
     # row 1's state is where its 7th position put it
     assert _max_abs(h1[1], _recurrence(xs[:, :7], b[:, :7], c[:, :7],
                                        dt[:, :7], a, d, h0)[1][1]) < 2e-5
+    if groups > 1:
+        # the groups matter: every head on group 0's B and C reads otherwise
+        same = np.repeat(b[:, :, :1], groups, 2), np.repeat(c[:, :, :1],
+                                                            groups, 2)
+        assert _max_abs(y[0], _recurrence(xs, *same, dt, a, d, h0)[0][0]) \
+            > 0.1
 
 
 @pytest.mark.parametrize("chunk,quantum", [(16, 4), (8, 1), (32, 8)])
@@ -584,7 +601,7 @@ def test_refusals_by_name(kwargs, name):
 
 
 @pytest.mark.parametrize("overrides,what", [
-    ({"mamba_n_groups": 2}, "mamba_n_groups"),
+    ({"mamba_n_groups": 3}, "mamba_n_groups"),
     ({"position_embedding_type": "rope"}, "position"),
     ({"tie_word_embeddings": False}, "untied"),
     ({"mamba_proj_bias": True}, "bias"),

@@ -64,7 +64,8 @@ def unscoped(fn, args):
 
 
 def _model(family):
-    from paddle_tpu.nlp import afmoe, deepseek_v3, granitemoehybrid, llama
+    from paddle_tpu.nlp import (afmoe, deepseek_v3, granitemoehybrid, llama,
+                                nemotron_h)
 
     paddle.seed(0)
     model = {
@@ -76,6 +77,8 @@ def _model(family):
             lambda: granitemoehybrid.GraniteMoeHybridForCausalLM(
                 granitemoehybrid.GraniteMoeHybridConfig.tiny()),
         "afmoe": lambda: afmoe.AfmoeForCausalLM(afmoe.AfmoeConfig.tiny()),
+        "nemotron_h": lambda: nemotron_h.NemotronHForCausalLM(
+            nemotron_h.NemotronHConfig.tiny(held_experts=(0, 4))),
     }[family]()
     model.eval()
     return model
@@ -107,13 +110,18 @@ EXPECTED = {
               "attn.gate", "cache.write", "mlp", "moe.router",
               "moe.dispatch", "moe.products", "moe.combine", "moe.shared",
               "head", "sample"},
+    "nemotron_h": {"embed", "norm", "attn.proj", "attn.full", "cache.write",
+                   "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.out",
+                   "moe.router", "moe.dispatch", "moe.products",
+                   "moe.combine", "moe.shared", "head", "sample"},
     "train": {"embed", "norm", "attn.proj", "attn.window", "mlp", "head",
               "loss", "optimizer"},
 }
 
 
 @pytest.mark.parametrize("family,program", [
-    (f, p) for f in ("llama", "deepseek_v3", "granitemoehybrid", "afmoe")
+    (f, p) for f in ("llama", "deepseek_v3", "granitemoehybrid", "afmoe",
+                     "nemotron_h")
     for p in ("mixed", "quantum")] + [("train", "step")])
 def test_every_equation_sits_in_a_scope(family, program):
     if family == "train":

@@ -565,3 +565,93 @@ def test_the_window_cells_programs_move_no_ring_and_no_pool(chip,
                           mixed)) == 8
     assert "grouped_matmul" not in compiled_kernel_names(
         compiled["quantum"].as_text())
+
+
+def test_the_nemotron_cells_programs_fit_and_take_both_kernels(chip,
+                                                               monkeypatch):
+    """``nemotron-3-nano-30b-a3b.gen512-o256``'s REAL ``jit_quantum`` and
+    ``jit_mixed`` (the family's model from the cell's configuration, 4.94 B
+    parameters as zeros; the engine with the cell's options; a batch of 64
+    x 512 admitted) compiled for the described v5e. Both kernels take G 16
+    / HK 2 (``paged_decode_attention`` in the quantum,
+    ``gqa_chunk_attention`` in the mixed step); the experts' width 1856 is
+    14.5 lanes, so by ``grouped_matmul.supports`` both products are
+    ``ragged_dot`` in BOTH programs. What that width costs in memory: the
+    chip stores ``up_proj`` (64, 2688, 1856) with the 2688 minor and the
+    ragged-dot custom call wants the 1856 minor, padded to 1920, so every
+    expert layer's ``up_proj`` is copied, and in the quantum the six copies
+    are hoisted out of the scan and live together: 3.99 GB of temporaries.
+    At the issue's 16 layers that is 16.65 GiB of the chip's 15.75 and
+    the program does not compile (PERF.md section 6, PR 39); the cut of 14
+    fits with more than a GiB to spare."""
+    import json
+
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.nn import initializer as I
+    from paddle_tpu.ops.pallas._utils import compiled_kernel_names
+    from paddle_tpu.serving import ServingEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from benchmark.families import nemotron_h as family
+    from benchmark.harness import counts_nemotron_h as counts
+
+    class Zeros(I.Constant):        # 9.9 GB of weights nobody reads
+        def __init__(self, *a, **k):
+            super().__init__(0.0)
+
+    for name in ("XavierNormal", "XavierUniform", "Normal"):
+        monkeypatch.setattr(I, name, Zeros)
+    with open(os.path.join(root, "benchmark", "configs",
+                           "nemotron-3-nano-30b-a3b-l14-ep2.json")) as f:
+        cfg = json.load(f)
+    dtype_was = paddle.get_default_dtype()
+    paddle.set_flags({"FLAGS_pallas_force": True})
+    try:
+        model = family.build_model(cfg)
+        model.eval()
+        eng = ServingEngine(model, **cfg["engine"])
+        for _ in range(64):
+            eng.submit(np.ones(512, np.int32), max_new_tokens=128)
+        eng._admit()
+
+        def shapes(args):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                               sharding=chip), args)
+
+        compiled = {}
+        for name, (step, args) in (("quantum", eng.decode_step_target()),
+                                   ("mixed", eng.mixed_step_target())):
+            compiled[name] = step.lower(*shapes(args)).compile()
+    finally:
+        paddle.set_flags({"FLAGS_pallas_force": False})
+        paddle.set_default_dtype(dtype_was)
+    n_params = sum(int(p._value.size) for _, p in model.named_parameters())
+    assert n_params == counts.total_params(cfg) == 4_937_225_472
+    resident = (2 * n_params + 2 * 2 * 1664 * 32 * 2 * 128 * 2
+                + 64 * counts.state_bytes_per_slot(cfg))
+    hbm = 15.75 * 2 ** 30
+    for name, program in compiled.items():
+        mem = program.memory_analysis()
+        assert 0 <= mem.argument_size_in_bytes - resident < 1 << 20, name
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < hbm - (1 << 30), (name, mem.temp_size_in_bytes)
+    quantum, mixed = (compiled[k].as_text() for k in ("quantum", "mixed"))
+    # six expert layers' up_proj, re-laid out for the ragged-dot and alive
+    # together: the quantum's temporaries
+    up = 64 * 2688 * 1920 * 2
+    temp = compiled["quantum"].memory_analysis().temp_size_in_bytes
+    assert 6 * up < temp < 6.5 * up
+    assert len(re.findall(r" copy\(", "\n".join(
+        line for line in quantum.splitlines()
+        if "bf16[64,2688,1856]" in line.split(" copy(")[0]))) >= 6
+    assert "paged_decode_attention" in compiled_kernel_names(quantum)
+    assert "gqa_chunk_attention" in compiled_kernel_names(mixed)
+    assert len(re.findall(r" custom-call\(.*gqa_chunk_attention/pallas_call",
+                          mixed)) == 2
+    for text in (quantum, mixed):
+        assert "grouped_matmul" not in compiled_kernel_names(text)
+        assert "ragged-dot" in text
