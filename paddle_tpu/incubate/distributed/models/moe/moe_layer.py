@@ -64,10 +64,14 @@ def grouped_expert_ffn(xt, expert_ids, gate_vals, w1, w2, act, b1=None,
     The two products are ONE algorithm in two forms, chosen by a static
     rule that reads the inputs (``ops/pallas/grouped_matmul.supports``
     beside ``use_pallas_kernels``): the Pallas kernel ``grouped_matmul``
-    on a TPU where the mean rows a group is at least its row tile
-    (prefill) and both products' widths are whole lanes,
-    ``jax.lax.ragged_dot`` elsewhere (the CPU, decode's few rows a group,
-    a mesh). Same precision either way, and ``ragged_dot``'s backward.
+    on a TPU where both products' widths are whole sublane tiles of the
+    dtype (at most one of a product's two not whole lanes: the stack is
+    then read as the device stores it) and either the mean rows a group
+    fill its 512-row tile (prefill) or, below that (decode's few rows a
+    group, at a 16-row tile), ``ragged-dot`` would tile one of the widths
+    by a lane tile or less; ``jax.lax.ragged_dot`` elsewhere (the CPU,
+    decode at widths with many factors of two, a mesh). Same precision
+    either way, and ``ragged_dot``'s backward.
     Where ``act`` is :func:`swiglu` itself (no ``b1``) the kernel applies
     it as the first product's epilogue; any other callable runs after the
     plain kernel, as it does after ``ragged_dot``.

@@ -1,8 +1,9 @@
 """Grouped matrix product — the Pallas TPU kernel of the routed experts'
-two products in prefill (``incubate/.../moe/moe_layer.py::
-grouped_expert_ffn``, scope ``moe.products``). Elsewhere, and wherever the
-rule of :func:`supports` says so, ``jax.lax.ragged_dot``: the CPU path,
-the decode path and the reference the parity tests compare with.
+two products (``incubate/.../moe/moe_layer.py::grouped_expert_ffn``, scope
+``moe.products``): in prefill, and in decode where ``ragged-dot`` tiles a
+width badly. Elsewhere, and wherever the rule of :func:`supports` says so,
+``jax.lax.ragged_dot``: the CPU path, a mesh's path, decode at widths with
+many factors of two, and the reference the parity tests compare with.
 
 ``grouped_matmul(lhs (rows, K), rhs (E, K, N), group_sizes (E,))``: the
 rows are sorted by group, group ``g`` owns the next ``group_sizes[g]`` of
@@ -25,16 +26,41 @@ split into gate | up and written as (sub, N / 2): ``h`` is never stored.
 The roundings are the XLA fusion's (bit for bit on the interpreter; on the
 chip Mosaic's sigmoid leaves a quarter of the elements one bf16 step off).
 
+**The row tile comes from the shape** (:func:`_tiles`): 512 rows in
+sub-tiles of 128 where the mean rows a group fill it (prefill: the MXU
+bounds the product), halved down to ONE sub-tile of 16 rows where they do
+not. In decode a group holds a few rows, so a visit is those rows against
+the group's ``(K, N)`` block, the block is fetched once a touched group and
+an untouched expert costs nothing: the weights' bytes bound the product.
+
 **An expert's weight block changes only when the group does**, and it is
 fetched a whole group ahead: ``rhs`` stays in HBM and the kernel keeps two
 ``(K, block_n)`` buffers, starting the copy of the NEXT non-empty group's
 block at a group's first visit (a (2048, 2048) bf16 block is 10 us of the
 bandwidth, a group's ~512 rows 27 us of the MXU; BlockSpec pipelining
-looks one VISIT ahead, and a group's last visit may hold a few rows).
+looks one VISIT ahead, and a group's last visit may hold a few rows). At
+the 16-row tile the same schedule keeps the DMA engine busy: the next
+block's copy runs under this group's one product.
+
+**A width that is not whole lanes** (1856 = 116 x 16 = 14.5 lanes) is
+taken where it is whole SUBLANE tiles of the dtype and the product's other
+width is whole lanes; the stack is read AS THE DEVICE STORES IT, so no
+program re-lays it out. *N ragged* (``(E, K, N)`` with K whole lanes): the
+TPU keeps such an array with K minor, physically ``(E, N, K)`` row-major,
+so the kernel takes ``swapaxes(rhs, 1, 2)`` (a bitcast), copies a group's
+whole ``(N, K)`` block (N on sublanes: whole tiles; every copied row whole
+lanes) and contracts on the block's MINOR axis (an NT product); the result
+block ``(rows, N)`` is stored with its last lane tile masked. *K ragged*
+(``(E, K, N)`` with N whole lanes, stored as it reads): the block lands in
+the first K rows of a ``(Kp, N)`` buffer and each row sub-tile in the first
+K lanes of a ``(sub, Kp)`` one, ``Kp`` = K in whole lanes; rows and lanes
+K .. Kp are zeroed once a call and never written again, so they add exact
+zeros to the contraction, not whatever VMEM held.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +74,7 @@ __all__ = ["grouped_matmul", "supports", "swiglu"]
 
 _BLOCK_M = 512            # rows a tile (what a visit fetches and stores)
 _BLOCK_SUB = 128          # rows a product inside it
+_MIN_BLOCK_M = 16         # the least row tile: one sublane tile of bf16
 _RHS_BLOCK_BYTES = 16 << 20   # a (K, block_n) weight block may take this
 
 
@@ -60,19 +87,57 @@ def _block_n(k, n, itemsize):
     return tn
 
 
+def _tiles(rows, groups):
+    """``(block_m, block_sub)`` from the mean rows a group: ``_BLOCK_M`` /
+    ``_BLOCK_SUB`` where a group fills a row tile (prefill), halved down to
+    ``_MIN_BLOCK_M`` where it does not, so that decode's few rows a group
+    are one visit of one sub-tile against the group's weight block."""
+    tm = _BLOCK_M
+    while tm > max(rows // groups, _MIN_BLOCK_M):
+        tm //= 2
+    return tm, min(_BLOCK_SUB, tm)
+
+
+def _ragged_dot_tile(width):
+    """What the TPU's ``ragged-dot`` tiles a width by: the largest power
+    of two that divides it (1856 -> 64, 1920 -> 128, 1792 -> 256)."""
+    return width & -width
+
+
+def _takes(rows, e, k, n, itemsize):
+    """One product's part of :func:`supports`."""
+    sublanes = 32 // itemsize       # rows of one tile of the dtype
+    ragged = [w for w in (k, n) if w % 128]
+    if k % sublanes or n % sublanes or len(ragged) > 1:
+        return False
+    if ragged and round_up(k, 128) * round_up(n, 128) * itemsize \
+            > _RHS_BLOCK_BYTES:
+        return False    # the width padded to whole lanes, in ONE block
+    # a group fills a row tile: the MXU's product. Below that the weights'
+    # bytes bound it, and ragged-dot reads them well unless it tiles a
+    # width by one lane tile or less
+    return rows // e >= _BLOCK_M or any(
+        _ragged_dot_tile(w) <= 128 < w for w in (k, n))
+
+
 def supports(rows, *weights):
     """The static rule, read from the shapes of a call's ``rows`` and its
-    ``weights`` (E, K, N): the kernel where the mean rows a group, ``rows
-    // E``, is at least one row tile (prefill; below it the products are
-    bound by the weights' bytes and ``ragged_dot`` reads them at 65-79 %
-    of the bandwidth), every K and N is whole lanes, and no mesh of more
-    than one device is installed (a Mosaic kernel cannot be partitioned,
-    and an expert axis over the weights has a schedule of its own,
-    ``MoELayer._grouped_ep_fn``)."""
+    ``weights`` (E, K, N). The kernel can take a product whose K and N are
+    whole sublane tiles of the dtype (16 for bf16), at most one of them
+    not whole lanes, under no mesh of more than one device (a Mosaic kernel
+    cannot be partitioned, and an expert axis over the weights has a
+    schedule of its own, ``MoELayer._grouped_ep_fn``). It does take it
+    where the mean rows a group, ``rows // E``, is at least the 512-row
+    tile (prefill: the MXU bounds it), and below that (decode: the
+    weights' bytes bound it) only where ``ragged-dot`` tiles one of the
+    widths badly: its tile is the largest power of two dividing a width,
+    and at a tile of one lane tile or less (1856 -> 64, 2688 -> 128) it
+    reads 9-12 % of the bandwidth, where widths with many factors of two
+    (768 -> 256 and up) read 65-85 % and keep it."""
     mesh = mesh_state.get_mesh()
     return (mesh is None or mesh.size == 1) and all(
-        rows // e >= _BLOCK_M and k % 128 == 0 and n % 128 == 0
-        for e, k, n in (w.shape for w in weights))
+        _takes(rows, *w.shape, jnp.dtype(w.dtype).itemsize)
+        for w in weights)
 
 
 def swiglu(h):
@@ -110,15 +175,30 @@ def _visits(group_sizes, rows, tm):
 
 
 def _kernel(offs_ref, gid_ref, tile_ref, first_ref, next_ref, slot_ref,
-            lhs_ref, rhs_hbm, out_ref, w_buf, sem, *, tm, sub, tn, groups,
-            epilogue):
+            lhs_ref, rhs_hbm, out_ref, w_buf, sem, *scratch, tm, sub, tn,
+            groups, epilogue, nt):
     ni, v = pl.program_id(0), pl.program_id(1)
     g, slot = gid_ref[v], slot_ref[v]
+    k = lhs_ref.shape[1]
+    x_pad = scratch[0] if scratch else None     # (sub, Kp): K is ragged
 
     def fetch(group, into):
-        return pltpu.make_async_copy(
-            rhs_hbm.at[group, :, pl.ds(pl.multiple_of(ni * tn, 128), tn)],
-            w_buf.at[into], sem.at[into])
+        if nt:      # the whole stored (N, K) block: N on sublanes
+            src, dst = rhs_hbm.at[group], w_buf.at[into, pl.ds(0, tn), :]
+        else:
+            src = rhs_hbm.at[group, :, pl.ds(pl.multiple_of(ni * tn, 128),
+                                             tn)]
+            dst = w_buf.at[into] if x_pad is None \
+                else w_buf.at[into, pl.ds(0, k), :]
+        return pltpu.make_async_copy(src, dst, sem.at[into])
+
+    if x_pad is not None:
+        # K is not whole lanes: the lanes past K of a row sub-tile and the
+        # rows past K of both weight buffers are zeroed once (no copy ever
+        # writes them), so they add exact zeros to every contraction
+        @pl.when(v == 0)
+        def _zero_the_padding():
+            _zero_pad(x_pad, w_buf, k)
 
     @pl.when(v == 0)
     def _first_group():
@@ -136,10 +216,29 @@ def _kernel(offs_ref, gid_ref, tile_ref, first_ref, next_ref, slot_ref,
     lo = jnp.maximum(offs_ref[g], row0) - row0
     hi = jnp.minimum(offs_ref[g + 1], row0 + tm) - row0
 
+    def rows_of(r):
+        return lhs_ref[pl.ds(r, sub), :]
+
+    def padded_rows_of(r):
+        x_pad[:, pl.ds(0, k)] = lhs_ref[pl.ds(r, sub), :]
+        return x_pad[...]
+
+    def dot(x):
+        return jnp.dot(x, w_buf[slot], preferred_element_type=jnp.float32)
+
+    def dot_nt(x):
+        # contract on the block's minor axis; the sublanes past N give
+        # columns nobody stores
+        return jax.lax.dot_general(
+            x, w_buf[slot], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)[:, :tn]
+
+    load = rows_of if x_pad is None else padded_rows_of
+    times_block = dot_nt if nt else dot
+
     def product(i, carry):
         r = pl.multiple_of(i * sub, sub)
-        acc = jnp.dot(lhs_ref[pl.ds(r, sub), :], w_buf[slot],
-                      preferred_element_type=jnp.float32)
+        acc = times_block(load(r))
         new = epilogue(acc.astype(out_ref.dtype)).astype(jnp.float32)
         row = r + jax.lax.broadcasted_iota(jnp.int32, new.shape, 0)
         out_ref[pl.ds(r, sub), :] = jnp.where(
@@ -149,6 +248,16 @@ def _kernel(offs_ref, gid_ref, tile_ref, first_ref, next_ref, slot_ref,
         return carry
 
     jax.lax.fori_loop(lo // sub, (hi + sub - 1) // sub, product, 0)
+
+
+def _zero_pad(x_pad, w_buf, k):
+    """Zeros in the lane tile of ``x_pad`` (sub, Kp) that holds lane K and
+    in rows K .. Kp of both weight buffers ``w_buf`` (2, Kp, tn)."""
+    kp = x_pad.shape[1]
+    x_pad[:, pl.ds(kp - 128, 128)] = jnp.zeros((x_pad.shape[0], 128),
+                                               x_pad.dtype)
+    w_buf[:, pl.ds(k, kp - k), :] = jnp.zeros(
+        (2, kp - k, w_buf.shape[2]), w_buf.dtype)
 
 
 def _reference(lhs, rhs, group_sizes, fused):
@@ -171,6 +280,17 @@ def _grouped_matmul(lhs, rhs, group_sizes, fused, block_m, block_sub,
     assert not fused or tn == n, (k, n)     # gate and up in one block
     tn_out, n_out = (tn // 2, n // 2) if fused else (tn, n)
     *meta, visits = _visits(group_sizes.astype(jnp.int32), padded, tm)
+    rhs = rhs.astype(lhs.dtype)
+    kp, nt = round_up(k, 128), n % 128 != 0
+    assert kp == k or not nt, (k, n)
+    if nt:
+        # the stack as the device stores it: (E, N, K), a bitcast
+        assert tn == n, (k, n)
+        rhs = jnp.swapaxes(rhs, 1, 2)
+        w_buf = (2, round_up(n, 128), k)
+    else:
+        w_buf = (2, kp, tn)
+    x_pad = [pltpu.VMEM((sub, kp), lhs.dtype)] if kp != k else []
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
@@ -182,15 +302,18 @@ def _grouped_matmul(lhs, rhs, group_sizes, fused, block_m, block_sub,
         out_specs=pl.BlockSpec((tm, tn_out),
                                lambda ni, v, o, g, t, *_: (t[v], ni)),
         scratch_shapes=[
-            pltpu.VMEM((2, k, tn), lhs.dtype),
+            pltpu.VMEM(w_buf, lhs.dtype),
             pltpu.SemaphoreType.DMA((2,)),
+            *x_pad,
         ],
     )
-    vmem = (2 * k * tn + 2 * tm * k + 2 * tm * tn_out) * itemsize \
-        + 3 * sub * tn * 4
+    lanes_out = round_up(tn_out, 128)
+    vmem = (math.prod(w_buf) + 2 * tm * kp + 2 * tm * lanes_out
+            + (sub * kp if x_pad else 0)) * itemsize \
+        + 3 * sub * round_up(tn, 128) * 4
     out = pl.pallas_call(
         functools.partial(_kernel, tm=tm, sub=sub, tn=tn, groups=e,
-                          epilogue=swiglu if fused else lambda h: h),
+                          epilogue=swiglu if fused else lambda h: h, nt=nt),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((padded, n_out), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -198,7 +321,7 @@ def _grouped_matmul(lhs, rhs, group_sizes, fused, block_m, block_sub,
             vmem_limit_bytes=min(vmem + (8 << 20), 100 << 20)),
         interpret=interpret,
         name="grouped_matmul",
-    )(*meta, lhs, rhs.astype(lhs.dtype))
+    )(*meta, lhs, rhs)
     return out[:rows]
 
 
@@ -239,5 +362,6 @@ def grouped_matmul(lhs, rhs, group_sizes, swiglu=False, block_m=None,
     ``swiglu=True`` (where :func:`fuses_swiglu`): -> (rows, N / 2),
     :func:`swiglu` of that result with the same roundings. Differentiable:
     the backward is that of ``jax.lax.ragged_dot`` (and the activation)."""
-    return _jitted(lhs, rhs, group_sizes, bool(swiglu), block_m or _BLOCK_M,
-                   block_sub or _BLOCK_SUB, _interpret_mode())
+    tm, sub = _tiles(lhs.shape[0], rhs.shape[0])
+    return _jitted(lhs, rhs, group_sizes, bool(swiglu), block_m or tm,
+                   block_sub or sub, _interpret_mode())

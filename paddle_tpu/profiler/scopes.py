@@ -40,8 +40,9 @@ SCOPES = {
     "moe.experts": "the routed expert block outside its three parts",
     "moe.dispatch": "sort by expert, gather of the rows, group sizes",
     "moe.products": "the routed experts' two grouped products (the "
-                    "grouped_matmul kernel in prefill on a TPU, "
-                    "jax.lax.ragged_dot elsewhere) and the activation "
+                    "grouped_matmul kernel on a TPU where its rule "
+                    "takes them, jax.lax.ragged_dot elsewhere) and the "
+                    "activation "
                     "between them",
     "moe.combine": "un-sort, the routing weights, the sum over choices",
     "moe.shared": "the shared expert(s)",

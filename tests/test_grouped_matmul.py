@@ -1,8 +1,9 @@
 """The grouped-matmul kernel of the routed experts' two products
-(``ops/pallas/grouped_matmul.py``, ISSUE 38), through the interpreter on
-the CPU: parity with ``jax.lax.ragged_dot`` (the reference it replaces in
-prefill), gradients through ``grouped_expert_ffn`` with the kernel forced,
-and the static rule that selects it with the counter that says so.
+(``ops/pallas/grouped_matmul.py``, ISSUE 38; a width that is not whole
+lanes and decode's 16-row tile, ISSUE 40), through the interpreter on the
+CPU: parity with ``jax.lax.ragged_dot`` (the reference it replaces),
+gradients through ``grouped_expert_ffn`` with the kernel forced, and the
+static rule that selects it with the counter that says so.
 """
 import jax
 import jax.numpy as jnp
@@ -38,13 +39,31 @@ CASES = {
     "rows-not-whole-tiles": (250, 128, 128, [10, 20, 30, 40, 50, 100], F32,
                              64, 16),
     # the three cells' (K, N) pairs at a few hundred rows, the kernel's
-    # own tiles
-    "window-p1": (1024, 2048, 2048, [300, 0, 724], BF16, None, None),
-    "window-p2": (1024, 1024, 2048, [300, 0, 724], BF16, None, None),
-    "latent-p1": (1024, 2048, 1536, [511, 513], BF16, None, None),
-    "latent-p2": (1024, 768, 2048, [511, 513], BF16, None, None),
-    "state-space-p1": (1024, 4096, 1536, [200, 300], BF16, None, None),
-    "state-space-p2": (1024, 768, 4096, [200, 300], BF16, None, None),
+    # own prefill tiles
+    "window-p1": (1024, 2048, 2048, [300, 0, 724], BF16, 512, 128),
+    "window-p2": (1024, 1024, 2048, [300, 0, 724], BF16, 512, 128),
+    "latent-p1": (1024, 2048, 1536, [511, 513], BF16, 512, 128),
+    "latent-p2": (1024, 768, 2048, [511, 513], BF16, 512, 128),
+    "state-space-p1": (1024, 4096, 1536, [200, 300], BF16, 512, 128),
+    "state-space-p2": (1024, 768, 4096, [200, 300], BF16, 512, 128),
+    # the Nemotron cell's two products, 1856 = 116 sublane tiles = 14.5
+    # lanes: p1's N (the stack read as stored, contracted on the block's
+    # minor axis) and p2's K (the contraction padded with exact zeros). At
+    # the decode tile: empty first / middle / last groups, one group
+    # holding most rows, a held= tail of 384 - 174 rows never visited
+    "nemotron-decode-p1": (384, 2688, 1856, [0, 3, 150, 0, 5, 16, 0], BF16,
+                           16, 16),
+    "nemotron-decode-p2": (384, 1856, 2688, [0, 3, 150, 0, 5, 16, 0], BF16,
+                           16, 16),
+    # ... and at the prefill tiles
+    "nemotron-prefill-p1": (1024, 2688, 1856, [300, 0, 600], BF16, 512,
+                            128),
+    "nemotron-prefill-p2": (1024, 1856, 2688, [300, 0, 600], BF16, 512,
+                            128),
+    # the same two forms in float32, whose sublane tile is 8 rows: the
+    # tiles read from the shape (256 rows / 4 groups -> 64)
+    "n-not-whole-lanes": (256, 128, 200, [30, 0, 100, 90], F32, None, None),
+    "k-not-whole-lanes": (256, 200, 128, [30, 0, 100, 90], F32, None, None),
 }
 
 
@@ -66,8 +85,33 @@ def test_matches_ragged_dot(case):
     live = int(sum(CASES[case][3]))     # rows inside groups
     gap = jnp.max(jnp.abs(got[:live].astype(F32) - want[:live].astype(F32)))
     # float32 accumulation on both sides: a bf16 result may round the
-    # last bit the other way
+    # last bit the other way. (The interpreter's uninitialised VMEM is NaN:
+    # a padded lane that leaked into a contraction would read NaN here.)
     assert float(gap) <= (2e-5 if dtype == F32 else 2 ** -6), float(gap)
+
+
+def test_the_interpreters_vmem_is_poisoned_past_a_ragged_k(monkeypatch):
+    """What keeps lanes K .. Kp of a row sub-tile and rows K .. Kp of a
+    weight block out of the second product's contraction is the kernel's
+    own zeroing (``_zero_pad``), and the parity cases would see it fail:
+    the interpreter hands out scratch VMEM full of NaN, so without the
+    zeroing every row of the result is NaN."""
+    from jax._src.pallas.primitives import uninitialized_value
+
+    assert bool(jnp.all(jnp.isnan(uninitialized_value((8, 128), BF16))))
+    sizes = jnp.asarray([10, 0, 22], jnp.int32)
+    lhs, rhs = _operands(48, 144, 128, 3, BF16, 144 ** -0.5)   # K = 9 x 16
+    want = jax.lax.ragged_dot(lhs, rhs, sizes)
+    got = kernel.grouped_matmul(lhs, rhs, sizes)
+    assert float(jnp.max(jnp.abs(got[:32].astype(F32)
+                                 - want[:32].astype(F32)))) <= 2 ** -6
+    monkeypatch.setattr(kernel, "_zero_pad", lambda *a: None)
+    kernel._jitted.clear_cache()
+    try:
+        leaked = kernel.grouped_matmul(lhs, rhs, sizes)
+    finally:
+        kernel._jitted.clear_cache()
+    assert bool(jnp.all(jnp.isnan(leaked[:32].astype(F32))))
 
 
 @pytest.mark.parametrize("case", ["tile-straddles-three-bf16",
@@ -200,15 +244,21 @@ def test_held_absent_choices_add_exact_zeros(monkeypatch):
     assert float(jnp.max(jnp.abs(y - y_ref))) < 1e-4
 
 
+def _relu2(h):
+    return jnp.square(jax.nn.relu(h))
+
+
 def _traced_path(t, k, e, m, f1, f2, held=None, layers=1, dtype=BF16):
     """The ``path`` one traced program of ``layers`` expert layers raised
-    the counter by (shapes only: nothing is computed)."""
+    the counter by (shapes only: nothing is computed). Gated experts where
+    F1 = 2 x F2, ungated relu^2 ones where F1 = F2."""
     before = _counts()
     s = jax.ShapeDtypeStruct
+    act = swiglu if f1 == 2 * f2 else _relu2
 
     def program(xt, ids, gates, w1, w2):
         for _ in range(layers):
-            xt, _ = grouped_expert_ffn(xt, ids, gates, w1, w2, swiglu,
+            xt, _ = grouped_expert_ffn(xt, ids, gates, w1, w2, act,
                                        held=held)
         return xt
 
@@ -225,6 +275,7 @@ def _traced_path(t, k, e, m, f1, f2, held=None, layers=1, dtype=BF16):
 WINDOW = (8, 128, 2048, 2048, 1024)
 LATENT = (6, 128, 2048, 1536, 768)
 STATE_SPACE = (10, 36, 4096, 1536, 768)
+NEMOTRON = (6, 64, 2688, 1856, 1856)
 
 
 @pytest.mark.parametrize("shapes,positions,held,want", [
@@ -232,19 +283,45 @@ STATE_SPACE = (10, 36, 4096, 1536, 768)
     (WINDOW, 8192, None, "kernel"),
     (LATENT, 16384, None, "kernel"),
     (STATE_SPACE, 8192, (0, 36), "kernel"),
-    # a decode step's rows: 0.5, 1.5 and 17.8 rows a group
+    # 64 x 128 positions, 768 rows a group; 1856 is 116 sublane tiles
+    (NEMOTRON, 8192, (0, 64), "kernel"),
+    # a decode step's rows: 0.5, 1.5 and 17.8 rows a group at widths
+    # ragged-dot tiles by 256 lanes or more
     (WINDOW, 64, None, "ragged_dot"),
     (LATENT, 32, None, "ragged_dot"),
     (STATE_SPACE, 64, (0, 36), "ragged_dot"),
-    # a K that is not whole lanes (F2 = 1000)
+    # 6 rows a group at widths it tiles by 64 and 128: the 16-row tile
+    (NEMOTRON, 64, (0, 64), "kernel"),
+    # a K that is not whole sublane tiles (F2 = 1000 = 62.5 x 16)
     ((8, 128, 2048, 2000, 1000), 8192, None, "ragged_dot"),
+    # both widths of a product not whole lanes
+    ((6, 64, 1856, 1856, 1856), 8192, None, "ragged_dot"),
+    # whole sublane tiles of bfloat16 are 16 rows, of float32 8
+    ((6, 64, 2688, 1864, 1864), 8192, None, "ragged_dot"),
 ], ids=["window-prefill", "latent-prefill", "state-space-prefill",
-        "window-decode", "latent-decode", "state-space-decode",
-        "k-not-whole-lanes"])
+        "nemotron-prefill", "window-decode", "latent-decode",
+        "state-space-decode", "nemotron-decode", "k-not-whole-lanes",
+        "k-and-n-not-whole-lanes", "half-a-sublane-tile"])
 def test_the_rule_reads_the_shapes(pallas_forced, shapes, positions, held,
                                    want):
     k, e, m, f1, f2 = shapes
     assert _traced_path(positions, k, e, m, f1, f2, held, layers=3) == want
+
+
+def test_the_tiles_read_the_shapes():
+    """512 / 128 where a group fills a row tile (every prefill shape of
+    the four expert cells), halved down to one 16-row sub-tile below it."""
+    assert kernel._tiles(49152, 64) == (512, 128)
+    assert kernel._tiles(65536, 128) == (512, 128)
+    assert kernel._tiles(40960, 36) == (512, 128)
+    assert kernel._tiles(384, 64) == (16, 16)      # the Nemotron decode
+    assert kernel._tiles(640, 36) == (16, 16)
+    assert kernel._tiles(64, 128) == (16, 16)
+    assert kernel._tiles(6400, 36) == (128, 128)
+    assert kernel._tiles(2560, 36) == (64, 64)
+    assert [kernel._ragged_dot_tile(w) for w in (1856, 1920, 1792, 2048,
+                                                 2688, 768)] \
+        == [64, 128, 256, 2048, 128, 256]
 
 
 def test_the_rule_keeps_ragged_dot_off_the_tpu():

@@ -45,6 +45,8 @@ from benchmark.families import nemotron_h as family  # noqa: E402
 # the host, the door drained, and the engine's two bodies driven by hand
 from test_granitemoehybrid import (  # noqa: E402
     _Paged, _drain, _host, _max_abs, _prompts, _serve, _stamp)
+# moe_products_programs_total{path}, read as a dict
+from test_grouped_matmul import _counts as _products_counts  # noqa: E402
 
 reference = family.reference
 LOGIT_TOL = 5e-5     # summation order in float32, logits of magnitude ~1
@@ -418,35 +420,71 @@ def test_one_block_serves_gated_and_ungated_experts():
         Block(16, 8, 6, 2, 12, act="gelu")
 
 
-def test_the_ungated_products_take_ragged_dot_by_the_rule(monkeypatch):
+def test_the_ungated_products_take_the_kernel_by_the_rule(monkeypatch):
     """``grouped_matmul.supports`` answers by shapes: a width of 1856 is
-    14.5 lanes, so both products take ``ragged_dot`` even with rows enough
-    for the kernel (prefill), and ``moe_products_programs_total`` says so;
-    whole-lane widths with the same rows take the kernel. No kernel was
-    added or widened for this family."""
+    14.5 lanes but 116 whole sublane tiles of bfloat16, so both products
+    take the kernel with rows enough for its 512-row tile (prefill) AND at
+    decode's 6 rows a group (``ragged-dot`` tiles 1856 by 64 and 2688 by
+    128), and ``moe_products_programs_total`` says so; a width that is not
+    whole sublane tiles keeps ``ragged_dot``. No second kernel, no flag:
+    the tiles come from the shapes."""
     from paddle_tpu.ops.pallas import grouped_matmul as kernel
 
-    monkeypatch.setattr(kernel, "_interpret_mode", lambda: False)
-    w_up = jax.ShapeDtypeStruct((64, 2688, 1856), jnp.bfloat16)
-    w_down = jax.ShapeDtypeStruct((64, 1856, 2688), jnp.bfloat16)
-    assert not kernel.supports(64 * 128 * 6, w_up, w_down)
-    assert kernel.supports(
-        64 * 128 * 6, jax.ShapeDtypeStruct((64, 2688, 1792), jnp.bfloat16),
-        jax.ShapeDtypeStruct((64, 1792, 2688), jnp.bfloat16))
-    counter = moe_layer.moe_products_programs()
-    before = {p: counter.value(path=p) for p in ("kernel", "ragged_dot")}
+    bf16 = jnp.bfloat16
+    w_up = jax.ShapeDtypeStruct((64, 2688, 1856), bf16)
+    w_down = jax.ShapeDtypeStruct((64, 1856, 2688), bf16)
+    assert kernel.supports(64 * 128 * 6, w_up, w_down)      # prefill
+    assert kernel._tiles(64 * 128 * 6, 64) == (512, 128)
+    assert kernel.supports(64 * 6, w_up, w_down)            # decode
+    assert kernel._tiles(64 * 6, 64) == (16, 16)
+    assert not kernel.supports(
+        64 * 128 * 6, jax.ShapeDtypeStruct((64, 2688, 1864), bf16),
+        jax.ShapeDtypeStruct((64, 1864, 2688), bf16))
+    for positions in (64 * 128, 64):
+        before = _products_counts()
+        paddle.set_flags({"FLAGS_pallas_force": True})
+        try:
+            jax.eval_shape(
+                lambda x, i, g, a, b: moe_layer.grouped_expert_ffn(
+                    x, i, g, a, b, R.relu2, held=(0, 64)),
+                jax.ShapeDtypeStruct((positions, 2688), bf16),
+                jax.ShapeDtypeStruct((positions, 6), jnp.int32),
+                jax.ShapeDtypeStruct((positions, 6), jnp.float32),
+                w_up, w_down)
+        finally:
+            paddle.set_flags({"FLAGS_pallas_force": False})
+        after = _products_counts()
+        assert after["kernel"] == before["kernel"] + 1, positions
+        assert after["ragged_dot"] == before["ragged_dot"], positions
+
+
+def test_served_tokens_with_the_kernel_are_the_ragged_dot_paths(monkeypatch):
+    """The toy with experts 144 wide (18 sublane tiles of float32, 1.125
+    lanes: ``ragged-dot`` would tile it by 16), through the engine with
+    the kernel forced onto the interpreter: the first product reads the
+    stack as stored (N not whole lanes), the second contracts over a
+    padded K; the mixed program takes the kernel at a row tile (patched
+    down to the toy's 48 rows a group) and the quantum at the 16-row tile
+    by the rule itself. The served tokens of the prefill and two quanta
+    are the ``ragged_dot`` path's, token for token."""
+    from paddle_tpu.ops.pallas import grouped_matmul as kernel
+
+    cfg = dict(_toy_cfg(), moe_intermediate_size=144)
+    model = _model(cfg, _slow_leaves(cfg))
+    prompts = _prompts(cfg, (37, 20, 9), seed=3)
+    want = _drain(_serve(model), prompts, 9)     # the first token + 2 x 4
+    before = _products_counts()
+    monkeypatch.setattr(kernel, "_BLOCK_M", 32)
     paddle.set_flags({"FLAGS_pallas_force": True})
-    monkeypatch.setattr(kernel, "_BLOCK_M", 8)
     try:
-        jax.make_jaxpr(lambda x, i, g, a, b: moe_layer.grouped_expert_ffn(
-            x, i, g, a, b, R.relu2, held=(0, 4)))(
-                jnp.zeros((64, 128)), jnp.zeros((64, 2), jnp.int32),
-                jnp.ones((64, 2)), jnp.zeros((4, 128, 1856)),
-                jnp.zeros((4, 1856, 128)))
+        got = _drain(_serve(model), prompts, 9)
     finally:
         paddle.set_flags({"FLAGS_pallas_force": False})
-    assert counter.value(path="ragged_dot") == before["ragged_dot"] + 1
-    assert counter.value(path="kernel") == before["kernel"]
+    after = _products_counts()
+    assert after["ragged_dot"] == before["ragged_dot"]
+    assert after["kernel"] >= before["kernel"] + 2   # mixed and quantum
+    for g, w in zip(got, want):
+        assert g.shape == (9,) and bool(np.all(g == w))
 
 
 # -------------------------------------------- a layer that caches nothing

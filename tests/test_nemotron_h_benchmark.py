@@ -350,8 +350,14 @@ def test_the_leaf_table_is_the_programs_parameters(run, cfg):
     assert sum(1 for n, _, _ in table if n.endswith(".e_up")) == 6
     assert not [n for n, _, _ in table
                 if n.startswith("L1.") and n.split(".")[1] in ("in_w", "q_w")]
-    shapes = jax.eval_shape(lambda: {
-        k: p._value for k, p in fam.build_model(cfg).named_parameters()})
+    import paddle_tpu as paddle
+
+    dtype_was = paddle.get_default_dtype()  # build_model sets the cell's
+    try:
+        shapes = jax.eval_shape(lambda: {
+            k: p._value for k, p in fam.build_model(cfg).named_parameters()})
+    finally:    # ... and a later test of this worker would inherit bfloat16
+        paddle.set_default_dtype(dtype_was)
     assert {fam.program_path(n): tuple(s) for n, s, _ in table} \
         == {k: tuple(v.shape) for k, v in shapes.items()}
     scale = fam.leaf_scale(cfg)

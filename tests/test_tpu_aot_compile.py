@@ -415,15 +415,23 @@ _EXPERT_PRODUCTS = {
     "kanana_p2": (98304, 128, 768, 2048),
     "granite_p1": (40960, 36, 4096, 1536),
     "granite_p2": (40960, 36, 768, 4096),
+    # ungated, 1856 = 14.5 lanes: p1 reads the stack as stored (N on
+    # sublanes), p2 contracts over a K padded with zeros; the mixed step's
+    # rows at 512-row tiles and a decode step's at the 16-row tile
+    "nemotron_prefill_p1": (49152, 64, 2688, 1856),
+    "nemotron_prefill_p2": (49152, 64, 1856, 2688),
+    "nemotron_decode_p1": (384, 64, 2688, 1856),
+    "nemotron_decode_p2": (384, 64, 1856, 2688),
 }
 
 
 @pytest.mark.parametrize("shapes", sorted(_EXPERT_PRODUCTS))
 def test_grouped_matmul(chip, shapes):
-    """The routed experts' products of the three expert cells' mixed
-    steps compile to the kernel at its own tiles (the first with the
-    SwiGLU epilogue, as the cells run it): two whole (K, N) weight
-    blocks, a row tile in and one out fit the VMEM limit it asks for."""
+    """The routed experts' products of the four expert cells compile to
+    the kernel at the tiles it reads from the shapes (the gated cells'
+    first with the SwiGLU epilogue, as they run it): two whole (K, N)
+    weight blocks, a row tile in and one out fit the VMEM limit it asks
+    for."""
     import functools
 
     from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
@@ -432,7 +440,8 @@ def test_grouped_matmul(chip, shapes):
     bf16 = jnp.bfloat16
     assert _compiled_kernels(
         chip, functools.partial(grouped_matmul,
-                                swiglu=shapes.endswith("p1")),
+                                swiglu=shapes.endswith("p1")
+                                and not shapes.startswith("nemotron")),
         ((rows, k), bf16), ((e, k, n), bf16),
         ((e,), jnp.int32)) == {"grouped_matmul"}
 
@@ -575,15 +584,16 @@ def test_the_nemotron_cells_programs_fit_and_take_both_kernels(chip,
     x 512 admitted) compiled for the described v5e. Both kernels take G 16
     / HK 2 (``paged_decode_attention`` in the quantum,
     ``gqa_chunk_attention`` in the mixed step); the experts' width 1856 is
-    14.5 lanes, so by ``grouped_matmul.supports`` both products are
-    ``ragged_dot`` in BOTH programs. What that width costs in memory: the
-    chip stores ``up_proj`` (64, 2688, 1856) with the 2688 minor and the
-    ragged-dot custom call wants the 1856 minor, padded to 1920, so every
-    expert layer's ``up_proj`` is copied, and in the quantum the six copies
-    are hoisted out of the scan and live together: 3.99 GB of temporaries.
-    At the issue's 16 layers that is 16.65 GiB of the chip's 15.75 and
-    the program does not compile (PERF.md section 6, PR 39); the cut of 14
-    fits with more than a GiB to spare."""
+    14.5 lanes and 116 sublane tiles, so by ``grouped_matmul.supports``
+    both products are the ``grouped_matmul`` kernel in BOTH programs (ISSUE
+    40), two custom calls an expert layer, and no ``ragged-dot`` is left.
+    The chip stores ``up_proj`` (64, 2688, 1856) with the 2688 minor; the
+    kernel reads that through a bitcast and contracts on the block's
+    minor axis, so NO expert stack is copied in either program (the
+    ``ragged-dot`` custom call wanted 1856 minor padded to 1920: six
+    copies alive together in the quantum, 3.99 GB of temporaries, which
+    cut the configuration from 16 layers to 14: PERF.md section 6, PR
+    39)."""
     import json
 
     import numpy as np
@@ -640,18 +650,18 @@ def test_the_nemotron_cells_programs_fit_and_take_both_kernels(chip,
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
             < hbm - (1 << 30), (name, mem.temp_size_in_bytes)
     quantum, mixed = (compiled[k].as_text() for k in ("quantum", "mixed"))
-    # six expert layers' up_proj, re-laid out for the ragged-dot and alive
-    # together: the quantum's temporaries
-    up = 64 * 2688 * 1920 * 2
-    temp = compiled["quantum"].memory_analysis().temp_size_in_bytes
-    assert 6 * up < temp < 6.5 * up
-    assert len(re.findall(r" copy\(", "\n".join(
-        line for line in quantum.splitlines()
-        if "bf16[64,2688,1856]" in line.split(" copy(")[0]))) >= 6
+    # no expert stack is re-laid out: the quantum's temporaries are under
+    # ONE up_proj (they were six, 3.99 GB)
+    up = 64 * 2688 * 1856 * 2
+    assert compiled["quantum"].memory_analysis().temp_size_in_bytes < up
     assert "paged_decode_attention" in compiled_kernel_names(quantum)
     assert "gqa_chunk_attention" in compiled_kernel_names(mixed)
     assert len(re.findall(r" custom-call\(.*gqa_chunk_attention/pallas_call",
                           mixed)) == 2
     for text in (quantum, mixed):
-        assert "grouped_matmul" not in compiled_kernel_names(text)
-        assert "ragged-dot" in text
+        assert "ragged-dot" not in text
+        assert len(re.findall(
+            r" custom-call\(.*grouped_matmul/pallas_call", text)) == 2 * 6
+        assert not [line for line in text.splitlines()
+                    if re.search(r"= bf16\[64,(2688,1856|1856,2688)\]\S* "
+                                 r"(copy|transpose|fusion)\(", line)]
