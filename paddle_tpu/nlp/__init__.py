@@ -31,6 +31,10 @@ from .nemotron_h import (  # noqa: F401
     NemotronHConfig, NemotronHMoE, NemotronHBlock, NemotronHModel,
     NemotronHForCausalLM,
 )
+from .falcon_h1 import (  # noqa: F401
+    FalconH1Config, FalconH1Attention, FalconH1MLP, FalconH1DecoderLayer,
+    FalconH1Model, FalconH1ForCausalLM,
+)
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForPretraining,

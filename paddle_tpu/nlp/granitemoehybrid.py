@@ -54,6 +54,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.tensor import Tensor
 from ..incubate.distributed.models.moe.gate import SoftmaxTopKGate
@@ -68,7 +69,7 @@ from .llama import LlamaAttention
 from .paged_attention import normed
 
 __all__ = ["GraniteMoeHybridConfig", "Mamba2Mixer", "GraniteMoeHybridMamba",
-           "NoPositionAttention", "GraniteMoeHybridAttention",
+           "PlainAttention", "NoPositionAttention", "GraniteMoeHybridAttention",
            "GraniteMoeHybridMoE",
            "GraniteMoeHybridDecoderLayer", "GraniteMoeHybridModel",
            "GraniteMoeHybridForCausalLM", "ssd_chunk"]
@@ -282,10 +283,14 @@ class Mamba2Mixer(Layer):
     x ``d_head`` inner channels, ``groups`` of B and C of width ``d_state``,
     a convolution of width ``d_conv``; ``forward`` sums ``chunk_size``
     positions at a time. Parameter names are the source's (``in_proj``,
-    ``conv1d``, ``dt_bias``, ``A_log``, ``D``, ``norm``, ``out_proj``)."""
+    ``conv1d``, ``dt_bias``, ``A_log``, ``D``, ``norm``, ``out_proj``).
+    ``multipliers`` (``nlp/falcon_h1.py``'s ``ssm_multipliers``): five
+    factors on ``in_proj``'s output, one a section ``[z | xs | B | C |
+    dt_raw]``, applied in float32 and rounded once; None adds no
+    operation."""
 
     def __init__(self, hidden_size, heads, d_head, d_state, d_conv=4,
-                 groups=1, chunk_size=256, eps=1e-5):
+                 groups=1, chunk_size=256, eps=1e-5, multipliers=None):
         super().__init__()
         if heads % groups:
             raise ValueError(f"{groups} groups do not divide {heads} heads")
@@ -294,6 +299,13 @@ class Mamba2Mixer(Layer):
         self.eps = float(eps)
         self.d_inner = heads * d_head
         self.conv_dim = self.d_inner + 2 * groups * d_state
+        self._section_scale = None
+        if multipliers is not None:
+            z, x, b, c, dt = (float(m) for m in multipliers)
+            gn = groups * d_state
+            self._section_scale = np.repeat(
+                np.asarray([z, x, b, c, dt], np.float32),
+                [self.d_inner, self.d_inner, gn, gn, heads])
         self.in_proj = Linear(hidden_size,
                               self.d_inner + self.conv_dim + heads,
                               bias_attr=False)
@@ -318,6 +330,9 @@ class Mamba2Mixer(Layer):
         ``softplus(dt_raw + dt_bias)`` (..., H) float32, raw arrays."""
         with jax.named_scope("ssm.in_proj"):
             zxd = self.in_proj(u)._value
+            if self._section_scale is not None:
+                zxd = (zxd.astype(F32) * self._section_scale
+                       ).astype(zxd.dtype)
             d_in, cd = self.d_inner, self.conv_dim
             dt = jax.nn.softplus(zxd[..., d_in + cd:].astype(F32)
                                  + self.dt_bias._value.astype(F32))
@@ -463,29 +478,26 @@ class GraniteMoeHybridMamba(Mamba2Mixer):
             config.rms_norm_eps)
 
 
-class NoPositionAttention(LlamaAttention):
-    """GQA without positions, scores times ``softmax_scale``:
-    ``LlamaAttention``'s projections and its paged K/V forms, with the
-    rotation an identity and the scale handed in (None: ``1 /
-    sqrt(head_dim)``)."""
+class PlainAttention(LlamaAttention):
+    """``LlamaAttention``'s projections and its paged K/V forms with the
+    scale of the scores handed in (None: ``1 / sqrt(head_dim)``) and a
+    whole-sequence ``forward`` in plain ``jax.numpy``; q and k are rotated
+    by ``_rotate`` at ``paged_rope``'s angles (``nlp/falcon_h1.py``: the
+    rotary embedding)."""
 
     def __init__(self, config, softmax_scale=None):
         super().__init__(config)
         self.softmax_scale = (float(softmax_scale) if softmax_scale
                               else self.head_dim ** -0.5)
 
-    def _rotate(self, x, rope):
-        return x
-
-    def paged_rope(self, positions):
-        return None
-
     def forward(self, x):
         """Causal self-attention over x (B, S, E), nothing cached."""
         b, s = x.shape[0], x.shape[1]
         h, hk, d = self.num_heads, self.num_kv_heads, self.head_dim
-        q = self.q_proj(x)._value.reshape(b, s, hk, h // hk, d)
-        k = self.k_proj(x)._value.reshape(b, s, hk, d)
+        rope = self.paged_rope(jnp.arange(s, dtype=F32)[None])
+        q = self._rotate(self.q_proj(x)._value.reshape(b, s, h, d), rope
+                         ).reshape(b, s, hk, h // hk, d)
+        k = self._rotate(self.k_proj(x)._value.reshape(b, s, hk, d), rope)
         v = self.v_proj(x)._value.reshape(b, s, hk, d)
         logits = jnp.einsum("bqhgd,bkhd->bhgqk", q, k,
                             preferred_element_type=F32) * self.softmax_scale
@@ -496,6 +508,17 @@ class NoPositionAttention(LlamaAttention):
         return self.o_proj(Tensor(
             out.astype(x._value.dtype).reshape(b, s, h * d),
             stop_gradient=True))
+
+
+class NoPositionAttention(PlainAttention):
+    """GQA without positions, scores times ``softmax_scale``: the rotation
+    an identity."""
+
+    def _rotate(self, x, rope):
+        return x
+
+    def paged_rope(self, positions):
+        return None
 
 
 class GraniteMoeHybridAttention(NoPositionAttention):

@@ -168,7 +168,11 @@ class Layer:
         else:
             initializer, trainable = None, True
         if initializer is None:
-            initializer = default_initializer
+            # ``set_global_initializer`` (paddle's semantics): over a
+            # layer's default, never over a ParamAttr's own
+            initializer = (init_mod._global_bias_init if is_bias
+                           else init_mod._global_weight_init
+                           ) or default_initializer
         if initializer is None:
             if is_bias:
                 initializer = init_mod.Constant(0.0)

@@ -50,6 +50,8 @@ SCOPES = {
     "ssm.conv": "the causal convolution and its tail",
     "ssm.scan": "the chunked state-space sum / the one-step state update",
     "ssm.out": "the gated norm and the output product",
+    "mix.sum": "a parallel layer's two branches (attention, state-space "
+               "mixer), each times its multiplier, added to the stream",
     "head": "final norm and the vocabulary product",
     "sample": "argmax / the sampler inside the engine's jitted bodies",
     "loss": "the trainer's loss (cross entropy over the logits)",

@@ -40,14 +40,20 @@ head call, and know nothing of what a layer computes. Of the MODEL they ask
   positions), ``layers`` and ``norm``; ``model.lm_head`` (a call);
 - ``model.paged_cache_layout()``: the block pool's geometry (``layout``
   ``"kv"``: K and V rows; ``"latent"``: ONE row a token) and, per layer,
-  WHAT IT CACHES (``layers``: ``"kv"`` | ``"latent"``: block arrays, in
-  the order of such layers; ``"state"``: a row of the pool's SLOT side,
+  WHAT IT CACHES (``layers``: per layer ONE part, a string, or SEVERAL, a
+  tuple of them, as ``("kv", "state")`` for a layer that keeps key blocks
+  and a slot's state at once, ``nlp/falcon_h1.py``; the parts are
+  ``"kv"`` | ``"latent"``: block arrays, in
+  the order of such parts; ``"state"``: a row of the pool's SLOT side,
   whose per-slot arrays ``state`` lists as ``(shape, dtype)``; a None in a
   shape is the length of a window layer's RING of keys, ``window`` (the
   layout's, 0 or absent without such layers) plus ``prefill_chunk`` in
   whole blocks; ``"none"``: NOTHING, a layer that is a feed-forward alone,
   as ``nlp/nemotron_h.py``'s expert layers are: it is handed an empty
   tuple, hands one back, and takes no place on either side of the pool).
+  The pool's two sides, what is donated, committed and accounted
+  (``bytes_per_token``, ``state_bytes_per_slot``), admission's demand and
+  what preemption frees follow from the PARTS alone (``layout_parts``).
   A model whose layers all cache blocks has an empty slot side: no aval. What a model's layers cannot serve (one uniform window
   over the block path) the layout refuses, by raising; the engine reads no
   attribute of a model's config to decide it.
@@ -59,12 +65,15 @@ layer's new cache arrays. ``step`` holds ``rope``, ``tables``, ``lens``
 (decode: each live row's length with this token; chunk: each row's base
 length), ``write_blk`` / ``write_off``, ``live`` and, in a chunk, ``valid``
 (S, C): the positions that bring a token. ``cache`` is ``(k, v, k_scale,
-v_scale)`` for a block layer (a side the pool lacks None) and the tuple of
-``(num_slots, ...)`` arrays for a state layer, row ``s`` slot ``s``'s. A
-state layer leaves a row's state where the row's last valid position put
+v_scale)`` for a block part (a side the pool lacks None) and the tuple of
+``(num_slots, ...)`` arrays for a state part, row ``s`` slot ``s``'s; a
+layer of one part is handed (and hands back) that part's arrays, a layer
+of several a tuple of them in the order its layout entry names them. A
+state part leaves a row's state where the row's last valid position put
 it, starts a row whose base length is 0 from zeros (no host-side reset),
 and keeps the state of a row that is not live; preemption frees the slot
-and recompute-on-resume rebuilds the state from ``prompt + tokens``.
+and the blocks, and recompute-on-resume rebuilds the state AND the keys
+from ``prompt + tokens``.
 ``nlp/paged_attention.PagedResidualLayer`` is the pre-norm residual
 layer over K/V or latent attention (``nlp/llama.py``,
 ``nlp/deepseek_v3.py``: ``self_attn.paged_decode`` / ``paged_chunk``);
@@ -72,7 +81,9 @@ layer over K/V or latent attention (``nlp/llama.py``,
 ``nlp/afmoe.py`` window layers (rings: position ``p`` at row ``p mod R``,
 what a row may see decided by positions alone) beside full ones,
 ``nlp/nemotron_h.py`` layers of ONE part each: a state-space mixer, an
-attention or routed experts (``"none"``) under one norm. A
+attention or routed experts (``"none"``) under one norm,
+``nlp/falcon_h1.py`` layers that run a state-space mixer AND rotary
+attention on one normed input (``("kv", "state")``). A
 feed-forward that routes rows to experts shows ``rows_per_expert``; both
 programs hand back the rows the experts HELD here got beside the tokens
 (``moe_rows``; an empty tuple, no aval, without experts).
@@ -140,6 +151,7 @@ same seed, no mesh at model build, identical weights either way.
 from __future__ import annotations
 
 import contextlib
+import itertools
 
 import numpy as np
 import jax
@@ -272,42 +284,53 @@ def _tp_shard_params(model):
     return n_sharded
 
 
+def layout_parts(layers):
+    """``paged_cache_layout()["layers"]`` as one flat list of parts: a
+    layer names one part (a string) or several (a tuple of them)."""
+    return [part for kind in layers
+            for part in ((kind,) if isinstance(kind, str) else kind)]
+
+
 def _layer_caches(model, pools, state):
     """Per layer of ``model``, the cache arrays the step hands it, by
-    what ``model.paged_cache_layout()["layers"]`` says it caches: a layer
+    what ``model.paged_cache_layout()["layers"]`` says it caches: a part
     with block arrays gets ``(k, v, k_scale, v_scale)`` of its place among
-    such layers (a side the pool lacks, the scales of a float pool or the
+    such parts (a side the pool lacks, the scales of a float pool or the
     V side of a latent pool, is an empty tuple and reads None); a
-    ``"state"`` layer gets its tuple of per-slot arrays, a layer that
-    caches nothing (``"none"``) an empty tuple."""
-    out, n_block, n_state = [], 0, 0
-    for kind in model.paged_cache_layout()["layers"]:
-        if kind == "none":
-            out.append(())
-        elif kind == "state":
-            out.append(state[n_state])
-            n_state += 1
-        else:
-            out.append(tuple(p[n_block] if len(p) else None
-                             for p in pools))
-            n_block += 1
-    return out
+    ``"state"`` part gets its tuple of per-slot arrays, a layer that
+    caches nothing (``"none"``) an empty tuple. A layer of ONE part is
+    handed that part's arrays; a layer that names several (``("kv",
+    "state")``) a tuple of them, in the order it names them."""
+    slot_rows, block_places = iter(state), itertools.count()
+
+    def one(part):
+        if part == "none":
+            return ()
+        if part == "state":
+            return next(slot_rows)
+        i = next(block_places)
+        return tuple(p[i] if len(p) else None for p in pools)
+
+    return [one(kind) if isinstance(kind, str)
+            else tuple(one(part) for part in kind)
+            for kind in model.paged_cache_layout()["layers"]]
 
 
 def _collect_caches(model, new):
     """The layers' new cache arrays back in the step's order: the four
-    block sides (a None side stays empty) and the state side; a layer
-    that caches nothing has no place on either."""
+    block sides (a None side stays empty) and the state side; a part
+    that caches nothing has no place on either, a layer of several parts
+    a place on each side it names."""
     sides, state = ([], [], [], []), []
     for kind, arrays in zip(model.paged_cache_layout()["layers"], new):
-        if kind == "none":
-            continue
-        if kind == "state":
-            state.append(tuple(arrays))
-            continue
-        for side, arr in zip(sides, arrays):
-            if arr is not None:
-                side.append(arr)
+        for part, got in (((kind, arrays),) if isinstance(kind, str)
+                          else zip(kind, arrays)):
+            if part == "state":
+                state.append(tuple(got))
+            elif part != "none":
+                for side, arr in zip(sides, got):
+                    if arr is not None:
+                        side.append(arr)
     return (sides[0], sides[1], tuple(sides[2]), tuple(sides[3]),
             tuple(state))
 
@@ -676,7 +699,7 @@ class ServingEngine:
                 f"multi_quantum must be >= 1, got {multi_quantum}")
         self.mesh, self.tp = _resolve_tp_mesh(mesh, tp)
         layout = model.paged_cache_layout()
-        kinds = layout["layers"]
+        kinds = layout_parts(layout["layers"])
         # nothing is silently ignored: what a latent pool, latent
         # attention, a slot's recurrent state or a window layer's ring
         # cannot do yet is refused by name (what can be served at all is
@@ -697,8 +720,8 @@ class ServingEngine:
                 raise NotImplementedError(
                     f"ServingEngine does not compose {name} with {what} "
                     f"model ({type(model).__name__}) yet")
-        if spec_draft is not None and set(
-                spec_draft.paged_cache_layout()["layers"]) != {"kv"}:
+        if spec_draft is not None and set(layout_parts(
+                spec_draft.paged_cache_layout()["layers"])) != {"kv"}:
             raise NotImplementedError(
                 "ServingEngine does not take a latent-attention, "
                 "state-space or window-ring model "
@@ -768,10 +791,10 @@ class ServingEngine:
         if num_blocks is None:
             num_blocks = s * w + 1  # +1: the masked-write scratch block
         self.prefix_cache = bool(prefix_cache)
-        # block arrays for the layers that cache every key, a slot side
-        # for the layers that carry a state or a window's ring of keys
-        # (none: an empty side, no aval); a ring holds the window and one
-        # chunk, in whole blocks
+        # block arrays for the parts that cache every key, a slot side
+        # for the parts that carry a state or a window's ring of keys
+        # (none: an empty side, no aval; a layer may name a part on each
+        # side); a ring holds the window and one chunk, in whole blocks
         n_state = kinds.count("state")
         n_block = len(kinds) - n_state - kinds.count("none")
         self.pool = PagedKVCachePool(

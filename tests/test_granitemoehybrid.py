@@ -162,7 +162,7 @@ class _Paged:
 
     def __init__(self, model, slots=3):
         layout = model.paged_cache_layout()
-        kinds = layout["layers"]
+        kinds = engine_mod.layout_parts(layout["layers"])
         self.model, self.slots = model, slots
         self.pool = PagedKVCachePool(
             32, 8, layout["num_kv_heads"], layout["head_dim"],

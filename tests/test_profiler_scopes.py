@@ -64,8 +64,8 @@ def unscoped(fn, args):
 
 
 def _model(family):
-    from paddle_tpu.nlp import (afmoe, deepseek_v3, granitemoehybrid, llama,
-                                nemotron_h)
+    from paddle_tpu.nlp import (afmoe, deepseek_v3, falcon_h1,
+                                granitemoehybrid, llama, nemotron_h)
 
     paddle.seed(0)
     model = {
@@ -79,6 +79,8 @@ def _model(family):
         "afmoe": lambda: afmoe.AfmoeForCausalLM(afmoe.AfmoeConfig.tiny()),
         "nemotron_h": lambda: nemotron_h.NemotronHForCausalLM(
             nemotron_h.NemotronHConfig.tiny(held_experts=(0, 4))),
+        "falcon_h1": lambda: falcon_h1.FalconH1ForCausalLM(
+            falcon_h1.FalconH1Config.tiny()),
     }[family]()
     model.eval()
     return model
@@ -114,6 +116,11 @@ EXPECTED = {
                    "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.out",
                    "moe.router", "moe.dispatch", "moe.products",
                    "moe.combine", "moe.shared", "head", "sample"},
+    # every layer both branches: attention, the mixer, their scaled sum
+    # under a scope of its own, a dense MLP, and both writes of the cache
+    "falcon_h1": {"embed", "norm", "attn.proj", "attn.full", "cache.write",
+                  "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.out",
+                  "mix.sum", "mlp", "head", "sample"},
     "train": {"embed", "norm", "attn.proj", "attn.window", "mlp", "head",
               "loss", "optimizer"},
 }
@@ -121,7 +128,7 @@ EXPECTED = {
 
 @pytest.mark.parametrize("family,program", [
     (f, p) for f in ("llama", "deepseek_v3", "granitemoehybrid", "afmoe",
-                     "nemotron_h")
+                     "nemotron_h", "falcon_h1")
     for p in ("mixed", "quantum")] + [("train", "step")])
 def test_every_equation_sits_in_a_scope(family, program):
     if family == "train":

@@ -665,3 +665,81 @@ def test_the_nemotron_cells_programs_fit_and_take_both_kernels(chip,
         assert not [line for line in text.splitlines()
                     if re.search(r"= bf16\[64,(2688,1856|1856,2688)\]\S* "
                                  r"(copy|transpose|fusion)\(", line)]
+
+
+def test_the_falcon_h1_cells_programs_fit_and_take_both_kernels(chip,
+                                                                monkeypatch):
+    """``falcon-h1-34b.chat1k-o256``'s REAL ``jit_quantum`` and
+    ``jit_mixed`` (the family's model from the cell's configuration, 5.25 B
+    parameters as zeros; the engine with the cell's options; a batch of 64
+    x 1,024 admitted) compiled for the described v5e. Every one of the six
+    layers is on BOTH sides of the pool: six K and six V arrays and six
+    slot rows of a float32 state (32 x 128 x 256) and a convolution tail.
+    Both attention kernels take G 5 / HK 4 (``paged_decode_attention`` in
+    the quantum, ``gqa_chunk_attention`` in the mixed step, one call a
+    layer); arguments and temporaries fit the chip's 15.75 GiB with 1 GiB
+    to spare, and no weight is copied into another layout."""
+    import json
+
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.ops.pallas._utils import compiled_kernel_names
+    from paddle_tpu.serving import ServingEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from benchmark.families import falcon_h1 as family
+    from benchmark.harness import counts_falcon_h1 as counts
+
+    with open(os.path.join(root, "benchmark", "configs",
+                           "falcon-h1-34b-l6.json")) as f:
+        cfg = json.load(f)
+    dtype_was = paddle.get_default_dtype()
+    paddle.set_flags({"FLAGS_pallas_force": True})
+    try:
+        model = family.build_model(cfg)     # every matrix zeros
+        model.eval()
+        eng = ServingEngine(model, **cfg["engine"])
+        for _ in range(64):
+            eng.submit(np.ones(1024, np.int32), max_new_tokens=256)
+        eng._admit()
+
+        def shapes(args):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                               sharding=chip), args)
+
+        compiled = {}
+        for name, (step, args) in (("quantum", eng.decode_step_target()),
+                                   ("mixed", eng.mixed_step_target())):
+            compiled[name] = step.lower(*shapes(args)).compile()
+    finally:
+        paddle.set_flags({"FLAGS_pallas_force": False})
+        paddle.set_default_dtype(dtype_was)
+    layers = cfg["num_hidden_layers"]
+    n_params = sum(int(p._value.size) for _, p in model.named_parameters())
+    assert n_params == counts.total_params(cfg) \
+        == layers * 430_120_032 + 2 * 261120 * 5120 + 5120
+    blocks = cfg["engine"]["num_blocks"]
+    resident = (2 * n_params + blocks * 32 * counts.cache_bytes_per_token(cfg)
+                + cfg["engine"]["num_slots"]
+                * counts.state_bytes_per_slot(cfg))
+    hbm = 15.75 * 2 ** 30
+    for name, program in compiled.items():
+        mem = program.memory_analysis()
+        assert 0 <= mem.argument_size_in_bytes - resident < 1 << 20, name
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < hbm - (1 << 30), (name, mem.temp_size_in_bytes / 2 ** 30)
+    quantum, mixed = (compiled[k].as_text() for k in ("quantum", "mixed"))
+    assert "paged_decode_attention" in compiled_kernel_names(quantum)
+    assert "gqa_chunk_attention" in compiled_kernel_names(mixed)
+    assert len(re.findall(r" custom-call\(.*gqa_chunk_attention/pallas_call",
+                          mixed)) == layers
+    # no weight is re-laid out: no copy, transpose or fusion whose result
+    # has a matrix's shape (either order) in bf16
+    widths = r"(5120|21504|9248|4096|2560|512|261120)"
+    for text in (quantum, mixed):
+        assert not [line for line in text.splitlines()
+                    if re.search(r"= bf16\[" + widths + "," + widths
+                                 + r"\]\S* (copy|transpose)\(", line)]
