@@ -24,6 +24,16 @@ __all__ = [
 ]
 
 
+def _mark_last(v, axis):
+    """Constrain the one dim a tensor-parallel layer owns, the last, to
+    ``axis`` (``"mp"``, or None: whole on every ``mp`` member). The
+    leading (batch/seq) dims stay UNCONSTRAINED: in a PartitionSpec None
+    is "replicated over every axis", so naming them would gather a batch
+    that is split over dp / sharding / sep, and compute it once a member."""
+    return mesh_state.constraint(
+        v, *([mesh_state.UNCONSTRAINED] * (v.ndim - 1)), axis)
+
+
 class VocabParallelEmbedding(Layer):
     """Embedding with the vocab dim sharded over mp."""
 
@@ -45,14 +55,9 @@ class VocabParallelEmbedding(Layer):
         # state whose LAST dim must be replicated (Megatron semantics),
         # NOT E-over-mp: an E-sharded hidden colliding with a downstream
         # (dp, sep)-sharded constraint makes GSPMD fall back to
-        # replicate-then-repartition (full remat). Leading (batch/seq)
-        # dims stay UNCONSTRAINED so a dp/sep-sharded batch keeps its
-        # sharding instead of paying a batch-dim all-gather here.
-        return apply(
-            lambda v: mesh_state.constraint(
-                v, *([mesh_state.UNCONSTRAINED] * (v.ndim - 1)), None),
-            out, op_name="vocab_parallel_gather",
-        )
+        # replicate-then-repartition (full remat).
+        return apply(lambda v: _mark_last(v, None), out,
+                     op_name="vocab_parallel_gather")
 
 
 class ColumnParallelLinear(Layer):
@@ -86,13 +91,9 @@ class ColumnParallelLinear(Layer):
     def forward(self, x):
         out = F.linear(x, self.weight, self.bias)
 
-        def mark(v):
-            spec = [None] * (v.ndim - 1)
-            if self._gather_output:
-                return mesh_state.constraint(v, *spec, None)
-            return mesh_state.constraint(v, *spec, "mp")
-
-        return apply(mark, out, op_name="column_parallel_out")
+        return apply(
+            lambda v: _mark_last(v, None if self._gather_output else "mp"),
+            out, op_name="column_parallel_out")
 
 
 class RowParallelLinear(Layer):
@@ -122,18 +123,11 @@ class RowParallelLinear(Layer):
     def forward(self, x):
         x = ensure_tensor(x)
         if self._input_is_parallel:
-            def mark_in(v):
-                spec = [None] * (v.ndim - 1)
-                return mesh_state.constraint(v, *spec, "mp")
-
-            x = apply(mark_in, x, op_name="row_parallel_in")
+            x = apply(lambda v: _mark_last(v, "mp"), x,
+                      op_name="row_parallel_in")
         out = F.linear(x, self.weight, self.bias)
-
-        def mark_out(v):
-            spec = [None] * v.ndim
-            return mesh_state.constraint(v, *spec)
-
-        return apply(mark_out, out, op_name="row_parallel_out")
+        return apply(lambda v: _mark_last(v, None), out,
+                     op_name="row_parallel_out")
 
 
 class ParallelCrossEntropy(Layer):

@@ -24,7 +24,9 @@ class DataParallel:
             return x
 
         def fn(v):
-            spec = [("dp", "sharding")] + [None] * (v.ndim - 1)
+            if not v.ndim:
+                return v
+            spec = [mesh_state.data_axes(v.shape[0])] + [None] * (v.ndim - 1)
             return mesh_state.constraint(v, *spec)
 
         return apply(fn, x, op_name="dp_shard_batch")

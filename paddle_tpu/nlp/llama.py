@@ -140,17 +140,17 @@ def _use_mp(config):
 
 
 def _mark_hidden(t, config):
-    """Constrain hidden states (B, S, E): batch over dp(+sharding as fsdp
-    data axis), seq over sep when sequence-parallel."""
+    """Constrain hidden states (B, S, E): batch over the data axes (dp,
+    and sharding as fsdp data axis), seq over sep when sequence-parallel."""
     if not mesh_state.has_mesh():
         return t
     seq_axis = "sep" if (
         (config.sequence_parallel or config.context_parallel)
-        and mesh_state.mesh_axis_size("sep") > 1
-    ) else None
+        and mesh_state.mesh_axis_size("sep") > 1) else None
 
     def fn(v):
-        return mesh_state.constraint(v, "dp", seq_axis, None)
+        return mesh_state.constraint(
+            v, mesh_state.data_axes(v.shape[0]), seq_axis, None)
 
     return apply(fn, ensure_tensor(t), op_name="hidden_constraint")
 

@@ -1,12 +1,12 @@
 """Shared helpers for the Pallas kernel tier."""
 from __future__ import annotations
 
-import math
 import re
 
 import jax
 
 from ...parallel import mesh as mesh_state
+from ...parallel.mesh import data_axes  # noqa: F401  (the kernels' import)
 
 
 def interpret_mode():
@@ -57,17 +57,6 @@ def per_shard(kernel, in_specs, out_specs):
         return kernel
     return jax.shard_map(kernel, mesh=mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
-
-
-def data_axes(n: int):
-    """The data-parallel mesh axes (``dp``, then ZeRO's ``sharding``) a
-    batch dim of ``n`` rows divides over, as a PartitionSpec entry."""
-    axes = [a for a in ("dp", "sharding")
-            if mesh_state.mesh_axis_size(a) > 1]
-    while axes and n % math.prod(
-            mesh_state.mesh_axis_size(a) for a in axes):
-        axes.pop()
-    return tuple(axes) or None
 
 
 def head_axis(*head_counts: int):

@@ -9,6 +9,8 @@ per-stage sub-meshes) and layers place/constrain arrays with
 """
 from __future__ import annotations
 
+import math
+
 import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
@@ -33,6 +35,17 @@ def mesh_axis_size(axis: str) -> int:
     if _GLOBAL_MESH is None or axis not in _GLOBAL_MESH.shape:
         return 1
     return int(_GLOBAL_MESH.shape[axis])
+
+
+def data_axes(n: int):
+    """The data-parallel mesh axes (``dp``, then ZeRO's ``sharding``) a
+    batch dim of ``n`` rows divides over, as a PartitionSpec entry: what
+    the per-shard kernels split their batch over and what the model
+    constrains its hidden stream's batch to."""
+    axes = [a for a in ("dp", "sharding") if mesh_axis_size(a) > 1]
+    while axes and n % math.prod(mesh_axis_size(a) for a in axes):
+        axes.pop()
+    return tuple(axes) or None
 
 
 def axis_groups(mesh: Mesh | None = None):

@@ -1,6 +1,7 @@
 """Flagship model family tests: Llama/GPT forward+train, decode-cache
 parity, and the hybrid parallel==serial oracle through the fully-jitted
 train step (the bench/dryrun path)."""
+import jax
 import numpy as np
 import pytest
 
@@ -79,15 +80,36 @@ def test_gpt_forward():
     assert m(ids).shape == [2, 16, 128]
 
 
-def _train_losses(parallel, steps=3):
+def _fleet_mesh():
+    """dp 2 x sharding 2 x mp 2 through ``fleet.init``, the batch over
+    the step's default (``dp``)."""
+    strategy = fleet.DistributedStrategy()
+    strategy.hybrid_configs = {
+        "dp_degree": 2, "mp_degree": 2, "pp_degree": 1,
+        "sharding_degree": 2,
+    }
+    fleet.init(is_collective=True, strategy=strategy)
+    return {"state_sharding_axis": "sharding"}
+
+
+def _cell_mesh():
+    """The benchmark's mesh cell: sharding 2 x mp 2 on exactly four
+    devices (``fleet.init`` hands dp the rest of the eight), ZeRO's group
+    the data-parallel group: the batch over ``sharding``."""
+    mesh_state.set_mesh(jax.sharding.Mesh(
+        np.array(jax.devices()[:4]).reshape(1, 2, 1, 2),
+        ("dp", "sharding", "sep", "mp")))
+    return {"state_sharding_axis": "sharding",
+            "input_batch_axes": ("sharding",)}
+
+
+def _train_losses(install_mesh, steps=3):
+    """The toy TP step's first losses and its first gradient's norm a
+    leaf (from AdamW's first moment after one step, as the benchmark
+    reads it), on the mesh ``install_mesh`` installs (it returns the
+    step's keywords) or, None, on one device."""
     mesh_state.set_mesh(None)
-    if parallel:
-        strategy = fleet.DistributedStrategy()
-        strategy.hybrid_configs = {
-            "dp_degree": 2, "mp_degree": 2, "pp_degree": 1,
-            "sharding_degree": 2,
-        }
-        fleet.init(is_collective=True, strategy=strategy)
+    step_kw = install_mesh() if install_mesh else {}
     paddle.seed(0)
     cfg = LlamaConfig.tiny(tensor_parallel=True)
     m = LlamaForCausalLM(cfg)
@@ -95,18 +117,24 @@ def _train_losses(parallel, steps=3):
     opt = paddle.optimizer.AdamW(
         1e-3, parameters=m.parameters(), weight_decay=0.01)
     step = JittedTrainStep(
-        m, lambda out, labels: crit(out, labels), opt,
-        state_sharding_axis="sharding" if parallel else None)
+        m, lambda out, labels: crit(out, labels), opt, **step_kw)
     ids = paddle.to_tensor(
         np.random.RandomState(1).randint(0, 128, (4, 32)))
-    return [float(step(ids, ids)) for _ in range(steps)]
+    losses = [float(step(ids, ids))]
+    grad_norms = [np.linalg.norm(m) / (1.0 - 0.9) for m in jax.device_get(
+        [s["moment1"] for s in step._s_vals])]
+    losses += [float(step(ids, ids)) for _ in range(steps - 1)]
+    return losses, grad_norms
 
 
-def test_llama_jitted_hybrid_train_matches_serial():
-    """TP(mp=2) x ZeRO(sharding=2) x DP(2) fully-jitted step == serial."""
-    lp = _train_losses(True)
-    ls = _train_losses(False)
+@pytest.mark.parametrize("install_mesh", [_fleet_mesh, _cell_mesh])
+def test_llama_jitted_hybrid_train_matches_serial(install_mesh):
+    """TP(mp=2) x ZeRO(sharding=2) (x DP(2)) fully-jitted step == serial:
+    the first losses and the first gradient's norms."""
+    lp, gp = _train_losses(install_mesh)
+    ls, gs = _train_losses(None)
     np.testing.assert_allclose(lp, ls, rtol=5e-4, atol=5e-5)
+    np.testing.assert_allclose(gp, gs, rtol=5e-4, atol=5e-5)
 
 
 def test_jitted_multi_step_scan_matches_single_steps():

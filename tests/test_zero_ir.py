@@ -9,12 +9,15 @@ Since the analysis PR these invariants are asserted through
 ``paddle_tpu.analysis.check_budget`` — the same pass the CLI and bench
 suite run — instead of raw IR string matching, so the test and the
 production auditor cannot drift apart."""
+import functools
+
 import numpy as np
 import pytest
 import jax
 
 import paddle_tpu as paddle
 from paddle_tpu import analysis
+from paddle_tpu.analysis import collectives
 from paddle_tpu.parallel import mesh as mesh_state
 from paddle_tpu.distributed import fleet
 from paddle_tpu.jit.train import JittedTrainStep
@@ -200,3 +203,95 @@ def test_fused_lce_recipe_budget_matches_registered():
             "llama_tp_zero_fused_lce", report)
     finally:
         recipe.close()
+
+
+# ------------------------------------ the batch stays split in the layers
+
+def _whole_batch_gathers(hlo_text, batch, seq):
+    """Result shapes of the all-gathers (the census's own definitions)
+    that carry a WHOLE batch of an activation: (batch, seq | seq - 1,
+    ...) or its rows flattened."""
+    lead = {(batch, seq), (batch, seq - 1)}
+    flat = {batch * seq, batch * (seq - 1)}
+    found = []
+    for result, kind, suffix in collectives._DEF_RE.findall(hlo_text):
+        if kind != "all-gather" or suffix == "-done":
+            continue
+        for _, dims in collectives._SHAPE_RE.findall(result):
+            shape = tuple(int(d) for d in dims.split(",") if d)
+            if shape[:2] in lead or (len(shape) == 2 and shape[0] in flat):
+                found.append(shape)
+    return found
+
+
+def _tiny_llama_step(mp, sharding, split_batch):
+    """The benchmark's mesh cell at toy widths: Column/RowParallel layers
+    over ``mp``, ZeRO's states and (``split_batch``) the batch over
+    ``sharding``, the plain head and criterion. No mesh at 1 x 1."""
+    from paddle_tpu.nlp import (
+        LlamaConfig, LlamaForCausalLM, LlamaPretrainingCriterion,
+    )
+
+    if mp * sharding > 1:
+        # exactly mp x sharding devices (fleet.init would hand the rest
+        # of the eight to dp)
+        mesh_state.set_mesh(jax.sharding.Mesh(
+            np.array(jax.devices()[:mp * sharding]).reshape(
+                1, sharding, 1, mp), ("dp", "sharding", "sep", "mp")))
+    paddle.seed(0)
+    cfg = LlamaConfig.tiny(tensor_parallel=True)
+    model = LlamaForCausalLM(cfg)
+    crit = LlamaPretrainingCriterion(cfg)
+    opt = paddle.optimizer.AdamW(1e-3, parameters=model.parameters())
+    kw = {}
+    if sharding > 1:
+        kw["state_sharding_axis"] = "sharding"
+        if split_batch:
+            kw["input_batch_axes"] = ("sharding",)
+    return JittedTrainStep(
+        model, lambda out, labels: crit(out, labels), opt, **kw)
+
+
+@functools.lru_cache(maxsize=None)
+def _serial_first_loss(batch, seq):
+    step = _tiny_llama_step(1, 1, False)
+    ids = _cell_ids(batch, seq)
+    return float(step.run_steps(ids, ids).numpy()[0])
+
+
+def _cell_ids(batch, seq):
+    """One dispatch of one step: (1, batch, seq), as the cell feeds it."""
+    return paddle.to_tensor(
+        np.random.RandomState(0).randint(0, 128, (1, batch, seq)))
+
+
+@pytest.mark.parametrize("mp, sharding, batch", [
+    pytest.param(2, 2, 4, id="mp2-sharding2"),       # the cell's layout
+    pytest.param(2, 4, 4, id="mp2-sharding4"),
+    pytest.param(1, 4, 4, id="sharding4"),
+    pytest.param(2, 1, 4, id="mp2"),
+    pytest.param(2, 2, 3, id="mp2-sharding2-batch3"),  # 3 rows over 2
+])
+def test_batch_stays_split_inside_tensor_parallel_layers(mp, sharding, batch):
+    """At the benchmark's mesh layout (batch over ``sharding``, which the
+    registered recipe does not pass) a tensor-parallel layer constrains
+    the dim it owns and the hidden stream names the data axes: no
+    activation is gathered as a whole batch, the partitions together
+    execute the program's FLOPs once (a batch replicated over
+    ``sharding`` reads 1 / sharding), nothing is rematerialised, and the
+    first loss is the single-device step's. A batch the data axes do not
+    divide stays replicated over them, and correct."""
+    seq = 48
+    serial = _serial_first_loss(batch, seq)
+    divides = batch % sharding == 0
+    step = _tiny_llama_step(mp, sharding, split_batch=divides)
+    ids = _cell_ids(batch, seq)
+    # the scan-fused program ``run_steps`` dispatches, which the cell runs
+    report = analysis.audit(step._jitted_multi, *step._steps_args(ids, ids))
+    assert report.remat_events == []
+    if divides:
+        if sharding > 1:
+            assert _whole_batch_gathers(report.hlo_text, batch, seq) == []
+        assert report.cost.flops_ratio >= 0.9, report.cost.flops_ratio
+    np.testing.assert_allclose(step.run_steps(ids, ids).numpy()[0], serial,
+                               rtol=5e-4, atol=5e-5)
