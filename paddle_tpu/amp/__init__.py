@@ -21,6 +21,8 @@ WHITE_LIST = {
     "matmul", "mm", "bmm", "mv", "linear", "conv1d", "conv2d", "conv3d",
     "conv1d_transpose", "conv2d_transpose", "conv3d_transpose", "einsum",
     "addmm", "flash_attention", "scaled_dot_product_attention",
+    # mp_layers' sequence-split half-layers: linear's products
+    "column_parallel_group", "row_parallel_scatter",
 }
 BLACK_LIST = {
     "exp", "log", "log2", "log10", "log1p", "expm1", "pow", "square",

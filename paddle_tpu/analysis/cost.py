@@ -242,6 +242,10 @@ def _walk_cost(jaxpr, unroll_loops):
                     trips = max(int(eqn.params.get("length", 1)), 1)
                 except (TypeError, ValueError):
                     trips = 1
+            elif eqn.primitive.name == "shard_map":
+                # a manual region's body is ONE member's work, in both
+                # views: every member of its mesh runs it
+                trips = int(eqn.params["mesh"].size)
             # cond/switch branches all exist in the compiled module, so
             # both views SUM them (like XLA); while trip counts are
             # unknowable statically, so the unrolled view floors at x1

@@ -135,36 +135,58 @@ def _build_llama_tp_zero_fused_lce():
         max_remat=0,
         require_reduce_scatter=True,
         require_donated=True,
-        # pinned ~25% above the audited graph (18: see test_analysis):
-        # headroom for benign partitioner drift, but a structural
-        # regression (per-layer re-gather, lost fusion) blows through it
-        max_all_gathers=23,
-        # the batch stays split over ``sharding`` inside the layers:
-        # audited 609,272 B of collectives and 454,056 B of temps (CPU
-        # backend). A constraint that pins the batch replicated over
-        # the axis again (946,176 B and 1,759,000 B: every member
+        # pinned ~25% above the audited graph (28: see test_analysis; 18
+        # ZeRO / criterion gathers + the sequence-split hidden stream's
+        # 8, one a tensor-parallel half-layer each way, + the fused
+        # criterion's 2): headroom for benign partitioner drift, but a
+        # structural regression (per-layer re-gather, lost fusion) blows
+        # through it
+        max_all_gathers=35,
+        # the batch stays split over ``sharding`` inside the layers and
+        # the hidden stream over ``mp`` between them: audited 676,088 B
+        # of collectives (a gather counts its whole result, so the
+        # stream's 8 gathers + 8 reduce-scatters, 98,304 B, read like
+        # the 114,688 B of all-reduces they replace; the rest is the
+        # vocabulary-parallel embedding, which the CPU partitioner now
+        # feeds by gathering its 128-row table) and 452,904 B of temps
+        # (CPU backend). A constraint that pins the batch replicated
+        # over the axis again (946,176 B and 1,759,000 B: every member
         # all-reduces and holds the WHOLE batch) blows through both
-        max_collective_bytes=760_000,
-        max_temp_bytes=570_000,
+        max_collective_bytes=845_000,
+        max_temp_bytes=566_000,
         max_f32_matmuls=0,
-        # audited 3.80 MB trace-level peak; a lost donation or a
-        # full-logits buffer reappearing blows through the headroom
-        max_peak_live_bytes=4_750_000,
+        # audited 3.93 MB trace-level peak (3.80 MB before the hidden
+        # stream was split: a half-layer keeps its GATHERED input for
+        # the backward); a lost donation or a full-logits buffer
+        # reappearing blows through the headroom
+        max_peak_live_bytes=4_900_000,
         # norm scales (256 B) replicate by design; any 2-D leaf —
         # a weight or its moments — losing its TP/ZeRO axis is >4 KB
         max_replicated_param_bytes=4096,
         # 48 sharded leaves audited: params + both moments actually
         # carry the axis, not just the sharding rule table
         min_sharded_params=40,
-        # static cost model (analysis/cost.py): 117.9M flops / 61.5 MB
-        # accessed per step over the 4x32-token batch — ~921k flops
-        # and ~480 KB per token audited; a lost fusion or an
+        # static cost model (analysis/cost.py): 119.1M flops / 80.7 MB
+        # accessed per step over the 4x32-token batch — ~930k flops
+        # and ~630 KB per token audited; a lost fusion or an
         # accidental f32 re-materialization of the state blows the
-        # byte cap, a duplicated forward blows the flop cap
+        # byte cap, a duplicated forward blows the flop cap. The bytes
+        # rose from 61.4 MB (~480 KB a token) with the manual regions,
+        # and that rise is the walker's VIEW, not the hardware's: a
+        # region's body is counted once a member (8 x 2.98 MB = 23.9
+        # MB), so each ``mp`` member reads the whole gathered stream
+        # and each ``sharding`` member its own copy of the weight
+        # shards, where the partitioner's program, which did the same
+        # reads on every device, was counted at global shapes (~7.5 MB
+        # for the same products). What is real in it: 2.4 MB, each
+        # member's explicit float32 sum of its weight gradients
+        # (``_member_linear``; inside the products before). Tighten
+        # the two pins below again when the walker counts an operand a
+        # region's members share once (765,000 is 21 % above)
         cost_tokens_per_dispatch=128,
         max_flops_per_token=1_200_000,
-        max_hbm_bytes_per_token=650_000,
-        min_arithmetic_intensity=1.4,
+        max_hbm_bytes_per_token=765_000,
+        min_arithmetic_intensity=1.2,
     )
     return Recipe("llama_tp_zero_fused_lce", step, (ids, ids), budget,
                   teardown=_mesh_teardown())

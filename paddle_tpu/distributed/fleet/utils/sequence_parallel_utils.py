@@ -4,10 +4,15 @@ unverified, SURVEY.md §0).
 
 The reference all-gathers activations entering a parallel linear and
 reduce-scatters on exit so LayerNorm/dropout run sequence-sharded; under
-GSPMD the same schedule falls out of constraining the sequence dim to the
-``mp`` axis around the matmuls — XLA overlaps the ag/rs automatically.
+GSPMD the same schedule is asked for by constraining the sequence dim to
+the ``mp`` axis around the matmuls; each op names the one dim it owns and
+leaves the others to the propagation pass, as ``mp_layers._mark_last``
+does (None would replicate a batch that is split over the data axes).
 Layout convention matches the reference: (seq, batch, hidden) with the
-sequence dim sharded.
+sequence dim sharded. The training path of ``nlp/llama.py`` does not come
+through here: it writes the schedule out
+(``mp_layers.column_parallel_group`` / ``row_parallel_scatter``), because
+constraints alone keep every all-reduce and add a gather.
 """
 from __future__ import annotations
 
@@ -16,6 +21,7 @@ from ....nn import functional as F
 from ....nn import initializer as I
 from ....parallel import mesh as mesh_state
 from ....tensor._helpers import apply, ensure_tensor
+from ..layers.mpu.mp_layers import _mark_last
 
 __all__ = [
     "ScatterOp", "GatherOp", "AllGatherOp", "ReduceScatterOp",
@@ -25,13 +31,19 @@ __all__ = [
 ]
 
 
+def _mark_sequence(v, axis):
+    """Constrain dim 0, the sequence, to ``axis`` (``"mp"``, or None:
+    whole on every ``mp`` member) and nothing else."""
+    return mesh_state.constraint(
+        v, axis, *([mesh_state.UNCONSTRAINED] * (v.ndim - 1)))
+
+
 def _seq_shard(v):
-    spec = ["mp"] + [None] * (v.ndim - 1)
-    return mesh_state.constraint(v, *spec)
+    return _mark_sequence(v, "mp")
 
 
 def _seq_full(v):
-    return mesh_state.constraint(v, *([None] * v.ndim))
+    return _mark_sequence(v, None)
 
 
 class ScatterOp:
@@ -92,12 +104,8 @@ class ColumnSequenceParallelLinear(Layer):
         # entry: gather sequence (mp) → full activations for the matmul
         x = AllGatherOp.apply(x)
         out = F.linear(x, self.weight, self.bias)
-
-        def mark(v):
-            spec = [None] * (v.ndim - 1) + ["mp"]
-            return mesh_state.constraint(v, *spec)
-
-        return apply(mark, out, op_name="col_sp_out")
+        return apply(lambda v: _mark_last(v, "mp"), out,
+                     op_name="col_sp_out")
 
 
 class RowSequenceParallelLinear(Layer):

@@ -14,11 +14,17 @@ TPU-first design notes:
 - Sequence parallelism marks hidden states sharded over ``sep`` between
   the attention blocks; activations inside attention gather via the same
   GSPMD propagation.
+- Training's forward on a mesh with ``mp`` > 1 keeps the hidden stream, its
+  residual adds and its norms split over ``mp`` along the sequence
+  (``_stream_split``): a tensor-parallel half-layer all-gathers it into its
+  column-parallel group and reduce-scatters it out of its row-parallel
+  product (``mp_layers.column_parallel_group`` / ``row_parallel_scatter``).
 - With no mesh installed every class degrades to plain serial layers, so
   the same model file serves the single-chip and multi-chip paths.
 """
 from __future__ import annotations
 
+import contextlib
 import math
 
 from jax import named_scope
@@ -30,6 +36,9 @@ from ..nn import functional as F
 from ..nn.functional.rope import build_rope_cache, apply_rotary_emb
 from ..tensor._helpers import apply, ensure_tensor
 from ..parallel import mesh as mesh_state
+from ..distributed.fleet.layers.mpu.mp_layers import (
+    column_parallel_group, hidden_stream_axis, row_parallel_scatter,
+)
 from .paged_attention import PagedResidualLayer, normed
 
 __all__ = [
@@ -139,14 +148,60 @@ def _use_mp(config):
     return config.tensor_parallel
 
 
-def _mark_hidden(t, config):
-    """Constrain hidden states (B, S, E): batch over the data axes (dp,
-    and sharding as fsdp data axis), seq over sep when sequence-parallel."""
-    if not mesh_state.has_mesh():
-        return t
-    seq_axis = "sep" if (
+def _sep_axis(config):
+    return "sep" if (
         (config.sequence_parallel or config.context_parallel)
         and mesh_state.mesh_axis_size("sep") > 1) else None
+
+
+# The mesh axis the forward being traced splits its hidden stream's
+# sequence over, as a 1-tuple (None inside: the stream whole on every
+# member); None while no forward runs. Set by ``_stream_split`` alone.
+_STREAM = None
+
+
+def _stream_axis():
+    return _STREAM[0] if _STREAM else None
+
+
+@contextlib.contextmanager
+def _stream_split(model, seq_len, caches, head=None):
+    """The scope of ONE forward of ``model`` (a ``LlamaModel``, and the
+    ``head`` after it) over ``seq_len`` positions: its hidden stream split
+    over ``mp`` along the sequence where ``hidden_stream_axis`` allows,
+    else as it was. The outermost forward decides, once: inside
+    ``LlamaForCausalLM``'s scope ``LlamaModel``'s own is a no-op."""
+    global _STREAM
+    if _STREAM is not None:
+        yield
+        return
+    tp = [m for layer in model.layers for m in (
+        layer.self_attn.q_proj, layer.self_attn.k_proj,
+        layer.self_attn.v_proj, layer.self_attn.o_proj,
+        layer.mlp.gate_proj, layer.mlp.up_proj, layer.mlp.down_proj)]
+    if head is not None:
+        tp.append(head)
+    _STREAM = (hidden_stream_axis(
+        seq_len, tp, cached=caches is not None,
+        seq_taken=_sep_axis(model.config) is not None),)
+    try:
+        yield
+    finally:
+        _STREAM = None
+
+
+def _normed(norm, hidden):
+    """``normed`` on each member's own rows of the stream."""
+    return normed(norm, hidden, row_axis=_stream_axis())
+
+
+def _mark_hidden(t, config):
+    """Constrain hidden states (B, S, E): batch over the data axes (dp,
+    and sharding as fsdp data axis), seq over sep when sequence-parallel,
+    or over mp where the forward keeps the stream split (_stream_split)."""
+    if not mesh_state.has_mesh():
+        return t
+    seq_axis = _sep_axis(config) or _stream_axis()
 
     def fn(v):
         return mesh_state.constraint(
@@ -227,15 +282,28 @@ class LlamaAttention(Layer):
             out, cache = self._attend(q, k, v, position_offset, cache,
                                       cu_seqlens)
         out = out.reshape([b, s, self.num_heads * self.head_dim])
+        axis = _stream_axis()
         with named_scope("attn.proj"):
-            return self.o_proj(out), cache
+            return (self.o_proj(out) if axis is None else
+                    row_parallel_scatter(out, self.o_proj, axis)), cache
 
     def _rotated_qkv(self, hidden, position_offset, position_ids):
         """q, k, v (B, S, heads, D), q and k rotated at their positions."""
         b, s, _ = hidden.shape
-        q = self.q_proj(hidden).reshape([b, s, self.num_heads, self.head_dim])
-        k = self.k_proj(hidden).reshape([b, s, self.num_kv_heads, self.head_dim])
-        v = self.v_proj(hidden).reshape([b, s, self.num_kv_heads, self.head_dim])
+
+        def heads(t, n):
+            return t.reshape([b, s, n, self.head_dim])
+
+        h, hk = self.num_heads, self.num_kv_heads
+        axis = _stream_axis()
+        if axis is None:
+            q = heads(self.q_proj(hidden), h)
+            k = heads(self.k_proj(hidden), hk)
+            v = heads(self.v_proj(hidden), hk)
+        else:
+            q, k, v = map(heads, column_parallel_group(
+                hidden, (self.q_proj, self.k_proj, self.v_proj), axis),
+                (h, hk, hk))
 
         cos, sin = build_rope_cache(
             s, self.head_dim, base=self.config.rope_theta,
@@ -543,8 +611,14 @@ class LlamaMLP(Layer):
 
     def forward(self, x):
         with named_scope("mlp"):
-            return self.down_proj(
-                F.silu(self.gate_proj(x)) * self.up_proj(x))
+            axis = _stream_axis()
+            if axis is None:
+                return self.down_proj(
+                    F.silu(self.gate_proj(x)) * self.up_proj(x))
+            gate, up = column_parallel_group(
+                x, (self.gate_proj, self.up_proj), axis)
+            return row_parallel_scatter(
+                F.silu(gate) * up, self.down_proj, axis)
 
 
 class LlamaDecoderLayer(PagedResidualLayer, Layer):
@@ -575,17 +649,17 @@ class LlamaDecoderLayer(PagedResidualLayer, Layer):
             # silently freeze q/k/v/o in eager training)
             attn_out = recompute(
                 self.self_attn.forward_no_cache,
-                normed(self.input_layernorm, hidden),
+                _normed(self.input_layernorm, hidden),
                 position_offset, cu_seqlens, position_ids,
             )
         else:
             attn_out, cache = self.self_attn(
-                normed(self.input_layernorm, hidden),
+                _normed(self.input_layernorm, hidden),
                 position_offset, cache, cu_seqlens, position_ids)
         hidden = residual + attn_out
         hidden = _mark_hidden(hidden, self.config)
         hidden = hidden + self.mlp(
-            normed(self.post_attention_layernorm, hidden))
+            _normed(self.post_attention_layernorm, hidden))
         hidden = _mark_hidden(hidden, self.config)
         return hidden, cache
 
@@ -632,6 +706,11 @@ class LlamaModel(Layer):
 
     def forward(self, input_ids, position_offset=0, caches=None,
                 cu_seqlens=None):
+        with _stream_split(self, int(input_ids.shape[1]), caches):
+            return self._forward(input_ids, position_offset, caches,
+                                 cu_seqlens)
+
+    def _forward(self, input_ids, position_offset, caches, cu_seqlens):
         with named_scope("embed"):
             hidden = self.embed_tokens(input_ids)
         hidden = _mark_hidden(hidden, self.config)
@@ -668,8 +747,10 @@ class LlamaModel(Layer):
                                         cu_seqlens, position_ids)
             if new_caches is not None:
                 new_caches.append(cache_i)
+        axis = _stream_axis()
         with named_scope("head"):
-            return self.norm(hidden), new_caches
+            return (self.norm(hidden) if axis is None else
+                    self.norm(hidden, row_axis=axis)), new_caches
 
     def paged_rope(self, positions):
         """What every layer's rotary embedding needs at ``positions``
@@ -696,15 +777,25 @@ class LlamaForCausalLM(Layer):
 
     def forward(self, input_ids, position_offset=0, caches=None,
                 cu_seqlens=None):
-        hidden, new_caches = self.llama(input_ids, position_offset, caches,
-                                        cu_seqlens)
-        if self.config.fuse_linear_cross_entropy and caches is None:
-            # training-loss fusion: the lm_head matmul happens inside
-            # LlamaPretrainingCriterion's chunked fused op — returning
-            # logits here would defeat the point (full (N, V) buffers)
-            return hidden
-        with named_scope("head"):
-            logits = self.lm_head(hidden)
+        # training-loss fusion: the lm_head matmul happens inside
+        # LlamaPretrainingCriterion's chunked fused op — returning
+        # logits here would defeat the point (full (N, V) buffers)
+        fused = self.config.fuse_linear_cross_entropy and caches is None
+        with _stream_split(self.llama, int(input_ids.shape[1]), caches,
+                           None if fused else self.lm_head):
+            hidden, new_caches = self.llama(input_ids, position_offset,
+                                            caches, cu_seqlens)
+            axis = _stream_axis()
+            if not fused:
+                with named_scope("head"):
+                    # the group gathers the final norm's split output
+                    logits = self.lm_head(hidden) if axis is None else (
+                        column_parallel_group(hidden, (self.lm_head,),
+                                              axis)[0])
+        if fused:
+            # the criterion's row chunks take a split stream whole again
+            return hidden if axis is None else _mark_hidden(
+                hidden, self.config)
         if caches is not None:
             return logits, new_caches
         return logits

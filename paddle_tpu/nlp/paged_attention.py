@@ -340,11 +340,13 @@ def _xla_ring_chunk_attn(q, ring_k, ring_v, base_lens, counts, window,
         q.dtype)
 
 
-def normed(norm, hidden):
+def normed(norm, hidden, row_axis=None):
     """A layer-level norm under the profiler scope every family gives
-    it (``norm``)."""
+    it (``norm``); ``row_axis``: the mesh axis ``hidden``'s (B, S, E)
+    sequence is split over (``RMSNorm.forward``)."""
     with jax.named_scope("norm"):
-        return norm(hidden)
+        return norm(hidden) if row_axis is None else norm(
+            hidden, row_axis=row_axis)
 
 
 class PagedResidualLayer:

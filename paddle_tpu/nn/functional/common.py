@@ -11,22 +11,24 @@ from ...tensor.manipulation import pad, unfold  # re-export paddle F.pad  # noqa
 from ...core.random import next_key
 
 
+def linear_values(v, w, *maybe_b):
+    """``linear`` on jax values: half-precision products accumulate in
+    float32 and come back in ``v``'s dtype."""
+    pet = jnp.float32 if v.dtype in (jnp.bfloat16, jnp.float16) else None
+    out = jnp.matmul(v, w, preferred_element_type=pet)
+    if pet is not None:
+        out = out.astype(v.dtype)
+    if maybe_b:
+        out = out + maybe_b[0].astype(out.dtype)
+    return out
+
+
 def linear(x, weight, bias=None, name=None):
     """paddle weight layout: (in_features, out_features) — x @ W + b."""
-
-    def fn(v, w, *maybe_b):
-        pet = jnp.float32 if v.dtype in (jnp.bfloat16, jnp.float16) else None
-        out = jnp.matmul(v, w, preferred_element_type=pet)
-        if pet is not None:
-            out = out.astype(v.dtype)
-        if maybe_b:
-            out = out + maybe_b[0].astype(out.dtype)
-        return out
-
     args = [ensure_tensor(x), ensure_tensor(weight)]
     if bias is not None:
         args.append(ensure_tensor(bias))
-    return apply(fn, *args, op_name="linear")
+    return apply(linear_values, *args, op_name="linear")
 
 
 def embedding(x, weight, padding_idx=None, sparse=False, name=None):

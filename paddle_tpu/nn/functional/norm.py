@@ -43,11 +43,15 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5, name=N
     return apply(fn, *args, op_name="layer_norm")
 
 
-def rms_norm(x, weight=None, bias=None, epsilon=1e-6, begin_norm_axis=-1, name=None):
+def rms_norm(x, weight=None, bias=None, epsilon=1e-6, begin_norm_axis=-1,
+             name=None, row_axis=None):
     """RMSNorm over dims [begin_norm_axis:]; the hot path of Llama-family
     models. Routes to the Pallas kernel (normalized dims flattened to one
     feature axis) on TPU; the XLA path serves other backends and the
-    weightless / biased forms the kernel does not take."""
+    weightless / biased forms the kernel does not take. ``row_axis``: the
+    mesh axis the caller keeps dim 1 of ``x`` split over, where it does
+    (a sequence-split hidden stream): the kernel then runs on each
+    member's own rows; the XLA path is partitioned from ``x``'s layout."""
     x = ensure_tensor(x)
     from ...core.flags import get_flags
 
@@ -65,7 +69,8 @@ def rms_norm(x, weight=None, bias=None, epsilon=1e-6, begin_norm_axis=-1, name=N
             # flatten the normalized dims into one feature axis
             lead = v.shape[:axis0]
             out = _pallas_rms_norm(
-                v.reshape(*lead, -1), w.reshape(-1), epsilon)
+                v.reshape(*lead, -1), w.reshape(-1), epsilon,
+                row_axis=row_axis)
             return out.reshape(v.shape)
 
         return apply(pk, x, ensure_tensor(weight), op_name="rms_norm")

@@ -149,8 +149,9 @@ class RMSNorm(Layer):
             default_initializer=I.Constant(1.0),
         )
 
-    def forward(self, x):
-        return F.rms_norm(x, self.weight, epsilon=self._epsilon)
+    def forward(self, x, row_axis=None):
+        return F.rms_norm(x, self.weight, epsilon=self._epsilon,
+                          row_axis=row_axis)
 
 
 class GroupNorm(Layer):

@@ -49,8 +49,10 @@ def per_shard(kernel, in_specs, out_specs):
     than one device, whatever the operands' layout). So under an
     installed mesh every kernel entry point runs its kernel PER SHARD,
     split over the dims whose work is independent (batch rows over the
-    data axes, heads over ``mp``) and replicated over the rest. With no
-    mesh, or already inside a shard_map body, it is the kernel itself."""
+    data axes, heads over ``mp``, the rows of a sequence-split hidden
+    stream over the axis its owner names) and replicated over the rest.
+    With no mesh, or already inside a shard_map body, it is the kernel
+    itself."""
     mesh = mesh_state.get_mesh()
     if (mesh is None or mesh.size == 1
             or jax.sharding.get_abstract_mesh().manual_axes):

@@ -138,10 +138,15 @@ def _bwd_rule(eps, block_rows, residuals, dy):
 _rms_norm_2d.defvjp(_fwd_rule, _bwd_rule)
 
 
-def rms_norm(x, weight, epsilon=1e-6, block_rows=None):
-    """RMSNorm over the last axis; x (..., N), weight (N,)."""
-    # rows are independent: one kernel per data shard of the leading dim
+def rms_norm(x, weight, epsilon=1e-6, block_rows=None, row_axis=None):
+    """RMSNorm over the last axis; x (..., N), weight (N,). ``row_axis``:
+    the mesh axis the caller keeps dim 1 split over, if any."""
+    # rows are independent: one kernel per data shard of the leading dim,
+    # and per member of ``row_axis`` along dim 1: the transpose then has
+    # no dx to sum over that axis
     lead_spec = (_data_axes(x.shape[0]),) if x.ndim > 1 else ()
+    if row_axis is not None:
+        lead_spec += (row_axis,)
     x_spec = P(*lead_spec, *([None] * (x.ndim - len(lead_spec))))
     return _per_shard(
         functools.partial(_rms_norm_rows, epsilon=epsilon,

@@ -179,9 +179,9 @@ def test_rms_norm_dispatch_selects_pallas(monkeypatch):
     calls = {}
     real = norm_mod._pallas_rms_norm
 
-    def spy(v, w, eps):
+    def spy(v, w, eps, **kw):
         calls["hit"] = True
-        return real(v, w, eps)
+        return real(v, w, eps, **kw)
 
     monkeypatch.setattr(norm_mod, "_pallas_rms_norm", spy)
     paddle.set_flags({"FLAGS_pallas_force": True})

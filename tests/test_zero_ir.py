@@ -10,6 +10,7 @@ Since the analysis PR these invariants are asserted through
 suite run — instead of raw IR string matching, so the test and the
 production auditor cannot drift apart."""
 import functools
+import math
 
 import numpy as np
 import pytest
@@ -295,3 +296,157 @@ def test_batch_stays_split_inside_tensor_parallel_layers(mp, sharding, batch):
         assert report.cost.flops_ratio >= 0.9, report.cost.flops_ratio
     np.testing.assert_allclose(step.run_steps(ids, ids).numpy()[0], serial,
                                rtol=5e-4, atol=5e-5)
+
+
+# ------------------------- the hidden stream moves once each way over mp
+
+def _stream_collectives(hlo_text, rows, width):
+    """``{kind: count}`` of the collectives (the census's definitions) one
+    of whose results holds ``rows`` rows of the hidden stream (any shape
+    of ``rows * width`` elements whose last dim is ``width``), the
+    embedding's own excepted: its lookup's partial sums are reduced into
+    the stream and its backward gathers the stream's cotangent."""
+    found = {"all-reduce": 0, "all-gather": 0, "reduce-scatter": 0}
+    for line in hlo_text.splitlines():
+        m = collectives._DEF_RE.search(line)
+        if not m or m.group(3) == "-done" or "(embed)" in line:
+            continue
+        for _, dims in collectives._SHAPE_RE.findall(m.group(1)):
+            shape = [int(d) for d in dims.split(",") if d]
+            if shape[-1:] == [width] and math.prod(shape) == rows * width:
+                found[m.group(2)] += 1
+    return found
+
+
+def _stream_count(path):
+    from paddle_tpu.distributed.fleet.layers.mpu.mp_layers import (
+        mp_hidden_stream_programs,
+    )
+
+    return mp_hidden_stream_programs().value(path=path)
+
+
+@pytest.mark.parametrize("mp, sharding, batch, seq", [
+    pytest.param(2, 2, 4, 48, id="mp2-sharding2"),       # the cell's layout
+    pytest.param(2, 4, 4, 48, id="mp2-sharding4"),
+    pytest.param(1, 4, 4, 48, id="sharding4"),
+    pytest.param(2, 1, 4, 48, id="mp2"),
+    pytest.param(2, 2, 3, 48, id="mp2-sharding2-batch3"),
+    pytest.param(2, 2, 4, 47, id="mp2-sharding2-seq47"),  # 47 rows over 2
+])
+def test_hidden_stream_moves_once_each_way_over_mp(mp, sharding, batch, seq):
+    """Training's forward on a mesh with ``mp`` > 1 keeps the hidden
+    stream split over ``mp`` along the sequence: a tensor-parallel
+    half-layer (two a decoder layer, and the head) gathers it once and
+    reduce-scatters it once in the forward, and once each in the
+    backward; NO all-reduce carries it (the replicated stream's backward
+    all-reduced it once a column-parallel product). A length ``mp`` does
+    not divide keeps the replicated stream; without ``mp`` neither is
+    counted. Every layout computes the single-device step's loss."""
+    layers, width = 2, 64
+    serial = _serial_first_loss(batch, seq)
+    divides = batch % sharding == 0
+    step = _tiny_llama_step(mp, sharding, split_batch=divides)
+    ids = _cell_ids(batch, seq)
+    before = {p: _stream_count(p) for p in ("sequence", "replicated")}
+    report = analysis.audit(step._jitted_multi, *step._steps_args(ids, ids))
+    counted = {p: _stream_count(p) - n for p, n in before.items()}
+    split = mp > 1 and seq % mp == 0
+    assert counted == {"sequence": int(split),
+                       "replicated": int(mp > 1 and not split)}
+    assert report.remat_events == []
+    if split:
+        rows = (batch // sharding if divides else batch) * seq
+        whole = _stream_collectives(report.hlo_text, rows, width)
+        half = _stream_collectives(report.hlo_text, rows // mp, width)
+        assert whole["all-reduce"] == half["all-reduce"] == 0, (whole, half)
+        half_layers = 2 * layers + 1          # the head's group is one
+        assert 0 < whole["all-gather"] <= 2 * half_layers, whole
+        assert 0 < half["reduce-scatter"] <= 2 * half_layers, half
+        assert report.cost.flops_ratio >= 0.9, report.cost.flops_ratio
+    np.testing.assert_allclose(step.run_steps(ids, ids).numpy()[0], serial,
+                               rtol=5e-4, atol=5e-5)
+
+
+@pytest.mark.parametrize("biased, dtype", [
+    pytest.param(False, "float32", id="plain"),
+    pytest.param(True, "float32", id="biased"),
+    pytest.param(True, "bfloat16", id="bf16"),
+])
+def test_sequence_split_half_layer_gradients_match_linear(biased, dtype):
+    """``column_parallel_group`` and ``row_parallel_scatter`` on the
+    8-device mesh (mp 2 x sharding 4) against plain ``F.linear`` on the
+    same leaves: the outputs and the gradient of EVERY leaf (the input,
+    each weight, each bias). The weights are whole on the data axes
+    inside the manual regions; the program asks for the sum of each
+    one's gradient over ``sharding`` in FLOAT32 whatever the weight's
+    dtype (``_member_linear``), as the partitioner reduces
+    ``F.linear``'s: pinned on the lowered program, since the CPU
+    compiler widens every bf16 collective and would hide a bf16 one."""
+    import re
+
+    import jax.numpy as jnp
+
+    import paddle_tpu.nn.functional as F
+    from paddle_tpu.distributed.fleet.layers.mpu import mp_layers
+
+    mesh_state.set_mesh(jax.sharding.Mesh(
+        np.array(jax.devices()).reshape(1, 4, 1, 2),
+        ("dp", "sharding", "sep", "mp")))
+    paddle.seed(0)
+    group = [mp_layers.ColumnParallelLinear(16, 32, has_bias=biased,
+                                            gather_output=False),
+             mp_layers.ColumnParallelLinear(16, 8, has_bias=False,
+                                            gather_output=False),
+             mp_layers.ColumnParallelLinear(16, 8, has_bias=biased,
+                                            gather_output=True)]
+    row = mp_layers.RowParallelLinear(32, 16, has_bias=biased,
+                                      input_is_parallel=True)
+    x = paddle.randn([4, 8, 16])
+    leaves = [x] + [p for m in (*group, row) for p in (m.weight, m.bias)
+                    if p is not None]
+    for t in leaves:
+        t._value = t._value.astype(dtype)
+    x.stop_gradient = False
+
+    def forward(axis):
+        if axis:
+            a, b, c = mp_layers.column_parallel_group(x, group, axis)
+            return mp_layers.row_parallel_scatter(a, row, axis), b, c
+        a, b, c = [F.linear(x, m.weight, m.bias) for m in group]
+        return F.linear(a, row.weight, row.bias), b, c
+
+    def run(axis):
+        y, b, c = forward(axis)
+        outs = [t.numpy() for t in (y, b, c)]
+        ((y * y).sum() + (b * b).sum() + (c * c).sum()).backward()
+        grads = [t.grad.numpy().copy() for t in leaves]
+        for t in leaves:
+            t.clear_grad()
+        return [np.asarray(v, np.float32) for v in outs + grads]
+
+    tol = 1e-5 if dtype == "float32" else 4e-2    # bf16: 8 bits, twice
+    for got, want in zip(run("mp"), run(None)):
+        np.testing.assert_allclose(got, want, rtol=tol,
+                                   atol=tol * np.abs(want).max())
+
+    def loss(*values):
+        held = [t._value for t in leaves]
+        for t, v in zip(leaves, values):
+            t._value = v
+        try:
+            with paddle.no_grad():
+                return sum((t._value.astype(jnp.float32) ** 2).sum()
+                           for t in forward("mp"))
+        finally:
+            for t, v in zip(leaves, held):
+                t._value = v
+
+    weights = [i for i, t in enumerate(leaves) if t._value.ndim == 2]
+    text = jax.jit(jax.grad(loss, argnums=weights)).lower(
+        *[t._value for t in leaves]).as_text()
+    summed = re.findall(
+        r"stablehlo\.all_reduce.*?\n\s*\}\) : \(tensor<([^>]*)>\)", text, re.S)
+    assert sorted(summed) == sorted(
+        "x".join(map(str, m.weight._value.sharding.shard_shape(
+            tuple(m.weight.shape)))) + "xf32" for m in (*group, row)), summed
