@@ -22,8 +22,7 @@ from ..core import autograd
 from ..jit import functional_call
 
 __all__ = ["greedy_search", "generate_on_device", "sampling_search",
-           "beam_search", "generate", "speculative_greedy_search",
-           "speculative_generate"]
+           "beam_search", "generate", "speculative_generate"]
 
 
 def _logits_fn(model, p_vals, ids, offset_val, kc, vc):
@@ -485,15 +484,12 @@ def speculative_generate(target, draft, input_ids, max_new_tokens=32,
     (serving/speculative.py). The greedy arm emits EXACTLY the
     target's greedy decode; ``decode_strategy="sampling"`` is
     distribution-exact rejection sampling (row i seeds with
-    ``seed + i``), deterministic given seeds. This is the serving-grade
-    path that replaces the host-driven ``speculative_greedy_search``
-    (kept below as the reference/bench baseline it beat). For an
-    operated service around this loop — streaming, priorities with
-    preemption, SLO load shedding, drain — front the engine with
-    ``paddle.inference.serve()`` instead of calling this batch facade
-    (a speculative engine composes with the front door's priority /
-    preemption / shedding tier; per-request temperature needs the
-    plain quantum for now).
+    ``seed + i``), deterministic given seeds. For an operated service
+    around this loop — streaming, priorities with preemption, SLO load
+    shedding, drain — front the engine with ``paddle.inference.serve()``
+    instead of calling this batch facade (a speculative engine composes
+    with the front door's priority / preemption / shedding tier;
+    per-request temperature needs the plain quantum for now).
 
     Returns ``(tokens, acceptance_rate)``: (B, S_in+max_new) ids (rows
     finishing early at ``eos_token_id`` pad the tail with it) and the
@@ -530,103 +526,3 @@ def speculative_generate(target, draft, input_ids, max_new_tokens=32,
         out[i, :toks.shape[0]] = toks
     stats = engine.engine_stats()
     return paddle.to_tensor(out), stats["spec_acceptance_rate"]
-
-
-def speculative_greedy_search(target, draft, input_ids, max_new_tokens=32,
-                              gamma=4):
-    """Speculative decoding, greedy variant, HOST-DRIVEN (reference:
-    the speculative decode serving mode in the reference NLP stack —
-    unverified, SURVEY §0): the DRAFT model proposes ``gamma`` tokens
-    autoregressively, the TARGET verifies them in ONE forward, and the
-    longest prefix matching the target's own greedy choices is accepted
-    plus the target's correction token. Output is EXACTLY the target's
-    greedy decode — the draft only changes how many target forwards it
-    takes. Kept as the debuggable reference and the bench baseline; the
-    serving-grade one-dispatch-per-round path is
-    ``speculative_generate`` / ``ServingEngine(spec_draft=...)``.
-
-    Both models share the vocab; batch 1 (acceptance lengths are
-    per-sequence). KV caches roll back by position: rejected slots are
-    simply overwritten on the next round (valid_len masks the stale
-    tail) — which is also why sliding-window models are rejected up
-    front (a rolling buffer wrap-writes over live slots that rollback
-    cannot restore). Exactness caveat: the emitted tokens follow the
-    target's BATCHED verify forwards; a floating-point argmax tie can
-    in principle resolve differently there than in step-wise decode.
-    Returns (tokens, acceptance_rate)."""
-    import numpy as np
-    import paddle_tpu as paddle
-
-    input_ids = input_ids if isinstance(input_ids, Tensor) \
-        else paddle.to_tensor(input_ids)
-    b, s_in = input_ids.shape
-    if b != 1:
-        raise ValueError(
-            f"speculative decoding is per-sequence (batch 1), got {b}")
-    for name, m in (("target", target), ("draft", draft)):
-        if getattr(m.config, "sliding_window", None):
-            raise NotImplementedError(
-                f"speculative decoding with a sliding-window {name} is "
-                f"not supported: rollback-by-overwrite cannot restore "
-                f"rolling-buffer slots the rejected proposals wrapped "
-                f"over")
-    total = s_in + max_new_tokens + gamma + 1
-    t_caches = target.init_caches(1, total)
-    d_caches = draft.init_caches(1, total)
-
-    with autograd.no_grad():
-        t_logits, t_caches = target(input_ids, caches=t_caches)
-        _, d_caches = draft(input_ids, caches=d_caches)
-    cur = int(np.asarray(t_logits._value)[0, -1].argmax())
-
-    out = [int(x) for x in np.asarray(input_ids._value)[0]] + [cur]
-    pos = s_in
-    n = 1
-    proposed = accepted = 0
-    while n < max_new_tokens:
-        g = min(gamma, max_new_tokens - n)
-        # draft proposes g tokens from `cur`
-        props = []
-        d_cur, d_pos = cur, pos
-        with autograd.no_grad():
-            for _ in range(g):
-                dl, d_caches = draft(
-                    paddle.to_tensor(np.asarray([[d_cur]], np.int32)),
-                    caches=d_caches, position_offset=d_pos)
-                d_cur = int(np.asarray(dl._value)[0, -1].argmax())
-                props.append(d_cur)
-                d_pos += 1
-            # one target forward verifies every proposal (+ bonus slot)
-            seq = np.asarray([[cur] + props], np.int32)
-            tl, t_caches = target(paddle.to_tensor(seq),
-                                  caches=t_caches, position_offset=pos)
-        t_choice = np.asarray(tl._value)[0].argmax(-1)  # (g+1,)
-        a = 0
-        while a < g and props[a] == int(t_choice[a]):
-            a += 1
-        emit = props[:a] + [int(t_choice[a])]
-        proposed += g
-        accepted += a
-        out.extend(emit)
-        n += len(emit)
-        cur = emit[-1]
-        pos += a + 1
-        # draft cache must also hold the accepted history. Partial
-        # accept (a < g): replaying the correction token is unnecessary
-        # — the next round's first draft call writes `cur` at `pos`;
-        # slots beyond are stale and get overwritten (valid_len masks
-        # them). FULL accept (a == g): the draft proposed props[g-1]
-        # but never consumed it (the loop fed cur, props[:g-1]), and
-        # pos advances by g+1, so slot pos-1 would stay stale/zero
-        # forever and every later draft forward would attend a hole in
-        # the accepted history — run the one extra draft forward now.
-        if a == g and n < max_new_tokens:
-            with autograd.no_grad():
-                _, d_caches = draft(
-                    paddle.to_tensor(np.asarray([[props[g - 1]]],
-                                                np.int32)),
-                    caches=d_caches, position_offset=pos - 1)
-    tokens = paddle.to_tensor(
-        np.asarray([out[: s_in + max_new_tokens]], np.int32))
-    rate = accepted / max(proposed, 1)
-    return tokens, rate

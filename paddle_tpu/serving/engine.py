@@ -291,6 +291,28 @@ def layout_parts(layers):
             for part in ((kind,) if isinstance(kind, str) else kind)]
 
 
+def pool_geometry(layout, num_slots, block_size, prefill_chunk):
+    """What a model's ``paged_cache_layout()`` decides of its
+    ``PagedKVCachePool``, as that class's keywords: block arrays for the
+    parts that cache every key, a slot side for the parts that carry a
+    state or a window's ring of keys (none: an empty side, no aval; a layer
+    may name a part on each side); a ring holds the window and one chunk,
+    in whole blocks."""
+    kinds = layout_parts(layout["layers"])
+    n_state = kinds.count("state")
+    window = int(layout.get("window", 0))
+    return {
+        "num_kv_heads": layout["num_kv_heads"],
+        "head_dim": layout["head_dim"],
+        "num_layers": len(kinds) - n_state - kinds.count("none"),
+        "layout": layout["layout"],
+        "state": {"slots": num_slots, "layers": n_state,
+                  "arrays": layout["state"],
+                  "ring_tokens": ring_tokens(window, prefill_chunk,
+                                             block_size) if window else 0}
+        if n_state else None}
+
+
 def _layer_caches(model, pools, state):
     """Per layer of ``model``, the cache arrays the step hands it, by
     what ``model.paged_cache_layout()["layers"]`` says it caches: a part
@@ -791,22 +813,11 @@ class ServingEngine:
         if num_blocks is None:
             num_blocks = s * w + 1  # +1: the masked-write scratch block
         self.prefix_cache = bool(prefix_cache)
-        # block arrays for the parts that cache every key, a slot side
-        # for the parts that carry a state or a window's ring of keys
-        # (none: an empty side, no aval; a layer may name a part on each
-        # side); a ring holds the window and one chunk, in whole blocks
-        n_state = kinds.count("state")
-        n_block = len(kinds) - n_state - kinds.count("none")
         self.pool = PagedKVCachePool(
-            num_blocks, bs, layout["num_kv_heads"], layout["head_dim"],
-            num_layers=n_block, dtype=cache_dtype,
+            num_blocks, bs, dtype=cache_dtype,
             prefix_cache=self.prefix_cache, mesh=self.mesh,
-            kv_dtype=kv_dtype, layout=layout["layout"],
-            state={"slots": s, "layers": n_state,
-                   "arrays": layout["state"],
-                   "ring_tokens": ring_tokens(
-                       self._window, self.config.prefill_chunk, bs)
-                   if self._window else 0} if n_state else None)
+            kv_dtype=kv_dtype,
+            **pool_geometry(layout, s, bs, self.config.prefill_chunk))
         self.pool.commit_like(self._p_vals[0])
         # masked (retired/empty) rows dump their KV writes here
         self._scratch_block = self.pool.ensure("__scratch__", 1)[0]

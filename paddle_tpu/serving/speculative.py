@@ -3,9 +3,9 @@
 stack — unverified, SURVEY.md §0; algorithm: speculative sampling à la
 Leviathan et al. / Chen et al.).
 
-The host-driven ``speculative_greedy_search`` pays γ draft dispatches,
-one verify dispatch and a host sync per proposal round. Here the ENTIRE
-round is one jitted program batched over the serving slot dimension:
+A host-driven round would pay γ draft dispatches, one verify dispatch
+and a host sync per proposal round. Here the ENTIRE round is one jitted
+program batched over the serving slot dimension:
 
 - **draft phase**: a ``lax.scan`` of γ+1 single-token draft steps over
   the draft's own paged pool (``engine.paged_decode_math`` — the same

@@ -54,3 +54,13 @@ def chunk_programs():
 
     counter = chunk_attention_programs()
     return lambda: {p: counter.value(path=p) for p in ("xla", "kernel")}
+
+
+@pytest.fixture
+def toy(request):
+    """(configuration, model, get_leaf) of the toy of the family whose row
+    the test's module names ``ROW`` (``tests/family_harness.py`` builds it
+    once a worker)."""
+    from family_harness import toy as build
+
+    return build(request.module.ROW.name)

@@ -1,21 +1,11 @@
 """The DeepSeek-V3-shaped decoder (latent attention, routed experts beside
-shared ones) on the normal serving path, against the benchmark's plain
-reference (``benchmark/reference/deepseek_v3.py``: float32, HIGHEST,
-un-absorbed, no cache), on the toy configuration with seeded weights in
-float32.
-
-Tolerances: program and reference compute the same float32 numbers in
-another order (the program fuses gate|up, sorts rows by expert, folds
-attention tiles, absorbs the up-projection at decode), so they differ by
-summation order only: logits of magnitude ~1 agree to 2e-5. The reference's
-int8-operand control moves the same logits by ~0.1 and a served token's gap
-to ~1e-2, so each tolerance below is asserted to be tight enough that the
-control fails it.
+shared ones): what is this family's own. The contract every served family
+holds is ``tests/test_family_contract.py`` over this family's row of
+``tests/family_harness.py`` (the benchmark's seeded weights; the tolerances
+and their reasons are there). Here: the absorbed decode form, the gate, no
+token dropped at any imbalance, the latent pool with its prefix cache, and
+the latent pool's two kernels (chunk attention, PR 31; paged decode, PR 33).
 """
-import json
-import os
-import sys
-
 import numpy as np
 import pytest
 import jax
@@ -25,109 +15,16 @@ import paddle_tpu as paddle
 from paddle_tpu.incubate.distributed.models.moe import SigmoidTopKGate
 from paddle_tpu.incubate.distributed.models.moe.moe_layer import (
     grouped_expert_ffn)
-from paddle_tpu.nlp import (DeepseekV3Config, DeepseekV3ForCausalLM,
-                            LlamaConfig, LlamaForCausalLM, PagedKVCachePool)
+from paddle_tpu.nlp import DeepseekV3Config, PagedKVCachePool
 from paddle_tpu.nlp.deepseek_v3 import DeepseekV3MoE
-from paddle_tpu.obs.trace import TraceRecorder
-from paddle_tpu.serving import ServingEngine, no_shed_policy
+from paddle_tpu.serving import ServingEngine
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+from family_harness import (
+    FAMILIES, door, drain, host, llama_tiny, max_abs, prompts)
 
-from benchmark.families import deepseek_v3 as family  # noqa: E402
-
-SEED = 2147483659
-LOGIT_TOL = 2e-5     # summation order in float32, logits of magnitude ~1
-GAP_TOL = 1e-4       # a served token lies this close to the reference's best
-
-
-@pytest.fixture(scope="module")
-def toy():
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "toy-mla-moe.json")) as f:
-        cfg = json.load(f)
-    model = family.build_model(cfg)
-    family.install_weights(model, cfg, SEED)
-    model.eval()
-    return cfg, model, family.leaf_reader(cfg, SEED)
-
-
-def _serve(model, **kw):
-    kw = {"num_slots": 4, "block_size": 8, "num_blocks": 64,
-          "max_context": 96, "prefill_chunk": 16, "decode_quantum": 4, **kw}
-    return paddle.inference.serve(model, policy=no_shed_policy(), **kw)
-
-
-def _drain(door, prompts, new_tokens):
-    streams = [door.submit(p, max_new_tokens=new_tokens) for p in prompts]
-    while door.engine.has_work:
-        door.pump()
-    return [_host(s.request.tokens, np.int32) for s in streams]
-
-
-def _host(x, dtype=None):
-    """A device value on the host, said out loud (the repo's lint takes a
-    bare ``np.asarray`` / ``float`` over a jax value for an accident)."""
-    return np.asarray(jax.device_get(x), dtype)
-
-
-def _max_abs(a, b=0.0):
-    return float(np.abs(_host(a) - _host(b)).max())
-
-
-def _prompts(cfg, lengths, seed=0):
-    rng = np.random.default_rng(seed)
-    return [rng.integers(1, cfg["vocab_size"], (n,), dtype=np.int32)
-            for n in lengths]
-
-
-# ------------------------------------------------------ forward, reference
-def test_forward_matches_the_reference_logits(toy):
-    cfg, model, get_leaf = toy
-    ids = np.stack(_prompts(cfg, (40, 40)))
-    ref = family.reference.logits(cfg, get_leaf, ids)
-    got = model(paddle.to_tensor(ids))._value
-    assert _max_abs(ref) > 0.5
-    assert _max_abs(ref, got) < LOGIT_TOL
-    # the tolerance is earned: the int8-operand control fails it
-    control = family.reference.logits(cfg, get_leaf, ids, control=True)
-    assert _max_abs(ref, control) > 100 * LOGIT_TOL
-
-
-@pytest.mark.parametrize("chunk,quantum", [(16, 4), (8, 1), (32, 8)])
-def test_served_tokens_are_the_references_best(toy, chunk, quantum):
-    """Prefill in chunks, then decode, through the engine's latent pool:
-    every served token is the reference's best to within GAP_TOL, and the
-    stream is the one a whole-sequence forward would pick greedily."""
-    cfg, model, get_leaf = toy
-    prompts = _prompts(cfg, (37, 20, 9), seed=chunk)
-    door = _serve(model, prefill_chunk=chunk, decode_quantum=quantum)
-    served = _drain(door, prompts, 12)
-    rows = list(zip(prompts, served))
-    gaps, _ = family.reference.gap_below_best(cfg, get_leaf, rows)
-    assert gaps.shape == (36,) and float(_host(gaps).max()) < GAP_TOL
-    # and the logits where the test can reach them: the program's own
-    # whole-sequence forward picks the same stream
-    for p, toks in rows:
-        ids = np.concatenate([p, toks[:-1]])[None]
-        lg = model(paddle.to_tensor(ids))._value[0, len(p) - 1:]
-        assert np.array_equal(_host(jnp.argmax(lg, -1)), toks)
-    pool = door.engine.pool
-    assert pool.layout == "latent" and pool.v_pools == []
-    assert pool.k_pools[0].shape == (64, 8, 64 + 16)
-
-
-def test_the_int8_control_fails_the_gap_tolerance(toy):
-    """GAP_TOL is earned: over 160 served positions the reference with
-    int8 operands, standing in the program's place, lies further below the
-    best than any served token may."""
-    cfg, model, get_leaf = toy
-    prompts = _prompts(cfg, (24, 24, 24, 24), seed=7)
-    served = _drain(_serve(model), prompts, 40)
-    gaps, cgaps = family.reference.gap_below_best(
-        cfg, get_leaf, list(zip(prompts, served)), control=True)
-    assert float(_host(gaps).max()) < GAP_TOL < 10 * GAP_TOL < float(_host(cgaps).max())
+ROW = FAMILIES["deepseek_v3"]
+family = ROW.module
+LOGIT_TOL = ROW.logit_tol
 
 
 def test_absorbed_decode_equals_the_unabsorbed_form(toy):
@@ -140,23 +37,23 @@ def test_absorbed_decode_equals_the_unabsorbed_form(toy):
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.standard_normal((s, n, cfg["hidden_size"])),
                     jnp.float32)
-    want = attn(paddle.to_tensor(x))._value[:, -1]
+    want = jax.jit(attn)(paddle.to_tensor(x))._value[:, -1]
     pool = jnp.zeros((8, bs, 64 + 16), jnp.float32)
     tables = jnp.asarray(1 + np.arange(s * 3).reshape(s, 3), jnp.int32)
     pos = jnp.arange(n - 1)
     rope = attn.paged_rope(jnp.broadcast_to(pos, (s, n - 1)).astype(
         jnp.float32))
-    _, (pool, *_) = attn.paged_chunk(
+    _, (pool, *_) = jax.jit(attn.paged_chunk)(
         paddle.to_tensor(x[:, :-1]), rope, tables, jnp.zeros(s, jnp.int32),
         tables[jnp.arange(s)[:, None], pos[None, :] // bs],
         jnp.broadcast_to(pos % bs, (s, n - 1)), (pool, None, None, None))
     last = jnp.full((s,), n - 1)
-    got, (pool2, v, ks, vs) = attn.paged_decode(
+    got, (pool2, v, ks, vs) = jax.jit(attn.paged_decode)(
         paddle.to_tensor(x[:, -1:]), attn.paged_rope(last.astype(
             jnp.float32)), tables, last + 1, tables[:, (n - 1) // bs],
         last % bs, (pool, None, None, None))
     assert v is None and ks is None and vs is None
-    assert _max_abs(got._value[:, 0], want) < LOGIT_TOL
+    assert max_abs(got._value[:, 0], want) < LOGIT_TOL
 
 
 # ---------------------------------------------------------------- the gate
@@ -171,23 +68,23 @@ def test_gate_against_the_reference_on_hand_made_scores():
     s = jax.nn.sigmoid(logits)
     assert aux is None
     # without the bias row 0 picks experts 0, 1; with it 0, 2
-    assert sorted(_host(gate.topk_assignments(logits)[0][0])) == [0, 1]
-    assert sorted(_host(sel[0])) == [0, 2]
-    picked = np.take_along_axis(_host(s), _host(sel), 1)
+    assert sorted(host(gate.topk_assignments(logits)[0][0])) == [0, 1]
+    assert sorted(host(sel[0])) == [0, 2]
+    picked = np.take_along_axis(host(s), host(sel), 1)
     np.testing.assert_allclose(
-        _host(w), 2.448 * picked / picked.sum(1, keepdims=True),
+        host(w), 2.448 * picked / picked.sum(1, keepdims=True),
         rtol=1e-6)
-    np.testing.assert_allclose(_host(w).sum(1), 2.448, rtol=1e-6)
+    np.testing.assert_allclose(host(w).sum(1), 2.448, rtol=1e-6)
     m = {"top_k": 2, "norm_topk": True, "scaling": 2.448}
     eye = {"router_w": jnp.eye(4, dtype=jnp.float32), "router_b": bias}
     rsel, rw = family.reference.route(logits, eye, m)
-    order = np.argsort(_host(sel), 1), np.argsort(_host(rsel), 1)
+    order = np.argsort(host(sel), 1), np.argsort(host(rsel), 1)
     np.testing.assert_array_equal(
-        np.take_along_axis(_host(sel), order[0], 1),
-        np.take_along_axis(_host(rsel), order[1], 1))
+        np.take_along_axis(host(sel), order[0], 1),
+        np.take_along_axis(host(rsel), order[1], 1))
     np.testing.assert_allclose(
-        np.take_along_axis(_host(w), order[0], 1),
-        np.take_along_axis(_host(rw), order[1], 1), rtol=1e-6)
+        np.take_along_axis(host(w), order[0], 1),
+        np.take_along_axis(host(rw), order[1], 1), rtol=1e-6)
 
 
 def test_gate_refuses_groups_by_name():
@@ -210,7 +107,7 @@ def test_no_token_is_dropped_at_any_imbalance(spread):
     x = jnp.asarray(np.random.default_rng(1).standard_normal((3, 11, 64)),
                     jnp.float32)
     got = block(paddle.to_tensor(x))._value
-    rows = _host(block.rows_per_expert)
+    rows = host(block.rows_per_expert)
     assert rows.sum() == 33 * cfg.num_experts_per_tok
     if spread == "one_expert_set":
         assert list(rows) == [0, 0, 0, 0, 0, 33, 33, 33]
@@ -219,7 +116,7 @@ def test_no_token_is_dropped_at_any_imbalance(spread):
     sel = jax.lax.top_k(s + block.gate.e_score_correction_bias._value, 3)[1]
     w = jnp.take_along_axis(s, sel, 1)
     w = 2.448 * w / w.sum(1, keepdims=True)
-    sel_host = _host(sel).tolist()
+    sel_host = host(sel).tolist()
     w1, w2 = (block.experts.gate_up_proj._value,
               block.experts.down_proj._value)
     want = block.shared_experts(paddle.to_tensor(xt))._value
@@ -229,8 +126,8 @@ def test_no_token_is_dropped_at_any_imbalance(spread):
             gu = xt[t] @ w1[e]
             want = want.at[t].add(
                 w[t, j] * ((jax.nn.silu(gu[:32]) * gu[32:]) @ w2[e]))
-    np.testing.assert_allclose(_host(got.reshape(-1, 64)),
-                               _host(want), atol=2e-5)
+    np.testing.assert_allclose(host(got.reshape(-1, 64)),
+                               host(want), atol=2e-5)
 
 
 def test_grouped_core_counts_every_row():
@@ -243,10 +140,10 @@ def test_grouped_core_counts_every_row():
     w1 = jnp.asarray(rng.standard_normal((4, 4, 4)), jnp.float32)
     w2 = jnp.asarray(rng.standard_normal((4, 4, 4)), jnp.float32)
     y, rows = grouped_expert_ffn(xt, ids, w, w1, w2, jnp.tanh)
-    assert list(_host(rows)) == [1, 8, 2, 1]
+    assert list(host(rows)) == [1, 8, 2, 1]
     want = sum(jnp.einsum("tf,tfm->tm", jnp.tanh(jnp.einsum(
         "tm,tmf->tf", xt, w1[ids[:, j]])), w2[ids[:, j]]) for j in range(2))
-    np.testing.assert_allclose(_host(y), _host(want), atol=1e-5)
+    np.testing.assert_allclose(host(y), host(want), atol=1e-5)
 
 
 # ---------------------------------------------------------------- the pool
@@ -289,8 +186,8 @@ def test_latent_pool_copy_on_write_and_prefix_publication():
     fresh = pool._tables["b"][1]
     assert fresh != table[1] and pool._tables["b"][0] == table[0]
     for i in range(3):                       # the copy carries the rows
-        np.testing.assert_array_equal(_host(pool.k_pools[i][fresh]),
-                                      _host(pool.k_pools[i][table[1]]))
+        np.testing.assert_array_equal(host(pool.k_pools[i][fresh]),
+                                      host(pool.k_pools[i][table[1]]))
     st = pool.fragmentation_stats()          # raises on accounting drift
     assert st["shared_blocks"] >= 1 and st["cached_blocks"] == 2
 
@@ -300,108 +197,30 @@ def test_engine_serves_a_shared_prefix_from_the_latent_pool(toy):
     request aliases the first's blocks and both streams are what an
     unshared engine serves."""
     cfg, model, _ = toy
-    base = _prompts(cfg, (32,))[0]
-    prompts = [np.concatenate([base, t]) for t in _prompts(cfg, (5, 7), 9)]
-    plain = _drain(_serve(model), prompts, 8)
-    door = _serve(model, prefix_cache=True)
-    first = _drain(door, prompts[:1], 8)
-    second = _drain(door, prompts[1:], 8)
-    assert door.engine.pool.prefix_hits >= 4
+    base = prompts(cfg, (32,))[0]
+    rows = [np.concatenate([base, t]) for t in prompts(cfg, (5, 7), 9)]
+    plain = drain(door(ROW.name), rows, 8)
+    cached = ROW.serve(prefix_cache=True)
+    first = drain(cached, rows[:1], 8)
+    second = drain(cached, rows[1:], 8)
+    assert cached.engine.pool.prefix_hits >= 4
     for got, want in zip(first + second, plain):
         assert np.array_equal(got, want)
-
-
-# ------------------------------------------------------------ the refusals
-def _tiny():
-    paddle.seed(0)
-    return DeepseekV3ForCausalLM(DeepseekV3Config.tiny())
-
-
-@pytest.mark.parametrize("kwargs,name", [
-    ({"kv_dtype": "int8"}, "kv_dtype='int8'"),
-    ({"tp": 2}, "tp > 1"),
-    ({"spec_draft": "llama"}, "spec_draft"),
-    ({"spec_draft": "latent"}, "spec_draft"),
-    ({"sliding_window": 16}, "sliding_window"),
-])
-def test_refusals_by_name(kwargs, name):
-    """What the latent pool and the latent attention cannot do yet is
-    refused by name; nothing is silently ignored."""
-    model, kwargs = _tiny(), dict(kwargs)
-    if kwargs.get("spec_draft") == "llama":
-        kwargs["spec_draft"] = LlamaForCausalLM(
-            LlamaConfig.tiny(tensor_parallel=False))
-    elif kwargs.get("spec_draft") == "latent":
-        model, kwargs["spec_draft"] = LlamaForCausalLM(
-            LlamaConfig.tiny(tensor_parallel=False)), _tiny()
-    if "sliding_window" in kwargs:
-        with pytest.raises(NotImplementedError, match="sliding_window"):
-            DeepseekV3ForCausalLM(DeepseekV3Config.tiny(**kwargs))
-        model.config.sliding_window = kwargs.pop("sliding_window")
-    with pytest.raises(NotImplementedError) as err:
-        ServingEngine(model, num_slots=2, block_size=8, max_context=32,
-                      **kwargs)
-    assert name in str(err.value)
 
 
 @pytest.mark.parametrize("kwargs", [{"kv_dtype": "int8"}, {"mesh": True}])
 def test_latent_pool_refusals(kwargs):
     if kwargs.get("mesh"):
-        kwargs = {"mesh": jax.sharding.Mesh(_host(jax.devices()[:2]),
+        kwargs = {"mesh": jax.sharding.Mesh(host(jax.devices()[:2]),
                                             ("mp",))}
     with pytest.raises(NotImplementedError, match="latent pool"):
         _latent_pool(**kwargs)
 
 
-# ------------------------------------------------------ spans and counters
-def test_counters_spans_and_scopes(toy):
-    cfg, model, _ = toy
-    rec = TraceRecorder.process()
-    first = rec.next_id()
-    door = _serve(model)
-    _drain(door, _prompts(cfg, (20, 9)), 9)
-    eng = door.engine
-    reg = eng.obs.registry
-    rows, touched, fullest, steps = (
-        reg.get(f"serving_moe_{k}_total").value()
-        for k in ("routed_rows", "experts_touched", "expert_rows_max",
-                  "layer_steps"))
-    quanta = eng.stats["decode_quanta"]
-    # two expert layers, four steps a quantum, four slots x top 3 rows
-    assert steps == quanta * 4 * 2 and rows == steps * 4 * 3
-    assert steps <= touched <= steps * 8 and fullest * 8 >= rows
-    spans = [e for e in rec.events
-             if e.get("args", {}).get("id", -1) >= first]
-    collect = [e["args"] for e in spans if e["name"] == "engine.decode"
-               and e["args"].get("half") == "collect"]
-    assert sum(a["moe_rows"] for a in collect) == rows
-    assert sum(a["moe_experts_touched"] for a in collect) == touched
-    assert sum(a["moe_rows_max"] for a in collect) == fullest
-    assert sum(a["moe_layer_steps"] for a in collect) == steps
-    mixed = [e["args"] for e in spans if e["name"] == "engine.mixed"]
-    assert mixed and all(a["moe_rows"] == 4 * a["bucket"] * 3 * 2
-                         for a in mixed)
-    stats = eng.engine_stats()["pool"]
-    assert stats["bytes_per_token"] == 3 * (64 + 16) * 4
-    assert reg.get("serving_pool_bytes_per_token").value(
-        pool="target") == stats["bytes_per_token"]
-    # the cost ledger's 2N counts a token's top 3 of 8 experts a layer:
-    # all parameters but the embedding, less 2 layers x 5 experts x 3
-    # matrices of 128 x 64
-    n = sum(int(p._value.size) for _, p in model.named_parameters())
-    assert eng.obs.ledger.flops_per_token == 2.0 * (
-        n - 2048 * 128 - 2 * 5 * 3 * 128 * 64)
-    step, args = eng.decode_step_target()
-    text = step.lower(*args).as_text(debug_info=True)
-    for scope in ("mla", "moe.router", "moe.experts", "moe.shared"):
-        assert scope in text, scope
-
-
 def test_a_model_without_experts_returns_no_rows():
     """Llama's programs return an empty tuple where the rows would be: no
     aval, so its graphs are what they were (the goldens hold that)."""
-    paddle.seed(0)
-    model = LlamaForCausalLM(LlamaConfig.tiny(tensor_parallel=False))
+    model = llama_tiny()
     eng = ServingEngine(model, num_slots=2, block_size=8, max_context=32)
     step, args = eng.decode_step_target()
     assert jax.eval_shape(step._jitted, *args)[-1] == ()
@@ -411,15 +230,15 @@ def test_a_model_without_experts_returns_no_rows():
 
 def test_multi_quantum_carries_the_rows(toy):
     cfg, model, _ = toy
-    prompts = _prompts(cfg, (20, 9))
-    want = _drain(_serve(model), prompts, 14)
-    door = _serve(model, multi_quantum=2)
-    got = _drain(door, prompts, 14)
+    rows = prompts(cfg, (20, 9))
+    want = drain(door(ROW.name), rows, 14)
+    multi = ROW.serve(multi_quantum=2)
+    got = drain(multi, rows, 14)
     for a, b in zip(got, want):
         assert np.array_equal(a, b)
-    reg = door.engine.obs.registry
+    reg = multi.engine.obs.registry
     assert reg.get("serving_moe_layer_steps_total").value() \
-        == door.engine.stats["decode_quanta"] * 4 * 2
+        == multi.engine.stats["decode_quanta"] * 4 * 2
 
 
 # ------------------------------------- the chunk-attention kernel (PR 31)
@@ -498,62 +317,56 @@ def test_chunk_attention_kernel_matches_the_xla_loop_and_a_plain_softmax(
     scale = 1.0 / np.sqrt(_KW["dn"] + _KW["dr"])
     got = latent_chunk_attention(*args, w_kvb, scale, **kw)
     w3 = w_kvb.reshape(_KW["r"], _KW["h"], -1)
-    loop = _latent_chunk_attn(*args, w3[..., :_KW["dn"]],
-                              w3[..., _KW["dn"]:], scale)
-    plain = _plain_chunk_attention(*args, w_kvb, scale)
+    loop = jax.jit(_latent_chunk_attn, static_argnums=7)(
+        *args, w3[..., :_KW["dn"]], w3[..., _KW["dn"]:], scale)
+    plain = jax.jit(_plain_chunk_attention, static_argnums=6)(
+        *args, w_kvb, scale)
     assert got.shape == loop.shape and got.dtype == loop.dtype == dtype
-    assert np.isfinite(_host(got.astype(jnp.float32))).all()
-    assert _max_abs(plain) > 0.5
+    assert np.isfinite(host(got.astype(jnp.float32))).all()
+    assert max_abs(plain) > 0.5
     tol = 2e-5 if dtype == jnp.float32 else 4e-2
-    assert _max_abs(got.astype(jnp.float32), loop.astype(jnp.float32)) < tol
-    assert _max_abs(got.astype(jnp.float32), plain) < tol
-
-
-def _chunk_programs(path):
-    from paddle_tpu.nlp.paged_attention import chunk_attention_programs
-
-    return chunk_attention_programs().value(path=path)
+    assert max_abs(got.astype(jnp.float32), loop.astype(jnp.float32)) < tol
+    assert max_abs(got.astype(jnp.float32), plain) < tol
 
 
 def test_mixed_step_through_the_kernel_serves_the_xla_routes_tokens(
-        toy, request):
+        toy, request, chunk_programs):
     """The engine's mixed step with the kernel route forced (prompts of
     several chunks, rows of uneven length, idle slots) picks the tokens
     of the XLA route, and each engine's programs are counted under their
     own path, on the engine's registry too."""
     cfg, model, _ = toy
-    prompts = _prompts(cfg, (37, 20, 9), seed=31)
-    xla0, kernel0 = _chunk_programs("xla"), _chunk_programs("kernel")
-    want = _drain(_serve(model), prompts, 6)
-    assert _chunk_programs("xla") > xla0
-    assert _chunk_programs("kernel") == kernel0
-    xla1 = _chunk_programs("xla")
+    rows = prompts(cfg, (37, 20, 9), seed=31)
+    before = chunk_programs()
+    want = drain(ROW.serve(), rows, 6)
+    mid = chunk_programs()
+    assert mid["xla"] > before["xla"] and mid["kernel"] == before["kernel"]
     request.getfixturevalue("pallas_forced")
-    door = _serve(model)
-    got = _drain(door, prompts, 6)
+    forced = ROW.serve()
+    got = drain(forced, rows, 6)
     for a, b in zip(got, want):
         assert np.array_equal(a, b)
-    assert _chunk_programs("kernel") > kernel0
-    assert _chunk_programs("xla") == xla1
-    assert door.engine.obs.registry.get(
+    after = chunk_programs()
+    assert after["kernel"] > mid["kernel"] and after["xla"] == mid["xla"]
+    assert forced.engine.obs.registry.get(
         "serving_chunk_attention_programs_total").value(
-            path="kernel") == _chunk_programs("kernel")
+            path="kernel") == after["kernel"]
 
 
 @pytest.mark.parametrize("path", ["xla", "kernel"])
 def test_a_traced_mixed_program_counts_its_chunk_attention_path(
-        toy, path, request):
+        toy, path, request, chunk_programs):
     """Tracing one mixed program raises the counter by ONE on its path's
     label, however many layers the model has (three here)."""
-    _, model, _ = toy
     if path == "kernel":
         request.getfixturevalue("pallas_forced")
-    before = {p: _chunk_programs(p) for p in ("xla", "kernel")}
-    step, args = _serve(model).engine.mixed_step_target()
+    before = chunk_programs()
+    step, args = ROW.serve().engine.mixed_step_target()
     step.lower(*args)
     other = "xla" if path == "kernel" else "kernel"
-    assert _chunk_programs(path) == before[path] + 1
-    assert _chunk_programs(other) == before[other]
+    after = chunk_programs()
+    assert after[path] == before[path] + 1
+    assert after[other] == before[other]
 
 
 def test_a_dense_models_mixed_program_counts_the_same_counter(
@@ -561,8 +374,7 @@ def test_a_dense_models_mixed_program_counts_the_same_counter(
     """The counter is the mixed step's, not the latent pool's: since the
     K/V table has a chunk kernel too (``gqa_chunk_attention``), a dense
     model's traced program raises it on its own route, once."""
-    paddle.seed(0)
-    model = LlamaForCausalLM(LlamaConfig.tiny(tensor_parallel=False))
+    model = llama_tiny()
     eng = ServingEngine(model, num_slots=2, block_size=8, max_context=32)
     before = chunk_programs()
     step, args = eng.mixed_step_target()
@@ -617,7 +429,7 @@ def test_latent_decode_kernel_matches_the_xla_gather(widths, dtype,
         jnp.asarray(lens), scale)
     assert got.shape == want.shape == (s_, h, r)
     assert got.dtype == want.dtype == jnp.float32
-    got, want = _host(got), _host(want)
+    got, want = host(got), host(want)
     assert np.isfinite(got).all() and not got[-1].any()
     assert np.abs(want[:-1]).max() > 0.5
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
@@ -635,23 +447,23 @@ def _serve_with_a_shared_prefix_and_a_preemption(cfg, model):
     (prefix reuse, and copy-on-write where it recomputes its last token
     into a shared block) beside one that is preempted in mid-decode and
     resumed: every stream's tokens."""
-    base = _prompts(cfg, (32,), seed=5)[0]
-    prompts = [np.concatenate([base, _prompts(cfg, (5,), seed=6)[0]]), base,
-               _prompts(cfg, (21,))[0]]
-    door = _serve(model, prefix_cache=True)
-    first = door.submit(prompts[0], max_new_tokens=10)
-    while door.engine.has_work:
-        door.pump()
-    rest = [door.submit(p, max_new_tokens=10) for p in prompts[1:]]
+    base = prompts(cfg, (32,), seed=5)[0]
+    rows = [np.concatenate([base, prompts(cfg, (5,), seed=6)[0]]), base,
+            prompts(cfg, (21,))[0]]
+    cached = ROW.serve(prefix_cache=True)
+    first = cached.submit(rows[0], max_new_tokens=10)
+    while cached.engine.has_work:
+        cached.pump()
+    rest = [cached.submit(p, max_new_tokens=10) for p in rows[1:]]
     while len(rest[1].request.tokens) < 3:
-        door.pump()
-    door.engine.preempt(rest[1].request)
-    while door.engine.has_work:
-        door.pump()
-    pool = door.engine.pool
+        cached.pump()
+    cached.engine.preempt(rest[1].request)
+    while cached.engine.has_work:
+        cached.pump()
+    pool = cached.engine.pool
     assert pool.prefix_hits >= 4 and pool.cow_copies >= 1
-    assert door.engine.scheduler.preempted_total >= 1
-    return [_host(s.request.tokens, np.int32) for s in [first] + rest]
+    assert cached.engine.scheduler.preempted_total >= 1
+    return [host(s.request.tokens, np.int32) for s in [first] + rest]
 
 
 def test_engine_decodes_the_latent_pool_through_the_kernel(toy, request):
@@ -684,7 +496,7 @@ def test_a_traced_quantum_counts_its_latent_decode_path(toy, path, request):
     if path == "kernel":
         request.getfixturevalue("pallas_forced")
     before = {p: _decode_programs(p) for p in ("xla", "kernel")}
-    engine = _serve(model).engine
+    engine = ROW.serve().engine
     step, args = engine.decode_step_target()
     step.lower(*args)
     other = "xla" if path == "kernel" else "kernel"
@@ -698,8 +510,7 @@ def test_a_traced_quantum_counts_its_latent_decode_path(toy, path, request):
 
 
 def test_a_dense_models_quantum_counts_no_latent_decode_path(pallas_forced):
-    paddle.seed(0)
-    model = LlamaForCausalLM(LlamaConfig.tiny(tensor_parallel=False))
+    model = llama_tiny()
     eng = ServingEngine(model, num_slots=2, block_size=8, max_context=32)
     before = {p: _decode_programs(p) for p in ("xla", "kernel")}
     step, args = eng.decode_step_target()

@@ -15,6 +15,8 @@ from paddle_tpu.incubate.distributed.models.moe.moe_layer import (
     grouped_expert_ffn, moe_products_programs, swiglu)
 from paddle_tpu.ops.pallas import grouped_matmul as kernel
 
+from family_harness import products_counts as _counts
+
 F32, BF16 = jnp.float32, jnp.bfloat16
 
 # id -> (rows, K, N, group sizes, dtype, block_m, block_sub)
@@ -146,11 +148,6 @@ def _ffn_inputs(t=64, k=2, e=4, m=128, f=128, published=None):
     w1 = jax.random.normal(keys[3], (e, m, 2 * f), F32) * m ** -0.5
     w2 = jax.random.normal(keys[4], (e, f, m), F32) * f ** -0.5
     return xt, ids, gates, w1, w2
-
-
-def _counts():
-    counter = moe_products_programs()
-    return {p: counter.value(path=p) for p in ("kernel", "ragged_dot")}
 
 
 def _paths(fn):

@@ -242,9 +242,10 @@ def test_llama_packed_varlen_matches_per_sequence():
                    cu_seqlens=paddle.to_tensor(cu))
     packed_np = np.asarray(packed._value)
 
+    fwd = jax.jit(model)    # the oracle: one program a length (ROADMAP D7)
     for i in range(len(lens)):
         seg = ids_np[:, cu[i]:cu[i + 1]]
-        alone = np.asarray(model(paddle.to_tensor(seg))._value)
+        alone = np.asarray(fwd(paddle.to_tensor(seg))._value)
         np.testing.assert_allclose(
             packed_np[:, cu[i]:cu[i + 1]], alone, rtol=2e-4, atol=2e-4)
 
@@ -258,7 +259,7 @@ def test_llama_packed_varlen_matches_per_sequence():
         seg = ids_np[:, cu[i]:cu[i + 1]]
         if seg.shape[1] < 2:
             continue
-        out = model(paddle.to_tensor(seg))
+        out = fwd(paddle.to_tensor(seg))
         import paddle_tpu.nn.functional as F
 
         per = F.cross_entropy(

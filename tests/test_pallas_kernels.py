@@ -495,8 +495,9 @@ def test_llama_packed_sliding_window_matches_per_sequence():
         np.concatenate([[0], np.cumsum(lens)]).astype(np.int32))
     out = m(paddle.to_tensor(packed), cu_seqlens=cu).numpy()[0]
     ofs = 0
+    fwd = jax.jit(m)    # the oracle: one program a length (ROADMAP D7)
     for seg in segs:
-        alone = m(paddle.to_tensor(seg[None, :])).numpy()[0]
+        alone = fwd(paddle.to_tensor(seg[None, :])).numpy()[0]
         np.testing.assert_allclose(out[ofs:ofs + len(seg)], alone,
                                    rtol=2e-4, atol=2e-4)
         ofs += len(seg)

@@ -21,6 +21,8 @@ from paddle_tpu.profiler.reader import scope_of
 from paddle_tpu.profiler.scopes import SCOPES
 from paddle_tpu.serving import ServingEngine
 
+from family_harness import tiny_model as _model
+
 RESIDUAL_STREAM = {"add", "mul", "reshape", "broadcast_in_dim",
                    "convert_element_type"}
 
@@ -61,29 +63,6 @@ def unscoped(fn, args):
            if scope_of(stack)[0] == "unscoped"
            and not allowed_outside(p, eqn)]
     return bad, seen
-
-
-def _model(family):
-    from paddle_tpu.nlp import (afmoe, deepseek_v3, falcon_h1,
-                                granitemoehybrid, llama, nemotron_h)
-
-    paddle.seed(0)
-    model = {
-        "llama": lambda: llama.LlamaForCausalLM(
-            llama.LlamaConfig.tiny(tensor_parallel=False)),
-        "deepseek_v3": lambda: deepseek_v3.DeepseekV3ForCausalLM(
-            deepseek_v3.DeepseekV3Config.tiny()),
-        "granitemoehybrid":
-            lambda: granitemoehybrid.GraniteMoeHybridForCausalLM(
-                granitemoehybrid.GraniteMoeHybridConfig.tiny()),
-        "afmoe": lambda: afmoe.AfmoeForCausalLM(afmoe.AfmoeConfig.tiny()),
-        "nemotron_h": lambda: nemotron_h.NemotronHForCausalLM(
-            nemotron_h.NemotronHConfig.tiny(held_experts=(0, 4))),
-        "falcon_h1": lambda: falcon_h1.FalconH1ForCausalLM(
-            falcon_h1.FalconH1Config.tiny()),
-    }[family]()
-    model.eval()
-    return model
 
 
 def _train_step():

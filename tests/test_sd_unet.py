@@ -1,5 +1,6 @@
 """SD-UNet conditional diffusion (BASELINE config #5): forward shapes,
 training step, and the one-program jitted DDIM denoising loop."""
+import jax
 import numpy as np
 import pytest
 
@@ -25,7 +26,9 @@ def _build(b=2):
 def test_unet_forward_shape():
     cfg, unet, lat, ctx = _build()
     t = paddle.to_tensor(np.array([10, 500], "i4"))
-    out = unet(lat, t, ctx)
+    # one jitted program (ROADMAP D7's rule): eagerly this forward is
+    # hundreds of one-primitive compiles, 94 s of tier-1 where this is 3
+    out = jax.jit(unet)(lat, t, ctx)
     assert out.shape == list(lat.shape)
 
 
