@@ -35,6 +35,11 @@ from .falcon_h1 import (  # noqa: F401
     FalconH1Config, FalconH1Attention, FalconH1MLP, FalconH1DecoderLayer,
     FalconH1Model, FalconH1ForCausalLM,
 )
+from .solar_open2 import (  # noqa: F401
+    SolarOpen2Config, KimiDeltaAttention, SolarOpen2Attention,
+    SolarOpen2MoE, SolarOpen2DecoderLayer, SolarOpen2Model,
+    SolarOpen2ForCausalLM,
+)
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM  # noqa: F401
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForPretraining,

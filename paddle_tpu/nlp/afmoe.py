@@ -49,7 +49,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..core.tensor import Tensor
 from ..nn.layer.common import Embedding, Linear
 from ..nn.layer.layers import Layer
 from ..nn.layer.norm import RMSNorm
@@ -198,12 +197,7 @@ class AfmoeAttention(LlamaAttention):
 
     def _gated_out(self, att, gate):
         """``(concat_heads(att) * sigmoid(g)) W_o``."""
-        with jax.named_scope("attn.gate"):
-            att = att.reshape(gate.shape)
-            att = (att.astype(F32) * jax.nn.sigmoid(gate.astype(F32))
-                   ).astype(gate.dtype)
-        with jax.named_scope("attn.proj"):
-            return self.o_proj(Tensor(att, stop_gradient=True))
+        return PA.sigmoid_gated_out(self.o_proj, att, gate)
 
     def forward(self, x):
         """Causal (and, in a window layer, windowed) self-attention over

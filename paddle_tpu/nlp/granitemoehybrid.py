@@ -72,7 +72,7 @@ __all__ = ["GraniteMoeHybridConfig", "Mamba2Mixer", "GraniteMoeHybridMamba",
            "PlainAttention", "NoPositionAttention", "GraniteMoeHybridAttention",
            "GraniteMoeHybridMoE",
            "GraniteMoeHybridDecoderLayer", "GraniteMoeHybridModel",
-           "GraniteMoeHybridForCausalLM", "ssd_chunk"]
+           "GraniteMoeHybridForCausalLM", "ssd_chunk", "conv_silu"]
 
 F32 = jnp.float32
 _PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
@@ -266,6 +266,21 @@ def ssd_chunk(xs, b, c, dt, a, d, h0, keep=None):
     return out
 
 
+def conv_silu(window, weight, bias=None):
+    """The depthwise causal convolution: ``window`` (S, C + width - 1, D),
+    each position's input behind the ``width - 1`` before it, ``weight``
+    (D, width) with tap ``j`` multiplying the input ``width - 1 - j``
+    positions back -> silu(conv + bias), (S, C, D), in float32 and rounded
+    once (``nlp/solar_open2.py``'s three convolutions have no bias)."""
+    w = weight.astype(F32)
+    k = w.shape[1]
+    n = window.shape[1] - k + 1
+    acc = sum(window[:, j:j + n].astype(F32) * w[:, j] for j in range(k))
+    if bias is not None:
+        acc = acc + bias.astype(F32)
+    return jax.nn.silu(acc).astype(window.dtype)
+
+
 class _Conv1d(Layer):
     """The depthwise convolution's parameters: ``weight`` (channels,
     width), tap ``j`` multiplying the input ``width - 1 - j`` positions
@@ -339,15 +354,8 @@ class Mamba2Mixer(Layer):
             return zxd[..., :d_in], zxd[..., d_in:d_in + cd], dt
 
     def _conv(self, window):
-        """``window`` (S, C + width - 1, D): each position's input behind
-        the ``width - 1`` before it -> silu(conv + bias), (S, C, D)."""
-        w = self.conv1d.weight._value.astype(F32)
-        k = w.shape[1]
-        n = window.shape[1] - k + 1
-        acc = sum(window[:, j:j + n].astype(F32) * w[:, j]
-                  for j in range(k))
-        return jax.nn.silu(acc + self.conv1d.bias._value.astype(F32)
-                           ).astype(window.dtype)
+        return conv_silu(window, self.conv1d.weight._value,
+                         self.conv1d.bias._value)
 
     def _split(self, xbc):
         """[xs | B | C] -> xs as heads (..., H, P), B, C (..., G, N)."""
@@ -505,9 +513,9 @@ class PlainAttention(LlamaAttention):
         p = jax.nn.softmax(jnp.where(causal, logits, -1e30), axis=-1)
         out = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v,
                          preferred_element_type=F32)
-        return self.o_proj(Tensor(
+        return self._project_out(Tensor(
             out.astype(x._value.dtype).reshape(b, s, h * d),
-            stop_gradient=True))
+            stop_gradient=True), x)
 
 
 class NoPositionAttention(PlainAttention):

@@ -409,6 +409,12 @@ class LlamaAttention(Layer):
 
         return _rope_rows(x, *rope)
 
+    def _project_out(self, att, x):
+        """The attention output (..., H x D) -> the layer's output; ``x``
+        is the normed input the heads were projected from (a model that
+        gates the output by it overrides this: ``nlp/solar_open2.py``)."""
+        return self.o_proj(att)
+
     def paged_rope(self, positions):
         """What the rotary embedding needs at ``positions`` (float32, any
         shape): ``(cos, sin)`` with a trailing D/2. The engine's bodies
@@ -449,7 +455,7 @@ class LlamaAttention(Layer):
                               scale=self.softmax_scale)
         att_t = Tensor(att.reshape(s, 1, h * d), stop_gradient=True)
         with named_scope("attn.proj"):
-            return self.o_proj(att_t), new
+            return self._project_out(att_t, x), new
 
     def paged_chunk(self, x, rope, tables, base_lens, write_blk,
                     write_off, cache):
@@ -479,7 +485,7 @@ class LlamaAttention(Layer):
                                     scale=self.softmax_scale)
         att_t = Tensor(att.reshape(s, c, h * d), stop_gradient=True)
         with named_scope("attn.proj"):
-            return self.o_proj(att_t), new
+            return self._project_out(att_t, x), new
 
     def forward_no_cache(self, hidden, position_offset=0,
                          cu_seqlens=None, position_ids=None):

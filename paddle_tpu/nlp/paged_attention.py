@@ -377,6 +377,20 @@ class PagedResidualLayer:
         return self._paged(self.self_attn.paged_chunk, hidden, step, cache)
 
 
+def sigmoid_gated_out(o_proj, att, gate):
+    """``(concat_heads(att) * sigmoid(gate)) W_o``: the gate on an
+    attention's output (``nlp/afmoe.py``, ``nlp/solar_open2.py``); ``att``
+    and ``gate`` raw arrays, the gate's pre-activation (..., H x D)."""
+    from ..core.tensor import Tensor
+
+    with jax.named_scope("attn.gate"):
+        att = att.reshape(gate.shape)
+        att = (att.astype(jnp.float32)
+               * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(gate.dtype)
+    with jax.named_scope("attn.proj"):
+        return o_proj(Tensor(att, stop_gradient=True))
+
+
 def _paged_attn(q, kp, vp, tables, lens, ks=None, vs=None, scale=None):
     """Route decode attention: Pallas paged kernel on TPU (it takes the
     pool arrays as they are stored — no relayout on the way in — and

@@ -50,6 +50,14 @@ SCOPES = {
     "ssm.conv": "the causal convolution and its tail",
     "ssm.scan": "the chunked state-space sum / the one-step state update",
     "ssm.out": "the gated norm and the output product",
+    "kda.proj": "a KDA mixer's q / k / v products",
+    "kda.conv": "its three causal convolutions and their tails",
+    "kda.gates": "the two low-rank pairs' decay, beta and the l2 norms of "
+                 "q and k",
+    "kda.scan": "the delta rule: the chunked solve and sums / the one-step "
+                "state update (the kda_decode_update kernel on a TPU)",
+    "kda.out": "the per-head norm, the low-rank output gate and the "
+               "output product",
     "mix.sum": "a parallel layer's two branches (attention, state-space "
                "mixer), each times its multiplier, added to the stream",
     "head": "final norm and the vocabulary product",

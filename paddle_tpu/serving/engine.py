@@ -83,7 +83,10 @@ what a row may see decided by positions alone) beside full ones,
 ``nlp/nemotron_h.py`` layers of ONE part each: a state-space mixer, an
 attention or routed experts (``"none"``) under one norm,
 ``nlp/falcon_h1.py`` layers that run a state-space mixer AND rotary
-attention on one normed input (``("kv", "state")``). A
+attention on one normed input (``("kv", "state")``),
+``nlp/solar_open2.py`` delta-rule layers whose state part is SEVERAL
+arrays of different dtypes a layer (a float32 matrix state a head and
+three convolution tails) beside one block layer in four. A
 feed-forward that routes rows to experts shows ``rows_per_expert``; both
 programs hand back the rows the experts HELD here got beside the tokens
 (``moe_rows``; an empty tuple, no aval, without experts).

@@ -35,6 +35,19 @@ def _no_mesh_leak():
         mesh_state.set_mesh(None)
 
 
+@pytest.fixture(autouse=True)
+def _no_pallas_force_leak():
+    """``benchmark/selfcheck.py::rehearse_cell`` forces the kernel routes
+    and does not put the flag back; a later test of the same worker then
+    lowers through the interpreter's kernels (seen: the jaxpr walk of
+    ``tests/test_serving_counts.py`` meeting a DMA semaphore whenever
+    xdist ran a ``*_benchmark.py`` file before it)."""
+    yield
+    import paddle_tpu as paddle
+
+    paddle.set_flags({"FLAGS_pallas_force": False})
+
+
 @pytest.fixture
 def pallas_forced():
     """The Pallas kernel routes taken off-TPU (through the interpreter)

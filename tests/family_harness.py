@@ -804,12 +804,98 @@ def _falcon():
         expert_layers=0, offshare=False, inactive=0, counters=None)
 
 
+# ------------------------------------------------------------ solar_open2
+def _solar_leaf(cfg, short, v):
+    if short.endswith("_conv"):
+        # taps of ~0.5, as ``conv_w`` is drawn (the default would divide
+        # them by sqrt(channels))
+        return v * np.sqrt(v.shape[-2]) * 0.5
+    return v
+
+
+def _solar_pool(pool, slots, chunk):
+    assert len(pool.k_pools) == 1 == len(pool.v_pools)   # one GQA layer
+    assert tuple(pool.k_pools[0].shape) == (pool.num_blocks, 8, 2, 48)
+    # a KDA layer's slot row: the matrix state a head and three tails
+    assert [tuple(a.shape) for a in pool.state[0]] == [
+        (slots, 4, 32, 32)] + [(slots, 3, 4 * 32)] * 3
+    assert len(pool.state) == 3                          # three KDA layers
+    assert pool.state[0][0].dtype == jnp.float32
+
+
+def _solar_counters(eng, moved, collect, mixed, model):
+    eng = _built_now("solar_open2")
+    stats = eng.engine_stats()["pool"]
+    per_slot = 3 * (4 * 32 * 32 * 4 + 3 * 3 * 128 * 4)
+    assert stats["state_bytes_per_slot"] == per_slot
+    assert stats["state_slots"] == SLOTS
+    assert stats["bytes_per_token"] == 2 * 2 * 48 * 4     # one K/V layer
+    assert eng.obs.registry.get("serving_state_bytes_per_slot").value(
+        pool="target") == per_slot
+
+
+def _solar_preset(cfg):
+    assert cfg.gqa_layers == tuple(range(0, 48, 4))
+    assert (cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv) == (64, 128, 4)
+
+
+def _solar():
+    from paddle_tpu.nlp.solar_open2 import (
+        SolarOpen2Config, SolarOpen2ForCausalLM)
+
+    preset = SolarOpen2Config.solar_open2_250b
+    kda, gqa = 137_732_288, 109_051_904              # ISSUE 46's mixers
+    # a layer outside its mixer: two norms, router and bias, shared expert
+    fixed, expert = 2 * 4096 + 4096 * 320 + 320 + 15_728_640, 15_728_640
+    return Family(
+        name="solar_open2", config="toy-kda-moe.json", logit_tol=5e-5,
+        gap_tol=2e-4, magnitude=0.5, weights=_solar_leaf, overrides={},
+        engine={}, served=_SERVED, pool_shapes=_solar_pool,
+        paged=_STATE_SPACE_PLAN,
+        model=SolarOpen2ForCausalLM, tiny=SolarOpen2Config.tiny,
+        # decided by what the layers cache (a slot's matrix state), never
+        # by the model's class or a config attribute
+        refusals=_refusals("state-space", {"spec_draft_self": "self"},
+                           more=(_PREFIX,)),
+        config_refusals=(
+            ({"kda_use_full_proj": True}, "kda_use_full_proj"),
+            ({"linear_attn_config": {"short_conv_kernel_size": 4,
+                                     "head_dim": 8, "num_heads": 4,
+                                     "num_kv_heads": 2}}, "num_kv_heads"),
+            ({"use_rope": True}, "use_rope"),
+            ({"first_k_dense_replace": 1}, "first_k_dense_replace"),
+            ({"tie_word_embeddings": True}, "tied"),
+            ({"gqa_layers": [1]}, "gqa_layers"),
+            ({"gqa_layers": [0, 2]}, "gqa_layers"),
+            ({"use_gqa_gate": False}, "use_gqa_gate"),
+            ({"sliding_window": 16}, "sliding_window"),
+            ({"model_type": "kimi_linear"}, "model_type")),
+        # solar_open2_250b() is the source's config: whole it counts 250.3 B
+        # (36 KDA and 12 GQA layers, 320 experts each), and the cell's cut
+        # (one period, experts 0-39 held, an eighth of the vocabulary)
+        presets=((preset, {}, 36 * kda + 12 * gqa
+                  + 48 * (fixed + 320 * expert) + 2 * 196608 * 4096 + 4096,
+                  _solar_preset),
+                 (preset, {"num_hidden_layers": 4, "held_experts": (0, 40),
+                           "vocab_size": 24576},
+                  3 * kda + gqa + 4 * (fixed + 40 * expert)
+                  + 2 * 24576 * 4096 + 4096, None)),
+        scopes=("kda.proj", "kda.conv", "kda.gates", "kda.scan", "kda.out",
+                *_MOE_SCOPES, "attn.proj", "attn.full", "attn.gate",
+                "cache.write", "norm"),
+        expert_layers=4, offshare=True,
+        # of a layer's 4 held experts, the 3 x 4 / 8 = 1 a token multiplies
+        # on average (untied head: the embedding alone is a lookup)
+        inactive=4 * 3 * 3 * 128 * 40, counters=_solar_counters)
+
+
 FAMILIES = {row.name: row for row in (
-    _deepseek(), _granite(), _afmoe(), _nemotron(), _falcon())}
+    _deepseek(), _granite(), _afmoe(), _nemotron(), _falcon(), _solar())}
 # served toy families whose parity suite is not the contract's, and where
 # it is
 ELSEWHERE = {"llama_decoder": "tests/test_mixed_step.py: the dense decoder "
                               "is what the engine's own tests serve"}
 # the keywords of each family's tiny preset in the step-program golden and
 # the scope audit
-PRESET_KEYWORDS = {"nemotron_h": {"held_experts": (0, 4)}}
+PRESET_KEYWORDS = {"nemotron_h": {"held_experts": (0, 4)},
+                   "solar_open2": {"held_experts": (0, 4)}}

@@ -1,5 +1,5 @@
 """One scope vocabulary over every step program (ISSUE 37): each
-equation of the traced ``_mixed`` and ``_quantum`` programs of the four
+equation of the traced ``_mixed`` and ``_quantum`` programs of the
 serving families, and of the trainer's step, sits in a scope of
 ``paddle_tpu.profiler.scopes.SCOPES`` unless the short allow-list below
 takes it. Name stacks of the jaxpr: nothing is compiled.
@@ -100,6 +100,12 @@ EXPECTED = {
     "falcon_h1": {"embed", "norm", "attn.proj", "attn.full", "cache.write",
                   "ssm.in_proj", "ssm.conv", "ssm.scan", "ssm.out",
                   "mix.sum", "mlp", "head", "sample"},
+    # one gated GQA layer and three KDA mixers, experts in every layer
+    "solar_open2": {"embed", "norm", "attn.proj", "attn.full", "attn.gate",
+                    "cache.write", "kda.proj", "kda.conv", "kda.gates",
+                    "kda.scan", "kda.out", "moe.router", "moe.dispatch",
+                    "moe.products", "moe.combine", "moe.shared", "head",
+                    "sample"},
     "train": {"embed", "norm", "attn.proj", "attn.window", "mlp", "head",
               "loss", "optimizer"},
 }
@@ -107,7 +113,7 @@ EXPECTED = {
 
 @pytest.mark.parametrize("family,program", [
     (f, p) for f in ("llama", "deepseek_v3", "granitemoehybrid", "afmoe",
-                     "nemotron_h", "falcon_h1")
+                     "nemotron_h", "falcon_h1", "solar_open2")
     for p in ("mixed", "quantum")] + [("train", "step")])
 def test_every_equation_sits_in_a_scope(family, program):
     if family == "train":

@@ -743,3 +743,111 @@ def test_the_falcon_h1_cells_programs_fit_and_take_both_kernels(chip,
         assert not [line for line in text.splitlines()
                     if re.search(r"= bf16\[" + widths + "," + widths
                                  + r"\]\S* (copy|transpose)\(", line)]
+
+
+def test_the_solar_open2_cells_programs_fit_and_take_their_kernels(
+        chip, monkeypatch):
+    """``solar-open2-250b.chat1k-o256``'s REAL ``jit_quantum`` and
+    ``jit_mixed`` (the family's model from the cell's configuration, 3.31 B
+    parameters as zeros; the engine with the cell's options; one batch of
+    the cell's traffic file admitted, 96 x 1,024 as the cell is committed:
+    96 slots, 12,288 positions a mixed step; the issue's 128 made a batch
+    12.1 s, PERF.md section 6) compiled for the described v5e. Arguments
+    (the weights, one K and one V array, three slot rows of a float32 state
+    (64 x 128 x 128) and three tails) and temporaries fit the chip's
+    15.75 GiB; the quantum's delta rule is the
+    ``kda_decode_update`` kernel, one call a KDA layer, its state aliased
+    in place; no weight and no matrix state is copied whole in either
+    program. The experts' products, at width 1280 (ten whole lane tiles):
+    ``grouped_matmul`` in the mixed step (two calls a layer) and, by the
+    rule's own measurements (ISSUE 38 / 40: a width with many factors of two
+    keeps ``ragged-dot`` at a few rows a group), ``ragged-dot`` in the
+    quantum, with no expert stack re-laid out for it."""
+    import json
+
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu.ops.pallas._utils import compiled_kernel_names
+    from paddle_tpu.serving import ServingEngine
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from benchmark.families import solar_open2 as family
+    from benchmark.harness import counts_solar_open2 as counts
+    from paddle_tpu.ops.pallas import kda_decode
+
+    monkeypatch.setattr(kda_decode, "_interpret_mode", lambda: False)
+    with open(os.path.join(root, "benchmark", "configs",
+                           "solar-open2-250b-l4-ep8.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        cell = next(w for w in json.load(f)["workloads"]
+                    if w["name"] == "solar-open2-250b.chat1k-o256")
+    with open(os.path.join(root, "benchmark", "traffic",
+                           cell["traffic"] + ".json")) as f:
+        traffic = json.load(f)
+    slots = cfg["engine"]["num_slots"]
+    assert slots == traffic["batch"]
+    dtype_was = paddle.get_default_dtype()
+    paddle.set_flags({"FLAGS_pallas_force": True})
+    try:
+        model = family.build_model(cfg)     # every leaf zeros
+        model.eval()
+        eng = ServingEngine(model, **cfg["engine"])
+        for _ in range(slots):
+            eng.submit(np.ones(traffic["prompt_len"], np.int32),
+                       max_new_tokens=traffic["new_tokens"])
+        eng._admit()
+
+        def shapes(args):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype,
+                                               sharding=chip), args)
+
+        compiled = {}
+        for name, (step, args) in (("quantum", eng.decode_step_target()),
+                                   ("mixed", eng.mixed_step_target())):
+            compiled[name] = step.lower(*shapes(args)).compile()
+    finally:
+        paddle.set_flags({"FLAGS_pallas_force": False})
+        paddle.set_default_dtype(dtype_was)
+    n_params = sum(int(p._value.size) for _, p in model.named_parameters())
+    assert n_params == counts.total_params(cfg) == 3_308_353_344
+    blocks = cfg["engine"]["num_blocks"]
+    resident = (2 * n_params + blocks * 32 * counts.cache_bytes_per_token(cfg)
+                + slots * counts.state_bytes_per_slot(cfg))
+    hbm = 15.75 * 2 ** 30
+    for name, program in compiled.items():
+        mem = program.memory_analysis()
+        assert 0 <= mem.argument_size_in_bytes - resident < 1 << 20, name
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+            < hbm - (1 << 30), (name, mem.temp_size_in_bytes / 2 ** 30)
+    assert compiled["quantum"].memory_analysis().temp_size_in_bytes < 1 << 28
+    quantum, mixed = (compiled[k].as_text() for k in ("quantum", "mixed"))
+    assert {"paged_decode_attention", "kda_decode_update"} \
+        <= compiled_kernel_names(quantum)
+    assert len(re.findall(r" custom-call\(.*kda_decode_update/pallas_call",
+                          quantum)) == 3
+    assert "output_to_operand_aliasing={{1}: (1, {})}" in quantum
+    assert "gqa_chunk_attention" in compiled_kernel_names(mixed)
+    assert len(re.findall(r" custom-call\(.*grouped_matmul/pallas_call",
+                          mixed)) == 2 * 4
+    assert "ragged-dot" not in mixed
+    assert "grouped_matmul" not in compiled_kernel_names(quantum)
+    assert "ragged-dot" in quantum
+    # nothing the size of a weight or of a layer's matrix state is copied
+    # or transposed whole (the tails, 6 MB a layer, the compiler may move)
+    weights = "|".join((
+        "4096,8192", "8192,4096", "4096,1024", "4096,24576", "24576,4096",
+        "40,4096,2560", "40,1280,4096", "4096,1280", "1280,4096",
+        "4096,128", "128,8192", "4096,320", "4096,64"))
+    state = rf"f32\[{slots},64,128,128\]"
+    for text in (quantum, mixed):
+        # (rows gathered for the experts have a weight's shape, 8,192 x
+        # 4,096: `moe.dispatch` / `moe.combine` move those, not weights)
+        assert not [line for line in text.splitlines() if re.search(
+            r"= bf16\[(" + weights + r")\]\S* (copy|transpose)\(", line)
+            and "/moe." not in line]
+        assert not [line for line in text.splitlines() if re.search(
+            "= " + state + r"\S* (copy|transpose)\(", line)]
