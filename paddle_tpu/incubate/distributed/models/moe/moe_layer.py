@@ -66,12 +66,10 @@ def grouped_expert_ffn(xt, expert_ids, gate_vals, w1, w2, act, b1=None,
     beside ``use_pallas_kernels``): the Pallas kernel ``grouped_matmul``
     on a TPU where both products' widths are whole sublane tiles of the
     dtype (at most one of a product's two not whole lanes: the stack is
-    then read as the device stores it) and either the mean rows a group
-    fill its 512-row tile (prefill) or, below that (decode's few rows a
-    group, at a 16-row tile), ``ragged-dot`` would tile one of the widths
-    by a lane tile or less; ``jax.lax.ragged_dot`` elsewhere (the CPU,
-    decode at widths with many factors of two, a mesh). Same precision
-    either way, and ``ragged_dot``'s backward.
+    then read as the device stores it), in prefill and in decode alike,
+    its row tile read from the mean rows a group; ``jax.lax.ragged_dot``
+    elsewhere (the CPU, a mesh, a width the kernel cannot take). Same
+    precision either way, and ``ragged_dot``'s backward.
     Where ``act`` is :func:`swiglu` itself (no ``b1``) the kernel applies
     it as the first product's epilogue; any other callable runs after the
     plain kernel, as it does after ``ragged_dot``.
@@ -90,8 +88,7 @@ def grouped_expert_ffn(xt, expert_ids, gate_vals, w1, w2, act, b1=None,
                 // _SORTED_ROWS_BYTES)
     while t % n_tiles:      # whole tiles of positions
         n_tiles += 1
-    kernel = use_pallas_kernels() and _kernel.supports(
-        t // n_tiles * k, w1, w2)
+    kernel = use_pallas_kernels() and _kernel.supports(w1, w2)
     count_traced_program(moe_products_programs(),
                          "kernel" if kernel else "ragged_dot")
     if n_tiles > 1:

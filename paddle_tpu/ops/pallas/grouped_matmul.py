@@ -1,9 +1,9 @@
 """Grouped matrix product — the Pallas TPU kernel of the routed experts'
 two products (``incubate/.../moe/moe_layer.py::grouped_expert_ffn``, scope
-``moe.products``): in prefill, and in decode where ``ragged-dot`` tiles a
-width badly. Elsewhere, and wherever the rule of :func:`supports` says so,
-``jax.lax.ragged_dot``: the CPU path, a mesh's path, decode at widths with
-many factors of two, and the reference the parity tests compare with.
+``moe.products``), in prefill and in decode alike, wherever the rule of
+:func:`supports` says its widths allow. Elsewhere ``jax.lax.ragged_dot``:
+the CPU path, a mesh's path, a width the kernel cannot take, and the
+reference the parity tests compare with.
 
 ``grouped_matmul(lhs (rows, K), rhs (E, K, N), group_sizes (E,))``: the
 rows are sorted by group, group ``g`` owns the next ``group_sizes[g]`` of
@@ -98,13 +98,7 @@ def _tiles(rows, groups):
     return tm, min(_BLOCK_SUB, tm)
 
 
-def _ragged_dot_tile(width):
-    """What the TPU's ``ragged-dot`` tiles a width by: the largest power
-    of two that divides it (1856 -> 64, 1920 -> 128, 1792 -> 256)."""
-    return width & -width
-
-
-def _takes(rows, e, k, n, itemsize):
+def _takes(k, n, itemsize):
     """One product's part of :func:`supports`."""
     sublanes = 32 // itemsize       # rows of one tile of the dtype
     ragged = [w for w in (k, n) if w % 128]
@@ -113,31 +107,24 @@ def _takes(rows, e, k, n, itemsize):
     if ragged and round_up(k, 128) * round_up(n, 128) * itemsize \
             > _RHS_BLOCK_BYTES:
         return False    # the width padded to whole lanes, in ONE block
-    # a group fills a row tile: the MXU's product. Below that the weights'
-    # bytes bound it, and ragged-dot reads them well unless it tiles a
-    # width by one lane tile or less
-    return rows // e >= _BLOCK_M or any(
-        _ragged_dot_tile(w) <= 128 < w for w in (k, n))
+    return True
 
 
-def supports(rows, *weights):
-    """The static rule, read from the shapes of a call's ``rows`` and its
-    ``weights`` (E, K, N). The kernel can take a product whose K and N are
-    whole sublane tiles of the dtype (16 for bf16), at most one of them
-    not whole lanes, under no mesh of more than one device (a Mosaic kernel
-    cannot be partitioned, and an expert axis over the weights has a
-    schedule of its own, ``MoELayer._grouped_ep_fn``). It does take it
-    where the mean rows a group, ``rows // E``, is at least the 512-row
-    tile (prefill: the MXU bounds it), and below that (decode: the
-    weights' bytes bound it) only where ``ragged-dot`` tiles one of the
-    widths badly: its tile is the largest power of two dividing a width,
-    and at a tile of one lane tile or less (1856 -> 64, 2688 -> 128) it
-    reads 9-12 % of the bandwidth, where widths with many factors of two
-    (768 -> 256 and up) read 65-85 % and keep it."""
+def supports(*weights):
+    """The static rule, read from the shapes of a call's ``weights`` (E, K,
+    N): the kernel takes a product whose K and N are whole sublane tiles
+    of the dtype (16 for bf16), at most one of them not whole lanes (its
+    padded block within ``_RHS_BLOCK_BYTES``), under no mesh of more than
+    one device (a Mosaic kernel cannot be partitioned, and an expert axis
+    over the weights has a schedule of its own,
+    ``MoELayer._grouped_ep_fn``). The rows do not enter: :func:`_tiles`
+    fits the row tile to them, and at every mean rows a group measured,
+    from decode's 0.5 through 64, 128 and 256 to prefill's thousands, the
+    kernel read the weights faster than ``ragged-dot`` at every draw of
+    the routing (by 6 % at the least: PERF.md section 6, PR 47)."""
     mesh = mesh_state.get_mesh()
     return (mesh is None or mesh.size == 1) and all(
-        _takes(rows, *w.shape, jnp.dtype(w.dtype).itemsize)
-        for w in weights)
+        _takes(*w.shape[1:], jnp.dtype(w.dtype).itemsize) for w in weights)
 
 
 def swiglu(h):

@@ -40,8 +40,8 @@ SCOPES = {
     "moe.experts": "the routed expert block outside its three parts",
     "moe.dispatch": "sort by expert, gather of the rows, group sizes",
     "moe.products": "the routed experts' two grouped products (the "
-                    "grouped_matmul kernel on a TPU where its rule "
-                    "takes them, jax.lax.ragged_dot elsewhere) and the "
+                    "grouped_matmul kernel on a TPU wherever its widths "
+                    "allow, jax.lax.ragged_dot elsewhere) and the "
                     "activation "
                     "between them",
     "moe.combine": "un-sort, the routing weights, the sum over choices",
@@ -81,8 +81,9 @@ SPANS = (
 # the operation into calls of its own (``ragged-dot-none``,
 # ``ragged-dot-metadata``): a prefix -> the scope the operation sits in.
 # ``jax.lax.ragged_dot`` has two sites, both the routed experts' two
-# products: ``grouped_expert_ffn`` (under ``moe.products``, where the rule
-# does not take the ``grouped_matmul`` kernel, whose own rows read
-# ``<program>/grouped_matmul``) and ``MoELayer._grouped_ep_fn`` (the
-# expert-parallel schedule inside ``shard_map``).
+# products: ``grouped_expert_ffn`` (under ``moe.products``, off the TPU,
+# under a mesh and at a width the ``grouped_matmul`` kernel cannot take;
+# the kernel's own rows read ``<program>/grouped_matmul``) and
+# ``MoELayer._grouped_ep_fn`` (the expert-parallel schedule inside
+# ``shard_map``).
 COMPILER_NAMES = {"ragged-dot": "moe.products"}

@@ -1,9 +1,10 @@
 """The grouped-matmul kernel of the routed experts' two products
 (``ops/pallas/grouped_matmul.py``, ISSUE 38; a width that is not whole
-lanes and decode's 16-row tile, ISSUE 40), through the interpreter on the
-CPU: parity with ``jax.lax.ragged_dot`` (the reference it replaces),
-gradients through ``grouped_expert_ffn`` with the kernel forced, and the
-static rule that selects it with the counter that says so.
+lanes and decode's 16-row tile, ISSUE 40; every decode shape, ISSUE 47),
+through the interpreter on the CPU: parity with ``jax.lax.ragged_dot``
+(the reference it replaces), gradients through ``grouped_expert_ffn`` with
+the kernel forced, and the static rule that selects it with the counter
+that says so.
 """
 import jax
 import jax.numpy as jnp
@@ -62,6 +63,14 @@ CASES = {
                             128),
     "nemotron-prefill-p2": (1024, 1856, 2688, [300, 0, 600], BF16, 512,
                             128),
+    # the Solar-Open2 cell's decode step (ISSUE 47): 768 sorted rows of
+    # which 96 sit in groups and 672 (the choices on absent experts) past
+    # the last one; a (4096, 2560) block is over 16 MiB, so p1 runs TWO N
+    # tiles of 1280 and every visit is made once a tile
+    "solar-open2-decode-p1": (768, 4096, 2560, [0, 3, 40, 0, 5, 16, 2, 30],
+                              BF16, 16, 16),
+    "solar-open2-decode-p2": (768, 1280, 4096, [0, 3, 40, 0, 5, 16, 2, 30],
+                              BF16, 16, 16),
     # the same two forms in float32, whose sublane tile is 8 rows: the
     # tiles read from the shape (256 rows / 4 groups -> 64)
     "n-not-whole-lanes": (256, 128, 200, [30, 0, 100, 90], F32, None, None),
@@ -90,6 +99,26 @@ def test_matches_ragged_dot(case):
     # last bit the other way. (The interpreter's uninitialised VMEM is NaN:
     # a padded lane that leaked into a contraction would read NaN here.)
     assert float(gap) <= (2e-5 if dtype == F32 else 2 ** -6), float(gap)
+
+
+@pytest.mark.parametrize("case", ["solar-open2-decode-p1",
+                                  "solar-open2-decode-p2"])
+def test_a_decode_visit_over_two_n_tiles_is_ragged_dot_bit_for_bit(case):
+    """Operands of small whole numbers: every partial sum is exact in
+    float32 whatever the order a backend adds them in, so the two forms
+    must agree to the bit on every row inside a group, the N-halved first
+    product's second tile included."""
+    rows, k, n, sizes, dtype, tm, sub = CASES[case]
+    assert (kernel._block_n(k, n, 2) == n // 2) == case.endswith("p1")
+    sizes = jnp.asarray(sizes, jnp.int32)
+    ka, kb = jax.random.split(jax.random.PRNGKey(47))
+    lhs = jax.random.randint(ka, (rows, k), -2, 3).astype(dtype)
+    rhs = jax.random.randint(kb, (sizes.shape[0], k, n), -2, 3).astype(dtype)
+    got = kernel.grouped_matmul(lhs, rhs, sizes, block_m=tm, block_sub=sub)
+    want = jax.lax.ragged_dot(lhs, rhs, sizes)
+    live = int(sum(CASES[case][3]))
+    assert bool(jnp.any(want[:live] != 0))
+    assert bool(jnp.all(got[:live] == want[:live]))
 
 
 def test_the_interpreters_vmem_is_poisoned_past_a_ragged_k(monkeypatch):
@@ -273,6 +302,7 @@ WINDOW = (8, 128, 2048, 2048, 1024)
 LATENT = (6, 128, 2048, 1536, 768)
 STATE_SPACE = (10, 36, 4096, 1536, 768)
 NEMOTRON = (6, 64, 2688, 1856, 1856)
+SOLAR_OPEN2 = (8, 40, 4096, 2560, 1280)
 
 
 @pytest.mark.parametrize("shapes,positions,held,want", [
@@ -282,13 +312,23 @@ NEMOTRON = (6, 64, 2688, 1856, 1856)
     (STATE_SPACE, 8192, (0, 36), "kernel"),
     # 64 x 128 positions, 768 rows a group; 1856 is 116 sublane tiles
     (NEMOTRON, 8192, (0, 64), "kernel"),
-    # a decode step's rows: 0.5, 1.5 and 17.8 rows a group at widths
-    # ragged-dot tiles by 256 lanes or more
-    (WINDOW, 64, None, "ragged_dot"),
-    (LATENT, 32, None, "ragged_dot"),
-    (STATE_SPACE, 64, (0, 36), "ragged_dot"),
-    # 6 rows a group at widths it tiles by 64 and 128: the 16-row tile
+    # 96 x 128 positions, 2,457 rows a group; the first product's block
+    # is over 16 MiB: two N tiles
+    (SOLAR_OPEN2, 12288, (0, 40), "kernel"),
+    # a decode step's rows, 4 (0.5 at the cell's 8 slots), 1.5, 17.8, 6
+    # and 19.2 rows a group: the 16-row tile, whatever ragged-dot would
+    # tile the widths by (ISSUE 47)
+    (WINDOW, 64, None, "kernel"),
+    (WINDOW, 8, None, "kernel"),
+    (LATENT, 32, None, "kernel"),
+    (STATE_SPACE, 64, (0, 36), "kernel"),
     (NEMOTRON, 64, (0, 64), "kernel"),
+    (SOLAR_OPEN2, 96, (0, 40), "kernel"),
+    # between decode and prefill (a short mixed step): 64, 128 and 256
+    # rows a group, the row tiles of as many rows
+    (STATE_SPACE, 231, (0, 36), "kernel"),
+    (STATE_SPACE, 461, (0, 36), "kernel"),
+    (STATE_SPACE, 922, (0, 36), "kernel"),
     # a K that is not whole sublane tiles (F2 = 1000 = 62.5 x 16)
     ((8, 128, 2048, 2000, 1000), 8192, None, "ragged_dot"),
     # both widths of a product not whole lanes
@@ -296,9 +336,13 @@ NEMOTRON = (6, 64, 2688, 1856, 1856)
     # whole sublane tiles of bfloat16 are 16 rows, of float32 8
     ((6, 64, 2688, 1864, 1864), 8192, None, "ragged_dot"),
 ], ids=["window-prefill", "latent-prefill", "state-space-prefill",
-        "nemotron-prefill", "window-decode", "latent-decode",
-        "state-space-decode", "nemotron-decode", "k-not-whole-lanes",
-        "k-and-n-not-whole-lanes", "half-a-sublane-tile"])
+        "nemotron-prefill", "solar-open2-prefill", "window-decode",
+        "window-decode-8-slots", "latent-decode", "state-space-decode",
+        "nemotron-decode", "solar-open2-decode",
+        "state-space-64-rows-a-group",
+        "state-space-128-rows-a-group", "state-space-256-rows-a-group",
+        "k-not-whole-lanes", "k-and-n-not-whole-lanes",
+        "half-a-sublane-tile"])
 def test_the_rule_reads_the_shapes(pallas_forced, shapes, positions, held,
                                    want):
     k, e, m, f1, f2 = shapes
@@ -307,18 +351,17 @@ def test_the_rule_reads_the_shapes(pallas_forced, shapes, positions, held,
 
 def test_the_tiles_read_the_shapes():
     """512 / 128 where a group fills a row tile (every prefill shape of
-    the four expert cells), halved down to one 16-row sub-tile below it."""
+    the five expert cells), halved down to one 16-row sub-tile below it."""
     assert kernel._tiles(49152, 64) == (512, 128)
     assert kernel._tiles(65536, 128) == (512, 128)
     assert kernel._tiles(40960, 36) == (512, 128)
     assert kernel._tiles(384, 64) == (16, 16)      # the Nemotron decode
     assert kernel._tiles(640, 36) == (16, 16)
     assert kernel._tiles(64, 128) == (16, 16)
+    assert kernel._tiles(768, 40) == (16, 16)      # the Solar-Open2 decode
     assert kernel._tiles(6400, 36) == (128, 128)
     assert kernel._tiles(2560, 36) == (64, 64)
-    assert [kernel._ragged_dot_tile(w) for w in (1856, 1920, 1792, 2048,
-                                                 2688, 768)] \
-        == [64, 128, 256, 2048, 128, 256]
+    assert kernel._tiles(9220, 36) == (256, 128)
 
 
 def test_the_rule_keeps_ragged_dot_off_the_tpu():

@@ -232,8 +232,8 @@ def test_the_ungated_products_take_the_kernel_by_the_rule(monkeypatch):
     """``grouped_matmul.supports`` answers by shapes: a width of 1856 is
     14.5 lanes but 116 whole sublane tiles of bfloat16, so both products
     take the kernel with rows enough for its 512-row tile (prefill) AND at
-    decode's 6 rows a group (``ragged-dot`` tiles 1856 by 64 and 2688 by
-    128), and ``moe_products_programs_total`` says so; a width that is not
+    decode's 6 rows a group (the 16-row tile), and
+    ``moe_products_programs_total`` says so; a width that is not
     whole sublane tiles keeps ``ragged_dot``. No second kernel, no flag:
     the tiles come from the shapes."""
     from paddle_tpu.ops.pallas import grouped_matmul as kernel
@@ -241,12 +241,11 @@ def test_the_ungated_products_take_the_kernel_by_the_rule(monkeypatch):
     bf16 = jnp.bfloat16
     w_up = jax.ShapeDtypeStruct((64, 2688, 1856), bf16)
     w_down = jax.ShapeDtypeStruct((64, 1856, 2688), bf16)
-    assert kernel.supports(64 * 128 * 6, w_up, w_down)      # prefill
-    assert kernel._tiles(64 * 128 * 6, 64) == (512, 128)
-    assert kernel.supports(64 * 6, w_up, w_down)            # decode
-    assert kernel._tiles(64 * 6, 64) == (16, 16)
+    assert kernel.supports(w_up, w_down)
+    assert kernel._tiles(64 * 128 * 6, 64) == (512, 128)    # prefill
+    assert kernel._tiles(64 * 6, 64) == (16, 16)            # decode
     assert not kernel.supports(
-        64 * 128 * 6, jax.ShapeDtypeStruct((64, 2688, 1864), bf16),
+        jax.ShapeDtypeStruct((64, 2688, 1864), bf16),
         jax.ShapeDtypeStruct((64, 1864, 2688), bf16))
     for positions in (64 * 128, 64):
         before = products_counts()

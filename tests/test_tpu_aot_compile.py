@@ -406,8 +406,8 @@ def test_gqa_chunk_attention(chip, shapes, pallas_forced):
                                      "copy-done"))
 
 
-# a mixed step's routed rows (a tile's, in the state-space cell), experts
-# held, and the two products' (K, N)
+# a mixed step's routed rows (a tile's, in the state-space cell) or a
+# decode step's, experts held, and the two products' (K, N)
 _EXPERT_PRODUCTS = {
     "trinity_p1": (65536, 128, 2048, 2048),
     "trinity_p2": (65536, 128, 1024, 2048),
@@ -422,26 +422,45 @@ _EXPERT_PRODUCTS = {
     "nemotron_prefill_p2": (49152, 64, 1856, 2688),
     "nemotron_decode_p1": (384, 64, 2688, 1856),
     "nemotron_decode_p2": (384, 64, 1856, 2688),
+    # the four other cells' decode steps at the 16-row tile (ISSUE 47), and
+    # the points between at the state-space widths (64-, 128-, 256-row
+    # tiles); Solar-Open2's first block is over 16 MiB: two N tiles, no
+    # epilogue
+    "trinity_decode_p1": (64, 128, 2048, 2048),
+    "trinity_decode_p2": (64, 128, 1024, 2048),
+    "kanana_decode_p1": (192, 128, 2048, 1536),
+    "kanana_decode_p2": (192, 128, 768, 2048),
+    "granite_decode_p1": (640, 36, 4096, 1536),
+    "granite_decode_p2": (640, 36, 768, 4096),
+    "granite_64_rows_a_group_p1": (2310, 36, 4096, 1536),
+    "granite_128_rows_a_group_p1": (4610, 36, 4096, 1536),
+    "granite_256_rows_a_group_p1": (9220, 36, 4096, 1536),
+    "solar_prefill_p1": (98304, 40, 4096, 2560),
+    "solar_prefill_p2": (98304, 40, 1280, 4096),
+    "solar_decode_p1": (768, 40, 4096, 2560),
+    "solar_decode_p2": (768, 40, 1280, 4096),
 }
 
 
 @pytest.mark.parametrize("shapes", sorted(_EXPERT_PRODUCTS))
 def test_grouped_matmul(chip, shapes):
-    """The routed experts' products of the four expert cells compile to
-    the kernel at the tiles it reads from the shapes (the gated cells'
-    first with the SwiGLU epilogue, as they run it): two whole (K, N)
-    weight blocks, a row tile in and one out fit the VMEM limit it asks
-    for."""
+    """The routed experts' products of the five expert cells compile to
+    the kernel at the tiles it reads from the shapes (a gated cell's
+    first with the SwiGLU epilogue where gate and up share a block, as
+    the cells run it): two (K, block_n) weight blocks, a row tile in and
+    one out fit the VMEM limit it asks for."""
     import functools
 
-    from paddle_tpu.ops.pallas.grouped_matmul import grouped_matmul
+    from paddle_tpu.ops.pallas.grouped_matmul import (fuses_swiglu,
+                                                      grouped_matmul)
 
     rows, e, k, n = _EXPERT_PRODUCTS[shapes]
     bf16 = jnp.bfloat16
+    gated = shapes.endswith("p1") and not shapes.startswith("nemotron")
     assert _compiled_kernels(
-        chip, functools.partial(grouped_matmul,
-                                swiglu=shapes.endswith("p1")
-                                and not shapes.startswith("nemotron")),
+        chip, functools.partial(
+            grouped_matmul, swiglu=gated and fuses_swiglu(
+                jax.ShapeDtypeStruct((e, k, n), bf16))),
         ((rows, k), bf16), ((e, k, n), bf16),
         ((e,), jnp.int32)) == {"grouped_matmul"}
 
@@ -568,12 +587,12 @@ def test_the_window_cells_programs_move_no_ring_and_no_pool(chip,
                           mixed)) == 5
     assert not re.search(r"f32\[8,4,8,1024,\d+\]", mixed)
     # the four expert layers' two products are the grouped-matmul kernel
-    # in the mixed step (65,536 routed rows over 128 experts) and
-    # ragged_dot in the quantum (512 rows): ISSUE 38's rule
-    assert len(re.findall(r" custom-call\(.*grouped_matmul/pallas_call",
-                          mixed)) == 8
-    assert "grouped_matmul" not in compiled_kernel_names(
-        compiled["quantum"].as_text())
+    # in the mixed step (65,536 routed rows over 128 experts, 512-row
+    # tiles) AND in the quantum (64 rows, the 16-row tile: ISSUE 47)
+    for text in (mixed, compiled["quantum"].as_text()):
+        assert len(re.findall(
+            r" custom-call\(.*grouped_matmul/pallas_call", text)) == 2 * 4
+        assert "ragged-dot" not in text
 
 
 def test_the_nemotron_cells_programs_fit_and_take_both_kernels(chip,
@@ -759,10 +778,9 @@ def test_the_solar_open2_cells_programs_fit_and_take_their_kernels(
     ``kda_decode_update`` kernel, one call a KDA layer, its state aliased
     in place; no weight and no matrix state is copied whole in either
     program. The experts' products, at width 1280 (ten whole lane tiles):
-    ``grouped_matmul`` in the mixed step (two calls a layer) and, by the
-    rule's own measurements (ISSUE 38 / 40: a width with many factors of two
-    keeps ``ragged-dot`` at a few rows a group), ``ragged-dot`` in the
-    quantum, with no expert stack re-laid out for it."""
+    ``grouped_matmul`` in BOTH programs, two calls a layer (ISSUE 47: the
+    quantum's 2.4 rows a held expert at the 16-row tile, the first
+    product's 21 MB block as two N tiles), and no ``ragged-dot`` is left."""
     import json
 
     import numpy as np
@@ -831,11 +849,10 @@ def test_the_solar_open2_cells_programs_fit_and_take_their_kernels(
                           quantum)) == 3
     assert "output_to_operand_aliasing={{1}: (1, {})}" in quantum
     assert "gqa_chunk_attention" in compiled_kernel_names(mixed)
-    assert len(re.findall(r" custom-call\(.*grouped_matmul/pallas_call",
-                          mixed)) == 2 * 4
-    assert "ragged-dot" not in mixed
-    assert "grouped_matmul" not in compiled_kernel_names(quantum)
-    assert "ragged-dot" in quantum
+    for text in (quantum, mixed):
+        assert len(re.findall(
+            r" custom-call\(.*grouped_matmul/pallas_call", text)) == 2 * 4
+        assert "ragged-dot" not in text
     # nothing the size of a weight or of a layer's matrix state is copied
     # or transposed whole (the tails, 6 MB a layer, the compiler may move)
     weights = "|".join((
