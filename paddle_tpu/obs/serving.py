@@ -104,6 +104,7 @@ _LEGACY_KEYS = {
     "spec_rounds": "serving_spec_rounds_total",
     "spec_proposed": "serving_spec_proposed_total",
     "spec_accepted": "serving_spec_accepted_total",
+    "quanta_ahead": "serving_quanta_ahead_total",
 }
 _FLOAT_KEYS = ("occupancy_sum",)
 
@@ -167,6 +168,22 @@ class ServingObs:
             key: r.counter(name, f"legacy engine.stats[{key!r}]")
             for key, name in _LEGACY_KEYS.items()
         }
+        # step()'s one-deep pipeline: decode quanta dispatched before
+        # the quantum ahead of them was read back (this engine's count
+        # is the legacy key ``quanta_ahead``), and those of them whose
+        # rows had all finished by then (run done-masked, dropped). The
+        # process's registry counts both over every engine, for a
+        # reader that comes after the engine is gone
+        self._c_ahead = {
+            dropped: {id(c): c for c in (
+                reg.counter(name, text)
+                for reg in (r, MetricsRegistry.process()))}.values()
+            for dropped, name, text in (
+                (False, _LEGACY_KEYS["quanta_ahead"],
+                 "decode quanta dispatched ahead of the last collect"),
+                (True, "serving_quanta_ahead_dropped_total",
+                 "ahead decode quanta dropped: every row had finished "
+                 "in the quantum before"))}
         self._c_mixed_programs = r.counter(
             "serving_mixed_programs_total",
             "mixed-step programs built, by chunk-length bucket")
@@ -716,6 +733,14 @@ class ServingObs:
         if self.tracer is not None:
             self.tracer.counter("tokens_per_s", t1,
                                 {"window": self._g_rate.value()})
+
+    def on_quantum_ahead(self, dropped=False):
+        """One decode quantum dispatched before the one ahead of it was
+        collected; ``dropped``: collected with every row finished, its
+        tokens no one's. Counted whether or not the rich hooks are on,
+        as the legacy stats are."""
+        for c in self._c_ahead[dropped]:
+            c.inc()
 
     def on_spec_round(self, now, proposed, accepted):
         if not self.enabled or proposed <= 0:

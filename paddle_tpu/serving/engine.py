@@ -17,7 +17,10 @@ shape of that loop:
   eos/max-len retirement masks computed ON DEVICE and the pool buffers
   donated (audited by the ``serving_decode_step`` analysis Budget: zero
   involuntary remat, zero host callbacks, pools donated). The host
-  scheduler runs only at quantum boundaries.
+  scheduler runs only at quantum boundaries, and in steady decode
+  BESIDE the device: ``step()`` keeps one quantum in flight, the next
+  enqueued on the last one's device-resident carry before the last is
+  read back.
 - **chunked prefill interleaved with decode**: new arrivals push their
   prompt through the pool in ``prefill_chunk``-token slices, sharing
   MIXED batches with the in-flight slots' decode rows — admission never
@@ -873,6 +876,21 @@ class ServingEngine:
         self._temps = (np.ones(s, np.float32)
                        if decode_strategy == "sampling"
                        and spec_draft is None else None)
+        # what of that state is ON the device between two quanta: the
+        # last dispatched quantum's own outputs (seq_lens, last_tok,
+        # n_gen, done), which are the next one's arguments while the
+        # four mirrors still read what they read when host and device
+        # last agreed (``_carry_seen``: at an upload, at a collect); and,
+        # by name, the last upload of each mirror the device never
+        # writes (tables, max_new, keys, temps), kept while it is equal
+        self._carry = None
+        self._carry_seen = None
+        self._kept = {}
+        # step()'s one-deep pipeline: the quantum dispatched AHEAD of the
+        # last collect, and where the last collected quantum ended (an
+        # ahead quantum's wall starts there, not at its dispatch)
+        self._inflight = None
+        self._decode_end = 0.0
         # front-door streaming hook: called (req, token) for EVERY
         # token appended to a request's stream, at the same host
         # boundary obs.on_token fires on
@@ -940,6 +958,14 @@ class ServingEngine:
 
             self._rep_sharding = NamedSharding(self.mesh,
                                                PartitionSpec())
+        # where a quantum's small outputs live: committed to the pools'
+        # device beside committed weights (a jitted step's outputs are
+        # committed when any input is), else wherever JAX puts them; a
+        # carry mirror is uploaded THERE, so the quantum has one
+        # executable whichever of the two its arguments come from
+        leaf = jax.tree_util.tree_leaves(self.pool.arrays())[0]
+        self._carry_sharding = (leaf.sharding if self.mesh is None
+                                and leaf.committed else self._rep_sharding)
         # build-time collective census (tp > 1 only): lower + compile
         # the quantum ONCE under the mesh, census the post-GSPMD module
         # for the obs gauges, and KEEP the compiled executable as the
@@ -1112,6 +1138,12 @@ class ServingEngine:
             raise ValueError(
                 f"request {req.req_id} is not live — only an admitted, "
                 f"unfinished request can be preempted")
+        # a quantum step() left in flight holds this row: its tokens are
+        # the request's (and may finish it: then there is nothing left
+        # to evict, its blocks are free already)
+        self._drain()
+        if req.finished:
+            return req
         slot = req.slot
         now = self._now()
         cached = int(self._seq_lens[slot])
@@ -1139,6 +1171,20 @@ class ServingEngine:
         """One scheduler iteration: admit, then either a mixed
         prefill(+decode) step or a jitted decode quantum, then retire.
 
+        In steady decode the iteration is PIPELINED one quantum deep:
+        with quantum *n* dispatched, the step dispatches *n+1* on the
+        device-resident carry of *n* BEFORE it collects *n*
+        (``_runs_ahead`` says when: nothing the tokens of *n* could
+        change decides what *n+1* is), and leaves *n+1* in flight for
+        the next call, which collects it behind *n+2*. The host's half
+        of a pump (admission, the pool walk, table growth, the uploads,
+        the token loop) then runs beside the device and not between two
+        programs. A call still delivers ONE quantum's tokens; streams,
+        finish reasons and retire order are the serial pump's
+        (``step_collect(step_dispatch())``, which the cluster pump
+        drives and which keeps nothing in flight). Whatever changes slot
+        state collects the quantum in flight first (``_drain``).
+
         With ``resilience=`` the step is also the FAULT BOUNDARY: pool
         accounting is audited first (drift rebuilds the allocator from
         the live block tables instead of killing the engine), and an
@@ -1146,22 +1192,95 @@ class ServingEngine:
         the retry budget is contained here — a poison request is
         isolated by batch bisect and finished with
         ``finish_reason="error"``; a transient fault skips the step
-        (nothing was dispatched, so the next step simply retries)."""
-        return self.step_collect(self.step_dispatch())
+        (nothing was dispatched, so the next step simply retries). Such
+        an engine never runs ahead."""
+        pending, self._inflight = self._inflight, None
+        if pending is None:
+            pending = self._step_dispatch()
+        if self._runs_ahead(pending):
+            self._inflight = self._step_dispatch(ahead=True)
+        self.step_collect(pending)
+        self._drop_finished_ahead()
+        return self.scheduler.has_work
+
+    def _runs_ahead(self, pending):
+        """May the quantum AFTER ``pending`` (dispatched, not collected)
+        be dispatched before ``pending`` is read back? Only where the
+        host can prove, from what it holds now, that the next step is a
+        decode quantum of the same rows whatever tokens come back:
+        nothing waits and no slot prefills (``steady_state``, the
+        predicate ``_choose_k`` uses), a row cannot reach its
+        ``max_new_tokens`` within ``pending`` (so a batch's last quantum
+        is followed by none), the host has changed no carry since host
+        and device last agreed (a stop rule or a closed stream that
+        finished a row the device still runs must reach the device
+        first), and no seam needs the host between two quanta: an armed
+        fault injector, a watchdog, a bisect probe or its ``include=``,
+        a speculative draft, a dispatch of several quanta."""
+        if (pending is None or pending["k"] != 1 or pending["excluded"]
+                or self._mq_quantum is not None
+                or self.spec_draft is not None or self._isolating
+                or self.watchdog is not None or self.faults.armed):
+            return False
+        return (self.scheduler.steady_state() and self._carry_in_step()
+                and any(self._outlives(r)
+                        for r in self.scheduler.decoding()))
+
+    def _outlives(self, req):
+        """Is ``req`` still decoding after the quantum that takes the
+        mirrors as they are, whatever it emits short of a stop token?"""
+        return (self._n_gen[req.slot] + self.config.decode_quantum
+                < self._max_new[req.slot])
+
+    def _drain(self):
+        """Collect the quantum ``step()`` left in flight, if any: what
+        touches a mirror or the pool's tables outside ``step()`` calls
+        this first."""
+        pending, self._inflight = self._inflight, None
+        if pending is not None:
+            self.step_collect(pending)
+
+    def _drop_finished_ahead(self):
+        """An ahead quantum whose rows ALL finished in the quantum
+        before it (a stop token each) runs done-masked from end to end:
+        its record is dropped here, in the same step and counted, so
+        that none outlives ``has_work``. Nothing waits for it: the pools
+        it hands on are adopted, whatever comes next queues behind it on
+        the device, and the host goes on meanwhile. Its collect row of
+        ``engine.decode`` is a mark (``dropped=1``); no histogram,
+        ledger or token count sees it."""
+        ahead = self._inflight
+        if ahead is None or not all(r.finished for r in ahead["rows"]):
+            return
+        self._inflight = None
+        with RecordEvent("engine.decode", step_kind="decode",
+                         step=ahead["step"], half="collect", dropped=1):
+            self.obs.on_quantum_ahead(dropped=True)
 
     def step_dispatch(self):
-        """DISPATCH HALF of :meth:`step` — admit, then enqueue the
+        """DISPATCH HALF of a step — admit, then enqueue the
         decode quantum WITHOUT forcing its results, returning an opaque
         pending record for :meth:`step_collect` (or ``None`` when the
         step completed synchronously: mixed prefill steps, speculative
         rounds, fault-contained steps, and idle engines). JAX dispatch
         is async, so between the two halves the device executes while
         the host is free to run OTHER work — the cluster front door
-        dispatches every replica before collecting any, and a single
-        engine's ``step()`` is exactly ``step_collect(step_dispatch())``
-        (same ordering, same fault boundaries, bit-identical streams).
+        dispatches every replica before collecting any. Driven as
+        ``step_collect(step_dispatch())`` the halves are the SERIAL
+        pump: one dispatch, one collect, nothing in flight across them
+        (a quantum :meth:`step` left in flight is collected first);
+        :meth:`step` runs the same two halves one quantum apart in
+        steady decode (same fault boundaries, bit-identical streams).
         Each half is its own ``engine.step`` span (``half=dispatch`` |
         ``collect``), so none stays open across another engine's work."""
+        self._drain()
+        return self._step_dispatch()
+
+    def _step_dispatch(self, ahead=False):
+        """:meth:`step_dispatch`'s body. ``ahead``: a quantum is in
+        flight and this is the step after it, dispatched early; the
+        mirrors are one quantum behind, so what is counted live is what
+        outlives that quantum."""
         with RecordEvent("engine.step", half="dispatch"):
             self.stats["steps"] += 1
             if self.resilience is not None:
@@ -1173,6 +1292,8 @@ class ServingEngine:
                 with RecordEvent("engine.admit"):
                     self._admit()
                 live = self.scheduler.live()
+                if ahead:
+                    live = [r for r in live if self._outlives(r)]
                 self.stats["occupancy_sum"] += (
                     len(live) / self.config.num_slots)
                 self.obs.on_step(self._now(), len(live),
@@ -1181,7 +1302,7 @@ class ServingEngine:
                 if self.scheduler.prefilling():
                     self._mixed_step()
                 elif self.scheduler.decoding():
-                    pending = self._decode_dispatch()
+                    pending = self._decode_dispatch(ahead=ahead)
             except InjectedFault as e:
                 self._contain_fault(e)
             finally:
@@ -1193,7 +1314,7 @@ class ServingEngine:
             return pending
 
     def step_collect(self, pending):
-        """COLLECT HALF of :meth:`step`: force the pending dispatch's
+        """COLLECT HALF of a step: force the pending dispatch's
         results, emit/account/retire, and close the step's fault
         boundary. ``pending=None`` (the step already completed in
         :meth:`step_dispatch`) just reports whether work remains."""
@@ -1291,6 +1412,7 @@ class ServingEngine:
         with the engine's live state as the example batch. A
         spec-disabled engine hands out the plain fallback quantum (the
         degraded-mode golden test fingerprints exactly this)."""
+        self._drain()
         if self._spec_disabled:
             return self._plain_audited, self._quantum_args()
         return self._audited, self._quantum_args()
@@ -1305,6 +1427,7 @@ class ServingEngine:
             raise ValueError(
                 "engine built without multi_quantum>1 (or with "
                 "spec_draft): no multi-quantum variant to audit")
+        self._drain()
         return self._mq_audited, self._quantum_args()
 
     def health(self, now=None):
@@ -1817,6 +1940,7 @@ class ServingEngine:
         the EXACT jitted program ``_mixed_step`` dispatches, with the
         rows the scheduler holds now as the example batch (the
         ``serving_mixed_step`` recipe fingerprints this)."""
+        self._drain()
         args, _, _, _ = self._mixed_args(
             self.scheduler.prefilling(), self.scheduler.decoding(), False)
         return self._mixed, args
@@ -2108,9 +2232,58 @@ class ServingEngine:
             return v
         return jax.device_put(v, self._rep_sharding)
 
+    def _dev_kept(self, name, mirror):
+        """Device view of a mirror the device never writes (the tables,
+        ``max_new``, the keys, the temperatures): the last upload while
+        the mirror still equals it, else a new one. What is uploaded is
+        a COPY no one writes: a jitted dispatch returns before it has
+        run, ``jnp.asarray`` aliases a numpy buffer on the CPU and an
+        accelerator reads it until the transfer is done, and with a
+        quantum in flight the host writes the mirrors (``_tables[slot]``
+        for the next one) while a program handed the last upload may
+        still run."""
+        kept = self._kept.get(name)
+        if kept is None or not np.array_equal(kept[0], mirror):
+            host = mirror.copy()
+            kept = self._kept[name] = (host, self._dev(host))
+        return kept[1]
+
+    def _carry_in_step(self):
+        """Do the four carry mirrors read what they read when host and
+        device last agreed? Then the last dispatched quantum's outputs
+        are the next one's arguments; else the host has changed one
+        (admission, a mixed step's token, retire, preempt, a bisect
+        mask) and the mirrors go up again."""
+        return self._carry is not None and all(
+            np.array_equal(m, seen) for m, seen in zip(
+                (self._seq_lens, self._last_tok, self._n_gen, self._done),
+                self._carry_seen))
+
+    def _carry_args(self):
+        """``seq_lens, last_tok, n_gen, done`` as the next quantum takes
+        them: the device-resident outputs of the last one where
+        ``_carry_in_step``; else copies of the mirrors (see
+        ``_dev_kept``), put where those outputs live so that the one
+        executable takes either."""
+        if not self._carry_in_step():
+            self._carry_seen = tuple(m.copy() for m in (
+                self._seq_lens, self._last_tok, self._n_gen, self._done))
+            self._carry = tuple(map(self._put_carry, self._carry_seen))
+        return self._carry
+
+    def _put_carry(self, a):
+        """``a`` where a quantum's small outputs live (see
+        ``_carry_sharding``): a mirror's copy on its way up, or, under
+        tp, an output put replicated as the executable takes it (no
+        copy where it lies so already)."""
+        if self._carry_sharding is None:
+            return jnp.asarray(a)
+        return jax.device_put(a, self._carry_sharding)
+
     def _quantum_args(self):
         """The quantum's argument tuple; its uploads (the ``_dev``
-        calls) are the span ``engine.decode.args``."""
+        calls: in steady decode none but a grown table) are the span
+        ``engine.decode.args``."""
         with RecordEvent("engine.decode.args"):
             # the scale tuples ride right after their pool's v_pools and the
             # slot side after them (empty on a float engine without state
@@ -2132,12 +2305,13 @@ class ServingEngine:
                         self._dev(self._n_gen), self._dev(self._done),
                         self._dev(self._max_new),
                         self._dev(self._keys))
-            return (*self.pool.arrays(),
-                    self._p_vals, self._dev(self._tables),
-                    self._dev(self._seq_lens),
-                    self._dev(self._last_tok), self._dev(self._n_gen),
-                    self._dev(self._done), self._dev(self._max_new),
-                    self._dev(self._keys), *self._temps_arg())
+            return (*self.pool.arrays(), self._p_vals,
+                    self._dev_kept("tables", self._tables),
+                    *self._carry_args(),
+                    self._dev_kept("max_new", self._max_new),
+                    self._dev_kept("keys", self._keys),
+                    *(() if self._temps is None
+                      else (self._dev_kept("temps", self._temps),)))
 
     def _dispatch_quantum(self, quanta=1):
         """Run ONE quantum dispatch. Single chip: the jitted callable,
@@ -2262,26 +2436,52 @@ class ServingEngine:
         return self._mq_max
 
     def _decode_quantum(self, include=None):
-        """Dispatch + collect one decode step SYNCHRONOUSLY — the
-        single-engine path and the bisect probe. The overlap tier
-        (cluster pump, `step_dispatch`/`step_collect`) drives the two
-        halves separately instead."""
+        """Dispatch + collect one decode step SYNCHRONOUSLY: the bisect
+        probe. ``step()`` runs the two halves one quantum apart in
+        steady decode, and the overlap tier (cluster pump,
+        `step_dispatch`/`step_collect`) drives them separately."""
         pending = self._decode_dispatch(include=include)
         if pending is not None:
             self._decode_collect(pending)
 
-    def _decode_dispatch(self, include=None):
+    def _grow_tables(self, rows, ahead, tokens):
+        """The span ``engine.decode.prepare``: grow each row's block
+        table to cover the dispatch (``tokens`` more than it holds)
+        before the device loop is entered (tables are static inside);
+        capped by the request's own prompt+max_new bound, which
+        admission already reserved, so growth can never oversubscribe
+        the pool. ``ahead``: a quantum is in flight, the length mirrors
+        are its arguments and the rows hold ``decode_quantum`` more by
+        the time this dispatch runs. The mirror's row is REPLACED, never
+        written through: the program in flight was handed the last
+        upload's copy (``_dev_kept``)."""
+        with RecordEvent("engine.decode.prepare"):
+            for req in rows:
+                slot = req.slot
+                have = int(self._seq_lens[slot])
+                need = min(have + ahead * self.config.decode_quantum
+                           + tokens,
+                           req.prompt_len + req.max_new_tokens - 1)
+                self._tables[slot] = self.pool.grow_decode_table(
+                    req.req_id, need, have, pad_to=self._table_width,
+                    cow=self.prefix_cache)[:self._table_width]
+
+    def _decode_dispatch(self, include=None, ahead=False):
         """DISPATCH HALF of the decode step: grow block tables, enqueue
         the jitted quantum (K quanta when `_choose_k` allows), adopt
         the async donated pool outputs, and return a pending record for
         `_decode_collect` — WITHOUT forcing a host sync, so the device
-        executes while the host moves on (the overlap the cluster pump
-        exploits). ``include`` restricts the quantum to a subset of the
-        decoding rows (the bisect-quarantine probe path): excluded rows
-        ride along done-masked — inert through the dispatch — and
-        their host state is restored at collect. A speculative round
-        (host needs its acceptance counts to proceed) runs to
-        completion here and returns None.
+        executes while the host moves on (the overlap ``step()`` and the
+        cluster pump exploit). ``include`` restricts the quantum to a
+        subset of the decoding rows (the bisect-quarantine probe path):
+        excluded rows ride along done-masked — inert through the
+        dispatch — and their host state is restored at collect.
+        ``ahead`` (``step()`` alone, where ``_runs_ahead``): the quantum
+        before this one is still in flight; this one takes that one's
+        outputs where they lie on the device, its rows are those that
+        outlive it by their lengths, and its row says ``ahead=1``. A
+        speculative round (host needs its acceptance counts to proceed)
+        runs to completion here and returns None.
 
         The frames from ``door.pump`` down to the jitted call take the
         words of data stack they took before the spans (the quantum's
@@ -2293,11 +2493,10 @@ class ServingEngine:
         if self.spec_draft is not None and not self._spec_disabled:
             self._spec_round_step(include=include)
             return None
-        with RecordEvent("engine.decode", step_kind="decode",
-                         step=self.stats["steps"],
+        step, t_steps = self.stats["steps"], self.config.decode_quantum
+        with RecordEvent("engine.decode", step_kind="decode", step=step,
                          half="dispatch") as span:
-            t_steps = self.config.decode_quantum
-            k = 1 if include is not None else self._choose_k()
+            k = 1 if include is not None or ahead else self._choose_k()
             rows = self.scheduler.decoding()
             excluded = []
             if include is not None:
@@ -2306,24 +2505,13 @@ class ServingEngine:
                 rows = [r for r in rows if id(r) in keep]
                 for r in excluded:
                     self._done[r.slot] = True
+            if ahead:
+                rows = [r for r in rows if self._outlives(r)]
+                span.args["ahead"] = 1
+                self.obs.on_quantum_ahead()
             span.args.update(rows=len(rows), k=k)
             try:
-                # grow each live slot's block table to cover the whole
-                # dispatch (K quanta) before entering the device loop
-                # (tables static inside); capped by the request's own
-                # prompt+max_new bound, which admission already reserved
-                # — K-wide growth can never oversubscribe the pool
-                with RecordEvent("engine.decode.prepare"):
-                    for req in rows:
-                        slot = req.slot
-                        cap = req.prompt_len + req.max_new_tokens - 1
-                        need = min(
-                            int(self._seq_lens[slot]) + k * t_steps, cap)
-                        row = self.pool.grow_decode_table(
-                            req.req_id, need, int(self._seq_lens[slot]),
-                            pad_to=self._table_width,
-                            cow=self.prefix_cache)
-                        self._tables[slot] = row[:self._table_width]
+                self._grow_tables(rows, ahead, k * t_steps)
                 # the jitted call until it returns (its uploads, the
                 # span engine.decode.args, lie inside)
                 with RecordEvent("engine.decode.enqueue") as enqueue:
@@ -2336,15 +2524,21 @@ class ServingEngine:
             # adopt the donated pool outputs NOW (async handles — no
             # sync): the pre-dispatch buffers were consumed by donation
             # (the slot side leads ``out``: no name of its own, see the
-            # docstring's note on this frame's words)
+            # docstring's note on this frame's words); the four carries
+            # stay where they are for the next quantum (under tp, put
+            # replicated as the executable takes them)
             self.pool.adopt(kc, vc, ks, vs, out.pop(0))
+            self._carry = tuple(
+                out[:4] if self._rep_sharding is None
+                else (self._put_carry(c) for c in out[:4]))
             # out: seq_lens, last_tok, n_gen, done, toks, the experts'
             # rows (() without experts), and the count of quanta that
             # ran where the dispatch was of several. The
             # device's share of the wall starts where the call returned:
             # the enqueue span's end
             return {"rows": rows, "excluded": excluded, "t0": span.t0,
-                    "t_disp": enqueue.t1, "k": k,
+                    "t_disp": enqueue.t1, "k": k, "ahead": ahead,
+                    "step": step,
                     "out": (*out, None) if k == 1 else tuple(out)}
 
     def _decode_collect(self, pending):
@@ -2354,22 +2548,40 @@ class ServingEngine:
         ``n_exec`` quanta that actually ran (obs histograms, cost
         ledger, host-gap gauge — each sub-quantum gets an equal slice
         of the wall, so the conservation invariants partition exactly),
-        and retire finished rows."""
+        and retire finished rows. A quantum dispatched AHEAD ran behind
+        the one before it: its wall, and the device's share of it,
+        start where that one ended (its sync span's end), not at its
+        own dispatch, so no second is counted twice; and the rows that
+        one finished are not rows of this one (they rode done-masked)."""
         with RecordEvent("engine.decode", step_kind="decode",
-                         step=self.stats["steps"],
-                         half="collect") as step:
+                         step=pending["step"], half="collect") as step:
             rows, excluded = pending["rows"], pending["excluded"]
-            t0, k = pending["t0"], pending["k"]
+            if pending["ahead"]:
+                rows = [r for r in rows if not r.finished]
+            k = pending["k"]
+            t0 = max(pending["t0"], self._decode_end)
             seq_lens, last_tok, n_gen, done, toks, moe, nq = \
                 pending["out"]
             t_steps = self.config.decode_quantum
             with RecordEvent("engine.decode.sync") as sync:
                 toks = np.asarray(toks)                      # sync
                 before = self._seq_lens
-                self._seq_lens = np.asarray(seq_lens).copy()
-                self._last_tok = np.asarray(last_tok).copy()
-                self._n_gen = np.asarray(n_gen).copy()
-                self._done = np.asarray(done).copy()
+                # host and device agree here: on these values the next
+                # quantum ran (or will run) where it takes this one's
+                # outputs as they lie; the mirrors are copies, and what
+                # the host writes to them from here on (below: a slot
+                # freed meanwhile) is its own change
+                self._carry_seen = tuple(
+                    np.asarray(a) for a in (seq_lens, last_tok, n_gen, done))
+                (self._seq_lens, self._last_tok, self._n_gen,
+                 self._done) = (a.copy() for a in self._carry_seen)
+                if pending["ahead"]:
+                    # a row the host finished while this quantum was in
+                    # flight (a stop rule, a closed stream) ran on: its
+                    # slot is free, the device does not know
+                    for slot, req in enumerate(self.scheduler.slots):
+                        if req is None or req.finished:
+                            self._done[slot] = True
                 if not isinstance(moe, tuple):
                     # (T, layers, experts), or (K, T, ...) of which the
                     # quanta that ran: per expert layer and decode step
@@ -2386,7 +2598,9 @@ class ServingEngine:
             # the device's share of the wall: from the jitted call's return
             # (the enqueue span's end) to the sync span's end
             now = sync.t1
-            device_s = max(now - pending["t_disp"], 0.0)
+            device_s = max(
+                now - max(pending["t_disp"], self._decode_end), 0.0)
+            self._decode_end = now
             with RecordEvent("engine.decode.emit"):
                 for r in excluded:
                     # a masked row's device state carried through unchanged;

@@ -353,7 +353,11 @@ class ServingFrontDoor:
         """One front-door iteration: preemption policy, then one engine
         scheduler step (admit -> mixed prefill | decode quantum ->
         retire), then the finished-stream reap. Returns True while work
-        remains. One ``door.pump`` span."""
+        remains. One ``door.pump`` span. In steady decode the engine's
+        ``step()`` keeps one quantum in flight: a pump then delivers the
+        tokens of the quantum the pump before it dispatched, and
+        dispatches the next before it reads that one back (a preemption
+        collects the quantum in flight first)."""
         with RecordEvent("door.pump"):
             self._apply_preemption()
             alive = self.engine.step()
@@ -366,12 +370,13 @@ class ServingFrontDoor:
         the opaque pending record for :meth:`pump_collect`. The cluster
         front door drives every replica's dispatch half before any
         collect half, so no replica's host work serializes on another
-        replica's device wall; ``pump()`` is equivalent to
-        ``pump_collect(pump_dispatch())`` (it goes through
-        ``engine.step()`` — the composition of the same two halves — so
-        wrappers around ``step`` still see every pump). Driven apart,
-        each half is its own ``door.pump`` row (``half=dispatch`` |
-        ``collect``)."""
+        replica's device wall. ``pump_collect(pump_dispatch())`` is the
+        SERIAL pump: one dispatch, one collect, nothing in flight across
+        them. ``pump()`` gives the same streams in the same order through
+        ``engine.step()``, which runs the same two halves one quantum
+        apart in steady decode (wrappers around ``step`` still see every
+        pump). Driven apart, each half is its own ``door.pump`` row
+        (``half=dispatch`` | ``collect``)."""
         with RecordEvent("door.pump", half="dispatch"):
             self._apply_preemption()
             return self.engine.step_dispatch()

@@ -36,6 +36,7 @@ SHARED_READERS = {
     "cache_bytes_per_token", "serve_device_idle_pct", "serve_hbm_peak_gib",
     "queue_wait_ms", "mixed_forward_ms", "mixed_trace_lower_ms",
     "quantum_host_ms", "quantum_args_ms", "compiles_in_decode",
+    "quanta_ahead_pct",
     "mixed_host_ms"}
 
 

@@ -38,6 +38,7 @@ SHARED_READERS = {
     "cache_bytes_per_token", "serve_device_idle_pct", "serve_hbm_peak_gib",
     "queue_wait_ms", "mixed_forward_ms", "mixed_trace_lower_ms",
     "quantum_host_ms", "quantum_args_ms", "compiles_in_decode",
+    "quanta_ahead_pct",
     "mixed_host_ms"}
 
 
@@ -229,7 +230,7 @@ def test_the_cell_and_its_files(run, real, cfg):
     later = {w["name"] for w in real["workloads"][8:]}
     joined = [m for m in real["per_layer"] + real["end_to_end"]
               if CELL in m.get("workloads", ())]
-    assert len(joined) == 2 + 17 + 4
+    assert len(joined) == 2 + 17 + 4 + 1    # PR 48: quanta_ahead_pct
     assert all(set(m["workloads"][m["workloads"].index(CELL) + 1:]) <= later
                for m in joined)
     new = [m for m in real["per_layer"] if m["name"].startswith("falconh1_")]

@@ -96,6 +96,11 @@ def arm_prefill_and_decode_rows():
         engine.step()
     engine.step()                      # one quantum: the row decodes
     reqs.append(engine.submit(second, max_new_tokens=6))
+    # the arrival finds the next quantum in flight behind that one
+    # (steady decode runs one ahead): this step collects it
+    assert engine._inflight is not None
+    engine.step()
+    assert engine._inflight is None and engine.scheduler.waiting
     before = len(reqs[0].tokens)
     mark = TraceRecorder.process().next_id()
     engine.step()                      # chunk 1 of 3 + one decode row

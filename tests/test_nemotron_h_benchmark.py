@@ -41,6 +41,7 @@ SHARED_READERS = {
     "cache_bytes_per_token", "serve_device_idle_pct", "serve_hbm_peak_gib",
     "queue_wait_ms", "mixed_forward_ms", "mixed_trace_lower_ms",
     "quantum_host_ms", "quantum_args_ms", "compiles_in_decode",
+    "quanta_ahead_pct",
     "mixed_host_ms"}
 
 
@@ -252,7 +253,7 @@ def test_the_cell_and_its_files(run, real, cfg):
     later = {w["name"] for w in real["workloads"][7:]}
     joined = [m for m in real["per_layer"] + real["end_to_end"]
               if CELL in m.get("workloads", ())]
-    assert len(joined) == 2 + 17 + 5
+    assert len(joined) == 2 + 17 + 5 + 1    # PR 48: quanta_ahead_pct
     assert all(set(m["workloads"][m["workloads"].index(CELL) + 1:]) <= later
                for m in joined)
     assert [m["name"] for m in real["per_layer"]][-5:] == [

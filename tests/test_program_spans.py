@@ -5,7 +5,8 @@ to the step that caused them.
 A toy engine behind a front door and a toy train step, on the CPU. What
 is held here: the rows nest as ``PERF.md`` section 3 says, children never
 sum past their parent, there is one step row per step made (two halves
-for a step that is dispatched and collected), a profiler session sees
+for a step that is dispatched and collected; in steady decode ``step()``
+dispatches the next quantum before it collects the last), a profiler session sees
 the same spans on its own clock, JAX's compile events land on the span
 its caller marked as the step, the buffer is bounded, threads keep their
 own stacks, no name can be mistaken for one of the benchmark's own rows,
@@ -71,7 +72,10 @@ PARENT_TOKENS = [[81, 73, 112, 112], [32, 21, 34],
 PARENT_STATS = {"steps": 10, "mixed_steps": 6, "decode_quanta": 4,
                 "quantum_tokens": 24, "prefill_tokens": 28,
                 "generated_tokens": 20, "occupancy_sum": 9.0,
-                "spec_rounds": 0, "spec_proposed": 0, "spec_accepted": 0}
+                "spec_rounds": 0, "spec_proposed": 0, "spec_accepted": 0,
+                # ISSUE 48: how often step() ran a quantum ahead; every
+                # other count is the serial pump's
+                "quanta_ahead": 1}
 
 
 def rows_since(mark):
@@ -186,9 +190,17 @@ def test_one_step_row_per_step_made(run):
     names = [r["name"] for r in run["rows"]]
     stats = run["door"].engine.stats
     assert names.count("engine.mixed") == stats["mixed_steps"]
-    # a quantum is dispatched, then collected: a row for each half
-    assert halves(run["rows"], "engine.decode") \
-        == ["dispatch", "collect"] * stats["decode_quanta"]
+    # a quantum is dispatched, then collected: a row for each half; in
+    # steady decode the next is dispatched before the last is collected,
+    # never more than that one
+    got = halves(run["rows"], "engine.decode")
+    assert got.count("dispatch") == got.count("collect") \
+        == stats["decode_quanta"]
+    in_flight = [got[:i + 1].count("dispatch") - got[:i + 1].count("collect")
+                 for i in range(len(got))]
+    assert set(in_flight) == {0, 1, 2} and in_flight[-1] == 0
+    assert sum(bool(r["args"].get("ahead")) for r in run["rows"]
+               if r["name"] == "engine.decode") == stats["quanta_ahead"]
     # a step whose dispatch half left nothing pending has no other half
     assert halves(run["rows"], "engine.step").count("dispatch") \
         == stats["steps"]
@@ -291,23 +303,104 @@ def test_mixed_host_ms_is_the_pump_less_the_forward(run, monkeypatch):
     assert mixed_host_ms.read(obs) is None
 
 
+def decode_quanta(rows):
+    """A run's ``engine.decode`` rows as (dispatch half, collect half)
+    pairs: the halves of one quantum carry one ``step``."""
+    decode = [r for r in rows if r["name"] == "engine.decode"]
+    by_step = {}
+    for r in decode:
+        by_step.setdefault(r["args"]["step"], {})[r["args"]["half"]] = r
+    assert all(set(h) == {"dispatch", "collect"} for h in by_step.values())
+    assert len(decode) == 2 * len(by_step)
+    return [(h["dispatch"], h["collect"])
+            for _, h in sorted(by_step.items())]
+
+
 def test_the_halves_of_a_quantum_pair_by_their_step(run):
-    """``step()`` is ``step_collect(step_dispatch())``: a quantum's
-    dispatch half is followed by its collect half under the same pump,
-    both carry the step's number, and the device's wait (the sync span)
-    is in the second."""
+    """A quantum's two halves carry its step's number, the dispatch half
+    ends before the collect half begins, and the device's wait (the sync
+    span) is in the second. ``step()`` runs the halves ONE QUANTUM APART
+    in steady decode: a quantum marked ``ahead=1`` was dispatched under
+    the pump before the one that collects it, before the quantum ahead of
+    it was collected (D, D, C, D, C, ..., C); every other quantum's halves
+    lie under one pump, as the halves driven apart always do
+    (``test_halves_are_rows_of_their_own``)."""
     by_id = {r["args"]["id"]: r for r in run["rows"]}
-    decode = [r for r in run["rows"] if r["name"] == "engine.decode"]
-    assert decode
-    for first, second in zip(decode[::2], decode[1::2]):
-        assert first["args"]["step"] == second["args"]["step"]
+    pump_of = lambda r: by_id[by_id[r["args"]["parent"]]["args"]["parent"]]
+    end = lambda r: r["ts"] + r["dur"]
+    quanta = decode_quanta(run["rows"])
+    assert quanta
+    ahead = 0
+    for i, (first, second) in enumerate(quanta):
         assert first["args"]["rows"] >= 1 and first["args"]["k"] == 1
-        assert first["ts"] + first["dur"] <= second["ts"] + 1e-3
-        pumps = [by_id[by_id[h["args"]["parent"]]["args"]["parent"]]
-                 for h in (first, second)]
-        assert pumps[0] is pumps[1] and pumps[0]["name"] == "door.pump"
-    steps = [r["args"]["step"] for r in decode[::2]]
-    assert steps == sorted(set(steps))
+        assert end(first) <= second["ts"] + 1e-3
+        pumps = [pump_of(first), pump_of(second)]
+        assert all(p["name"] == "door.pump" for p in pumps)
+        if first["args"].get("ahead"):
+            ahead += 1
+            before = quanta[i - 1]
+            # behind the quantum before it: after that one's dispatch,
+            # before its collect, under its collect's pump
+            assert end(before[0]) <= first["ts"] + 1e-3
+            assert end(first) <= before[1]["ts"] + 1e-3
+            assert pumps[0] is pump_of(before[1])
+            assert pumps[0] is not pumps[1]
+        else:
+            assert pumps[0] is pumps[1]
+    assert ahead == run["door"].engine.stats["quanta_ahead"] == 1
+    # collects come in the order of their dispatches
+    assert [end(c) for _, c in quanta] == sorted(end(c) for _, c in quanta)
+
+
+def test_the_benchmarks_readers_read_a_pipelined_run(monkeypatch):
+    """ISSUE 48: a closed batch in steady decode (nothing waits: every
+    quantum but the first is dispatched ahead) through the benchmark's
+    own ``window_steps`` and the readers of its ``engine.decode`` rows. A
+    collect half joins the dispatch half BEFORE it, so a step is (the
+    next quantum's dispatch, this one's collect) and the batch's last
+    collect is left out: each reader still returns a number."""
+    monkeypatch.syspath_prepend(ROOT)
+    from benchmark.harness import program_spans
+    from benchmark.metrics import (compiles_in_decode, quanta_ahead_pct,
+                                   quantum_args_ms, quantum_host_ms)
+
+    cfg, door = build_door()
+    rng = np.random.RandomState(5)
+
+    def batch():
+        streams = [door.submit(rng.randint(1, cfg.vocab_size, 6)
+                               .astype(np.int32), max_new_tokens=32)
+                   for _ in range(2)]
+        door.run_until_idle()
+        return streams
+
+    batch()                                       # warm: compiles
+    m0 = mark()
+    stats0 = dict(door.engine.stats)
+    assert all(len(s.request.tokens) == 32 for s in batch())
+    stats = {k: v - stats0[k] for k, v in door.engine.stats.items()}
+    rows = program_spans.from_events(rows_since(m0))
+    obs = {"engine_steps": {"mixed_steps": stats["mixed_steps"],
+                            "decode_quanta": stats["decode_quanta"]}}
+    _, steps = program_spans.window_steps(obs, rows)
+    # 31 tokens after the prefill's, 3 a quantum: 11 quanta, 10 ahead
+    assert stats["decode_quanta"] == 11 and stats["quanta_ahead"] == 10
+    assert len(steps["decode"]) == 11
+    assert [len(s) for s in steps["decode"]] == [1] + [2] * 10
+    assert all(s[0]["args"]["half"] == "dispatch" for s in steps["decode"])
+    monkeypatch.setattr(program_spans, "rows", lambda: rows)
+    assert quantum_host_ms.read(obs) > 0
+    assert quantum_args_ms.read(obs) > 0
+    assert compiles_in_decode.read(obs) == 0
+    assert quanta_ahead_pct.read(obs) == pytest.approx(100 * 10 / 11)
+    assert quanta_ahead_pct.read(obs) >= 80
+    # a program without the mark (the parent) reads 0, a window without
+    # decode steps nothing
+    for r in rows:
+        r["args"].pop("ahead", None)
+    assert quanta_ahead_pct.read(obs) == 0.0
+    monkeypatch.setattr(program_spans, "rows", lambda: [])
+    assert quanta_ahead_pct.read(obs) is None
 
 
 def test_queue_wait_is_the_histogram_sample(run):
@@ -330,33 +423,35 @@ def test_spans_change_no_behaviour(run):
 
 def test_sync_span_feeds_the_host_gap_gauge(run):
     """``device_s`` is the enqueue span's end to the sync span's end: no
-    second pair of stamps, and the gauge stays a fraction."""
+    second pair of stamps, and the gauge stays a fraction. A quantum that
+    was dispatched ahead starts, for the gauge as for every seam
+    downstream, where the quantum before it ended (its sync span's end):
+    the device went from one into the other, the gap reads 0."""
     gap = run["door"].engine.obs.registry.get(
         "serving_host_gap_fraction").value()
     assert 0.0 <= gap < 1.0
     by_id = {r["args"]["id"]: r for r in run["rows"]}
-    last, collect = [r for r in run["rows"]
-                     if r["name"] == "engine.decode"][-2:]
-    assert last["args"]["half"] == "dispatch"
-    assert last["args"]["step"] == collect["args"]["step"]
-    kids = {r["name"]: r for r in run["rows"]
-            if r["args"]["parent"] in (last["args"]["id"],
-                                       collect["args"]["id"])}
-    device_us = (kids["engine.decode.sync"]["ts"]
-                 + kids["engine.decode.sync"]["dur"]
-                 - kids["engine.decode.enqueue"]["ts"]
-                 - kids["engine.decode.enqueue"]["dur"])
-    assert gap == pytest.approx(
-        max(last_wall(kids, last) - device_us, 0.0) / last_wall(kids, last),
-        rel=1e-6, abs=1e-9)
-    assert by_id[last["args"]["parent"]]["name"] == "engine.step"
 
+    def kid(of, name):
+        return next(r for r in run["rows"] if r["name"] == name
+                    and r["args"]["parent"] == of["args"]["id"])
 
-def last_wall(kids, decode):
-    """The quantum's wall as ``on_quantum`` sees it: the decode span's
-    start to the sync span's end."""
-    sync = kids["engine.decode.sync"]
-    return sync["ts"] + sync["dur"] - decode["ts"]
+    def sync_end(collect):
+        sync = kid(collect, "engine.decode.sync")
+        return sync["ts"] + sync["dur"]
+
+    quanta = decode_quanta(run["rows"])
+    (dispatch, collect), before = quanta[-1], quanta[-2]
+    assert dispatch["args"].get("ahead") == 1   # the run's last quantum
+    enqueue = kid(dispatch, "engine.decode.enqueue")
+    floor = sync_end(before[1])
+    assert dispatch["ts"] < floor
+    wall = sync_end(collect) - max(dispatch["ts"], floor)
+    device_us = sync_end(collect) - max(enqueue["ts"] + enqueue["dur"],
+                                        floor)
+    assert gap == pytest.approx(max(wall - device_us, 0.0) / wall,
+                                rel=1e-6, abs=1e-9) == 0.0
+    assert by_id[dispatch["args"]["parent"]]["name"] == "engine.step"
 
 
 # ---------------------------------------------- driven apart: two halves
@@ -384,6 +479,14 @@ def test_halves_are_rows_of_their_own():
     decode = [r for r in rows if r["name"] == "engine.decode"]
     assert sum(r["args"]["half"] == "dispatch" for r in decode) \
         == stats["decode_quanta"]
+    # the serial pump: one dispatch, one collect, nothing in flight
+    # across them, no quantum ahead; the halves pair by their step
+    assert halves(rows, "engine.decode") \
+        == ["dispatch", "collect"] * stats["decode_quanta"]
+    assert stats["quanta_ahead"] == 0
+    assert not any("ahead" in r["args"] for r in decode)
+    assert [d["args"]["step"] for d, _ in decode_quanta(rows)] \
+        == [r["args"]["step"] for r in decode[::2]]
     by_id = {r["args"]["id"]: r for r in rows}
     for r in rows:
         if r["name"] == "engine.decode.sync":
@@ -465,19 +568,23 @@ def test_compile_events_are_charged_to_the_open_step():
     assert row["args"]["compile_backend_s"] > 0
     assert row["args"]["compile_trace_s"] > 0
     assert row["args"]["compile_lower_s"] > 0
-    door.pump()                                   # first quantum: compiles
-    assert door.engine.stats["decode_quanta"] == 1
+    door.pump()                      # first quantum: compiles; the
+    assert door.engine.stats["decode_quanta"] == 1  # second goes ahead
     decode1 = _requests("decode")
     assert decode1 > decode0
-    door.pump()                                   # second quantum: warm
+    door.pump()                      # collects the second behind a third
     assert door.engine.stats["decode_quanta"] == 2
     assert _requests("decode") == decode1
     quanta = [r for r in rows_since(m0) if r["name"] == "engine.decode"]
     assert [r["args"]["half"] for r in quanta] \
-        == ["dispatch", "collect"] * 2
-    assert sum(r["args"].get("compile_requests", 0) for r in quanta[:2]) \
-        == decode1 - decode0
-    assert not any("compile_requests" in r["args"] for r in quanta[2:])
+        == ["dispatch", "dispatch", "collect", "dispatch", "collect"]
+    assert [r["args"].get("ahead") for r in quanta] \
+        == [None, 1, None, 1, None]
+    # the first dispatch built the ONE executable: the quanta that took
+    # their carry from the device asked for none
+    assert quanta[0]["args"]["compile_requests"] == decode1 - decode0
+    assert not any("compile_requests" in r["args"] for r in quanta[1:])
+    assert door.engine._quantum._cache_size() == 1
     # on the engine's own scrape like every other counter
     assert 'jax_compile_requests_total{step="mixed"}' in reg.prometheus()
     assert 'stage="trace",step="mixed"' in reg.prometheus()
@@ -657,7 +764,10 @@ def test_the_quantum_keeps_its_stack_offset():
     above it, so the frames this repo owns there keep the sum they had
     before they were spanned. (The eager mixed forward, whose every
     step hung on the same thing, went with ROADMAP S1, and its sum with
-    it.)"""
+    it.) The path is ``step()``'s: the first quantum of a run, the one
+    that is traced, is dispatched through ``_step_dispatch`` with nothing
+    in flight (ISSUE 48; the public ``step_dispatch`` drains and calls
+    it)."""
     from paddle_tpu.serving.engine import ServingEngine
     from paddle_tpu.serving.frontend import ServingFrontDoor
 
@@ -667,7 +777,7 @@ def test_the_quantum_keeps_its_stack_offset():
                 + len(c.co_freevars) + c.co_stacksize)
 
     path = [ServingFrontDoor.pump, ServingEngine.step,
-            ServingEngine.step_dispatch, ServingEngine._decode_dispatch,
+            ServingEngine._step_dispatch, ServingEngine._decode_dispatch,
             ServingEngine._guarded_dispatch, ServingEngine._dispatch_quantum]
     assert sum(words(f) for f in path) == 88, [words(f) for f in path]
 

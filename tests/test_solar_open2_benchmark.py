@@ -42,6 +42,7 @@ SHARED_READERS = {
     "cache_bytes_per_token", "serve_device_idle_pct", "serve_hbm_peak_gib",
     "queue_wait_ms", "mixed_forward_ms", "mixed_trace_lower_ms",
     "quantum_host_ms", "quantum_args_ms", "compiles_in_decode",
+    "quanta_ahead_pct",
     "mixed_host_ms"}
 KDA, GQA, EXPERT = 137_732_288, 109_051_904, 15_728_640      # ISSUE 46's
 
@@ -273,7 +274,7 @@ def test_the_cell_and_its_files(run, real, cfg):
     later = {w["name"] for w in real["workloads"][9:]}
     joined = [m for m in real["per_layer"] + real["end_to_end"]
               if CELL in m.get("workloads", ())]
-    assert len(joined) == 2 + 17 + 6
+    assert len(joined) == 2 + 17 + 6 + 1    # PR 48: quanta_ahead_pct
     assert all(set(m["workloads"][m["workloads"].index(CELL) + 1:]) <= later
                for m in joined)
     # the names say what the files hold
